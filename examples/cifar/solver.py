@@ -17,7 +17,6 @@ from flashy_tpu import distrib
 from flashy_tpu.data import prefetch_to_device
 from flashy_tpu.models import resnet18, resnet50, vit_tiny
 from flashy_tpu.parallel import make_mesh, wrap
-from flashy_tpu.utils import device_sync
 
 
 class Solver(flashy_tpu.BaseSolver):
@@ -152,7 +151,7 @@ class Solver(flashy_tpu.BaseSolver):
                                       weight=weight)
             progress.update(**metrics)
             count += weight
-        device_sync(self.state["params"])  # real completion: block_until_ready can misreport on proxy backends
+        jax.block_until_ready(self.state["params"])
         metrics["images_per_sec"] = count / max(time.time() - begin, 1e-9)
         if not train:
             self.log_image("valid", "sample",

@@ -17,7 +17,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import flashy_tpu
 from flashy_tpu.models import TransformerConfig, TransformerLM, transformer_shardings
 from flashy_tpu.parallel import make_mesh, shard_batch
-from flashy_tpu.utils import device_sync
 
 
 def synthetic_token_stream(vocab_size: int, seed: int = 0):
@@ -100,20 +99,27 @@ class LMSolver(flashy_tpu.BaseSolver):
         self.optim = optax.chain(
             optax.clip_by_global_norm(1.0),
             optax.adamw(schedule, weight_decay=cfg.weight_decay))
-        # jit the optimizer init so its state inherits the parameter
-        # shardings through SPMD propagation (mu/nu land exactly where
-        # their parameters live — FSDP'd optimizer state for free).
-        opt_state = jax.jit(self.optim.init)(params)
+        # Every state leaf gets a DECLARED layout (the train step pins
+        # its outputs to it, see _jit_train_step): moments live exactly
+        # where their parameters live — FSDP'd optimizer state — and
+        # counters are replicated. Left to propagation, `zeros_like`
+        # carries no layout and the moments come back replicated.
+        replicated = NamedSharding(self.mesh, P())
+        opt_shardings = optax.tree_map_params(
+            self.optim, lambda _, sharding: sharding,
+            jax.eval_shape(self.optim.init, params), shardings,
+            transform_non_params=lambda _: replicated)
+        opt_state = jax.jit(self.optim.init,
+                            out_shardings=opt_shardings)(params)
         self.state = {"params": params, "opt_state": opt_state,
-                      "step": jnp.zeros((), jnp.int32)}
+                      "step": jax.device_put(jnp.zeros((), jnp.int32),
+                                             replicated)}
         # Optional parameter EMA (ema_decay > 0): the f32 shadow lives
         # INSIDE the jitted step (co-sharded with the params — zero
         # extra collectives, 1/N HBM under FSDP) and eval runs on it.
         self.ema_decay = float(cfg.get("ema_decay", 0.0))
         if self.ema_decay > 0.0:
-            self.state["ema"] = jax.jit(
-                lambda p: jax.tree_util.tree_map(
-                    lambda x: x.astype(jnp.float32), p))(params)
+            self.state["ema"] = self._ema_shadow(params)
         # restore() re-places every restored leaf onto the live state's
         # shardings automatically — no hand-rolled device_put needed.
         self.register_stateful("state")
@@ -217,8 +223,30 @@ class LMSolver(flashy_tpu.BaseSolver):
             return (new_state,
                     {"loss": loss, "grad_norm": optax.global_norm(grads)})
 
-        self._train_step = jax.jit(train_step, donate_argnums=(0,))
+        self._train_step_fn = train_step
+        self._jit_train_step()
         self._eval_step = jax.jit(lambda params, tokens: loss_fn(params, tokens))
+
+    @staticmethod
+    def _ema_shadow(params):
+        """f32 copy of the params, laid out exactly like them."""
+        return jax.jit(
+            lambda p: jax.tree_util.tree_map(
+                lambda x: x.astype(jnp.float32), p),
+            out_shardings=jax.tree_util.tree_map(
+                lambda x: x.sharding, params))(params)
+
+    def _jit_train_step(self) -> None:
+        """(Re)build the jitted train step with the new state pinned to
+        the CURRENT state's shardings. Left to propagation, XLA hands the
+        state back under different (if equivalent) shardings, the second
+        call no longer matches the first call's signature, and the whole
+        step compiles twice."""
+        state_shardings = jax.tree_util.tree_map(
+            lambda x: x.sharding, self.state)
+        self._train_step = jax.jit(
+            self._train_step_fn, donate_argnums=(0,),
+            out_shardings=(state_shardings, None))
 
     def get_formatter(self, stage_name):
         return flashy_tpu.Formatter({"loss": ".4f", "ppl": ".1f",
@@ -286,7 +314,7 @@ class LMSolver(flashy_tpu.BaseSolver):
                     pipe_stats["bubble_frac"]), idle_ticks_per_device=float(
                         pipe_stats.get("idle_ticks_per_device", 0.0)))
             progress.update(**metrics)
-        device_sync(self.state["params"])  # real completion: block_until_ready can misreport on proxy backends
+        jax.block_until_ready(self.state["params"])
         metrics["ppl"] = float(np.exp(min(metrics["loss"], 20.0)))
         metrics["tokens_per_sec"] = tokens_seen / (time.time() - begin)
         if pipe_stats is not None:
@@ -343,14 +371,15 @@ class LMSolver(flashy_tpu.BaseSolver):
                 "checkpoint has no EMA shadow but ema_decay=%s: "
                 "re-initializing the shadow from the restored params",
                 self.ema_decay)
-            self.state["ema"] = jax.jit(
-                lambda p: jax.tree_util.tree_map(
-                    lambda x: x.astype(jnp.float32), p))(self.state["params"])
+            self.state["ema"] = self._ema_shadow(self.state["params"])
         elif self.ema_decay <= 0.0 and "ema" in self.state:
             self.logger.warning(
                 "ema_decay=0 but the checkpoint carries an EMA shadow: "
                 "dropping it (eval will use the live params)")
             del self.state["ema"]
+        else:
+            return
+        self._jit_train_step()  # the state's structure changed
 
     def run(self):
         restored = self.restore()

@@ -135,8 +135,7 @@ class MLMSolver(flashy_tpu.BaseSolver):
                 self.state, self.batch_at(global_step))
             metrics = average(step_metrics)
             progress.update(**metrics)
-        from flashy_tpu.utils import device_sync
-        device_sync(self.state["params"])
+        jax.block_until_ready(self.state["params"])
         metrics["ppl"] = float(np.exp(min(metrics["loss"], 20.0)))
         return metrics
 

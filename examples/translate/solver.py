@@ -125,8 +125,7 @@ class TranslateSolver(flashy_tpu.BaseSolver):
                 self.state, self.batch_at(global_step))
             metrics = average(step_metrics)
             progress.update(**metrics)
-        from flashy_tpu.utils import device_sync
-        device_sync(self.state["params"])
+        jax.block_until_ready(self.state["params"])
         return metrics
 
     def valid(self):

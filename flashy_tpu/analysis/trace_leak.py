@@ -20,7 +20,6 @@ __all__ = ["TraceLeakChecker"]
 _ENTRY_BARE = {"jit", "pjit", "wrap", "shard_map"}
 _ENTRY_CHAINS = {
     ("jax", "jit"), ("jax", "pjit"), ("jax", "shard_map"),
-    ("_compat", "shard_map"),
 }
 # Host-converting builtins: poison only when fed a traced-looking value.
 _HOST_BUILTINS = {"int", "float", "bool", "complex"}

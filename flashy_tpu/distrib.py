@@ -34,20 +34,6 @@ logger = logging.getLogger(__name__)
 _initialized = False
 
 
-def _jax_distributed_initialized() -> bool:
-    """`jax.distributed.is_initialized()` across jax versions (it only
-    appeared after 0.4.x; older releases expose the state through the
-    private client handle)."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src import distributed as _jax_distributed
-        return getattr(_jax_distributed.global_state, "client", None) is not None
-    except ImportError:
-        return False
-
-
 def _env(*names: str, default: tp.Optional[str] = None) -> tp.Optional[str]:
     for name in names:
         if name in os.environ:
@@ -69,7 +55,7 @@ def init(backend: tp.Optional[str] = None) -> None:
     transport is always XLA over ICI/DCN.
     """
     global _initialized
-    if _initialized or _jax_distributed_initialized():
+    if _initialized or jax.distributed.is_initialized():
         # Already set up (by us or by the user calling jax.distributed
         # directly). Don't touch the backend: forcing device init here
         # would serialize every process on backend bring-up.
@@ -127,7 +113,7 @@ def rank() -> int:
     from_env = _launcher_rank_world()
     if from_env is not None:
         return from_env[0]
-    if _initialized or _jax_distributed_initialized():
+    if _initialized or jax.distributed.is_initialized():
         return jax.process_index()
     return 0
 
@@ -136,7 +122,7 @@ def world_size() -> int:
     from_env = _launcher_rank_world()
     if from_env is not None:
         return from_env[1]
-    if _initialized or _jax_distributed_initialized():
+    if _initialized or jax.distributed.is_initialized():
         return jax.process_count()
     return 1
 
@@ -157,7 +143,7 @@ def _require_backend() -> None:
     entry point forgot `distrib.init()`. multihost_utils collectives
     then see a 1-process world and return garbage (broadcast_object
     used to die with an opaque pickle EOFError three frames later)."""
-    if not (_initialized or _jax_distributed_initialized()):
+    if not (_initialized or jax.distributed.is_initialized()):
         raise RuntimeError(
             f"This run is distributed (world_size={world_size()} from the "
             "launcher environment) but flashy_tpu.distrib.init() was never "

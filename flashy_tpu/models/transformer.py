@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import dot_product_attention, flash_attention
+from ..ops.attention import (dot_product_attention, flash_attention,
+                             sharded_flash_attention)
 from .moe import MoEMLP
 
 
@@ -184,7 +185,14 @@ class Attention(nn.Module):
                 q, k, v, mesh=self.mesh, causal=cfg.causal,
                 impl="fused" if cfg.attention == "ring_fused" else "scan")
         elif cfg.attention == "flash":
-            out = flash_attention(q, k, v, causal=cfg.causal)
+            if (self.mesh is not None and self.mesh.size > 1
+                    and isinstance(q, jax.core.Tracer)):
+                # a Mosaic kernel inside a multi-device jit must run
+                # per device (an eager init stays on one device)
+                out = sharded_flash_attention(q, k, v, self.mesh,
+                                              causal=cfg.causal)
+            else:
+                out = flash_attention(q, k, v, causal=cfg.causal)
         else:
             out = dot_product_attention(q, k, v, causal=cfg.causal)
 

@@ -26,9 +26,11 @@ from ..utils import percentile
 
 logger = logging.getLogger(__name__)
 
-# (device_kind substring, peak bf16 FLOP/s, peak HBM bytes/s). Nominal
-# datasheet numbers, matched case-insensitively against
-# `jax.Device.device_kind` — same convention as bench.py's PEAK_FLOPS.
+# (device_kind substring, peak bf16 FLOP/s, peak HBM bytes/s) — the one
+# peaks table (bench.py reads it too). Nominal per-chip numbers from the
+# Google Cloud TPU documentation (cloud.google.com/tpu/docs, system
+# architecture pages), matched case-insensitively against
+# `jax.Device.device_kind`.
 DEVICE_SPECS: tp.Tuple[tp.Tuple[str, float, float], ...] = (
     ("v6e", 918e12, 1640e9), ("trillium", 918e12, 1640e9),
     ("v5p", 459e12, 2765e9),
@@ -41,23 +43,27 @@ DEVICE_SPECS: tp.Tuple[tp.Tuple[str, float, float], ...] = (
 
 def device_peaks(device_kind: tp.Optional[str] = None
                  ) -> tp.Tuple[tp.Optional[float], tp.Optional[float]]:
-    """(peak FLOP/s, peak HBM bytes/s) for a device kind, or (None, None).
+    """(peak FLOP/s, peak HBM bytes/s) for a device kind.
 
-    `device_kind=None` probes the default jax device lazily; any
-    failure (no backend, CPU) degrades to unknown peaks rather than
-    raising — the profiler stays usable on every platform.
+    `device_kind=None` reads the default jax device. The CPU has no
+    accelerator peak and yields `(None, None)` (the profiler still
+    reports realized rates there); an ACCELERATOR kind missing from
+    `DEVICE_SPECS` raises — a utilization printed as `None` hides that
+    nothing anchors it.
     """
     if device_kind is None:
-        try:
-            import jax
-            device_kind = getattr(jax.devices()[0], "device_kind", "")
-        except Exception:  # noqa: BLE001 — no backend is a valid state
-            return None, None
-    kind = (device_kind or "").lower()
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower()
+    if kind == "cpu":
+        return None, None
     for needle, flops, bandwidth in DEVICE_SPECS:
         if needle in kind:
             return flops, bandwidth
-    return None, None
+    raise ValueError(
+        f"no peak FLOP/s / HBM bandwidth entry for accelerator "
+        f"{device_kind!r}: add it to observability.roofline.DEVICE_SPECS "
+        f"with its source")
 
 
 def _cost_analysis_dict(compiled: tp.Any) -> tp.Dict[str, float]:

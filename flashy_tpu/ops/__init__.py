@@ -2,7 +2,9 @@
 # fallback elsewhere) and fused building blocks. flake8: noqa
 import typing as tp
 
-from .attention import dot_product_attention, flash_attention
+from .attention import (
+    dot_product_attention, flash_attention, sharded_flash_attention,
+)
 # NOTE: the paged_attention FUNCTION is deliberately not re-exported
 # here — it would shadow the `flashy_tpu.ops.paged_attention` submodule
 # attribute; reach it via the module, like the serve engine does. The

@@ -31,9 +31,10 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
-
-from .. import _compat
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -351,14 +352,6 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-try:  # pallas import is cheap but keep the module importable everywhere
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    _PALLAS_AVAILABLE = False
-
-
 def _fold(x: jax.Array) -> jax.Array:
     """[B, T, H, D] -> [B*H, T, D] (batch and heads become the grid axis)."""
     batch, t, heads, dim = x.shape
@@ -385,7 +378,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
                                offset=t_k - t_q)
     # Inside shard_map the outputs vary over the same mesh axes as the
     # inputs; pallas_call requires that stated explicitly on out_shape.
-    vma = _compat.vma_of(q)
+    vma = jax.typeof(q).vma
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -399,10 +392,10 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
             pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
-            _compat.shape_dtype_struct((batch * heads, t_q, dim), q.dtype,
-                                       vma=vma),
-            _compat.shape_dtype_struct((batch * heads, t_q, LANES),
-                                       jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((batch * heads, t_q, dim), q.dtype,
+                                 vma=vma),
+            jax.ShapeDtypeStruct((batch * heads, t_q, LANES), jnp.float32,
+                                 vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),  # running max
@@ -443,15 +436,14 @@ def _flash_backward(q, k, v, out, lse, grad_out, *, causal: bool,
         pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0)),  # lse
         pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0)),  # D
     ]
-    vma = _compat.vma_of(q)
+    vma = jax.typeof(q).vma
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, offset=offset),
         grid=(bh, t_q // block_q, t_k // block_k),
         in_specs=row_specs,
         out_specs=pl.BlockSpec((1, block_q, dim), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=_compat.shape_dtype_struct((bh, t_q, dim), q.dtype,
-                                             vma=vma),
+        out_shape=jax.ShapeDtypeStruct((bh, t_q, dim), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
@@ -474,8 +466,8 @@ def _flash_backward(q, k, v, out, lse, grad_out, *, causal: bool,
             pl.BlockSpec((1, block_k, dim), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
-            _compat.shape_dtype_struct((bh, t_k, dim), k.dtype, vma=vma),
-            _compat.shape_dtype_struct((bh, t_k, dim), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_k, dim), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_k, dim), v.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dim), jnp.float32),
@@ -518,7 +510,7 @@ def _flash_backward_fused(q, k, v, out, lse, grad_out, *, causal: bool,
         pl.BlockSpec((1, block_q, LANES), lambda b, ki, qi: (b, qi, 0)),  # lse
         pl.BlockSpec((1, block_q, LANES), lambda b, ki, qi: (b, qi, 0)),  # D
     ]
-    vma = _compat.vma_of(q)
+    vma = jax.typeof(q).vma
     dk, dv, dqp = pl.pallas_call(
         functools.partial(_flash_bwd_fused_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, offset=offset),
@@ -531,10 +523,10 @@ def _flash_backward_fused(q, k, v, out, lse, grad_out, *, causal: bool,
                          lambda b, ki, qi: (b, ki, qi, 0)),
         ],
         out_shape=[
-            _compat.shape_dtype_struct((bh, t_k, dim), k.dtype, vma=vma),
-            _compat.shape_dtype_struct((bh, t_k, dim), v.dtype, vma=vma),
-            _compat.shape_dtype_struct((bh, nk, t_q, dim), jnp.float32,
-                                       vma=vma),
+            jax.ShapeDtypeStruct((bh, t_k, dim), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_k, dim), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, nk, t_q, dim), jnp.float32,
+                                 vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dim), jnp.float32),
@@ -591,8 +583,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, fused, residuals,
                     block_q=bq, block_k=bk, interpret=interpret)
 
 
-if _PALLAS_AVAILABLE:
-    _flash.defvjp(_flash_fwd, _flash_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _dividing_block(t: int) -> int:
@@ -621,15 +612,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Q/K/V/dO block read from HBM once); `fused_backward=False` selects
     the split two-kernel path, kept as the bit-identical oracle (the
     paged-decode `--kernel gather` convention). Block sizes default to
-    a tuned table when one exists for this (device, shape) — populated
-    by `ops.tune_flash_blocks` / the bench / `tools/tpu_validate.py` —
-    else 256; they are clamped to the sequence length, and when the
-    requested block does not divide T, the largest dividing multiple
-    of 128 (up to 512) is used instead, so e.g. T=384 runs the kernel
-    at 384 rather than falling back. Only when no 128-multiple divides
-    T (T not 128-aligned), or pallas cannot run at all (non-TPU
-    backend without interpret mode), does it fall back to
-    `dot_product_attention`.
+    a winner recorded by `ops.tune_flash_blocks` for this (device,
+    shape) when one exists, else 256; they are clamped to the sequence
+    length, and when the requested block does not divide T, the largest
+    dividing multiple of 128 (up to 512) is used instead, so e.g. T=384
+    runs the kernel at 384 rather than falling back. Only when no
+    128-multiple divides T (T not 128-aligned), or on a GPU backend
+    (the kernel is TPU-targeted), does `dot_product_attention` run
+    instead.
     """
     t_q, t_k = q.shape[1], k.shape[1]
     if block_q is None and block_k is None and t_q == t_k:
@@ -644,7 +634,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         block_q = _dividing_block(t_q) or block_q
     if t_k % block_k:
         block_k = _dividing_block(t_k) or block_k
-    if not _PALLAS_AVAILABLE or t_q % block_q or t_k % block_k:
+    if t_q % block_q or t_k % block_k:
+        # T not 128-aligned: no legal tile divides it, the XLA path runs
         return dot_product_attention(q, k, v, causal=causal)
     backend = jax.default_backend()
     if interpret is None:
@@ -660,3 +651,40 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         fused_backward = True
     return _flash(q, k, v, causal, block_q, block_k, interpret,
                   fused_backward)
+
+
+def dividing_axes(size: int, mesh: Mesh,
+                  axes: tp.Sequence[str]) -> tp.Tuple[str, ...]:
+    """The mesh `axes` a dimension of `size` can be split over: taken
+    in order, an axis is kept while the combined size still divides."""
+    kept: tp.List[str] = []
+    ways = 1
+    for name in axes:
+        if size % (ways * mesh.shape[name]) == 0:
+            kept.append(name)
+            ways *= mesh.shape[name]
+    return tuple(kept)
+
+
+def sharded_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                            mesh: Mesh, causal: bool = False, *,
+                            batch_axes: tp.Sequence[str] = ("data", "fsdp"),
+                            head_axis: str = "tensor") -> jax.Array:
+    """`flash_attention` for a jitted step whose arrays live on a mesh.
+
+    GSPMD cannot partition a Mosaic kernel — lowering one inside a
+    multi-device jit fails with "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map" — so the kernel
+    runs per device under shard_map. Attention is local to a batch row
+    and to a head: the batch splits over `batch_axes`, the heads over
+    `head_axis`, no collective is needed, and the remaining mesh axes
+    see replicated operands.
+    """
+    batch = dividing_axes(q.shape[0], mesh, batch_axes)
+    heads = dividing_axes(q.shape[2], mesh, (head_axis,))
+    spec = P(batch or None, None, heads or None, None)
+    # check_vma=False: pallas interpret mode (the CPU test path) cannot
+    # propagate varying-axis types through its block slicing
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)(q, k, v)

@@ -2,9 +2,8 @@
 # (ops/paged_attention.py) asks XLA to fuse three steps — block-table
 # gather, int8 dequant, softmax(QK^T)V — and XLA obliges with an
 # unfused program: the gather materializes each slot's logical
-# [max_len] K/V view in HBM-sized intermediates every step, so paged
-# decode pays the 4x slot capacity with ~0.95x dense throughput
-# (BENCH_r05 `paged_vs_dense`) at MFU ~0.30. Decode is bandwidth-bound:
+# [max_len] K/V view in HBM-sized intermediates every step (what that
+# costs on the chip: not measured). Decode is bandwidth-bound:
 # the win is reading every pool byte exactly once, straight from the
 # physical blocks, with no logical view in between. This module is
 # that read path as ONE Pallas TPU kernel:
@@ -60,19 +59,13 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .. import _compat
 from .paged_attention import paged_attention
 
 NEG_INF = -1e30
 LANES = 128  # native f32 lane width; row stats ride it (attention.py)
-
-try:  # keep the module importable where pallas is absent
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    _PALLAS_AVAILABLE = False
 
 
 def fused_kernel_unsupported_reason() -> tp.Optional[str]:
@@ -82,8 +75,6 @@ def fused_kernel_unsupported_reason() -> tp.Optional[str]:
     instead of letting the gather fallback masquerade as the kernel —
     a demo/bench gate that reports 'fused' must have run it.
     """
-    if not _PALLAS_AVAILABLE:
-        return "pallas is unavailable in this jax install"
     backend = jax.default_backend()
     if backend in ("gpu", "cuda", "rocm"):
         return (f"the fused kernel is TPU-targeted and the backend is "
@@ -102,11 +93,18 @@ def default_kernel() -> str:
     return "fused"
 
 
-def _default_head_block(num_heads: int) -> int:
+def _default_head_block(num_heads: int, quantized: bool = False) -> int:
     """Largest power-of-two divisor of H not above 8 — enough rows
     (H*T) to fill a sublane tile at T=1 without blowing the VMEM
     scratch at long T, and power-of-two so the row block lands on the
-    8-sublane tile boundary instead of forcing pad rows per grid step."""
+    8-sublane tile boundary instead of forcing pad rows per grid step.
+
+    int8 pools take every head in one block: their `[block_size, H]`
+    scale blocks carry the heads in the LANE position, where Mosaic
+    accepts only the whole dimension (or a multiple of 128) — a
+    head_block of 8 out of 16 heads is refused at lowering."""
+    if quantized:
+        return num_heads
     cand = 8
     while cand > 1 and num_heads % cand:
         cand //= 2
@@ -265,11 +263,11 @@ def _fused_call(q, entry, table, base, *, head_block: int,
         _fused_kernel_quant if quant else _fused_kernel_dense,
         block_size=block_size, queries=queries, head_block=hb,
         head_dim=dim, scale=scale)
-    vma = _compat.vma_of(q)
+    vma = jax.typeof(q).vma
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=_compat.shape_dtype_struct(
-            (batch, queries, heads, dim), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((batch, queries, heads, dim),
+                                       q.dtype, vma=vma),
         interpret=interpret,
     )(table, base, *operands)
 
@@ -304,9 +302,6 @@ def fused_paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
     interpret mode on CPU, the real kernel on TPU, and the gather
     fallback on GPU (the kernel is TPU-targeted).
     """
-    if not _PALLAS_AVAILABLE:
-        return paged_attention(q, entry, table, positions,
-                               head_dim=head_dim, dtype=dtype)
     if interpret is None:
         backend = jax.default_backend()
         if backend == "cpu":
@@ -319,14 +314,15 @@ def fused_paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
     heads = q.shape[2]
     if head_block is None:
         from .tuning import lookup_tuned_paged_blocks
+        quantized = "k_scale" in entry
         head_block = lookup_tuned_paged_blocks(
             q.shape[0], q.shape[1], heads, head_dim,
             block_size=entry["k"].shape[-3], entries=table.shape[1],
-            quantized="k_scale" in entry, dtype=dtype)
+            quantized=quantized, dtype=dtype)
         if head_block is None or heads % head_block:
             # no winner (or a corrupt cache entry): keep the default —
             # a tuned pick must never be able to break correctness
-            head_block = _default_head_block(heads)
+            head_block = _default_head_block(heads, quantized)
     elif heads % head_block:
         raise ValueError(f"head_block {head_block} must divide "
                          f"num_heads {heads}")

@@ -42,8 +42,8 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .. import _compat
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Log-decay value that RESETS the state across a segment boundary:
 # exp(-1e30) underflows to exactly 0.0 in f32, and any masked sum
@@ -55,13 +55,6 @@ SSD_LOG_RESET = -1e30
 # default picks the largest one dividing T.
 CHUNK_CANDIDATES: tp.Tuple[int, ...] = (16, 32, 64, 128, 256)
 
-try:  # keep the module importable where pallas is absent
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    _PALLAS_AVAILABLE = False
-
 
 def fused_ssd_unsupported_reason() -> tp.Optional[str]:
     """None when the fused chunked-scan kernel can genuinely RUN here
@@ -69,8 +62,6 @@ def fused_ssd_unsupported_reason() -> tp.Optional[str]:
     reason — the `fused_kernel_unsupported_reason` convention, so an
     explicit kernel='fused' fails loudly instead of silently running
     the XLA reference under a fused label."""
-    if not _PALLAS_AVAILABLE:
-        return "pallas is unavailable in this jax install"
     backend = jax.default_backend()
     if backend in ("gpu", "cuda", "rocm"):
         return (f"the fused SSD kernel is TPU-targeted and the backend "
@@ -203,21 +194,20 @@ def _fused_ssd_body(c_ref, b_ref, v_ref, la_ref, s0_ref, y_ref, sout_ref,
     def _init():
         state_scr[:] = s0_ref[0, 0].astype(jnp.float32)
 
-    la = la_ref[0, 0].astype(jnp.float32)              # [C]
+    la_col = la_ref[0, 0].astype(jnp.float32)          # [C, 1]
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     incl_tril = (t_idx >= s_idx).astype(jnp.float32)
     strict = (t_idx > s_idx).astype(jnp.float32)
-    contrib = la[:, None] * strict                     # [C, C] (= la_r at [r, s])
+    contrib = la_col * strict                          # [C, C] (= la_r at [r, s])
     seg = jax.lax.dot(incl_tril, contrib,
                       preferred_element_type=jnp.float32)
     decay = jnp.where(t_idx >= s_idx, jnp.exp(seg), 0.0)
-    la_col = la[:, None]                               # [C, 1]
     incl = jax.lax.dot(incl_tril, la_col,
                        preferred_element_type=jnp.float32)    # [C, 1]
     suffix = jax.lax.dot(strict.T, la_col,
                          preferred_element_type=jnp.float32)  # [C, 1]
-    total = jnp.sum(la)
+    total = jnp.sum(la_col)
 
     ch = c_ref[0, 0]                                   # [C, N]
     bh = b_ref[0, 0]                                   # [C, N]
@@ -251,13 +241,10 @@ def _fused_call(c, b, v, la, state, *, chunk: int, interpret: bool):
     def tok_index(bi, hi, j):
         return (bi, hi, j, 0)
 
-    def la_index(bi, hi, j):
-        return (bi, hi, j)
-
     def state_index(bi, hi, j):
         return (bi, hi, 0, 0)
 
-    vma = _compat.vma_of(v)
+    vma = jax.typeof(v).vma
     kernel = functools.partial(_fused_ssd_body, chunk=chunk)
     return pl.pallas_call(
         kernel,
@@ -266,7 +253,10 @@ def _fused_call(c, b, v, la, state, *, chunk: int, interpret: bool):
             pl.BlockSpec((1, 1, chunk, dstate), tok_index),
             pl.BlockSpec((1, 1, chunk, dstate), tok_index),
             pl.BlockSpec((1, 1, chunk, dim), tok_index),
-            pl.BlockSpec((1, 1, chunk), la_index),
+            # log-decays ride a trailing unit dim: a (1, 1, chunk) block
+            # of the [B, H, T] array is a 1-row tile Mosaic refuses, and
+            # the body wants the [C, 1] column anyway
+            pl.BlockSpec((1, 1, chunk, 1), tok_index),
             pl.BlockSpec((1, 1, dim, dstate), state_index),
         ],
         out_specs=[
@@ -274,14 +264,14 @@ def _fused_call(c, b, v, la, state, *, chunk: int, interpret: bool):
             pl.BlockSpec((1, 1, dim, dstate), state_index),
         ],
         out_shape=[
-            _compat.shape_dtype_struct((batch, heads, seq, dim),
-                                       v.dtype, vma=vma),
-            _compat.shape_dtype_struct((batch, heads, dim, dstate),
-                                       jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((batch, heads, seq, dim), v.dtype,
+                                 vma=vma),
+            jax.ShapeDtypeStruct((batch, heads, dim, dstate), jnp.float32,
+                                 vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((dim, dstate), jnp.float32)],
         interpret=interpret,
-    )(c, b, v, la, state)
+    )(c, b, v, la[..., None], state)
 
 
 # ----------------------------------------------------------------------
@@ -359,17 +349,15 @@ def ssd_chunked_scan(c: jax.Array, b: jax.Array, v: jax.Array,
     vh = _to_heads_first(v)
     lah = _to_heads_first(log_decay[..., None])[..., 0].astype(jnp.float32)
 
-    if kernel == "fused":
-        if not _PALLAS_AVAILABLE:
+    if kernel == "fused" and interpret is None:
+        backend = jax.default_backend()
+        if backend == "cpu":
+            interpret = True
+        elif backend in ("gpu", "cuda", "rocm"):
             kernel = "gather"
-        elif interpret is None:
-            backend = jax.default_backend()
-            if backend == "cpu":
-                interpret = True
-            elif backend in ("gpu", "cuda", "rocm"):
-                kernel = "gather"
-            else:
-                interpret = False
+        else:
+            interpret = False
+
     def run(c_p, b_p, v_p, la_p, state_p, chunk_p):
         if kernel == "fused":
             return _fused_call(c_p, b_p, v_p, la_p, state_p, chunk=chunk_p,

@@ -24,8 +24,6 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
-
-from .. import _compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -173,7 +171,7 @@ def ep_dropless_moe(x_flat: jax.Array, probs: jax.Array, w_up: jax.Array,
             y_assign * (assignment_gate * keep)[:, None])
         return out.astype(dtype), aux[None]
 
-    out, aux = _compat.shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(all_axes, None), P(all_axes, None),
                   P(axis, None, None), P(axis, None, None)),

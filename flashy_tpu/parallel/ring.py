@@ -28,15 +28,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .. import _compat
 from ..ops import attention as _attn
 
 NEG_INF = -1e30
 
 
 def _use_pallas(t_q: int, t_k: int, block: int = 128) -> bool:
-    """Pallas path needs pallas importable and 128-aligned block dims."""
-    return (_attn._PALLAS_AVAILABLE and t_q % block == 0 and t_k % block == 0
+    """Pallas path needs 128-aligned block dims (and is TPU-targeted)."""
+    return (t_q % block == 0 and t_k % block == 0
             and jax.default_backend() not in ("gpu", "cuda", "rocm"))
 
 
@@ -151,13 +150,13 @@ def _mark_varying(tree, like):
     """Make every leaf device-varying on the axes `like` varies over —
     scan carries need stable varying types, and block outputs computed
     purely from replicated inputs would otherwise come back invariant."""
-    target = set(_compat.vma_of(like))
+    target = jax.typeof(like).vma
     if not target:
         return tree
 
     def mark(x):
-        missing = tuple(target - set(_compat.vma_of(x)))
-        return _compat.pcast_varying(x, missing)
+        missing = tuple(target - jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
 
     return jax.tree_util.tree_map(mark, tree)
 
@@ -338,23 +337,18 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # (a probe forward with a tiny batch — e.g. model.init — would
     # otherwise be rejected by shard_map). Falling short of the full
     # product means redundant compute, so make it loud.
-    use_batch_axes = []
-    ways = 1
-    for name in batch_axes:
-        if q.shape[0] % (ways * mesh.shape[name]) == 0:
-            use_batch_axes.append(name)
-            ways *= mesh.shape[name]
+    use_batch_axes = _attn.dividing_axes(q.shape[0], mesh, batch_axes)
     full_ways = 1
     for name in batch_axes:
         full_ways *= mesh.shape[name]
-    if ways != full_ways and q.shape[0] > 1:
+    if len(use_batch_axes) != len(tuple(batch_axes)) and q.shape[0] > 1:
         import logging
         logging.getLogger(__name__).warning(
             "ring_self_attention: batch %d not divisible by mesh axes %s "
             "(%d ways); sharding over %s only — redundant compute on the "
             "remaining axes.", q.shape[0], tuple(batch_axes), full_ways,
-            tuple(use_batch_axes))
-    spec = P(tuple(use_batch_axes) if use_batch_axes else None, axis, None, None)
+            use_batch_axes)
+    spec = P(use_batch_axes or None, axis, None, None)
     if (impl == "fused" and jax.default_backend() == "cpu"
             and mesh.devices.size > 1
             and mesh.devices.size >= len(jax.devices())):
@@ -385,7 +379,5 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # path) cannot yet propagate varying-axis types through its block
     # slicing — the workaround the upstream error message prescribes.
     # The vma checker is a tracer-level lint; numerics are unaffected.
-    # tools/tpu_validate.py probes check_vma=True on the real backend
-    # and records whether the strict check lowers there.
-    return _compat.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=check_vma)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=check_vma)(q, k, v)

@@ -53,8 +53,9 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .. import _compat
 from ..ops import attention as _attn
 from . import ring as _ring
 
@@ -69,10 +70,6 @@ FUSED_RING_COLLECTIVE_ID = 7
 # ~16 MiB of VMEM; leave headroom for Mosaic's own spills and the
 # pipeline's double buffering of the Q/out blocks.
 VMEM_BUDGET = 12 * 1024 * 1024
-
-if _attn._PALLAS_AVAILABLE:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
 
 def _fused_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -251,7 +248,7 @@ def _fused_forward(q, k, v, axis_name: str, mesh_axes, causal: bool,
         _fused_kernel, axis_name=axis_name, mesh_axes=mesh_axes,
         causal=causal,
         block_q=block_q, n_steps=n_steps, bh=bh, n_q=n_q, t_loc=t_loc)
-    vma = _compat.vma_of(q)
+    vma = jax.typeof(q).vma
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_steps),
@@ -271,13 +268,12 @@ def _fused_forward(q, k, v, axis_name: str, mesh_axes, causal: bool,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
-            _compat.shape_dtype_struct((bh, t_loc, dim), q.dtype, vma=vma),
-            _compat.shape_dtype_struct((bh, t_loc, LANES), jnp.float32,
-                                       vma=vma),
-            _compat.shape_dtype_struct((n_steps, bh, t_loc, dim), k.dtype,
-                                       vma=vma),
-            _compat.shape_dtype_struct((n_steps, bh, t_loc, dim), v.dtype,
-                                       vma=vma),
+            jax.ShapeDtypeStruct((bh, t_loc, dim), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_loc, LANES), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((n_steps, bh, t_loc, dim), k.dtype,
+                                 vma=vma),
+            jax.ShapeDtypeStruct((n_steps, bh, t_loc, dim), v.dtype,
+                                 vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((t_loc, dim), k.dtype),              # K tile
@@ -335,7 +331,7 @@ def _supported(t_loc: int, dim: int, q_itemsize: int = 4,
     resident tile set (K+V tiles, score tile, softmax state,
     accumulator, Q/out blocks) fits the VMEM budget at the smallest
     block_q."""
-    if not (_attn._PALLAS_AVAILABLE and t_loc % 128 == 0):
+    if t_loc % 128:
         return False
     _, total = _vmem_plan(t_loc, dim, q_itemsize, k_itemsize, v_itemsize)
     return total <= VMEM_BUDGET
@@ -364,11 +360,10 @@ def _fused_fwd_impl(q, k, v, axis_name, causal, mesh_axes):
     if not _supported(t_loc, dim, q.dtype.itemsize, k.dtype.itemsize,
                       v.dtype.itemsize):
         raise ValueError(
-            f"fused ring attention needs pallas and a 128-aligned local "
+            f"fused ring attention needs a 128-aligned local "
             f"sequence block whose resident tiles (K+V+scores+state) fit "
             f"the {VMEM_BUDGET >> 20} MiB VMEM budget; got "
-            f"t_local={t_loc}, head_dim={dim}, "
-            f"pallas={_attn._PALLAS_AVAILABLE}. "
+            f"t_local={t_loc}, head_dim={dim}. "
             f"Use impl='scan' for these shapes.")
     if mesh_axes is None:
         # Single-axis ring: the flat logical id IS the ring index.
@@ -382,6 +377,15 @@ def _fused_fwd_impl(q, k, v, axis_name, causal, mesh_axes):
             f"interpret machinery (CPU); backend {backend!r} is not "
             f"supported. Use impl='scan'.")
     interpret = backend == "cpu"
+    if not interpret and dim % LANES:
+        # Mosaic lays the HBM ring-gather buffers out in 128-lane tiles
+        # and refuses to slice them at a narrower head_dim ("Slice shape
+        # along dimension 3 must be aligned to tiling (128), but is
+        # 64"). Say so here instead of deep inside the compiler.
+        raise ValueError(
+            f"fused ring attention compiles on TPU only at head_dim a "
+            f"multiple of {LANES} (the HBM slot buffers are sliced in "
+            f"128-lane tiles); got head_dim={dim}. Use impl='scan'.")
     if interpret:
         # In interpret mode every simulated device's RDMA semaphore
         # waits occupy a slot of XLA's host intra-op thread pool. A mesh

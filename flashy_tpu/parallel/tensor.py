@@ -180,7 +180,6 @@ def run_tp_bench(steps: int = 3, *, dim: int = 128, num_layers: int = 2,
     from ..models import TransformerConfig, TransformerLM
     from ..observability import RecompileWatchdog
     from ..resilience import chaos
-    from ..utils import device_sync
     from .data_parallel import shard_batch, wrap
     from .mesh import make_mesh
 
@@ -285,14 +284,14 @@ def run_tp_bench(steps: int = 3, *, dim: int = 128, num_layers: int = 2,
                        state_sharding=spec, watchdog=watchdog)
         losses: tp.List[float] = []
         state, aux = wrapped(state, tokens)  # compile + step 1
-        device_sync(aux["loss"])
+        jax.block_until_ready(aux["loss"])
         losses.append(float(aux["loss"]))
         begin = time.perf_counter()
         for index in range(steps):
             chaos.fault_point("tensor.step", width=int(width), step=index)
             state, aux = wrapped(state, tokens)
             losses.append(float(aux["loss"]))
-        device_sync(aux["loss"])
+        jax.block_until_ready(aux["loss"])
         step_ms = (time.perf_counter() - begin) / steps * 1e3
         result["step_ms"][key] = round(step_ms, 2)
         result["tflops_per_chip"][key] = round(

@@ -319,7 +319,6 @@ def run_zero_bench(steps: int = 3, *, dim: int = 128, num_layers: int = 2,
 
     from ..models import TransformerConfig, TransformerLM
     from ..observability import RecompileWatchdog
-    from ..utils import device_sync
     from .data_parallel import fsdp_sharding, shard_batch, wrap
     from .mesh import make_mesh
 
@@ -389,11 +388,11 @@ def run_zero_bench(steps: int = 3, *, dim: int = 128, num_layers: int = 2,
         tokens = shard_batch(jnp.asarray(tokens_host), mesh,
                              batch_axes=batch_axes)
         state, aux = wrapped(state, tokens)  # compile + step 1
-        device_sync(aux["loss"])
+        jax.block_until_ready(aux["loss"])
         begin = time.perf_counter()
         for _ in range(steps):
             state, aux = wrapped(state, tokens)
-        device_sync(aux["loss"])
+        jax.block_until_ready(aux["loss"])
         result["step_ms"][name] = round(
             (time.perf_counter() - begin) / steps * 1e3, 2)
         result["opt_state_bytes_per_chip"][name] = per_device_bytes(

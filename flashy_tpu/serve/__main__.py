@@ -984,6 +984,8 @@ def main(argv: tp.Optional[tp.Sequence[str]] = None) -> int:
 
     logging.basicConfig(level=logging.INFO, stream=sys.stderr,
                         format="[%(levelname)s] %(message)s")
+    from ..utils import configure_compile_cache
+    configure_compile_cache()
     rc = 0
     if "batching" in legs:
         rc |= run_demo(requests=args.requests, slots=args.slots,

@@ -252,6 +252,11 @@ class DecodeEngine:
             engines' slot 0 would collide on one reservation key.
     """
 
+    # every compiled step takes the cache as operand 1, donated so XLA
+    # updates it in place; the step returns the new cache and the engine
+    # rebinds it
+    _donate = (1,)
+
     def __init__(self, model, params, *, slots: int,
                  max_seq_len: tp.Optional[int] = None,
                  temperature: float = 0.0,
@@ -476,9 +481,6 @@ class DecodeEngine:
         # device->host sync per call, S syncs per scheduler step.
         self._positions_host = np.full((slots,), self.max_seq_len, np.int64)
         self._active_host = np.zeros((slots,), bool)
-        # donation lets XLA update the cache in place on accelerators;
-        # the CPU backend would only warn, so skip it there.
-        self._donate = () if jax.default_backend() == "cpu" else (1,)
 
     # ------------------------------------------------------------------
     # compiled steps
@@ -759,7 +761,7 @@ class DecodeEngine:
         from .paged import copy_block_fn
         copy = copy_block_fn(self._cfg.scan_layers)
         return jax.jit(lambda cache, src, dst: copy(cache, src, dst),
-                       donate_argnums=(0,) if self._donate else ())
+                       donate_argnums=(0,))
 
     def _next_key(self):
         import jax

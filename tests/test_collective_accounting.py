@@ -373,8 +373,7 @@ def test_memory_stats_fsdp_shrinks_argument_footprint():
     # remat programs flow through the same accounting without error
     # (the temp DIRECTION is backend-specific: the CPU scheduler can
     # make recompute buffers outweigh the saved residuals at small
-    # sizes, so no direction is asserted here; on-chip probing lives
-    # in tools/ — see docs/PERF.md)
+    # sizes, so no direction is asserted here)
     compiled_rm, _ = _compile_train_step(
         mesh, TransformerConfig(**dict(_CFG, remat=True)), batch=16, seq=32)
     remat = memory_stats(compiled_rm)

@@ -15,7 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run_example(tmpdir, module, *overrides, timeout=420):
     env = dict(os.environ)
     env["_FLASHY_TMDIR"] = str(tmpdir)
-    env["FLASHY_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO] + env.get("PYTHONPATH", "").split(os.pathsep))
     sp.run([sys.executable, "-m", module, "--clear", *overrides],

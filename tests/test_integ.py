@@ -13,7 +13,7 @@ import pytest
 def _run(tmpdir, *args, workers=None):
     env = dict(os.environ)
     env["_FLASHY_TMDIR"] = str(tmpdir)
-    env["FLASHY_TPU_PLATFORM"] = "cpu"  # site config pins TPU otherwise
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + env.get("PYTHONPATH", "").split(os.pathsep))

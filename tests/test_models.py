@@ -310,14 +310,6 @@ def test_moe_sorted_dispatch_matches_einsum():
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(
-    strict=False,
-    reason="jax with the legacy experimental shard_map cannot transpose "
-           "the MoE stage body (_SpecError in the grad half) — "
-           "pre-existing; MoE pipelined TRAINING goes through "
-           "pipelined_value_and_grad(schedule='1f1b'), whose VJP is "
-           "explicit and never transposes a shard_map "
-           "(tests/test_pipeline_schedules.py covers it).")
 def test_pipelined_apply_moe_matches_unpipelined():
     # MoE in the pipeline: expert outputs are exact (capacity high enough
     # that nothing drops); the aux loss is the microbatch-mean estimator.

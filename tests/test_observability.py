@@ -461,7 +461,7 @@ def test_dummy_fixture_cli_with_telemetry(tmp_path):
 
     env = dict(os.environ)
     env["_FLASHY_TMDIR"] = str(tmp_path)
-    env["FLASHY_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + env.get("PYTHONPATH", "").split(os.pathsep))

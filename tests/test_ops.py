@@ -225,6 +225,36 @@ def test_tune_flash_blocks_sweeps_and_caches(tmp_path, monkeypatch):
     assert best3 == best and len(calls) == 2
 
 
+def test_tuned_tiles_ignore_the_home_directory(tmp_path, monkeypatch):
+    """A winners file decides which kernel gets compiled, so by default
+    nothing outside the checkout is read or written: a file at the old
+    ~/.cache location is ignored, and FLASHY_TPU_TUNE_CACHE is the
+    explicit opt-in that brings it back."""
+    import json
+
+    import flashy_tpu.ops.tuning as tuning
+
+    legacy = tmp_path / "home" / ".cache" / "flashy_tpu" / "attn_tune.json"
+    legacy.parent.mkdir(parents=True)
+    key = tuning._flash_key(2, 256, 2, 32, True, jnp.bfloat16, True)
+    legacy.write_text(json.dumps(
+        {"/".join(str(part) for part in key): [128, 128]}))
+    monkeypatch.setenv("HOME", str(tmp_path / "home"))
+    monkeypatch.delenv("FLASHY_TPU_TUNE_CACHE", raising=False)
+    tuning._cache.clear()
+
+    assert tuning._cache_path() is None
+    assert tuning.lookup_tuned_blocks(2, 256, 2, 32) is None
+    tuning._store_disk_cache("some/key", [256, 256])  # nowhere to go
+    assert [p.name for p in tmp_path.rglob("*") if p.is_file()] \
+        == ["attn_tune.json"]
+    assert tuning.main(["--show"]) == 0  # says so, touches nothing
+
+    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(legacy))
+    assert tuning.lookup_tuned_blocks(2, 256, 2, 32) == (128, 128)
+    tuning._cache.clear()
+
+
 def test_tune_flash_blocks_cpu_returns_default():
     from flashy_tpu.ops.tuning import tune_flash_blocks
     assert tune_flash_blocks(1, 256, 2, 16) == (256, 256)

@@ -399,9 +399,9 @@ def test_tuning_corrupt_cache_entries_read_as_misses(tmp_path,
 
 def test_tune_paged_blocks_never_sweeps_without_a_runnable_kernel(
         monkeypatch):
-    # gpu backend (gather fallback ignores head_block) and pallas-less
-    # installs must return the default WITHOUT timing anything — a
-    # sweep there persists a noise winner other hosts could replay
+    # the gpu backend (gather fallback ignores head_block) must return
+    # the default WITHOUT timing anything — a sweep there persists a
+    # noise winner other hosts could replay
     import jax
 
     import flashy_tpu.ops.paged_decode as paged_decode
@@ -413,10 +413,6 @@ def test_tune_paged_blocks_never_sweeps_without_a_runnable_kernel(
                         lambda fn, reps=1: calls.append(1) or 0.0)
     default = paged_decode._default_head_block(4)
     monkeypatch.setattr(jax, "default_backend", lambda: "cuda")
-    assert tuning.tune_paged_blocks(2, 1, 4, 8, block_size=4,
-                                    entries=3) == default
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(paged_decode, "_PALLAS_AVAILABLE", False)
     assert tuning.tune_paged_blocks(2, 1, 4, 8, block_size=4,
                                     entries=3) == default
     assert not calls

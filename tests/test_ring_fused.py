@@ -123,7 +123,6 @@ def test_all_device_interpret_mesh_direct_call_raises():
     mesh_axes = tuple((name, mesh.shape[name]) for name in mesh.axis_names)
     fn = functools.partial(fused_ring_attention, axis_name="seq",
                            causal=True, mesh_axes=mesh_axes)
-    from flashy_tpu import _compat
     with pytest.raises(Exception, match="deadlock"):
-        _compat.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_vma=False)(q, k, v)
+        jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                      out_specs=spec, check_vma=False)(q, k, v)

@@ -121,3 +121,33 @@ def test_main_decorator_dora_alias(tmp_path):
     entry.dora.dir = tmp_path  # reference-style override spelling
     assert isinstance(entry([]), str)
     assert (tmp_path / "xps").exists()
+
+
+def test_spawn_workers_refuses_on_a_tpu_host(monkeypatch):
+    """--workers=N starts N copies of the script; on a TPU host each
+    would claim every chip and all but one would fail or hang. The
+    launcher must refuse at once, naming the supported route, and start
+    no child."""
+    from flashy_tpu import xp
+
+    started = []
+    monkeypatch.setattr(xp.subprocess, "Popen",
+                        lambda *a, **k: started.append(a))
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="mesh.data=4"):
+        xp._spawn_workers(2, ["epochs=1"])
+    assert not started
+
+    # no platform chosen: the TPU device nodes decide, without touching
+    # the backend
+    monkeypatch.delenv("JAX_PLATFORMS")
+    monkeypatch.setattr(xp.glob, "glob", lambda pattern: (
+        ["/dev/accel0"] if "accel" in pattern else []))
+    assert xp._host_has_tpu()
+    monkeypatch.setattr(xp.glob, "glob", lambda pattern: [])
+    assert not xp._host_has_tpu()
+    # an explicit CPU choice on a TPU host is honoured (the test suite's
+    # own multi-worker runs)
+    monkeypatch.setattr(xp.glob, "glob", lambda pattern: ["/dev/accel0"])
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert not xp._host_has_tpu()
