@@ -31,6 +31,7 @@
 import argparse
 import json
 import os
+import resource
 import shutil
 import subprocess
 import sys
@@ -84,11 +85,19 @@ def parent(args: argparse.Namespace) -> int:
     for phase, reserve in (("run", WARM_RESERVE_S), ("warm", 0.0)):
         left = BUDGET_S - (time.monotonic() - begin) - reserve
         code = _run_child(phase, args, left)
+        record = os.path.join(args.out, f"{phase}.json")
+        if os.path.exists(record):
+            with open(record) as f:
+                results[phase] = json.load(f)
         if code != 0:
             say(f"child '{phase}' failed with exit code {code}")
+            # whoever reads only the end of stderr still learns why
+            for name, info in results.get(phase, {}).get("phases",
+                                                         {}).items():
+                for failure in info["failed"]:
+                    print(f"[chip_smoke] FAILED [{name}]: {failure}",
+                          file=sys.stderr, flush=True)
             return code if 0 < code < 256 else 1
-        with open(os.path.join(args.out, f"{phase}.json")) as f:
-            results[phase] = json.load(f)
 
     run, warm = results["run"], results["warm"]
     cold_s = run["phases"]["trainer"]["train_step_compile_seconds"]
@@ -105,7 +114,15 @@ def parent(args: argparse.Namespace) -> int:
         hit = warm["persistent_cache_hits"] >= 1
     else:
         hit = warm["persistent_cache_hits"] >= 1 and warm_s < 0.5 * cold_s
-    if not hit:
+    file_limit = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    if not hit and any(
+            os.path.getsize(os.path.join(run["compile_cache_dir"], name))
+            == file_limit for name in os.listdir(run["compile_cache_dir"])):
+        # the machine's doing, not the program's: say so, do not fail on it
+        say(f"COMPILE CACHE NOT VERIFIED: this machine's file size limit "
+            f"({file_limit} bytes) cut a cache entry short, so the train "
+            f"step could not be stored")
+    elif not hit:
         failures.append(f"the compile cache did not hit: warm {warm_s:.1f}s "
                         f"vs cold {cold_s:.1f}s")
     for name, phase in run["phases"].items():
@@ -192,6 +209,11 @@ def _start_child(args: argparse.Namespace):
         f"{cache_dir} (JAX_COMPILATION_CACHE_DIR "
         f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'}"
         f", JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+    # the trainer phase writes two ~2.6 GiB checkpoint slots under --out
+    file_limit = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    say(f"disk: {shutil.disk_usage(args.out).free / 2**30:.1f} GiB free at "
+        f"{args.out}, file size limit "
+        f"{'none' if file_limit == resource.RLIM_INFINITY else file_limit}")
     if device["platform"] != "tpu" and not args.rehearse:
         say("JAX found no TPU: nothing to prove here (use --rehearse to "
             "debug this script at a toy size)")
@@ -231,6 +253,12 @@ def _maxerr(a, b) -> float:
                                - np.asarray(b, np.float32))))
 
 
+def _largest_file(root) -> int:
+    return max((os.path.getsize(os.path.join(folder, name))
+                for folder, _, names in os.walk(root) for name in names),
+               default=0)
+
+
 def _mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
@@ -250,11 +278,11 @@ def phase_sync(ctx: dict, checks: Checks) -> dict:
     a = (jax.random.normal(jax.random.PRNGKey(0), (n, n))
          / n ** 0.5).astype(jnp.bfloat16)
     # a tiny result, so the readback below is a wait and nothing else
-    chain = jax.jit(lambda x: jax.lax.fori_loop(
+    chain = jax.jit(lambda a, x: jax.lax.fori_loop(
         0, reps, lambda i, y: (a @ y).astype(jnp.bfloat16), x)[:8, :128])
-    np.asarray(chain(a))  # compile
+    np.asarray(chain(a, a))  # compile
     begin = time.perf_counter()
-    out = chain(a)
+    out = chain(a, a)
     dispatched = time.perf_counter() - begin
     jax.block_until_ready(out)
     waited = time.perf_counter() - begin
@@ -434,6 +462,7 @@ def phase_trainer(ctx: dict, checks: Checks) -> dict:
     import numpy as np
 
     from examples.lm import solver as lm
+    from flashy_tpu import checkpoint
     from flashy_tpu.parallel import (describe_state_sharding, memory_stats,
                                      per_device_bytes)
     from flashy_tpu.utils import tree_bytes
@@ -524,8 +553,18 @@ def phase_trainer(ctx: dict, checks: Checks) -> dict:
     checks.expect(lowered.get("jit(train_step)") == 2,
                   f"expected exactly one train_step lowering per call, "
                   f"got {lowered}")
+    # a machine that caps file size must still take the checkpoint
+    info["largest_checkpoint_file_bytes"] = _largest_file(xp.folder)
     say(f"  trained, committed twice, resumed: losses {info['losses']}, "
-        f"slots {slots}, lowerings {lowered}")
+        f"slots {slots}, largest file "
+        f"{info['largest_checkpoint_file_bytes'] / 2**20:.1f} MiB, "
+        f"lowerings {lowered}")
+    if len(slots) == 2:
+        checks.expect(info["largest_checkpoint_file_bytes"]
+                      <= 2 * checkpoint.DATA_FILE_BYTES,
+                      f"a checkpoint file of "
+                      f"{info['largest_checkpoint_file_bytes']} bytes exceeds "
+                      f"twice checkpoint.DATA_FILE_BYTES")
     # the checkpoints did their job; leave only the small records behind
     for payload in xp.folder.glob("checkpoint*"):
         if payload.is_dir():
@@ -711,8 +750,10 @@ def child_run(args: argparse.Namespace) -> int:
             info = phase(ctx, checks)
         except Exception as exc:  # noqa: BLE001 — recorded, fails the run
             traceback.print_exc(file=sys.stdout)
-            checks.failed.append(f"raised {type(exc).__name__}: "
-                                 f"{str(exc)[:400]}")
+            text = str(exc)  # the cause is often at the end of a long one
+            if len(text) > 800:
+                text = f"{text[:400]} ... {text[-400:]}"
+            checks.failed.append(f"raised {type(exc).__name__}: {text}")
         record["phases"][name] = {
             "ok": not checks.failed, "failed": checks.failed,
             "seconds": round(time.perf_counter() - begin, 1),
@@ -720,8 +761,9 @@ def child_run(args: argparse.Namespace) -> int:
                                      1), **info}
         say(f"phase {name}: {'ok' if not checks.failed else 'FAILED'} in "
             f"{record['phases'][name]['seconds']}s")
-    record["persistent_cache"] = {"hits": log.cache_hits,
-                                  "misses": log.cache_misses}
+    record["persistent_cache"] = {
+        "hits": log.cache_hits, "misses": log.cache_misses,
+        "largest_entry_bytes": _largest_file(cache_dir)}
     with open(os.path.join(args.out, "run.json"), "w") as f:
         json.dump(record, f, indent=1, default=str)
     return 0 if all(p["ok"] for p in record["phases"].values()) else 1

@@ -31,6 +31,19 @@ from .utils import AnyPath, to_numpy, write_and_rename
 
 logger = logging.getLogger(__name__)
 
+# Orbax's default lets one OCDBT data file grow to 2 GB. A machine that
+# caps file size (`ulimit -f`, some network filesystems) then fails the
+# whole save with EFBIG, so arrays are cut into chunks of at most this
+# many bytes and a data file is closed once it holds this much: no file
+# of a sharded checkpoint exceeds twice this value.
+DATA_FILE_BYTES = 8 << 20
+
+
+def _orbax_save_args(arrays: tp.Any) -> tp.Any:
+    import orbax.checkpoint as ocp
+    return ocp.args.PyTreeSave(arrays,
+                               ocdbt_target_data_file_size=DATA_FILE_BYTES)
+
 
 def _write_state_file(path: AnyPath, payload: tp.Any,
                       sidecar: bool = True) -> None:
@@ -476,7 +489,8 @@ def save_state_sharded(state: tp.Any, directory: AnyPath) -> None:
     if arrays:
         import orbax.checkpoint as ocp
         with ocp.PyTreeCheckpointer() as checkpointer:
-            checkpointer.save(directory / target / "arrays", arrays, force=True)
+            checkpointer.save(directory / target / "arrays",
+                              args=_orbax_save_args(arrays), force=True)
     _commit_slot(directory, target, skeleton, topology=topology)
 
 
@@ -516,8 +530,8 @@ class AsyncShardedCheckpointer:
         skeleton, arrays = _extract_device_arrays(state)
         target = _prepare_slot(directory)
         if arrays:
-            self._orbax().save(directory / target / "arrays", arrays,
-                               force=True)
+            self._orbax().save(directory / target / "arrays",
+                               args=_orbax_save_args(arrays), force=True)
         self._pending = (directory, target, skeleton, on_commit, topology)
 
     def finalize_pending(self) -> None:
@@ -830,7 +844,7 @@ def save_sharded(state: tp.Any, directory: AnyPath) -> None:
     import orbax.checkpoint as ocp
     path = Path(directory).absolute()
     with ocp.PyTreeCheckpointer() as checkpointer:
-        checkpointer.save(path, state, force=True)
+        checkpointer.save(path, args=_orbax_save_args(state), force=True)
 
 
 def restore_sharded(directory: AnyPath, target: tp.Any = None) -> tp.Any:

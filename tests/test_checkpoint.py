@@ -259,6 +259,36 @@ def test_sharded_state_roundtrip_with_placements(tmp_path):
     assert restored["xp.cfg"] == {"lr": 0.1}
 
 
+def test_sharded_save_fits_a_file_size_limit(tmp_path, monkeypatch):
+    # a state several times larger than the process may write into one
+    # file (`ulimit -f`) must still save: Orbax's default of one data
+    # file of up to 2 GB fails there with EFBIG
+    pytest.importorskip("orbax.checkpoint")
+    import os
+    import resource
+    from flashy_tpu import checkpoint
+
+    monkeypatch.setattr(checkpoint, "DATA_FILE_BYTES", 256 << 10)
+    rng = np.random.default_rng(0)
+    state = {"w": jnp.asarray(rng.normal(size=(1024, 1024)), jnp.float32),
+             "v": jnp.asarray(rng.normal(size=(512, 256)), jnp.float32)}
+    directory = tmp_path / "ckpt.sharded"
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    resource.setrlimit(resource.RLIMIT_FSIZE, (1 << 20, hard))
+    try:
+        checkpoint.save_state_sharded(state, directory)
+    finally:
+        resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+    largest = max(os.path.getsize(os.path.join(folder, name))
+                  for folder, _, names in os.walk(directory)
+                  for name in names)
+    assert largest <= 2 * checkpoint.DATA_FILE_BYTES
+    restored = checkpoint.load_state_sharded(directory, state)
+    for key, value in state.items():
+        np.testing.assert_array_equal(np.asarray(restored[key]),
+                                      np.asarray(value))
+
+
 def test_sharded_ab_slots_survive_next_save(tmp_path):
     pytest.importorskip("orbax.checkpoint")
     from flashy_tpu.checkpoint import (_read_slot_pointer, load_state_sharded,
