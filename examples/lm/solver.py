@@ -185,8 +185,9 @@ class LMSolver(flashy_tpu.BaseSolver):
             else:
                 logits = model.apply(variables, tokens)
                 aux = 0.0
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1], tokens[:, 1:]).mean()
+            with jax.named_scope("loss"):
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    logits[:, :-1], tokens[:, 1:]).mean()
             return ce + aux
 
         from flashy_tpu.parallel import with_grad_accumulation
@@ -210,18 +211,22 @@ class LMSolver(flashy_tpu.BaseSolver):
         ema_decay = self.ema_decay
 
         def train_step(state, tokens):
+            # scopes a device trace splits the step by: the model's
+            # Flax module paths (.../attn, .../mlp), `loss` (the head
+            # and cross-entropy, ops.losses) and `optimizer`
             loss, grads = grad_fn(state["params"], tokens)
-            updates, opt_state = optim.update(grads, state["opt_state"],
-                                              state["params"])
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optim.update(
+                    grads, state["opt_state"], state["params"])
+                params = optax.apply_updates(state["params"], updates)
+                grad_norm = optax.global_norm(grads)
             new_state = {"params": params, "opt_state": opt_state,
                          "step": state["step"] + 1}
             if "ema" in state:
                 from flashy_tpu.ema import ema_update
                 new_state["ema"] = ema_update(state["ema"], params,
                                               ema_decay, step=state["step"])
-            return (new_state,
-                    {"loss": loss, "grad_norm": optax.global_norm(grads)})
+            return new_state, {"loss": loss, "grad_norm": grad_norm}
 
         self._train_step_fn = train_step
         self._jit_train_step()

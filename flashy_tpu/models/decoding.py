@@ -170,6 +170,44 @@ def _moe_forward(cfg: TransformerConfig, mp: tp.Dict, x: jax.Array) -> jax.Array
     return out.reshape(batch, seq, dim).astype(cfg.dtype)
 
 
+# The decode step's named scopes — ONE set for the dense step below and
+# the paged step (serve/paged.py), so a device trace attributes time the
+# same way under both layouts: embed, norm, qkv, rotary, kv_write, attn,
+# out_proj, mlp, head (+ `sample` in the engine). The scope is the HLO
+# `op_name` path of every op traced inside it.
+def _qkv_heads(cfg, bp: tp.Dict, x: jax.Array, positions: jax.Array
+               ) -> tp.Tuple[jax.Array, jax.Array, jax.Array]:
+    """Pre-norm, fused QKV projection and rotary: (q, k, v), each
+    [B, S, H, Dh] (quantized kernels supported)."""
+    with jax.named_scope("norm"):
+        normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
+    with jax.named_scope("qkv"):
+        qkv_w, qkv_s = _kernel(bp["attn"]["qkv"]["kernel"], cfg.dtype)
+        qkv = _postscale(jnp.einsum("btd,dchk->btchk", normed, qkv_w), qkv_s)
+        q, k, v = _split_heads(qkv)
+    with jax.named_scope("rotary"):
+        return _rotary(q, positions), _rotary(k, positions), v
+
+
+def _attn_residual(cfg, bp: tp.Dict, x: jax.Array, attn: jax.Array
+                   ) -> jax.Array:
+    """x + output projection of the attended heads [B, S, H, Dh]."""
+    with jax.named_scope("out_proj"):
+        out_w, out_s = _kernel(bp["attn"]["out"]["kernel"], cfg.dtype)
+        return x + _postscale(jnp.einsum("bqhd,hdD->bqD", attn, out_w),
+                              out_s)
+
+
+def _mlp_residual(cfg, bp: tp.Dict, x: jax.Array) -> jax.Array:
+    """x + the block's pre-normed MLP (gated, or MoE)."""
+    with jax.named_scope("norm"):
+        normed = _rmsnorm(x, bp["norm2"]["scale"], cfg.dtype)
+    with jax.named_scope("mlp"):
+        if "moe" in bp:
+            return x + _moe_forward(cfg, bp["moe"], normed)
+        return x + _gated_mlp(bp["mlp"], normed, cfg.dtype)
+
+
 def _cache_write(cache: jax.Array, new: jax.Array,
                  cache_index: jax.Array) -> jax.Array:
     """Write `new` [B, S, H, Dh] into `cache` at `cache_index`.
@@ -203,29 +241,25 @@ def _cached_self_attention(cfg, bp: tp.Dict, x: jax.Array,
     serving engine); the causal mask is per-row either way because it
     derives from `positions`, so rows at different lengths attend only
     their own live prefix."""
-    normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
-    qkv_w, qkv_s = _kernel(bp["attn"]["qkv"]["kernel"], cfg.dtype)
-    qkv = _postscale(jnp.einsum("btd,dchk->btchk", normed, qkv_w), qkv_s)
-    q, k, v = _split_heads(qkv)
-    q = _rotary(q, positions)
-    k = _rotary(k, positions)
-    k_cache = _cache_write(k_cache, k.astype(cfg.dtype), cache_index)
-    v_cache = _cache_write(v_cache, v.astype(cfg.dtype), cache_index)
+    q, k, v = _qkv_heads(cfg, bp, x, positions)
+    with jax.named_scope("kv_write"):
+        k_cache = _cache_write(k_cache, k.astype(cfg.dtype), cache_index)
+        v_cache = _cache_write(v_cache, v.astype(cfg.dtype), cache_index)
 
     # Attend over the cache prefix [0, cache_index + seq).
-    max_len = k_cache.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    key_pos = jnp.arange(max_len)[None, :]
-    query_pos = positions[:, :, None]  # [B, S, 1] global positions
-    mask = key_pos[None] <= query_pos  # causal over the cache
-    scores = jnp.where(mask[:, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype), v_cache)
-    out_w, out_s = _kernel(bp["attn"]["out"]["kernel"], cfg.dtype)
-    attn_out = _postscale(jnp.einsum("bqhd,hdD->bqD", attn, out_w), out_s)
-    return x + attn_out, k_cache, v_cache
+    with jax.named_scope("attn"):
+        max_len = k_cache.shape[1]
+        scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
+                            preferred_element_type=jnp.float32) * scale
+        key_pos = jnp.arange(max_len)[None, :]
+        query_pos = positions[:, :, None]  # [B, S, 1] global positions
+        mask = key_pos[None] <= query_pos  # causal over the cache
+        scores = jnp.where(mask[:, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype),
+                          v_cache)
+    return _attn_residual(cfg, bp, x, attn), k_cache, v_cache
 
 
 def _ssd_mixer_forward(cfg, bp: tp.Dict, x: jax.Array, state: jax.Array,
@@ -275,12 +309,7 @@ def _ssd_layer_forward(cfg: TransformerConfig, bp: tp.Dict, x: jax.Array,
     """One SSD block against the resident state: returns (x, state)."""
     x, state = _ssd_mixer_forward(cfg, bp, x, state, token_mask,
                                   state_mask)
-    normed = _rmsnorm(x, bp["norm2"]["scale"], cfg.dtype)
-    if "moe" in bp:
-        x = x + _moe_forward(cfg, bp["moe"], normed)
-    else:
-        x = x + _gated_mlp(bp["mlp"], normed, cfg.dtype)
-    return x, state
+    return _mlp_residual(cfg, bp, x), state
 
 
 def _gated_mlp(bp_mlp: tp.Dict, normed: jax.Array, dtype) -> jax.Array:
@@ -300,14 +329,10 @@ def _layer_forward(cfg: TransformerConfig, bp: tp.Dict, x: jax.Array,
     """One block against cached K/V: returns (x, k_cache, v_cache)."""
     x, k_cache, v_cache = _cached_self_attention(
         cfg, bp, x, positions, k_cache, v_cache, cache_index)
-    normed = _rmsnorm(x, bp["norm2"]["scale"], cfg.dtype)
-    if "moe" in bp:
-        x = x + _moe_forward(cfg, bp["moe"], normed)
-    else:
-        x = x + _gated_mlp(bp["mlp"], normed, cfg.dtype)
-    return x, k_cache, v_cache
+    return _mlp_residual(cfg, bp, x), k_cache, v_cache
 
 
+@jax.named_scope("embed")
 def _embed_tokens(p: tp.Dict, tokens: jax.Array, dtype) -> jax.Array:
     """Token ids [B, S] -> embeddings [B, S, D] (int8 tables supported).
 
@@ -331,14 +356,16 @@ def _head_logits(p: tp.Dict, x: jax.Array, cfg: TransformerConfig
     per-vocab-row scale applies to the f32 logits. Shared by the dense
     and paged apply steps.
     """
-    x = _rmsnorm(x, p["norm_f"]["scale"], cfg.dtype)
-    if is_quantized(p["embed"]):
-        logits = jnp.einsum("btd,vd->btv", x,
-                            p["embed"]["q"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        return logits * p["embed"]["scale"][:, 0]
-    return jnp.einsum("btd,vd->btv", x, p["embed"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("norm"):
+        x = _rmsnorm(x, p["norm_f"]["scale"], cfg.dtype)
+    with jax.named_scope("head"):
+        if is_quantized(p["embed"]):
+            logits = jnp.einsum("btd,vd->btv", x,
+                                p["embed"]["q"].astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            return logits * p["embed"]["scale"][:, 0]
+        return jnp.einsum("btd,vd->btv", x, p["embed"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,

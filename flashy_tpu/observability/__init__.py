@@ -2,6 +2,7 @@
 # reference never shipped (SURVEY §5). Four pieces, one switch:
 #
 #  * Tracer            host-side spans -> Perfetto trace + telemetry.jsonl
+#  * span              one host interval -> the profiler's clock + the Tracer
 #  * StepTimer         data-wait / host / device split per training step
 #  * RecompileWatchdog WARN when a jitted fn recompiles after warm-up
 #  * Heartbeat         per-rank liveness files + cross-host straggler report
@@ -26,7 +27,7 @@
 # is only imported inside functions that genuinely touch devices.
 """Runtime telemetry: tracing, step timing, recompile and straggler watch."""
 
-from .tracer import JsonlJournal, Tracer  # noqa
+from .tracer import JsonlJournal, Tracer, span  # noqa
 from .steptimer import StepTimer  # noqa
 from .watchdog import RecompileWatchdog  # noqa
 from .heartbeat import (  # noqa
@@ -44,7 +45,7 @@ from .telemetry import (  # noqa
 )
 
 __all__ = [
-    "Tracer", "JsonlJournal", "StepTimer", "RecompileWatchdog",
+    "Tracer", "JsonlJournal", "span", "StepTimer", "RecompileWatchdog",
     "Heartbeat", "Telemetry",
     "enable_telemetry", "disable_telemetry", "get_telemetry",
     "device_memory_stats", "read_heartbeats", "straggler_report",

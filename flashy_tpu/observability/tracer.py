@@ -90,6 +90,37 @@ class JsonlJournal:
             self._file = None
 
 
+@contextmanager
+def span(name: str, tracer: tp.Optional["Tracer"] = None,
+         category: str = "host", **stats: tp.Any):
+    """THE span primitive: one named host interval, two sinks.
+
+    It always enters `jax.profiler.TraceAnnotation(name, **stats)`: a
+    few hundred nanoseconds while no profiler session runs, and under
+    one (`solver.enable_profiling`, `jax.profiler.start_trace`) the span
+    lands on `/host:CPU` of the `.xplane.pb`, on the same clock as the
+    device's `XLA Ops` — so device idle time can be laid against the
+    host phase that caused it. When `tracer` (or, if None, the active
+    telemetry's tracer) exists, the same name and stats are also
+    recorded as a Chrome 'X' event, as `Tracer.span` always did.
+    Yields that tracer (or None). Names follow the `sub/name` track
+    convention (FT006's `TRACK_RE`).
+    """
+    import jax  # not at import time: the package loads without a backend
+    if tracer is None:
+        from .telemetry import get_telemetry
+        telemetry = get_telemetry()
+        tracer = telemetry.tracer if telemetry is not None else None
+    start = time.perf_counter()
+    with jax.profiler.TraceAnnotation(name, **stats):
+        try:
+            yield tracer
+        finally:
+            if tracer is not None:
+                tracer.complete(name, start, time.perf_counter() - start,
+                                category=category, **stats)
+
+
 class Tracer:
     """Records host-side monotonic events and exports them.
 
@@ -161,15 +192,10 @@ class Tracer:
                    "pid": self.rank, "tid": threading.get_ident() % (1 << 31),
                    "args": args})
 
-    @contextmanager
     def span(self, name: str, category: str = "host", **args: tp.Any):
-        """Context manager recording one complete ('X') event."""
-        start = time.perf_counter()
-        try:
-            yield self
-        finally:
-            duration = time.perf_counter() - start
-            self.complete(name, start, duration, category=category, **args)
+        """Context manager recording one complete ('X') event (and the
+        same span on the profiler's clock: see module-level `span`)."""
+        return span(name, tracer=self, category=category, **args)
 
     def wrap(self, fn: tp.Optional[tp.Callable] = None, *,
              name: tp.Optional[str] = None) -> tp.Callable:

@@ -403,6 +403,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
             pltpu.VMEM((block_q, dim), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return _unfold(out, batch, heads), lse
 
@@ -446,6 +447,7 @@ def _flash_backward(q, k, v, out, lse, grad_out, *, causal: bool,
         out_shape=jax.ShapeDtypeStruct((bh, t_q, dim), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lse, delta)
 
     col_specs = [
@@ -474,6 +476,7 @@ def _flash_backward(q, k, v, out, lse, grad_out, *, causal: bool,
             pltpu.VMEM((block_k, dim), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lse, delta)
 
     return (_unfold(dq, batch, heads), _unfold(dk, batch, heads),
@@ -533,6 +536,7 @@ def _flash_backward_fused(q, k, v, out, lse, grad_out, *, causal: bool,
             pltpu.VMEM((block_k, dim), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_fused",
     )(qf, kf, vf, dof, lse, delta)
 
     # Reduce the dQ partials with an explicit left fold in k order —

@@ -131,15 +131,19 @@ def lm_next_token_loss(model, variables, tokens, *, mode: str = "dense",
     'dense' materializes [B, T, V] logits (fine for small vocab);
     'chunked' runs `chunked_softmax_cross_entropy` over the final
     hidden states (large-vocab HBM saver). Both are the same math.
+    The cross-entropy (with the chunked head) is traced under the named
+    scope `loss`, which a device trace reads as the head's share.
     """
     if mode == "dense":
         import optax
         logits = model.apply(variables, tokens)
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1], tokens[:, 1:]).mean()
+        with jax.named_scope("loss"):
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], tokens[:, 1:]).mean()
     if mode != "chunked":
         raise ValueError(f"mode must be 'dense' or 'chunked', got {mode!r}")
     hidden, head = model.apply(variables, tokens, return_hidden=True)
-    loss = chunked_softmax_cross_entropy(hidden[:, :-1], head,
-                                         tokens[:, 1:], chunk_size)
-    return loss.mean()
+    with jax.named_scope("loss"):
+        loss = chunked_softmax_cross_entropy(hidden[:, :-1], head,
+                                             tokens[:, 1:], chunk_size)
+        return loss.mean()

@@ -269,6 +269,7 @@ def _fused_call(q, entry, table, base, *, head_block: int,
         out_shape=jax.ShapeDtypeStruct((batch, queries, heads, dim),
                                        q.dtype, vma=vma),
         interpret=interpret,
+        name="paged_decode_fused",
     )(table, base, *operands)
 
 

@@ -271,6 +271,7 @@ def _fused_call(c, b, v, la, state, *, chunk: int, interpret: bool):
         ],
         scratch_shapes=[pltpu.VMEM((dim, dstate), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan_fused",
     )(c, b, v, la[..., None], state)
 
 

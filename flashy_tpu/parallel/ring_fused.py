@@ -294,6 +294,7 @@ def _fused_forward(q, k, v, axis_name: str, mesh_axes, causal: bool,
         # transfer would never run for the blocked receiver).
         interpret=(pltpu.InterpretParams(dma_execution_mode="eager")
                    if interpret else False),
+        name="ring_flash_fwd",
     )(qf, kf, vf)[:2]
     lse_rows = lse[:, :, 0].reshape(batch, heads, t_loc)
     return _attn._unfold(out, batch, heads), lse_rows

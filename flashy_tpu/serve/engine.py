@@ -17,7 +17,7 @@ import typing as tp
 
 import numpy as np
 
-from ..observability import Tracer
+from ..observability import Tracer, span
 from .compile_cache import CompileCache, bucket_length
 
 logger = logging.getLogger(__name__)
@@ -28,6 +28,11 @@ SPAN_PREFILL_CHUNK = "serve/prefill_chunk"
 SPAN_DECODE = "serve/decode"
 SPAN_VERIFY = "serve/verify"
 SPAN_ADMIT = "serve/admit"
+# children of the spans above: the host's share of a step, split by who
+# makes the device wait (readers: benchmarks/readers/program_spans.py)
+SPAN_TABLE_UPLOAD = "serve/table_upload"
+SPAN_DISPATCH = "/dispatch"   # suffixes under decode / verify /
+SPAN_READBACK = "/readback"   # prefill_chunk
 
 
 def _zero_ssd_leaves(cache: tp.Any, fresh: tp.Any) -> tp.Any:
@@ -525,10 +530,11 @@ class DecodeEngine:
         """Next token from [S, V] logits (matches generate()'s rule)."""
         import jax
         import jax.numpy as jnp
-        if self.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(
-            key, logits / self.temperature, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if self.temperature <= 0.0:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.random.categorical(
+                key, logits / self.temperature, axis=-1).astype(jnp.int32)
 
     def _table(self):
         """Device copy of the block tables, refreshed only when the host
@@ -536,7 +542,9 @@ class DecodeEngine:
         reservations are materialized up front)."""
         import jax.numpy as jnp
         if self._table_dirty:
-            self._table_dev = jnp.asarray(self._table_host)
+            with span(SPAN_TABLE_UPLOAD, self.tracer, category="serve",
+                      bytes=int(self._table_host.nbytes)):
+                self._table_dev = jnp.asarray(self._table_host)
             self._table_dirty = False
         return self._table_dev
 
@@ -984,10 +992,8 @@ class DecodeEngine:
         fn = self.compile_cache.get(
             self._key("prefill", bucket),
             lambda: self._build_prefill(bucket))
-        span = (self.tracer.span(SPAN_PREFILL, category="serve", slot=slot,
-                                 bucket=bucket, length=length)
-                if self.tracer else _null_span())
-        with span:
+        with span(SPAN_PREFILL, self.tracer, category="serve", slot=slot,
+                  bucket=bucket, length=length):
             first, self._cache = fn(self._params, self._cache,
                                     jnp.asarray(padded), jnp.int32(length),
                                     jnp.int32(slot), self._next_key())
@@ -999,8 +1005,9 @@ class DecodeEngine:
         self._active_host[slot] = True
         return first
 
-    def prefill_chunk(self, slot: int, prompt: np.ndarray,
-                      start: int) -> tp.Tuple[int, tp.Optional[int]]:
+    def prefill_chunk(self, slot: int, prompt: np.ndarray, start: int,
+                      uid: tp.Optional[int] = None
+                      ) -> tp.Tuple[int, tp.Optional[int]]:
         """Advance `slot`'s prefill by ONE fixed-size slice.
 
         Processes `prompt[start : start + size]` where size is `chunk`,
@@ -1010,7 +1017,8 @@ class DecodeEngine:
         until the final slice, at which point the slot goes live. The
         scheduler interleaves these ticks with decode steps, bounding
         the stall a long prompt can impose on live slots to one
-        slice's compute.
+        slice's compute. `uid` (the scheduler's request id) only rides
+        on the span, so a request's slices can be followed in a trace.
         """
         import jax.numpy as jnp
         if self.chunk is None:
@@ -1039,29 +1047,29 @@ class DecodeEngine:
         fn = self.compile_cache.get(
             self._key("prefill_chunk", size),
             lambda: self._build_prefill_chunk(size))
-        span = (self.tracer.span(SPAN_PREFILL_CHUNK, category="serve",
-                                 slot=slot, size=size, offset=start,
-                                 length=length)
-                if self.tracer else _null_span())
-        with span:
+        stats = {} if uid is None else {"uid": uid}
+        with span(SPAN_PREFILL_CHUNK, self.tracer, category="serve",
+                  slot=slot, size=size, offset=start, length=length,
+                  final=final, **stats):
             first, self._cache = fn(self._params, self._cache,
                                     *self._layout_args(),
                                     jnp.asarray(padded), jnp.int32(start),
                                     jnp.int32(used), jnp.int32(slot),
                                     self._next_key())
-            if final:
+            if not final:
+                return start + used, None
+            with span(SPAN_PREFILL_CHUNK + SPAN_READBACK, self.tracer,
+                      category="serve"):
                 first = int(first)
-        if not final:
-            return start + used, None
-        if self._pool is not None:
-            # prompt fully written: index its full blocks so later
-            # admissions share them instead of re-prefilling
-            self._pool.on_live(self.pool_key(slot))
-        self._tokens = self._tokens.at[slot].set(first)
-        self._positions = self._positions.at[slot].set(length)
-        self._active = self._active.at[slot].set(True)
-        self._positions_host[slot] = length
-        self._active_host[slot] = True
+            if self._pool is not None:
+                # prompt fully written: index its full blocks so later
+                # admissions share them instead of re-prefilling
+                self._pool.on_live(self.pool_key(slot))
+            self._tokens = self._tokens.at[slot].set(first)
+            self._positions = self._positions.at[slot].set(length)
+            self._active = self._active.at[slot].set(True)
+            self._positions_host[slot] = length
+            self._active_host[slot] = True
         return start + used, first
 
     def decode(self) -> np.ndarray:
@@ -1070,20 +1078,23 @@ class DecodeEngine:
         executable, whatever the live mix."""
         fn = self.compile_cache.get(self._key("decode", self.slots),
                                     self._build_decode)
-        span = (self.tracer.span(SPAN_DECODE, category="serve",
-                                 live=self.allocator.live_count)
-                if self.tracer else _null_span())
-        with span:
-            tokens, self._cache = fn(self._params, self._cache,
-                                     *self._layout_args(), self._tokens,
-                                     self._positions, self._active,
-                                     self._next_key())
-            out = np.asarray(tokens)
-        # feed each live slot its own token back; lengths advance by 1
-        self._tokens = tokens
-        self._positions = self._positions + self._active.astype(
-            self._positions.dtype)
-        self._positions_host += self._active_host
+        with span(SPAN_DECODE, self.tracer, category="serve",
+                  live=self.allocator.live_count,
+                  running=int(self._active_host.sum())):
+            layout, key = self._layout_args(), self._next_key()
+            with span(SPAN_DECODE + SPAN_DISPATCH, self.tracer,
+                      category="serve"):
+                tokens, self._cache = fn(self._params, self._cache, *layout,
+                                         self._tokens, self._positions,
+                                         self._active, key)
+            with span(SPAN_DECODE + SPAN_READBACK, self.tracer,
+                      category="serve"):
+                out = np.asarray(tokens)
+            # feed each live slot its own token back; lengths advance by 1
+            self._tokens = tokens
+            self._positions = self._positions + self._active.astype(
+                self._positions.dtype)
+            self._positions_host += self._active_host
         return out
 
     def decode_speculative(self, drafts: np.ndarray
@@ -1115,16 +1126,20 @@ class DecodeEngine:
         k = int(drafts.shape[1])
         fn = self.compile_cache.get(self._key("verify", self.slots, k),
                                     lambda: self._build_verify(k))
-        span = (self.tracer.span(SPAN_VERIFY, category="serve", k=k,
-                                 live=self.allocator.live_count)
-                if self.tracer else _null_span())
-        with span:
-            out, accepted, self._tokens, self._positions, self._cache = fn(
-                self._params, self._cache, *self._layout_args(),
-                self._tokens, jnp.asarray(drafts), self._positions,
-                self._active, self._next_key())
-            out_np = np.asarray(out)
-            accepted_np = np.asarray(accepted)
+        with span(SPAN_VERIFY, self.tracer, category="serve", k=k,
+                  live=self.allocator.live_count,
+                  running=int(self._active_host.sum())):
+            layout, key = self._layout_args(), self._next_key()
+            with span(SPAN_VERIFY + SPAN_DISPATCH, self.tracer,
+                      category="serve"):
+                (out, accepted, self._tokens, self._positions,
+                 self._cache) = fn(
+                    self._params, self._cache, *layout, self._tokens,
+                    jnp.asarray(drafts), self._positions, self._active, key)
+            with span(SPAN_VERIFY + SPAN_READBACK, self.tracer,
+                      category="serve"):
+                out_np = np.asarray(out)
+                accepted_np = np.asarray(accepted)
         self._positions_host += np.where(self._active_host,
                                          accepted_np.astype(np.int64) + 1, 0)
         return out_np, accepted_np
@@ -1284,11 +1299,3 @@ class DecodeEngine:
     @property
     def free_count(self) -> int:
         return self.allocator.free_count
-
-
-class _null_span:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False

@@ -583,11 +583,9 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     per-row scatter XLA already fuses).
     """
     import jax
-    import jax.numpy as jnp
 
-    from ..models.decoding import (_embed_tokens, _gated_mlp, _head_logits,
-                                   _kernel, _moe_forward, _postscale,
-                                   _rmsnorm, _rotary, _split_heads)
+    from ..models.decoding import (_attn_residual, _embed_tokens,
+                                   _head_logits, _mlp_residual, _qkv_heads)
     from ..ops.paged_attention import paged_attention, paged_write
     from ..ops.paged_decode import fused_paged_attention
 
@@ -597,23 +595,15 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     attend = fused_paged_attention if kernel == "fused" else paged_attention
 
     def layer(bp, x, entry):
-        normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
-        qkv_w, qkv_s = _kernel(bp["attn"]["qkv"]["kernel"], cfg.dtype)
-        qkv = _postscale(jnp.einsum("btd,dchk->btchk", normed, qkv_w), qkv_s)
-        q, k, v = _split_heads(qkv)
-        q = _rotary(q, positions)
-        k = _rotary(k, positions)
-        entry = paged_write(entry, k, v, table, positions)
-        attn = attend(q, entry, table, positions,
-                      head_dim=cfg.head_dim, dtype=cfg.dtype)
-        out_w, out_s = _kernel(bp["attn"]["out"]["kernel"], cfg.dtype)
-        x = x + _postscale(jnp.einsum("bqhd,hdD->bqD", attn, out_w), out_s)
-        normed = _rmsnorm(x, bp["norm2"]["scale"], cfg.dtype)
-        if "moe" in bp:
-            x = x + _moe_forward(cfg, bp["moe"], normed)
-        else:
-            x = x + _gated_mlp(bp["mlp"], normed, cfg.dtype)
-        return x, entry
+        # the dense step's scopes (models/decoding.py), same names
+        q, k, v = _qkv_heads(cfg, bp, x, positions)
+        with jax.named_scope("kv_write"):
+            entry = paged_write(entry, k, v, table, positions)
+        with jax.named_scope("attn"):
+            attn = attend(q, entry, table, positions,
+                          head_dim=cfg.head_dim, dtype=cfg.dtype)
+        x = _attn_residual(cfg, bp, x, attn)
+        return _mlp_residual(cfg, bp, x), entry
 
     p = params["params"]
     x = _embed_tokens(p, tokens, cfg.dtype)

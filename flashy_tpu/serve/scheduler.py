@@ -17,12 +17,21 @@ import typing as tp
 
 import numpy as np
 
+from ..observability import span
 from ..resilience import chaos
 from .engine import DecodeEngine
 from .metrics import ServeMetrics
 from .paged import PoolExhausted
 
 logger = logging.getLogger(__name__)
+
+# One scheduler step as a span tree on the profiler's clock (the
+# engine's spans nest inside: serve/prefill_chunk, serve/table_upload,
+# serve/decode and its dispatch/readback children).
+SPAN_STEP = "serve/step"
+SPAN_ADMISSION = "serve/admission"
+SPAN_GAUGES = "serve/gauges"
+SPAN_RETIRE = "serve/retire"
 
 
 class QueueFull(RuntimeError):
@@ -193,6 +202,7 @@ class ContinuousBatchingScheduler:
         # run — the demo asserts max <= chunk (the stall bound).
         self.prefill_tokens_last_step = 0
         self.max_prefill_tokens_per_step = 0
+        self.steps = 0  # scheduler steps taken (the `step` stat of SPAN_STEP)
 
     # ------------------------------------------------------------------
     # admission
@@ -460,15 +470,13 @@ class ContinuousBatchingScheduler:
         return requests
 
     def _admit(self) -> int:
-        """Assign queued requests to free slots and advance prefill;
-        returns #admitted (slots assigned this step).
+        """Assign queued requests to free slots; returns #admitted
+        (slots assigned this step).
 
         Monolithic engines prefill the whole (bucketed) prompt at
-        assignment; chunked engines advance at most
-        `prefill_chunks_per_step` slices per step across the
-        in-progress prefills, oldest first (FIFO down to the tick).
-        A resumed request (preempted earlier) prefills its
-        `resume_prompt` under `remaining_budget`.
+        assignment; chunked engines only reserve here and prefill in
+        `_advance_prefill`. A resumed request (preempted earlier)
+        prefills its `resume_prompt` under `remaining_budget`.
         """
         admitted = 0
         while self._queue:
@@ -534,7 +542,12 @@ class ContinuousBatchingScheduler:
                 # shared by reference, never recomputed)
                 request.state = "prefilling"
                 self._prefilling[slot] = [request, start, prompt]
-        # advance chunked prefills, bounded per step (the stall bound)
+        return admitted
+
+    def _advance_prefill(self) -> None:
+        """Advance chunked prefills by at most `prefill_chunks_per_step`
+        slices across the in-progress prefills, oldest first (FIFO down
+        to the tick) — the bound on the stall a long prompt imposes."""
         self.prefill_tokens_last_step = 0
         budget = self.prefill_chunks_per_step
         for slot in list(self._prefilling):
@@ -542,7 +555,7 @@ class ContinuousBatchingScheduler:
                 break
             request, start, prompt = self._prefilling[slot]
             new_start, first = self.engine.prefill_chunk(
-                slot, prompt, start)
+                slot, prompt, start, uid=request.uid)
             budget -= 1
             if self.tracing is not None:
                 self.tracing.on_prefill_chunk(request, start, new_start)
@@ -554,7 +567,6 @@ class ContinuousBatchingScheduler:
                 self._first_token(slot, request, first)
         self.max_prefill_tokens_per_step = max(
             self.max_prefill_tokens_per_step, self.prefill_tokens_last_step)
-        return admitted
 
     # ------------------------------------------------------------------
     # decode + retirement
@@ -616,58 +628,78 @@ class ContinuousBatchingScheduler:
             raise
 
     def _step(self) -> int:
-        self._shed_expired()
-        self._admit()
-        self.metrics.on_gauges(queue_depth=len(self._queue),
-                               live=self.engine.live_count,
-                               capacity=self.engine.slots)
-        pool = self.engine.pool_stats()
-        if pool is not None:
-            self.metrics.on_pool(
-                occupancy=pool["occupancy"],
-                in_use=int(pool["in_use"]),
-                capacity=int(pool["capacity"]),
-                cached=int(pool["cached"]),
-                bytes_per_token=pool["kv_bytes_per_token"])
-        if not self._running:
-            return 0
+        # the ITL clock starts here: a token's gap is the whole step
+        # that produced it, the prefill slice the step carried included
         step_start = time.perf_counter()
-        # inside the ITL-measured region on purpose: an injected delay
-        # here lands in the per-token `gap` the SLO engine samples, and
-        # an injected raise still unwinds through step()'s finalize
-        chaos.fault_point("serve.step", queue_depth=len(self._queue),
-                          live=len(self._running))
-        if self.draft is None:
-            tokens = self.engine.decode()
+        tracer = self.engine.tracer
+        with span(SPAN_STEP, tracer, category="serve", step=self.steps,
+                  queued=len(self._queue), prefilling=len(self._prefilling),
+                  running=len(self._running)):
+            self.steps += 1
+            with span(SPAN_ADMISSION, tracer, category="serve",
+                      queued=len(self._queue)):
+                self._shed_expired()
+                self._admit()
+            self._advance_prefill()
+            with span(SPAN_GAUGES, tracer, category="serve"):
+                self.metrics.on_gauges(queue_depth=len(self._queue),
+                                       live=self.engine.live_count,
+                                       capacity=self.engine.slots)
+                pool = self.engine.pool_stats()
+                if pool is not None:
+                    self.metrics.on_pool(
+                        occupancy=pool["occupancy"],
+                        in_use=int(pool["in_use"]),
+                        capacity=int(pool["capacity"]),
+                        cached=int(pool["cached"]),
+                        bytes_per_token=pool["kv_bytes_per_token"])
+            if not self._running:
+                return 0
+            # inside the ITL-measured region on purpose: an injected
+            # delay here lands in the per-token `gap` the SLO engine
+            # samples, and an injected raise still unwinds through
+            # step()'s finalize
+            chaos.fault_point("serve.step", queue_depth=len(self._queue),
+                              live=len(self._running))
+            if self.draft is None:
+                tokens = self.engine.decode()
+                gap = time.perf_counter() - step_start
+                with span(SPAN_RETIRE, tracer, category="serve"):
+                    return self._retire_decoded(tokens, gap)
+            # speculative step: k drafted tokens per slot verified in
+            # ONE [S, k+1] call; each live slot emits accepted+1 tokens
+            # (EOS / budget may truncate the span — the engine slot is
+            # retired then, so the overshoot never lands anywhere).
+            drafts = self.draft.propose()
+            out, accepted = self.engine.decode_speculative(drafts)
             gap = time.perf_counter() - step_start
-            emitted = 0
-            for slot, request in list(self._running.items()):
-                kept, finished = self._feed(slot, request,
-                                            [int(tokens[slot])], gap)
-                emitted += kept
-                if not finished and self.tracing is not None:
-                    self.tracing.on_step_tokens(request, kept)
-            return emitted
+            with span(SPAN_RETIRE, tracer, category="serve"):
+                return self._retire_verified(drafts, out, accepted, gap)
 
-        # speculative step: k drafted tokens per slot verified in ONE
-        # [S, k+1] call; each live slot emits accepted+1 tokens (EOS /
-        # budget may truncate the span — the engine slot is retired
-        # then, so the overshoot never lands anywhere).
-        drafts = self.draft.propose()
-        out, accepted = self.engine.decode_speculative(drafts)
-        gap = time.perf_counter() - step_start
+    def _retire_decoded(self, tokens: np.ndarray, gap: float) -> int:
+        emitted = 0
+        for slot, request in list(self._running.items()):
+            kept, finished = self._feed(slot, request,
+                                        [int(tokens[slot])], gap)
+            emitted += kept
+            if not finished and self.tracing is not None:
+                self.tracing.on_step_tokens(request, kept)
+        return emitted
+
+    def _retire_verified(self, drafts: np.ndarray, out: np.ndarray,
+                         accepted: np.ndarray, gap: float) -> int:
         emitted = 0
         accepted_counts: tp.List[int] = []
         for slot, request in list(self._running.items()):
-            span = out[slot, :int(accepted[slot]) + 1]
+            tokens = out[slot, :int(accepted[slot]) + 1]
             accepted_counts.append(int(accepted[slot]))
-            kept, finished = self._feed(slot, request, span, gap)
+            kept, finished = self._feed(slot, request, tokens, gap)
             emitted += kept
             if not finished:
                 if self.tracing is not None:
                     self.tracing.on_step_tokens(
                         request, kept, accepted=int(accepted[slot]))
-                self.draft.observe(slot, span[:kept],
+                self.draft.observe(slot, tokens[:kept],
                                    self.engine.slot_length(slot))
         self.metrics.on_spec_step(drafted=int(drafts.shape[1]),
                                   accepted=accepted_counts,

@@ -122,13 +122,19 @@ def configure_compile_cache() -> str:
     location so every working directory and every process of one
     checkout share it. Returns the directory in use.
 
-    Either way MLIR locations stop carrying the full Python call stack:
-    a Pallas kernel's locations are serialized INTO the program (the
+    Either way MLIR locations stop carrying the Python call stack: a
+    Pallas kernel's locations are serialized INTO the program (the
     Mosaic body rides the custom call as bytes the cache key hashes), so
-    with full tracebacks the same train step compiled from two callers
-    gets two cache keys and never hits.
+    with the callers' frames the same train step compiled from two
+    callers gets two cache keys and never hits. One frame stays, the
+    op's own: with tracebacks switched off altogether jax 0.9.0 nests
+    the name stack under the primitive's name and XLA keeps only the
+    outer one, so every `jax.named_scope` and Flax module path would be
+    missing from the HLO `op_name` (and from a device trace) — measured
+    on the v5e: `dot_general` instead of `jit(decode_paged)/qkv/dot_general`.
     """
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

@@ -1,0 +1,385 @@
+# The span primitive (`observability.span`) and what it is used for:
+# the span tree of one scheduler step on the profiler's clock, the named
+# scopes of the decode and train programs, and the kernels' names. None
+# of this needs a TPU: `jax.profiler.TraceAnnotation` is replaced by a
+# recorder, scopes are read from lowered text, kernel names from jaxprs.
+import re
+
+import numpy as np
+import pytest
+
+from flashy_tpu.analysis.telemetry_names import TRACK_RE
+from flashy_tpu.observability import Tracer, span
+from flashy_tpu.serve import ContinuousBatchingScheduler, DecodeEngine
+from flashy_tpu.serve import engine as engine_lib, scheduler as scheduler_lib
+
+DECODE_SCOPES = ("embed", "norm", "qkv", "rotary", "kv_write", "attn",
+                 "out_proj", "mlp", "head", "sample")
+SPAN_NAMES = (
+    scheduler_lib.SPAN_STEP, scheduler_lib.SPAN_ADMISSION,
+    scheduler_lib.SPAN_GAUGES, scheduler_lib.SPAN_RETIRE,
+    engine_lib.SPAN_TABLE_UPLOAD, engine_lib.SPAN_PREFILL,
+    engine_lib.SPAN_PREFILL_CHUNK,
+    engine_lib.SPAN_PREFILL_CHUNK + engine_lib.SPAN_READBACK,
+    engine_lib.SPAN_DECODE,
+    engine_lib.SPAN_DECODE + engine_lib.SPAN_DISPATCH,
+    engine_lib.SPAN_DECODE + engine_lib.SPAN_READBACK,
+    engine_lib.SPAN_VERIFY,
+    engine_lib.SPAN_VERIFY + engine_lib.SPAN_DISPATCH,
+    engine_lib.SPAN_VERIFY + engine_lib.SPAN_READBACK)
+
+
+class Recorder:
+    """Stands in for jax.profiler.TraceAnnotation: every span entered,
+    as (depth, name, stats), and the stack of spans still open."""
+    entered: list = []
+    open: list = []
+
+    def __init__(self, name, **stats):
+        self.name, self.stats = name, stats
+
+    def __enter__(self):
+        Recorder.entered.append((len(Recorder.open), self.name, self.stats))
+        Recorder.open.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        assert Recorder.open.pop() == self.name
+        return False
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    import jax
+    Recorder.entered, Recorder.open = [], []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return Recorder
+
+
+def _tiny_model(remat=False):
+    import jax
+    import jax.numpy as jnp
+    from flashy_tpu.models import TransformerConfig, TransformerLM
+
+    cfg = TransformerConfig(vocab_size=32, dim=16, num_layers=2, num_heads=2,
+                            attention="dense", max_seq_len=32,
+                            dtype=jnp.float32, remat=remat)
+    model = TransformerLM(cfg)
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.ones((1, 4), jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def paged():
+    """A warm toy paged engine (chunk 4, two slots) and its scheduler."""
+    model, params = _tiny_model()
+    engine = DecodeEngine(model, params, slots=2, cache_layout="paged",
+                          block_size=4, chunk=4)
+    engine.warmup()
+    return engine
+
+
+def _tree(entered, step):
+    """[(depth, name)] of the spans of scheduler step number `step`."""
+    starts = [i for i, (depth, name, _) in enumerate(entered)
+              if name == scheduler_lib.SPAN_STEP]
+    end = starts[step + 1] if step + 1 < len(starts) else len(entered)
+    return [(depth, name) for depth, name, _ in entered[starts[step]:end]]
+
+
+def _drain(scheduler):
+    while not scheduler.idle:
+        scheduler.step()
+
+
+def test_scheduler_step_span_tree(recorder, paged):
+    """One request of 6 prompt tokens through chunk 4: slice, final
+    slice + first decode, decode — the tree of each step, exactly."""
+    scheduler = ContinuousBatchingScheduler(paged)
+    scheduler.submit(np.arange(1, 7, dtype=np.int32), 3)
+    emitted = [scheduler.step() for _ in range(3)]
+    assert emitted == [0, 1, 1] and scheduler.idle
+    head = [(0, "serve/step"), (1, "serve/admission")]
+    decode = [(1, "serve/decode"), (2, "serve/decode/dispatch"),
+              (2, "serve/decode/readback"), (1, "serve/retire")]
+    # step 0: admitted (tables changed), first slice, nothing decodes yet
+    assert _tree(recorder.entered, 0) == head + [
+        (1, "serve/prefill_chunk"), (2, "serve/table_upload"),
+        (1, "serve/gauges")]
+    # step 1: final slice reads the first token back; the slot decodes
+    assert _tree(recorder.entered, 1) == head + [
+        (1, "serve/prefill_chunk"), (2, "serve/prefill_chunk/readback"),
+        (1, "serve/gauges")] + decode
+    # step 2: decode only; the last token retires the request
+    assert _tree(recorder.entered, 2) == head + [(1, "serve/gauges")] + decode
+    stats = [s for _, name, s in recorder.entered if name == "serve/step"]
+    assert [s["step"] for s in stats] == [scheduler.steps - 3 + i
+                                          for i in range(3)]
+    assert (stats[0]["queued"], stats[1]["prefilling"],
+            stats[2]["running"]) == (1, 1, 1)
+    assert not recorder.open
+
+
+def test_decode_running_stat_is_the_tokens_emitted(recorder, paged):
+    scheduler = ContinuousBatchingScheduler(paged)
+    scheduler.submit(np.arange(1, 4, dtype=np.int32), 4)
+    scheduler.submit(np.arange(2, 5, dtype=np.int32), 2)
+    per_step = []
+    while not scheduler.idle:
+        before = len(recorder.entered)
+        emitted = scheduler.step()
+        running = [s["running"] for _, name, s in recorder.entered[before:]
+                   if name == "serve/decode"]
+        per_step.append((emitted, running))
+    assert any(emitted == 2 for emitted, _ in per_step)
+    for emitted, running in per_step:
+        # `live` counts acquired slots; `running` those that emit a token
+        assert running == ([emitted] if emitted else [])
+
+
+def test_prefill_slices_carry_the_request_uid(recorder, paged):
+    scheduler = ContinuousBatchingScheduler(paged)
+    # prompts no other test used: the shared engine's prefix cache
+    # would serve a known first block and skip its slice
+    first = scheduler.submit(np.arange(20, 29, dtype=np.int32), 1)
+    second = scheduler.submit(np.arange(12, 15, dtype=np.int32), 1)
+    _drain(scheduler)
+    slices = [s for _, name, s in recorder.entered
+              if name == "serve/prefill_chunk"]
+    assert [s["uid"] for s in slices] == [first.uid] * 3 + [second.uid]
+    assert [s["offset"] for s in slices] == [0, 4, 8, 0]
+    assert [bool(s["final"]) for s in slices] == [False, False, True, True]
+    assert all({"slot", "size", "length"} <= set(s) for s in slices)
+
+
+def test_table_upload_only_after_the_tables_changed(recorder, paged):
+    scheduler = ContinuousBatchingScheduler(paged)
+    scheduler.submit(np.arange(1, 4, dtype=np.int32), 6)
+    uploads = []
+    while not scheduler.idle:
+        before = len(recorder.entered)
+        scheduler.step()
+        uploads.append(sum(name == "serve/table_upload"
+                           for _, name, _ in recorder.entered[before:]))
+    # admission dirtied the tables once (the first step prefills and
+    # decodes); four more decode steps reuse the device copy;
+    # retirement dirties them for whoever comes next
+    assert uploads == [1, 0, 0, 0, 0]
+    scheduler.submit(np.arange(1, 4, dtype=np.int32), 1)
+    scheduler.step()
+    upload = [s for _, name, s in recorder.entered
+              if name == "serve/table_upload"][-1]
+    assert upload["bytes"] == paged._table_host.nbytes
+
+
+def test_a_step_that_raises_leaves_no_span_open(recorder, paged, monkeypatch):
+    scheduler = ContinuousBatchingScheduler(paged)
+    scheduler.submit(np.arange(1, 4, dtype=np.int32), 4)
+    scheduler.step()
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setitem(paged.compile_cache._fns,
+                        paged._key("decode", paged.slots), broken)
+    with pytest.raises(RuntimeError, match="device lost"):
+        scheduler.step()
+    assert not recorder.open
+    names = [name for _, name, _ in recorder.entered]
+    assert names[-1] == "serve/decode/dispatch"
+    monkeypatch.undo()
+    for slot in list(paged.allocator.live):
+        paged.retire(slot)
+
+
+def test_span_mirrors_name_and_stats_into_a_tracer(recorder):
+    tracer = Tracer()
+    with span("unit/outer", tracer, category="serve", slot=3) as got:
+        with span("unit/inner", tracer):
+            pass
+    assert got is tracer
+    events = [e for e in tracer.events if e["ph"] == "X"]
+    assert [(e["name"], e["cat"], e["args"]) for e in events] == [
+        ("unit/inner", "host", {}), ("unit/outer", "serve", {"slot": 3})]
+    assert events[1]["dur"] >= events[0]["dur"] >= 0
+    assert recorder.entered == [(0, "unit/outer", {"slot": 3}),
+                                (1, "unit/inner", {})]
+    # Tracer.span is the same primitive
+    with tracer.span("unit/method", category="data", n=1):
+        pass
+    assert recorder.entered[-1] == (0, "unit/method", {"n": 1})
+    assert tracer.events[-1]["args"] == {"n": 1}
+
+
+def test_span_without_a_tracer_records_nothing(recorder):
+    from flashy_tpu.observability import get_telemetry
+    assert get_telemetry() is None
+    with span("unit/alone", size=2) as got:
+        pass
+    assert got is None
+    assert recorder.entered == [(0, "unit/alone", {"size": 2})]
+
+
+def test_span_falls_back_to_the_active_telemetry(recorder, tmp_path):
+    from flashy_tpu.observability import disable_telemetry, enable_telemetry
+    telemetry = enable_telemetry(tmp_path, with_device_stats=False)
+    try:
+        with span("unit/global", k=1) as got:
+            pass
+        assert got is telemetry.tracer
+        assert telemetry.tracer.events[-1]["name"] == "unit/global"
+    finally:
+        disable_telemetry()
+
+
+def test_span_really_enters_the_profilers_annotation():
+    """Unpatched: the real TraceAnnotation accepts the stats we pass."""
+    with span("unit/real", slot=1, final=True, bytes=256):
+        pass
+
+
+@pytest.mark.parametrize("name", SPAN_NAMES)
+def test_span_names_follow_the_track_convention(name):
+    assert TRACK_RE.match(name), name
+
+
+def _decode_text(layout):
+    import jax.numpy as jnp
+    model, params = _tiny_model()
+    extra = ({"cache_layout": "paged", "block_size": 4, "chunk": 4}
+             if layout == "paged" else {})
+    engine = DecodeEngine(model, params, slots=2, **extra)
+    step = engine._build_decode()
+    return step.lower(engine._params, engine._cache, *engine._layout_args(),
+                      engine._tokens, engine._positions, engine._active,
+                      jnp.zeros((2,), jnp.uint32)).as_text(debug_info=True)
+
+
+def _train_text():
+    from examples.lm.solver import LMSolver
+    from flashy_tpu.xp import Config, temporary_xp
+    cfg = Config({
+        "model": {"vocab_size": 64, "dim": 32, "num_layers": 2,
+                  "num_heads": 2, "mlp_ratio": 2, "attention": "dense",
+                  "remat": True},
+        "mesh": {"data": -1}, "seq_len": 16, "batch_size": 8, "loss": "chunked",
+        "loss_chunk": 8, "accumulate": 1, "steps_per_epoch": 2, "epochs": 1,
+        "generate_every": 0, "lr": 1e-2, "warmup_steps": 1,
+        "weight_decay": 0.0})
+    with temporary_xp():
+        solver = LMSolver(cfg)
+        return solver._train_step.lower(
+            solver.state, solver.batch_at(0)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("paged", DECODE_SCOPES), ("dense", DECODE_SCOPES),
+    # the model's Flax module paths must survive nn.remat, forward and
+    # backward; `loss` and `optimizer` are the solver's own scopes
+    # (under value_and_grad: jvp(loss) forward, transpose(jvp(loss)) back)
+    ("train", ("block_0/attn/qkv", "block_1/mlp/up", "block_0/norm1",
+               "norm_f", "jvp(loss)", "transpose(jvp(loss))", "optimizer",
+               "rematted_computation/block_0/attn"))])
+def test_lowered_programs_carry_the_named_scopes(program, scopes):
+    text = _train_text() if program == "train" else _decode_text(program)
+    for scope in scopes:
+        assert re.search(f'/{re.escape(scope)}[/"]', text), (program, scope)
+    if program == "train":
+        backward = [line for line in text.splitlines()
+                    if "transpose(" in line and "/attn/" in line]
+        assert backward, "attention's backward lost its module path"
+
+
+def test_compile_cache_setup_keeps_scopes_and_one_key_per_program(
+        monkeypatch, tmp_path):
+    """`configure_compile_cache` leaves ONE traceback frame in MLIR
+    locations: with none, XLA's HLO `op_name` loses every named scope
+    (what a device trace shows); with all, the lowered text — and so
+    the persistent cache's key — depends on who called."""
+    import jax
+    import jax.numpy as jnp
+    from flashy_tpu.utils import configure_compile_cache
+    flags = ("jax_include_full_tracebacks_in_locations",
+             "jax_traceback_in_locations_limit")
+    saved = {flag: getattr(jax.config, flag) for flag in flags}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+    def step(x):
+        with jax.named_scope("qkv"):
+            return jnp.dot(x, x)
+
+    def lower():
+        jax.clear_caches()
+        return jax.jit(step).lower(jnp.ones((8, 8)))
+
+    def another_caller():
+        return (lambda: [lower() for _ in range(1)][0])()
+
+    try:
+        configure_compile_cache()
+        direct, nested = lower(), another_caller()
+        assert (direct.as_text(debug_info=True)
+                == nested.as_text(debug_info=True))
+        assert 'op_name="jit(step)/qkv/dot_general"' in (
+            direct.compile().as_text())
+    finally:
+        for flag, value in saved.items():
+            jax.config.update(flag, value)
+
+
+def _kernel_names(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _kernel_names(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("fused,names", [
+    (True, ["flash_fwd", "flash_bwd_fused"]),
+    (False, ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])])
+def test_flash_kernels_carry_their_names(fused, names):
+    import jax
+    import jax.numpy as jnp
+    from flashy_tpu.ops.attention import flash_attention
+    q = jnp.ones((1, 256, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               fused_backward=fused).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    assert _kernel_names(jaxpr.jaxpr, []) == names
+
+
+def test_paged_decode_kernel_carries_its_name():
+    import jax
+    import jax.numpy as jnp
+    model, params = _tiny_model()
+    engine = DecodeEngine(model, params, slots=2, cache_layout="paged",
+                          block_size=4, chunk=4, kernel="fused")
+    jaxpr = jax.make_jaxpr(engine._build_decode())(
+        engine._params, engine._cache, *engine._layout_args(),
+        engine._tokens, engine._positions, engine._active,
+        jnp.zeros((2,), jnp.uint32))
+    assert _kernel_names(jaxpr.jaxpr, []) == ["paged_decode_fused"] * 2
+
+
+def test_every_pallas_call_in_the_package_is_named():
+    """A kernel without `name=` shows up in a device trace as an
+    anonymous `tpu_custom_call` a reader can only find by shape."""
+    import ast
+    import pathlib
+    import flashy_tpu
+    unnamed = []
+    for path in pathlib.Path(flashy_tpu.__file__).parent.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "attr", None) == "pallas_call"
+                    and not any(k.arg == "name" for k in node.keywords)):
+                unnamed.append(f"{path.name}:{node.lineno}")
+    assert not unnamed, unnamed
