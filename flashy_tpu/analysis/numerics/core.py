@@ -105,6 +105,10 @@ DATA_MOVEMENT_PRIMS = frozenset({
     # REF_WRITE_PRIMS), so a value's identity survives a
     # write-then-read round trip through scratch.
     "get", "swap",
+    # an async copy moves a window of one ref into another (HBM pool
+    # block -> VMEM tile); ValueGraph rewires it source ref -> written
+    # ref (see _dma_endpoints)
+    "dma_start",
 })
 
 # Ref-mutating primitives (pallas kernel bodies): the written ref is
@@ -183,6 +187,13 @@ class ValueGraph:
         table.setdefault(src, []).append(dst)
         if not loop:
             self.bwd_alias.setdefault(dst, []).append(src)
+            if _is_ref(src[0]) and _is_ref(dst[0]) \
+                    and dst not in self.bwd_alias.get(src, ()):
+                # a memory ref is ONE buffer on both sides of a call
+                # boundary (kernel body -> loop body -> pl.when branch):
+                # a write made inside is read outside and in sibling
+                # bodies, so the alias runs both ways
+                self._alias(dst, src)
 
     def _walk(self, jaxpr: tp.Any, context: str) -> None:
         for eqn in jaxpr.eqns:
@@ -193,6 +204,13 @@ class ValueGraph:
             self.eqns.append(eqn)
             ins = [(v, context) for v in eqn.invars if not _is_literal(v)]
             outs = [(v, context) for v in eqn.outvars]
+            if name == "dma_start":
+                # the copy's invars are [src ref, its indices, dst ref,
+                # its indices, semaphores...]: for dataflow it reads the
+                # source ref and WRITES the destination ref — later
+                # `get`s of the destination see the source's content
+                src, dst = _dma_endpoints(eqn)
+                ins, outs = [(src, context)], [(dst, context)]
             if name in REF_WRITE_PRIMS and ins:
                 # the mutated ref (operand 0) is a dataflow OUTPUT:
                 # later reads of the ref see the stored value. The
@@ -387,6 +405,18 @@ class ValueGraph:
 
 def _is_literal(var: tp.Any) -> bool:
     return hasattr(var, "val") and not hasattr(var, "count")
+
+
+def _is_ref(var: tp.Any) -> bool:
+    return hasattr(getattr(var, "aval", None), "inner_aval")
+
+
+def _dma_endpoints(eqn: tp.Any) -> tp.Tuple[tp.Any, tp.Any]:
+    """(source ref var, destination ref var) of a pallas `dma_start`."""
+    import jax
+
+    parts = jax.tree_util.tree_unflatten(eqn.params["tree"], eqn.invars)
+    return parts[0], parts[2]
 
 
 def _unwrap(sub: tp.Any) -> tp.Any:

@@ -9,15 +9,40 @@
 # that read path as ONE Pallas TPU kernel:
 #
 #  * The per-slot block table `[max_blocks]` and the base positions are
-#    SCALAR-PREFETCH operands (SMEM): the grid iterates physical table
-#    entries directly, and each entry's BlockSpec index map reads
-#    `table[slot, entry]` to aim the next pipelined DMA at the physical
-#    pool block — no gathered copy, no logical view, exactly one HBM
-#    read per live block. Entries past the slot's causal horizon are
-#    clamped onto the last live block in the index map (the pipeline
-#    skips the re-fetch of an unchanged block) and their compute is
-#    `pl.when`-skipped, so a short slot in a long table costs its live
-#    blocks, not its table width.
+#    SCALAR-PREFETCH operands (SMEM) and the pools never leave HBM as
+#    operands: the kernel copies the physical blocks itself. One grid
+#    step is one (slot, head block, query tile) and holds the whole
+#    walk: an in-kernel loop whose every step attends a GROUP of pool
+#    blocks (16 x 16 tokens at T=1) — their async copies, aimed by
+#    `table[slot, step * group + g]`, land in one half of a
+#    double-buffered VMEM tile while the other half is attended — and
+#    whose trip count is `ceil(live_blocks / group)` from the slot's
+#    base position. No gathered copy, no logical view, one HBM read per
+#    live block, and no step at all for table entries past the horizon:
+#    a short slot in a long table costs its live blocks (the chip's
+#    word on that: PERF.md, PR 26 — the cost of this read is per step,
+#    not per byte). A parked slot walks one block.
+#  * Few query rows (decode, speculative verify) attend in a FLAT
+#    layout: the `[group * bs, H, Dh]` tile is read as the pool stores
+#    it, `[group * bs * H, Dh]`, and one 2-D dot of the `[T * H, Dh]`
+#    queries against it gives every (query head, key head) pair, of
+#    which the mask keeps the same-head ones. The spare MXU columns are
+#    free at T=1 (the MXU waits on weight loads either way); what it
+#    buys is no K/V relayout and H rows a pass instead of one. A prefill
+#    chunk has the rows already and takes the per-head batched dot (the
+#    tile transposed to `[H, group * bs, Dh]`), its T rows split into
+#    query tiles when the score tile would outgrow VMEM. `walk_shape`
+#    picks group, query tile and layout from the shapes alone, under an
+#    explicit VMEM budget.
+#  * A copy the kernel issues needs whole (8, 128) tiles (Mosaic), so
+#    pools of narrower heads (`head_dim % 128 != 0`) or of fewer than 8
+#    heads a step keep the walk the GRID makes: one block a grid step through a BlockSpec index map
+#    that reads the table, every table entry a step, dead ones clamped
+#    and skipped. For the same reason int8 scales reach the kernel as
+#    lane-dense `[bs * H]` rows copied beside their block (flat layout)
+#    or gathered through the table beforehand, a step's `[H, keys]`
+#    slab at a time (per-head layout). Same arithmetic in all of them
+#    (`_attend_tile`).
 #  * int8 pools dequantize IN the kernel under the FT203 scale-folding
 #    identity: the per-(row, head) K scales multiply the SCORES between
 #    the q.k contraction and the softmax, the V scales multiply the
@@ -28,7 +53,7 @@
 #    program (models/audit.py registers it; `make analyze-numerics`),
 #    so a rewrite that double-, un- or wrong-side-scales fails CI
 #    before it ever decodes garbage.
-#  * Online softmax across table entries (the ops/attention.py
+#  * Online softmax across the walk's steps (the ops/attention.py
 #    recurrence: running max / normalizer / f32 accumulator in VMEM
 #    scratch), so the [T, max_len] score matrix never exists.
 #
@@ -66,6 +91,11 @@ from .paged_attention import paged_attention
 
 NEG_INF = -1e30
 LANES = 128  # native f32 lane width; row stats ride it (attention.py)
+SUBLANES = 8
+VMEM_BUDGET = 16 * 2 ** 20  # scoped VMEM of one kernel on the v5e
+KEYS_PER_STEP = 256   # keys one compute step attends at T=1
+QUERY_TILE = 128      # query rows a grid step keeps when T must split
+FLAT_ROWS = 128       # at most this many (query, head) rows: flat layout
 
 
 def fused_kernel_unsupported_reason() -> tp.Optional[str]:
@@ -94,15 +124,11 @@ def default_kernel() -> str:
 
 
 def _default_head_block(num_heads: int, quantized: bool = False) -> int:
-    """Largest power-of-two divisor of H not above 8 — enough rows
-    (H*T) to fill a sublane tile at T=1 without blowing the VMEM
-    scratch at long T, and power-of-two so the row block lands on the
-    8-sublane tile boundary instead of forcing pad rows per grid step.
-
-    int8 pools take every head in one block: their `[block_size, H]`
-    scale blocks carry the heads in the LANE position, where Mosaic
-    accepts only the whole dimension (or a multiple of 128) — a
-    head_block of 8 out of 16 heads is refused at lowering."""
+    """Heads per grid step when nothing was tuned. int8 pools take every
+    head in one step: their `[block_size, H]` scale rows carry the heads
+    in the LANE position, where a copy window must be the whole dimension.
+    Dense pools take the largest power-of-two divisor of H not above 8,
+    so the row block lands on the 8-sublane tile boundary."""
     if quantized:
         return num_heads
     cand = 8
@@ -111,158 +137,467 @@ def _default_head_block(num_heads: int, quantized: bool = False) -> int:
     return cand
 
 
-def _fused_body(base_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                acc_scr, k_scale_ref, v_scale_ref, *, block_size: int,
-                queries: int, head_block: int, head_dim: int,
-                scale: float):
-    """One (slot, head-block, table-entry) grid step.
+class Walk(tp.NamedTuple):
+    """How one call walks a slot's block table — all from shapes."""
+    group: int        # pool blocks one compute step attends
+    head_block: int   # heads per grid step
+    query_tile: int   # query rows per grid step
+    flat: bool        # few rows: all heads in one 2-D score tile
+    dma: bool         # the kernel fetches the blocks itself (else the
+                      # grid does, one block a grid step)
 
-    The entry axis iterates fastest, so for a fixed (slot, head block)
-    the VMEM scratch (running max / normalizer / f32 accumulator,
-    rows = head_block * queries) carries the online-softmax state
-    across the slot's physical blocks; output lands on the final
-    entry. Rows with no visible key yet keep the _guarded_probs
-    convention (attention.py): exp is forced to zero while the running
-    max still sits at ~NEG_INF.
+
+def _vmem_estimate(queries: int, head_block: int, head_dim: int,
+                   block_size: int, group: int, *, flat: bool,
+                   pool_itemsize: int, q_itemsize: int) -> int:
+    """Bytes of scoped VMEM one grid step of `_dma_walk_body` needs,
+    counted from its own buffers: the pipelined q and o blocks, the
+    softmax state, the double-buffered K/V tiles of `group` pool blocks,
+    their upcast copies, the scale rows, and the score-shaped f32
+    temporaries (scores, probs, the mask, the select). An estimate to
+    choose by — Mosaic's own count is what refuses."""
+    rows = queries * head_block
+    keys = group * block_size
+    cols = keys * head_block if flat else keys
+    tile = keys * head_block * head_dim
+    sublane_pad = 2 if pool_itemsize == 1 and head_block < 32 else 1
+    total = 2 * 2 * rows * head_dim * q_itemsize          # q, o blocks
+    total += rows * (2 * LANES + head_dim) * 4            # m, l, acc
+    total += 2 * 2 * tile * pool_itemsize * sublane_pad   # K, V tiles x2
+    total += 2 * tile * (4 + q_itemsize)                  # upcast K, V
+    total += 2 * 2 * 8 * max(cols, LANES) * 4             # scale rows
+    total += 4 * rows * cols * 4                          # score-shaped
+    return total
+
+
+def walk_shape(queries: int, heads: int, head_dim: int, block_size: int,
+               entries: int, *, quantized: bool, pool_itemsize: int,
+               q_itemsize: int, head_block: tp.Optional[int] = None
+               ) -> Walk:
+    """The table walk of one call, from its shapes alone.
+
+    A copy the kernel issues itself needs whole (8, 128) tiles of the
+    pool's `[head_block, head_dim]` rows: pools of narrower heads, or of
+    fewer than 8 heads a step, keep the grid's walk (one block a grid
+    step, every table entry a step).
+
+    Otherwise decode (T=1) wants many blocks a step — its cost is per
+    step, not per byte — so the group grows until a step attends
+    `KEYS_PER_STEP` keys. A prefill chunk (T=256, 512) has the rows to
+    fill the MXU already and its `[hb, tq, group * bs]` score tile is
+    what grows, so the query tile halves first (each tile walks its own
+    causal prefix) and then the group, until `_vmem_estimate` fits
+    `VMEM_BUDGET`. The group is at least 1; a table it does not divide
+    ends in a partial last group.
     """
-    slot = pl.program_id(0)
-    entry = pl.program_id(2)
-    entries = pl.num_programs(2)
+    if head_block is None:
+        head_block = _default_head_block(heads, quantized)
+    if head_dim % LANES or head_block % SUBLANES:
+        return Walk(1, head_block, queries, False, False)
+
+    def flat(tile):
+        # few (query, head) rows, and an int8 pool's scale rows whole
+        # and lane-dense: `[bs * H]` is then one copy window per block
+        return tile * head_block <= FLAT_ROWS and (
+            not quantized or (head_block == heads
+                              and (block_size * heads) % LANES == 0))
+
+    def fits(group, tile):
+        return _vmem_estimate(
+            tile, head_block, head_dim, block_size, group, flat=flat(tile),
+            pool_itemsize=pool_itemsize, q_itemsize=q_itemsize
+        ) <= VMEM_BUDGET
+
+    group = max(1, min(KEYS_PER_STEP // block_size, entries))
+    tile = queries
+    while not fits(group, tile):
+        if tile > QUERY_TILE and tile % 2 == 0:
+            tile //= 2
+        elif group > 1:
+            group //= 2
+        elif tile > 8 and tile % 2 == 0:
+            tile //= 2
+        else:
+            break  # the smallest walk there is; Mosaic has the last word
+    return Walk(group, head_block, tile, flat(tile), True)
+
+
+def call_walk(batch: int, queries: int, heads: int, head_dim: int, *,
+              block_size: int, entries: int, quantized: bool, dtype,
+              head_block: tp.Optional[int] = None) -> Walk:
+    """The walk `fused_paged_attention` takes for a call of these shapes
+    (the engine asks too, for its `kv_steps` counter): `walk_shape` at
+    the tuned `head_block` when `ops.tuning.tune_paged_blocks` has
+    recorded one for this device, else at the default."""
+    if head_block is None:
+        from .tuning import lookup_tuned_paged_blocks
+        head_block = lookup_tuned_paged_blocks(
+            batch, queries, heads, head_dim, block_size=block_size,
+            entries=entries, quantized=quantized, dtype=dtype)
+        if head_block is not None and heads % head_block:
+            # a corrupt cache entry: keep the default — a tuned pick
+            # must never be able to break correctness
+            head_block = None
+    itemsize = jnp.dtype(dtype).itemsize
+    return walk_shape(queries, heads, head_dim, block_size, entries,
+                      quantized=quantized, q_itemsize=itemsize,
+                      pool_itemsize=1 if quantized else itemsize,
+                      head_block=head_block)
+
+
+def _live_blocks(base, last, block_size: int, entries: int, xp):
+    """Table entries a walk attends for query rows `base..last`: the
+    blocks up to the last row's horizon, clamped into the table. A parked
+    slot (`base` at the table's end: the engine parks idle slots at
+    max_seq_len) has no context; it walks one block — garbage like its
+    whole all-sentinel view was, discarded by the engine's active mask.
+    One formula for the kernel (jnp scalars) and the host's counters
+    (numpy arrays)."""
+    live = xp.clip(last // block_size + 1, 1, entries)
+    return xp.where(base >= entries * block_size, 1, live)
+
+
+def walk_counts(bases, queries: int, walk: Walk, block_size: int,
+                entries: int) -> tp.Tuple[int, int]:
+    """(kv_blocks, kv_steps) of one call and layer, on the host: pool
+    blocks the walk attends and compute steps it runs for them, summed
+    over the slots whose first query positions are `bases` (and over a
+    split call's query tiles). Their ratio nears `walk.group` when the
+    contexts are long beside a group; a step of the grid's walk is one
+    block."""
+    bases = np.asarray(bases, np.int64)
+    blocks = steps = 0
+    for rows in range(walk.query_tile, queries + 1, walk.query_tile):
+        last = bases + rows - 1  # each query tile walks its own prefix
+        if walk.dma:
+            live = _live_blocks(bases, last, block_size, entries, np)
+        else:  # the grid's walk clamps parked slots to the table's end
+            live = np.clip(last // block_size + 1, 1, entries)
+        blocks += int(live.sum())
+        steps += int((-(-live // walk.group)).sum())
+    return blocks, steps
+
+
+def _attend_tile(q, k, v, k_scale, v_scale, visible, m_scr, l_scr,
+                 acc_scr, *, flat: bool, scale: float):
+    """One online-softmax step over a `[keys, hb, Dh]` K/V tile.
+
+    Per head (`flat=False`): `q` is `[hb, T, Dh]`, the tile transposes
+    to `[hb, keys, Dh]` and the scores are `[hb, T, keys]` — a batched
+    dot with the heads as batch. Flat: `q` is `[T * hb, Dh]`, the tile
+    is read as the pool stores it, `[keys * hb, Dh]`, and ONE 2-D dot
+    gives `[T * hb, keys * hb]` scores of which `visible` keeps the
+    same-head ones: no K/V relayout, and T * hb rows on the MXU instead
+    of T. `k_scale` / `v_scale` broadcast against the scores (None for
+    dense pools), `visible` is the ONE mask. State (running max,
+    normalizer, f32 accumulator) is `[rows, ...]` VMEM scratch; rows
+    with no visible key yet keep the _guarded_probs convention
+    (attention.py): exp is forced to zero while the running max still
+    sits at ~NEG_INF.
+    """
+    keys, hb, dim = k.shape
+    quant = k_scale is not None
+    if flat:
+        contract = (((1,), (1,)), ((), ()))
+        kq = k.reshape(keys * hb, dim)
+    else:
+        contract = (((2,), (2,)), ((0,), (0,)))
+        kq = k.transpose(1, 0, 2)
+    scores = jax.lax.dot_general(q, kq.astype(q.dtype), contract,
+                                 preferred_element_type=jnp.float32) * scale
+    if quant:
+        # K scales fold into the SCORES pre-softmax — the FT203 placement
+        scores = scores * k_scale
+    scores = jnp.where(visible, scores, NEG_INF)
+
+    rows = scores.reshape(m_scr.shape[0], -1)
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, rows.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    probs = jnp.where(m_new > NEG_INF * 0.5, jnp.exp(rows - m_new), 0.0)
+    l_new = l_scr[:, :1] * alpha + probs.sum(axis=-1, keepdims=True)
+
+    probs = probs.reshape(scores.shape)
+    if quant:
+        # V scales fold into the PROBS post-softmax (FT203); the int8
+        # payload casts up instead of P casting down
+        probs = probs * v_scale
+        mxu = q.dtype
+    else:
+        # P cast to V's dtype for the MXU fast path (attention.py)
+        mxu = v.dtype
+    if flat:
+        contract = (((1,), (0,)), ((), ()))
+        vq = v.reshape(keys * hb, dim)
+    else:
+        contract = (((2,), (1,)), ((0,), (0,)))
+        vq = v.transpose(1, 0, 2)
+    pv = jax.lax.dot_general(probs.astype(mxu), vq.astype(mxu), contract,
+                             preferred_element_type=jnp.float32)
+    acc_scr[:] = acc_scr[:] * alpha + pv.reshape(acc_scr.shape)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _init_state(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _write_out(o_ref, l_scr, acc_scr, flat: bool):
+    _, tq, hb, dim = o_ref.shape
+    out = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+    if flat:
+        out = out.reshape(tq, hb, dim)
+    else:
+        out = out.reshape(hb, tq, dim).transpose(1, 0, 2)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _dma_walk_body(table_ref, base_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
+                   o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
+                   ks_buf=None, vs_buf=None, *, block_size: int,
+                   group: int, entries: int, flat: bool, scale: float):
+    """One (slot, head-block, query-tile) grid step: the whole walk.
+
+    The pools stay in HBM. A compute step attends `group` pool blocks:
+    their async copies land in one half of a double-buffered VMEM tile
+    while the other half is attended, and the loop runs
+    `ceil(live_blocks / group)` times — the slot's live context, not the
+    table's width. The output is written once, after the loop.
+    """
+    slot, head, qtile = (pl.program_id(i) for i in range(3))
+    _, tq, hb, dim = q_ref.shape
+    quant = ks_ref is not None
+    keys = group * block_size
+    all_heads = hb == k_hbm.shape[2]
+
+    first = base_ref[slot] + qtile * tq        # this tile's first q pos
+    live = _live_blocks(base_ref[slot], first + tq - 1, block_size,
+                        entries, jnp)
+    steps = (live + group - 1) // group
+
+    pools = [(k_hbm, k_buf, 0), (v_hbm, v_buf, 1)]
+    if ks_buf is not None:
+        pools += [(ks_ref, ks_buf, 2), (vs_ref, vs_buf, 3)]
+
+    def tile_copies(step, half, start: bool):
+        for g in range(group):
+            # a partial last group re-reads the last live block, never an
+            # entry past the live range (its keys sit past the horizon
+            # and are masked by position like any other); a wait needs
+            # the copy's shape, not its source
+            block = table_ref[slot, jnp.minimum(step * group + g,
+                                                live - 1)] if start else 0
+            for src, dst, sem in pools:
+                src = src.at[block]
+                if not all_heads:
+                    src = src.at[:, pl.ds(head * hb, hb)]
+                copy = pltpu.make_async_copy(src, dst.at[half, g],
+                                             sems.at[sem, half])
+                copy.start() if start else copy.wait()
+
+    _init_state(m_scr, l_scr, acc_scr)
+    tile_copies(0, 0, start=True)
+
+    # loop-invariant: how far each (query row, key column) pair is from
+    # the causal diagonal when the walk is at step 0. The ONE mask —
+    # causal AND sentinel/unassigned (a sentinel entry only covers
+    # positions past the slot's horizon) AND, in the flat layout, "same
+    # head" — is `ahead <= first - step * keys`.
+    if flat:
+        # rows (t, h), columns (block, row-in-block, h)
+        shape = (tq * hb, keys * hb)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        ahead = jnp.where(row % hb == col % hb,
+                          col // hb - row // hb, 2 ** 30)
+        q = q_ref[0].reshape(tq * hb, dim)
+    else:
+        shape = (tq, keys)
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                 - jax.lax.broadcasted_iota(jnp.int32, shape, 0))[None]
+        # [T, hb, Dh] -> [hb, T, Dh]: heads become the dot batch dim
+        q = q_ref[0].transpose(1, 0, 2)
+
+    def scales(ref, buf, step, half):
+        if not quant:
+            return None
+        if flat:
+            # one lane-dense row per block, in the columns' order
+            return jnp.concatenate([buf[half, g] for g in range(group)],
+                                   axis=-1)
+        # the step's [hb, keys] slab of the slot's gathered scales
+        return ref[0, step][:, None, :]
+
+    def attend(step, carry):
+        half = step % 2
+
+        @pl.when(step + 1 < steps)
+        def _prefetch():
+            tile_copies(step + 1, 1 - half, start=True)
+
+        tile_copies(step, half, start=False)
+        _attend_tile(q, k_buf[half].reshape(keys, hb, dim),
+                     v_buf[half].reshape(keys, hb, dim),
+                     scales(ks_ref, ks_buf, step, half),
+                     scales(vs_ref, vs_buf, step, half),
+                     ahead <= first - step * keys, m_scr, l_scr, acc_scr,
+                     flat=flat, scale=scale)
+        return carry
+
+    jax.lax.fori_loop(0, steps, attend, 0)
+    _write_out(o_ref, l_scr, acc_scr, flat)
+
+
+def _grid_walk_body(table_ref, base_ref, q_ref, k_ref, v_ref, ks_ref,
+                    vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                    block_size: int, scale: float):
+    """One (slot, head-block, table-entry) grid step: one pool block.
+
+    The walk of pools whose rows are narrower than a copy window: each
+    entry's BlockSpec index map reads `table[slot, entry]` to aim the
+    pipeline's next copy at the physical block. The entry axis iterates
+    fastest, so the VMEM state carries across a slot's blocks and the
+    output lands on the final entry. Entries past the horizon are
+    clamped onto the last live block by the index map (an unchanged
+    index skips the copy) and skip their arithmetic here, but each
+    still costs a grid step.
+    """
+    del table_ref  # consumed by the index maps, not the body
+    slot, entry = pl.program_id(0), pl.program_id(2)
+    queries = q_ref.shape[1]
+    base = base_ref[slot]
 
     @pl.when(entry == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _init_state(m_scr, l_scr, acc_scr)
 
-    base = base_ref[slot]
+    @pl.when(entry * block_size <= base + queries - 1)
+    def _live():
+        shape = (queries, block_size)
+        visible = (entry * block_size
+                   + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                   <= base + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
 
-    def _accumulate():
-        # [T, hb, Dh] -> [hb, T, Dh]: heads become the dot batch dim
-        qh = q_ref[0].transpose(1, 0, 2)
-        kh = k_ref[0].transpose(1, 0, 2)          # [hb, bs, Dh]
-        scores = jax.lax.dot_general(             # [hb, T, bs], f32
-            qh, kh.astype(qh.dtype), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        if k_scale_ref is not None:
-            # K scales fold into the SCORES pre-softmax — the FT203
-            # placement; [bs, hb] -> [hb, 1, bs] broadcast over queries
-            scores = scores * k_scale_ref[0].transpose(1, 0)[:, None, :]
-        q_pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (queries, block_size), 0)
-        k_pos = entry * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (queries, block_size), 1)
-        # the ONE mask: causal AND sentinel/unassigned (sentinel entries
-        # only cover logical positions beyond the slot's horizon)
-        scores = jnp.where((k_pos <= q_pos)[None], scores, NEG_INF)
+        def scales(ref):  # [bs, hb] -> [hb, 1, bs]
+            return None if ref is None \
+                else ref[0].transpose(1, 0)[:, None, :]
 
-        rows = scores.reshape(head_block * queries, block_size)
-        m_prev = m_scr[:, :1]                     # [rows, 1]
-        m_new = jnp.maximum(m_prev, rows.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.where(m_new > NEG_INF * 0.5,
-                          jnp.exp(rows - m_new), 0.0)
-        l_new = l_scr[:, :1] * alpha + probs.sum(axis=-1, keepdims=True)
-        p3 = probs.reshape(head_block, queries, block_size)
-        if v_scale_ref is not None:
-            # V scales fold into the PROBS post-softmax (FT203)
-            p3 = p3 * v_scale_ref[0].transpose(1, 0)[:, None, :]
-        vh = v_ref[0].transpose(1, 0, 2)          # [hb, bs, Dh]
-        if v_scale_ref is None:
-            # P cast to V's dtype for the MXU fast path (attention.py)
-            p3 = p3.astype(vh.dtype)
-        else:
-            # int8 V: the payload casts up instead (scale already in P)
-            vh = vh.astype(qh.dtype)
-            p3 = p3.astype(qh.dtype)
-        pv = jax.lax.dot_general(                 # [hb, T, Dh]
-            p3, vh, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha \
-            + pv.reshape(head_block * queries, head_dim)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _attend_tile(q_ref[0].transpose(1, 0, 2), k_ref[0], v_ref[0],
+                     scales(ks_ref), scales(vs_ref), visible[None], m_scr,
+                     l_scr, acc_scr, flat=False, scale=scale)
 
-    # entries whose whole block sits past the last query's horizon
-    # contribute nothing — skip their MXU work (their DMA was already
-    # skipped by the index-map clamp onto the last live block)
-    pl.when(entry * block_size <= base + queries - 1)(_accumulate)
-
-    @pl.when(entry == entries - 1)
+    @pl.when(entry == pl.num_programs(2) - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[:, :1], 1e-30)
-        out = (acc_scr[:] / denom).reshape(head_block, queries, head_dim)
-        o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+        _write_out(o_ref, l_scr, acc_scr, False)
 
 
-def _fused_kernel_quant(table_ref, base_ref, q_ref, k_ref, ks_ref, v_ref,
-                        vs_ref, o_ref, m_scr, l_scr, acc_scr, **kw):
-    del table_ref  # consumed by the index maps, not the body
-    _fused_body(base_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                acc_scr, ks_ref, vs_ref, **kw)
+def _kernel(body, quant: bool, **static):
+    """`body` with the pallas_call's positional refs put in its order:
+    table, base, q, then K, V, K scales, V scales (None for dense
+    pools), then the rest."""
+    def kernel(table_ref, base_ref, q_ref, *refs):
+        if quant:
+            k, ks, v, vs, *rest = refs
+        else:
+            (k, v, *rest), ks, vs = refs, None, None
+        body(table_ref, base_ref, q_ref, k, v, ks, vs, *rest, **static)
+    return kernel
 
 
-def _fused_kernel_dense(table_ref, base_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, **kw):
-    del table_ref
-    _fused_body(base_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                acc_scr, None, None, **kw)
+def _step_scales(scales: jax.Array, table: jax.Array, group: int
+                 ) -> jax.Array:
+    """`[N, bs, H]` pool scales -> `[B, steps, H, group * bs]`: each
+    slot's scales gathered through its table and laid out a compute step
+    at a time, heads on sublanes and keys on lanes, the way the per-head
+    score tile wants them. (A pool row of H scales is too narrow a window
+    for the kernel to copy itself; the scales are 3% of the bytes.) A
+    table `group` does not divide is padded with the sentinel."""
+    batch, entries = table.shape
+    steps = -(-entries // group)
+    table = jnp.pad(table, ((0, 0), (0, steps * group - entries)))
+    gathered = scales[table.reshape(batch, steps, group)]
+    return gathered.transpose(0, 1, 4, 2, 3).reshape(
+        batch, steps, scales.shape[-1], group * scales.shape[-2])
 
 
-def _fused_call(q, entry, table, base, *, head_block: int,
-                interpret: bool):
+@functools.partial(jax.jit, static_argnames=("walk", "interpret"))
+def _fused_call(q, entry, table, base, walk: Walk, *, interpret: bool):
+    # jitted so that a model's layers, which all make this call at the
+    # same shapes, trace and lower the kernel once between them: unrolled
+    # over 16 layers the walk's copies were most of an engine's warm-up
+    # (PERF.md, PR 26)
     batch, queries, heads, dim = q.shape
     entries = table.shape[1]
     block_size = entry["k"].shape[-3]
     quant = "k_scale" in entry
+    group, hb, tq, flat, dma = walk
     scale = 1.0 / np.sqrt(dim)
-    hb = head_block
+    state = [pltpu.VMEM((hb * tq, LANES), jnp.float32),  # running max
+             pltpu.VMEM((hb * tq, LANES), jnp.float32),  # normalizer
+             pltpu.VMEM((hb * tq, dim), jnp.float32)]    # accumulator
+    names = ("k", "k_scale", "v", "v_scale") if quant else ("k", "v")
+    operands = [entry[name] for name in names]
 
-    def block_index(b, h, e, table_ref, base_ref):
-        # Clamp dead entries onto the last live block: the pipeline
-        # recognizes an unchanged block index and skips the DMA, so a
-        # slot pays HBM reads for its live blocks only. Parked slots
-        # (base == max_seq_len) clamp to the table's end like the
-        # gather path attends their all-sentinel view — garbage either
-        # way, discarded by the engine's active mask.
-        last = jnp.minimum(
-            jnp.maximum(base_ref[b] + queries - 1, 0) // block_size,
-            entries - 1)
-        return (table_ref[b, jnp.minimum(e, last)], 0, h, 0)
+    if dma:
+        def q_index(b, h, t, *_):
+            return (b, t, h, 0)
 
-    def scale_index(b, h, e, table_ref, base_ref):
-        return block_index(b, h, e, table_ref, base_ref)[:3]
+        grid = (batch, heads // hb, queries // tq)
+        hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+        specs = [hbm] * len(operands)
+        tile = (2, group, block_size, hb, dim)
+        scratch = [pltpu.VMEM(tile, entry["k"].dtype),
+                   pltpu.VMEM(tile, entry["v"].dtype),
+                   pltpu.SemaphoreType.DMA((4, 2))] + state
+        if quant and flat:
+            # one lane-dense row per pool block, in the (row, head) order
+            # of the flat score columns, copied beside its K/V block
+            row = (1, block_size * heads)
+            for i in (1, 3):
+                operands[i] = operands[i].reshape((-1,) + row)
+            scratch += [pltpu.VMEM((2, group) + row, jnp.float32)] * 2
+        elif quant:
+            for i in (1, 3):
+                operands[i] = _step_scales(operands[i], table, group)
+                specs[i] = pl.BlockSpec(
+                    (1, operands[i].shape[1], hb, group * block_size),
+                    lambda b, h, t, *_: (b, 0, h, 0))
+        kernel = _kernel(_dma_walk_body, quant, block_size=block_size,
+                         group=group, entries=entries, flat=flat,
+                         scale=scale)
+    else:
+        def q_index(b, h, e, *_):
+            return (b, 0, h, 0)
 
-    def q_index(b, h, e, *_):
-        return (b, 0, h, 0)
+        def block_index(b, h, e, table_ref, base_ref):
+            # Clamp dead entries onto the last live block: the pipeline
+            # recognizes an unchanged block index and skips the copy.
+            # Parked slots (base == max_seq_len) clamp to the table's
+            # end like the gather path attends their all-sentinel view.
+            last = jnp.minimum(
+                jnp.maximum(base_ref[b] + queries - 1, 0) // block_size,
+                entries - 1)
+            return (table_ref[b, jnp.minimum(e, last)], 0, h, 0)
 
-    in_specs = [pl.BlockSpec((1, queries, hb, dim), q_index),
-                pl.BlockSpec((1, block_size, hb, dim), block_index)]
-    operands = [q, entry["k"]]
-    if quant:
-        in_specs.append(pl.BlockSpec((1, block_size, hb), scale_index))
-        operands.append(entry["k_scale"])
-    in_specs.append(pl.BlockSpec((1, block_size, hb, dim), block_index))
-    operands.append(entry["v"])
-    if quant:
-        in_specs.append(pl.BlockSpec((1, block_size, hb), scale_index))
-        operands.append(entry["v_scale"])
+        grid = (batch, heads // hb, entries)
+        specs = [pl.BlockSpec((1, block_size, hb, dim), block_index)
+                 if operand.ndim == 4 else
+                 pl.BlockSpec((1, block_size, hb),
+                              lambda *args: block_index(*args)[:3])
+                 for operand in operands]
+        scratch = state
+        kernel = _kernel(_grid_walk_body, quant, block_size=block_size,
+                         scale=scale)
 
+    q_spec = pl.BlockSpec((1, tq, hb, dim), q_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # the block table + the base positions
-        grid=(batch, heads // hb, entries),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, queries, hb, dim), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((hb * queries, LANES), jnp.float32),  # running max
-            pltpu.VMEM((hb * queries, LANES), jnp.float32),  # normalizer
-            pltpu.VMEM((hb * queries, dim), jnp.float32),    # accumulator
-        ],
+        grid=grid, in_specs=[q_spec] + specs, out_specs=q_spec,
+        scratch_shapes=scratch,
     )
-    kernel = functools.partial(
-        _fused_kernel_quant if quant else _fused_kernel_dense,
-        block_size=block_size, queries=queries, head_block=hb,
-        head_dim=dim, scale=scale)
     vma = jax.typeof(q).vma
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -270,7 +605,7 @@ def _fused_call(q, entry, table, base, *, head_block: int,
                                        q.dtype, vma=vma),
         interpret=interpret,
         name="paged_decode_fused",
-    )(table, base, *operands)
+    )(table, base, q, *operands)
 
 
 def fused_paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
@@ -313,24 +648,16 @@ def fused_paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
         else:
             interpret = False
     heads = q.shape[2]
-    if head_block is None:
-        from .tuning import lookup_tuned_paged_blocks
-        quantized = "k_scale" in entry
-        head_block = lookup_tuned_paged_blocks(
-            q.shape[0], q.shape[1], heads, head_dim,
-            block_size=entry["k"].shape[-3], entries=table.shape[1],
-            quantized=quantized, dtype=dtype)
-        if head_block is None or heads % head_block:
-            # no winner (or a corrupt cache entry): keep the default —
-            # a tuned pick must never be able to break correctness
-            head_block = _default_head_block(heads, quantized)
-    elif heads % head_block:
+    if head_block is not None and heads % head_block:
         raise ValueError(f"head_block {head_block} must divide "
                          f"num_heads {heads}")
+    walk = call_walk(q.shape[0], q.shape[1], heads, head_dim,
+                     block_size=entry["k"].shape[-3], entries=table.shape[1],
+                     quantized="k_scale" in entry, dtype=dtype,
+                     head_block=head_block)
     base = jax.lax.slice_in_dim(positions, 0, 1, axis=1)[:, 0]
-    q = q.astype(dtype)
-    return _fused_call(q, entry, table, base.astype(jnp.int32),
-                       head_block=int(head_block), interpret=interpret)
+    return _fused_call(q.astype(dtype), entry, table,
+                       base.astype(jnp.int32), walk, interpret=interpret)
 
 
 def fused_speculative_verify(q: jax.Array, entry: tp.Dict,

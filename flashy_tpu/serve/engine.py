@@ -486,6 +486,7 @@ class DecodeEngine:
         # device->host sync per call, S syncs per scheduler step.
         self._positions_host = np.full((slots,), self.max_seq_len, np.int64)
         self._active_host = np.zeros((slots,), bool)
+        self._kv_walks: tp.Dict[int, tp.Any] = {}  # queries -> Walk
 
     # ------------------------------------------------------------------
     # compiled steps
@@ -1048,6 +1049,7 @@ class DecodeEngine:
             self._key("prefill_chunk", size),
             lambda: self._build_prefill_chunk(size))
         stats = {} if uid is None else {"uid": uid}
+        stats.update(self._kv_walk_stats(size, [start]))
         with span(SPAN_PREFILL_CHUNK, self.tracer, category="serve",
                   slot=slot, size=size, offset=start, length=length,
                   final=final, **stats):
@@ -1072,6 +1074,27 @@ class DecodeEngine:
             self._active_host[slot] = True
         return start + used, first
 
+    def _kv_walk_stats(self, queries: int, bases) -> tp.Dict[str, int]:
+        """Span stats of one fused paged read of `queries` rows per slot
+        from first positions `bases` (host mirrors; no device work):
+        `kv_blocks`, the pool blocks the kernel attends in one layer, and
+        `kv_steps`, the compute steps it runs for them — their ratio is
+        how many blocks a step carries (ops/paged_decode.walk_counts).
+        Empty unless the read is the fused kernel's."""
+        if self._pool is None or self.kernel != "fused":
+            return {}
+        from ..ops.paged_decode import call_walk, walk_counts
+        walk = self._kv_walks.get(queries)
+        if walk is None:
+            cfg = self._cfg
+            walk = self._kv_walks[queries] = call_walk(
+                len(bases), queries, cfg.num_heads, cfg.head_dim,
+                block_size=self.block_size, entries=self._pool.max_blocks,
+                quantized=self.kv_dtype == "int8", dtype=cfg.dtype)
+        blocks, steps = walk_counts(bases, queries, walk, self.block_size,
+                                    self._pool.max_blocks)
+        return {"kv_blocks": blocks, "kv_steps": steps}
+
     def decode(self) -> np.ndarray:
         """One [S, 1] decode step over every slot; returns the [S] next
         tokens (pad_token on inactive slots). Always the same compiled
@@ -1080,7 +1103,8 @@ class DecodeEngine:
                                     self._build_decode)
         with span(SPAN_DECODE, self.tracer, category="serve",
                   live=self.allocator.live_count,
-                  running=int(self._active_host.sum())):
+                  running=int(self._active_host.sum()),
+                  **self._kv_walk_stats(1, self._positions_host)):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_DECODE + SPAN_DISPATCH, self.tracer,
                       category="serve"):
@@ -1128,7 +1152,8 @@ class DecodeEngine:
                                     lambda: self._build_verify(k))
         with span(SPAN_VERIFY, self.tracer, category="serve", k=k,
                   live=self.allocator.live_count,
-                  running=int(self._active_host.sum())):
+                  running=int(self._active_host.sum()),
+                  **self._kv_walk_stats(k + 1, self._positions_host)):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_VERIFY + SPAN_DISPATCH, self.tracer,
                       category="serve"):

@@ -76,23 +76,149 @@ def _serve_stream(model, params, workload, kernel, *, kv_dtype="model",
 # ----------------------------------------------------------------------
 # direct kernel parity vs the gather oracle
 # ----------------------------------------------------------------------
+def _ragged_fixture(kv_dtype, queries, seed=5):
+    """Wide pool rows (the walk that copies its own blocks) and a ragged
+    batch the walk must get right: contexts of 1, one short of a group,
+    a group, one past it and the full table, a parked slot, tables whose
+    live entries are scattered over the pool out of order, and a table
+    width the group does not divide."""
+    import jax.numpy as jnp
+    from flashy_tpu.models.quantize import quantize_kv
+    from flashy_tpu.ops.paged_decode import call_walk
+
+    bs, heads, dim, entries = 16, 8, 128, 40
+    walk = call_walk(6, queries, heads, dim, block_size=bs, entries=entries,
+                     quantized=kv_dtype == "int8", dtype=jnp.float32)
+    span = walk.group * bs
+    assert walk.dma, walk
+    max_seq_len = entries * bs
+    # context = tokens the LAST query row attends (its position + 1)
+    contexts = [queries, span - 1, span, span + 1, max_seq_len]
+    base = np.asarray([c - queries for c in contexts] + [max_seq_len])
+    blocks = [-(-c // bs) for c in contexts] + [0]
+    rng = np.random.default_rng(seed)
+    order = rng.permutation(np.arange(1, sum(blocks) + 9))
+    table = np.zeros((len(base), entries), np.int32)
+    for slot, n in enumerate(blocks):
+        table[slot, :n], order = order[:n], order[n:]
+    shape = (sum(blocks) + 9, bs, heads, dim)
+    k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    if kv_dtype == "int8":
+        (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+        entry = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    else:
+        entry = {"k": k, "v": v}
+    q = jnp.asarray(rng.normal(size=(len(base), queries, heads, dim)),
+                    jnp.float32)
+    return entry, jnp.asarray(table), q, jnp.asarray(base, jnp.int32), walk
+
+
 @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
-@pytest.mark.parametrize("queries", [1, 3, 5])
+@pytest.mark.parametrize("queries", [1, 3, 5, "ragged-1", "ragged-3",
+                                     "ragged-5", "ragged-chunk"])
 def test_fused_kernel_matches_gather_oracle(kv_dtype, queries):
     import jax.numpy as jnp
     from flashy_tpu.ops.paged_attention import paged_attention
     from flashy_tpu.ops.paged_decode import fused_paged_attention
 
-    entry, table = _pool_fixture(kv_dtype)
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(size=(2, queries, 2, 8)), jnp.float32)
-    base = jnp.asarray([9, 2], jnp.int32)
+    live = slice(None)
+    if isinstance(queries, int):
+        entry, table = _pool_fixture(kv_dtype)
+        rng = np.random.default_rng(1)
+        q = jnp.asarray(rng.normal(size=(2, queries, 2, 8)), jnp.float32)
+        base = jnp.asarray([9, 2], jnp.int32)
+    else:
+        queries = {"1": 1, "3": 3, "5": 5, "chunk": 32}[queries[7:]]
+        entry, table, q, base, walk = _ragged_fixture(kv_dtype, queries)
+        # decode and verify rows ride the flat layout, a chunk the
+        # per-head one; int8 scales flat only with every head in a step
+        assert walk.flat == (queries < 32) and 40 % walk.group, walk
+        live = slice(0, -1)  # the last slot is parked: garbage by design
     positions = base[:, None] + jnp.arange(queries, dtype=jnp.int32)[None]
-    want = paged_attention(q, entry, table, positions, head_dim=8,
+    want = paged_attention(q, entry, table, positions,
+                           head_dim=q.shape[-1], dtype=jnp.float32)
+    got = fused_paged_attention(q, entry, table, positions,
+                                head_dim=q.shape[-1], dtype=jnp.float32,
+                                interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("quantized", [True, False])
+@pytest.mark.parametrize("queries", [1, 5, 256, 512])
+def test_walk_shape_keeps_its_vmem_estimate_under_the_budget(queries,
+                                                             quantized):
+    # counts, not rates: at the benchmark's widths (H=16, Dh=128, bs=16)
+    # the chooser's own estimate fits the scoped VMEM for decode, verify
+    # and both chunk sizes, and the walk it returns is a legal one
+    from flashy_tpu.ops import paged_decode
+
+    sizes = dict(pool_itemsize=1 if quantized else 2, q_itemsize=2)
+    walk = paged_decode.walk_shape(queries, 16, 128, 16, 128,
+                                   quantized=quantized, **sizes)
+    assert walk.dma and walk.group >= 1
+    assert queries % walk.query_tile == 0 and 16 % walk.head_block == 0
+    assert paged_decode._vmem_estimate(
+        walk.query_tile, walk.head_block, 128, 16, walk.group,
+        flat=walk.flat, **sizes) <= paged_decode.VMEM_BUDGET
+    # decode and verify attend many blocks a step in the flat layout
+    assert walk.flat == (queries <= 5)
+    if queries <= 5:
+        assert walk.group * 16 == paged_decode.KEYS_PER_STEP
+    # a table the group does not divide, or shorter than a group
+    for entries in (100, 3, 1):
+        short = paged_decode.walk_shape(queries, 16, 128, 16, entries,
+                                        quantized=quantized, **sizes)
+        assert 1 <= short.group <= entries
+    # rows narrower than a copy window keep the grid's walk
+    for heads, dim in ((16, 64), (4, 128)):
+        narrow = paged_decode.walk_shape(queries, heads, dim, 16, 128,
+                                         quantized=quantized, **sizes)
+        assert narrow == (1, narrow.head_block, queries, False, False)
+
+
+def test_walk_counts_are_blocks_and_ceil_steps():
+    from flashy_tpu.ops.paged_decode import Walk, walk_counts
+
+    bs, entries = 16, 128
+    positions = np.asarray([0, 15, 16, 255, 256, 2047, 2048])
+    live = np.asarray([1, 1, 2, 16, 17, 128, 1])  # parked slot: 1 block
+    for group in (1, 8, 16):
+        walk = Walk(group, 16, 1, True, True)
+        blocks, steps = walk_counts(positions, 1, walk, bs, entries)
+        assert blocks == live.sum()
+        assert steps == sum(-(-n // group) for n in live)
+    # a chunk split into query tiles walks each tile's own prefix
+    walk = Walk(8, 16, 128, False, True)
+    blocks, steps = walk_counts([1536], 256, walk, bs, entries)
+    assert blocks == (1536 + 128) // bs + (1536 + 256) // bs
+    assert steps == 13 + 14
+    # the grid's walk: a step is a block, parked slots see the table
+    grid = Walk(1, 16, 1, False, False)
+    assert walk_counts([2048, 31], 1, grid, bs, entries) == (130, 130)
+
+
+def test_walk_query_tiles_match_the_oracle(monkeypatch):
+    # a chunk too large for one grid step splits into query tiles that
+    # each walk their own causal prefix: force the split at a toy size
+    import jax.numpy as jnp
+    from flashy_tpu.ops import paged_decode
+    from flashy_tpu.ops.paged_attention import paged_attention
+
+    for name, value in (("QUERY_TILE", 8), ("FLAT_ROWS", 8),
+                        ("VMEM_BUDGET", 3 * 2 ** 20)):
+        monkeypatch.setattr(paged_decode, name, value)
+    entry, table, q, base, walk = _ragged_fixture("int8", 32)
+    assert walk == (4, 8, 8, False, True), walk  # four tiles of 8 rows
+    positions = base[:, None] + jnp.arange(32, dtype=jnp.int32)[None]
+    want = paged_attention(q, entry, table, positions, head_dim=128,
                            dtype=jnp.float32)
-    got = fused_paged_attention(q, entry, table, positions, head_dim=8,
-                                dtype=jnp.float32, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    got = paged_decode.fused_paged_attention(
+        q, entry, table, positions, head_dim=128, dtype=jnp.float32,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got)[:-1], np.asarray(want)[:-1],
                                rtol=2e-5, atol=2e-6)
 
 
@@ -180,6 +306,48 @@ def test_fused_engine_token_exact_at_block_boundaries():
                              block_size=bs)
     for g, f in zip(gather, fused):
         assert np.array_equal(g, f), (g.tolist(), f.tolist())
+
+
+def _wide_model(max_seq_len=512):
+    """One layer of eight 128-wide heads: pool rows the kernel can copy
+    itself, so the engine drives the grouped walk (the toy model's two
+    8-wide heads keep the grid's)."""
+    import jax
+    import jax.numpy as jnp
+    from flashy_tpu.models import TransformerConfig, TransformerLM
+
+    cfg = TransformerConfig(vocab_size=32, dim=1024, num_layers=1,
+                            num_heads=8, attention="dense",
+                            max_seq_len=max_seq_len, dtype=jnp.float32)
+    model = TransformerLM(cfg)
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.ones((1, 4), jnp.int32))
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_fused_engine_token_exact_on_the_grouped_walk(kv_dtype):
+    # 32 table entries walked 16 blocks a step: contexts inside one
+    # group, across the group boundary (255 -> 257 while decoding) and
+    # deep in the second group, two slots at once, one parked at times
+    from flashy_tpu.ops.paged_decode import call_walk
+    import jax.numpy as jnp
+
+    model, params = _wide_model()
+    walk = call_walk(2, 1, 8, 128, block_size=16, entries=32,
+                     quantized=kv_dtype == "int8", dtype=jnp.float32)
+    assert walk.dma and walk.flat and walk.group == 16, walk
+    rng = np.random.default_rng(8)
+    workload = [(rng.integers(0, 32, n).astype(np.int32), 4)
+                for n in (20, 254, 300)]
+    gather, _ = _serve_stream(model, params, workload, "gather",
+                              kv_dtype=kv_dtype, block_size=16,
+                              prefix_cache=False)
+    fused, engine = _serve_stream(model, params, workload, "fused",
+                                  kv_dtype=kv_dtype, block_size=16,
+                                  prefix_cache=False)
+    for g, f in zip(gather, fused):
+        assert np.array_equal(g, f), (g.tolist(), f.tolist())
+    engine._pool.check()
 
 
 @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
@@ -395,6 +563,24 @@ def test_tuning_corrupt_cache_entries_read_as_misses(tmp_path,
                                 interpret=True)
     assert np.isfinite(np.asarray(out)).all()
     del jax
+    # a cache written before the walk grouped blocks holds a scalar
+    # `head_block` winner: still a valid winner, and the walk it picks
+    # is the default one at that head_block
+    from flashy_tpu.ops.paged_decode import call_walk, walk_shape
+    path.write_text(json.dumps({paged_key: 1}))
+    tuning._cache.clear()
+    assert tuning.lookup_tuned_paged_blocks(
+        2, 1, 2, 8, block_size=4, entries=3, quantized=True,
+        dtype=jnp.float32) == 1
+    shapes = dict(block_size=4, entries=3, quantized=True)
+    assert call_walk(2, 1, 2, 8, dtype=jnp.float32, **shapes) \
+        == walk_shape(1, 2, 8, pool_itemsize=1, q_itemsize=4, head_block=1,
+                      **shapes)
+    # one that does not divide the heads reads as a miss, not an error
+    path.write_text(json.dumps({paged_key: 3}))
+    tuning._cache.clear()
+    assert call_walk(2, 1, 2, 8, dtype=jnp.float32, **shapes).head_block == 2
+    tuning._cache.clear()
 
 
 def test_tune_paged_blocks_never_sweeps_without_a_runnable_kernel(

@@ -56,14 +56,15 @@ def recorder(monkeypatch):
     return Recorder
 
 
-def _tiny_model(remat=False):
+def _tiny_model(remat=False, **sizes):
     import jax
     import jax.numpy as jnp
     from flashy_tpu.models import TransformerConfig, TransformerLM
 
-    cfg = TransformerConfig(vocab_size=32, dim=16, num_layers=2, num_heads=2,
-                            attention="dense", max_seq_len=32,
-                            dtype=jnp.float32, remat=remat)
+    sizes = dict(dict(dim=16, num_layers=2, num_heads=2, max_seq_len=32),
+                 **sizes)
+    cfg = TransformerConfig(vocab_size=32, attention="dense",
+                            dtype=jnp.float32, remat=remat, **sizes)
     model = TransformerLM(cfg)
     return model, model.init(jax.random.PRNGKey(0),
                              jnp.ones((1, 4), jnp.int32))
@@ -135,6 +136,43 @@ def test_decode_running_stat_is_the_tokens_emitted(recorder, paged):
     for emitted, running in per_step:
         # `live` counts acquired slots; `running` those that emit a token
         assert running == ([emitted] if emitted else [])
+
+
+def test_fused_reads_count_kv_blocks_and_steps(recorder):
+    """`kv_blocks` and `kv_steps` ride the fused read's span beside
+    `live` and `running`: blocks the walk attends and compute steps it
+    runs for them, one layer, from the host's position mirror."""
+    # eight 128-wide heads: the walk that groups blocks (16 a step here)
+    model, params = _tiny_model(dim=1024, num_layers=1, num_heads=8,
+                                max_seq_len=512)
+    engine = DecodeEngine(model, params, slots=2, cache_layout="paged",
+                          block_size=16, chunk=64, kernel="fused")
+    scheduler = ContinuousBatchingScheduler(engine)
+    scheduler.submit(np.arange(300, dtype=np.int32) % 32, 3)
+    _drain(scheduler)
+    decodes = [s for _, name, s in recorder.entered
+               if name == "serve/decode"]
+    # the prefill emits the first token; positions 300 and 301 decode:
+    # 19 live blocks = 2 steps of 16, and the other slot is parked and
+    # walks one block in one step
+    assert [(s["kv_blocks"], s["kv_steps"]) for s in decodes] \
+        == [(19 + 1, 2 + 1)] * 2
+    assert all({"live", "running"} <= set(s) for s in decodes)
+    slices = [s for _, name, s in recorder.entered
+              if name == "serve/prefill_chunk"]
+    # a 64-row slice from offset 0, 64, ..: blocks up to its last row
+    assert [s["kv_blocks"] for s in slices] == [4, 8, 12, 16, 20]
+    assert [s["kv_steps"] for s in slices] == [1, 1, 1, 1, 2]
+
+
+def test_gather_reads_carry_no_walk_stats(recorder, paged):
+    scheduler = ContinuousBatchingScheduler(paged)
+    scheduler.submit(np.arange(3, 8, dtype=np.int32), 2)
+    _drain(scheduler)
+    assert paged.kernel == "gather"
+    for _, name, stats in recorder.entered:
+        if name in ("serve/decode", "serve/prefill_chunk"):
+            assert "kv_steps" not in stats and "kv_blocks" not in stats
 
 
 def test_prefill_slices_carry_the_request_uid(recorder, paged):
