@@ -8,10 +8,11 @@
 #
 # All TransformerLM layouts decode here: per-layer parameter trees
 # (block_i), scan-stacked models (stacked [L, ...] params — the cache is
-# stacked too and the layer loop is a lax.scan), and MoE blocks (routed
-# dropless at decode time: every token sees its top-k experts; capacity
-# buffers are a *training* batching artifact with no meaning for
-# autoregressive decoding).
+# stacked too and the layer loop is a lax.scan), MoE blocks (routed
+# dropless at decode time through `moe.expert_layer`: every token sees
+# its top-k experts; capacity buffers are a *training* batching artifact
+# with no meaning for autoregressive decoding) and latent-attention
+# blocks (`attn_kind='mla'`: the cache holds the latent, models/mla.py).
 """KV-cache decoding: generate(model, params, prompt, ...) -> tokens."""
 import numbers
 import typing as tp
@@ -21,9 +22,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.ssd_scan import ssd_chunked_scan, ssd_recurrent_scan
-from .transformer import (TransformerConfig, _rotary, mixer_pattern,
-                          rmsnorm as _rmsnorm)
-from .quantize import is_quantized
+from . import mla
+from .moe import expert_layer, gated_mlp
+from .transformer import (TransformerConfig, _rotary, expert_layers,
+                          mixer_pattern, rmsnorm as _rmsnorm)
+from .quantize import (is_quantized, kernel_operand as _kernel,
+                       postscale as _postscale)
 from .ssd import ssd_log_decay
 
 
@@ -31,33 +35,16 @@ def _split_heads(qkv: jax.Array) -> tp.Tuple[jax.Array, jax.Array, jax.Array]:
     return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
 
-def _kernel(w, dtype):
-    """Matmul operand + output scale for a (possibly int8) kernel leaf.
-
-    Quantized leaves ({"q", "scale"}, models/quantize.py) contribute
-    the raw int8 payload converted to the compute dtype — a pure
-    elementwise convert XLA fuses into the dot's operand read — and the
-    per-output-channel scale to apply to the einsum RESULT. Dense
-    leaves scale by None.
-    """
-    if is_quantized(w):
-        return w["q"].astype(dtype), w["scale"]
-    return w.astype(dtype), None
-
-
-def _postscale(out: jax.Array, scale) -> jax.Array:
-    """Apply a kernel's output scale (broadcast over leading dims)."""
-    if scale is None:
-        return out
-    return out * scale.astype(out.dtype)
-
-
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> tp.Dict:
     """Allocate the static-shape decode cache.
 
-    Attention layers get {'k','v'} slabs [B, max_len, H, Dh]; SSD
-    layers get one {'ssd'} f32 state [B, H, Dh, Dstate] — NO max_len
-    dim, the O(1)-in-context-length decode state. Per-layer models get
+    Attention layers get {'k','v'} slabs [B, max_len, H, Dh] — or,
+    under latent attention, {'c','kr'} slabs [B, max_len, 1, width]:
+    the normed latent and the rotated shared key, one row a token for
+    all heads (the singleton keeps the slot dim where the K/V slabs
+    have it); SSD layers get one {'ssd'} f32 state [B, H, Dh, Dstate] —
+    NO max_len dim, the O(1)-in-context-length decode state. Per-layer
+    models get
     one entry per block; scan-stacked models (uniform mixer pattern by
     construction) get single stacked [L, ...] arrays, the layer dim
     scanned together with the stacked parameters. Both layouts keep the
@@ -67,6 +54,12 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> tp.Dict:
     shape = (batch, max_len, cfg.num_heads, cfg.head_dim)
     sshape = (batch, cfg.num_heads, cfg.head_dim, cfg.ssd_state_dim)
     pattern = mixer_pattern(cfg)
+    expert_layers(cfg)  # refuses what the new kinds cannot combine with
+    if cfg.attn_kind == "mla":
+        attn = {"c": (batch, max_len, 1, cfg.kv_lora_rank),
+                "kr": (batch, max_len, 1, cfg.qk_rope_head_dim)}
+    else:
+        attn = {"k": shape, "v": shape}
     if cfg.scan_layers:
         if pattern[0] == "ssd":
             return {"ssd": jnp.zeros((cfg.num_layers,) + sshape,
@@ -78,103 +71,18 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> tp.Dict:
         f"block_{i}": (
             {"ssd": jnp.zeros(sshape, jnp.float32)}
             if pattern[i] == "ssd" else
-            {"k": jnp.zeros(shape, cfg.dtype),
-             "v": jnp.zeros(shape, cfg.dtype)})
+            {name: jnp.zeros(dims, cfg.dtype) for name, dims in attn.items()})
         for i in range(cfg.num_layers)
     }
-
-
-# Above this many tokens, per-token expert-weight gathers ([N, D, F]
-# buffers) dominate memory; switch to streaming over experts instead.
-_MOE_GATHER_MAX_TOKENS = 64
-
-
-def _moe_forward(cfg: TransformerConfig, mp: tp.Dict, x: jax.Array) -> jax.Array:
-    """Dropless routed MoE for decoding: [B, S, D] -> [B, S, D].
-
-    Matches MoEMLP's routing math (f32 softmax router, raw-probability
-    gates, sequential top-k argmax) but without capacity buffers — exact
-    for every token, no overflow drops. Two equivalent evaluation
-    orders: single-token decode steps gather each token's expert weights
-    directly (tiny N); the prefill streams over the experts under a
-    lax.scan, computing every token against one expert's weights at a
-    time (peak extra memory N*F, never N*D*F).
-    """
-    batch, seq, dim = x.shape
-    n_tokens = batch * seq
-    x_flat = x.reshape(n_tokens, dim)
-    logits = x_flat.astype(jnp.float32) @ mp["router"]["kernel"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)            # [N, E]
-    num_experts = probs.shape[-1]
-
-    # Combined per-(token, expert) gate over the top-k rounds.
-    combine = jnp.zeros_like(probs)
-    remaining = probs
-    for _ in range(cfg.moe_top_k):
-        expert_index = jnp.argmax(remaining, axis=-1)  # [N]
-        gate = jnp.take_along_axis(remaining, expert_index[:, None],
-                                   axis=-1)[:, 0]
-        onehot = jax.nn.one_hot(expert_index, num_experts)
-        combine = combine + gate[:, None] * onehot
-        remaining = remaining * (1.0 - onehot)
-
-    w_up = mp["w_up"]                                  # [E, D, F]
-    w_down = mp["w_down"]                              # [E, F, D]
-
-    def _take_expert(w, idx):
-        """Per-token expert slab + its output scale ([N, out] or None)."""
-        if is_quantized(w):
-            slab = jnp.take(w["q"], idx, axis=0).astype(cfg.dtype)
-            return slab, jnp.take(w["scale"], idx, axis=0)[:, 0, :]
-        return jnp.take(w, idx, axis=0).astype(cfg.dtype), None
-
-    if n_tokens <= _MOE_GATHER_MAX_TOKENS:
-        # Token-gather order: one [N, D, F] gather per used slot.
-        out = jnp.zeros_like(x_flat, dtype=jnp.float32)
-        remaining = probs
-        for _ in range(cfg.moe_top_k):
-            expert_index = jnp.argmax(remaining, axis=-1)
-            gate = jnp.take_along_axis(remaining, expert_index[:, None],
-                                       axis=-1)[:, 0]
-            up, up_s = _take_expert(w_up, expert_index)
-            down, down_s = _take_expert(w_down, expert_index)
-            # Scales apply to the einsum outputs, BEFORE the nonlinearity.
-            h = _postscale(jnp.einsum("nd,ndf->nf",
-                                      x_flat.astype(cfg.dtype), up), up_s)
-            y = _postscale(jnp.einsum("nf,nfd->nd", jax.nn.gelu(h), down),
-                           down_s)
-            out = out + gate[:, None] * y.astype(jnp.float32)
-            remaining = remaining * (1.0 - jax.nn.one_hot(
-                expert_index, num_experts))
-    else:
-        # Expert-stream order (prefill): every expert transforms the
-        # full token set once; the combine gate (zero for unrouted
-        # pairs) weights the sum. Identical result — f_e is linear in
-        # its weighting — without per-token weight copies. lax.scan
-        # slices quantized {"q","scale"} dicts leaf-wise, so each body
-        # sees one expert's int8 slab + [1, out] scale.
-        x_c = x_flat.astype(cfg.dtype)
-
-        def body(out, expert_in):
-            up, down, gates = expert_in          # [D,F], [F,D], [N]
-            up_w, up_s = _kernel(up, cfg.dtype)
-            down_w, down_s = _kernel(down, cfg.dtype)
-            h = jax.nn.gelu(_postscale(x_c @ up_w, up_s))
-            y = _postscale(h @ down_w, down_s)
-            return out + gates[:, None] * y.astype(jnp.float32), None
-
-        out, _ = jax.lax.scan(
-            body, jnp.zeros_like(x_flat, dtype=jnp.float32),
-            (w_up, w_down, combine.T))
-
-    return out.reshape(batch, seq, dim).astype(cfg.dtype)
 
 
 # The decode step's named scopes — ONE set for the dense step below and
 # the paged step (serve/paged.py), so a device trace attributes time the
 # same way under both layouts: embed, norm, qkv, rotary, kv_write, attn,
-# out_proj, mlp, head (+ `sample` in the engine). The scope is the HLO
-# `op_name` path of every op traced inside it.
+# out_proj, mlp, head (+ `sample` in the engine); a latent block has
+# mla_q, mla_kv, kv_write, attn, mla_out; an expert layer nests router,
+# experts, shared_expert under mlp. The scope is the HLO `op_name` path
+# of every op traced inside it.
 def _qkv_heads(cfg, bp: tp.Dict, x: jax.Array, positions: jax.Array
                ) -> tp.Tuple[jax.Array, jax.Array, jax.Array]:
     """Pre-norm, fused QKV projection and rotary: (q, k, v), each
@@ -186,7 +94,7 @@ def _qkv_heads(cfg, bp: tp.Dict, x: jax.Array, positions: jax.Array
         qkv = _postscale(jnp.einsum("btd,dchk->btchk", normed, qkv_w), qkv_s)
         q, k, v = _split_heads(qkv)
     with jax.named_scope("rotary"):
-        return _rotary(q, positions), _rotary(k, positions), v
+        return _rotary(q, positions, cfg), _rotary(k, positions, cfg), v
 
 
 def _attn_residual(cfg, bp: tp.Dict, x: jax.Array, attn: jax.Array
@@ -198,14 +106,19 @@ def _attn_residual(cfg, bp: tp.Dict, x: jax.Array, attn: jax.Array
                               out_s)
 
 
-def _mlp_residual(cfg, bp: tp.Dict, x: jax.Array) -> jax.Array:
-    """x + the block's pre-normed MLP (gated, or MoE)."""
+def _mlp_residual(cfg, bp: tp.Dict, x: jax.Array,
+                  stats: tp.Optional[tp.List] = None) -> jax.Array:
+    """x + the block's pre-normed MLP (gated, or the expert layer, whose
+    (assignments, experts hit) pair is appended to `stats` if given)."""
     with jax.named_scope("norm"):
         normed = _rmsnorm(x, bp["norm2"]["scale"], cfg.dtype)
     with jax.named_scope("mlp"):
         if "moe" in bp:
-            return x + _moe_forward(cfg, bp["moe"], normed)
-        return x + _gated_mlp(bp["mlp"], normed, cfg.dtype)
+            out, counts = expert_layer(cfg, bp["moe"], normed)
+            if stats is not None:
+                stats.append(counts)
+            return x + out
+        return x + gated_mlp(bp["mlp"], normed, cfg.dtype)
 
 
 def _cache_write(cache: jax.Array, new: jax.Array,
@@ -312,24 +225,60 @@ def _ssd_layer_forward(cfg: TransformerConfig, bp: tp.Dict, x: jax.Array,
     return _mlp_residual(cfg, bp, x), state
 
 
-def _gated_mlp(bp_mlp: tp.Dict, normed: jax.Array, dtype) -> jax.Array:
-    """SwiGLU MLP on pre-normed input (quantized kernels supported)."""
-    up_w, up_s = _kernel(bp_mlp["up"]["kernel"], dtype)
-    up = _postscale(jnp.einsum("btd,df->btf", normed, up_w), up_s)
-    gate, value = jnp.split(up, 2, axis=-1)
-    down_w, down_s = _kernel(bp_mlp["down"]["kernel"], dtype)
-    return _postscale(
-        jnp.einsum("btf,fd->btd", jax.nn.silu(gate) * value, down_w),
-        down_s)
+def latent_projections(cfg, bp: tp.Dict, x: jax.Array, positions: jax.Array):
+    """A latent block's pre-norm and projections, shared by the dense
+    and the paged step: (q_lat, q_rope) — the queries with W_kvb's key
+    half absorbed — and (c_kv, k_rope), the token's cache row."""
+    with jax.named_scope("norm"):
+        normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
+    with jax.named_scope("mla_q"):
+        q_nope, q_rope = mla.queries(cfg, bp["attn"], normed, positions)
+        q_lat = mla.absorb_queries(cfg, bp["attn"], q_nope)
+    with jax.named_scope("mla_kv"):
+        c_kv, k_rope = mla.latents(cfg, bp["attn"], normed, positions)
+    return (q_lat, q_rope), (c_kv, k_rope)
+
+
+def latent_residual(cfg, bp: tp.Dict, x: jax.Array, o_lat: jax.Array
+                    ) -> jax.Array:
+    """x + W_o of the attended latents expanded through W_kvb's value
+    half."""
+    with jax.named_scope("mla_out"):
+        return x + mla.output(cfg, bp["attn"],
+                              mla.expand_values(cfg, bp["attn"], o_lat))
+
+
+def _cached_latent_attention(cfg, bp: tp.Dict, x: jax.Array,
+                             positions: jax.Array, entry: tp.Dict,
+                             cache_index: jax.Array):
+    """Pre-norm latent attention (cached form, models/mla.py) against
+    the dense {'c','kr'} slabs: returns (x + attn_out, entry)."""
+    (q_lat, q_rope), (c_kv, k_rope) = latent_projections(cfg, bp, x,
+                                                         positions)
+    with jax.named_scope("kv_write"):
+        entry = {"c": _cache_write(entry["c"], c_kv[:, :, None], cache_index),
+                 "kr": _cache_write(entry["kr"], k_rope[:, :, None],
+                                    cache_index)}
+    with jax.named_scope("attn"):
+        o_lat = mla.cached_attention(cfg, q_lat, q_rope, entry["c"][:, :, 0],
+                                     entry["kr"][:, :, 0], positions)
+    return latent_residual(cfg, bp, x, o_lat), entry
 
 
 def _layer_forward(cfg: TransformerConfig, bp: tp.Dict, x: jax.Array,
-                   positions: jax.Array, k_cache: jax.Array,
-                   v_cache: jax.Array, cache_index: jax.Array):
-    """One block against cached K/V: returns (x, k_cache, v_cache)."""
-    x, k_cache, v_cache = _cached_self_attention(
-        cfg, bp, x, positions, k_cache, v_cache, cache_index)
-    return _mlp_residual(cfg, bp, x), k_cache, v_cache
+                   positions: jax.Array, entry: tp.Dict,
+                   cache_index: jax.Array,
+                   stats: tp.Optional[tp.List] = None):
+    """One block against its cache entry ({'k','v'} slabs, or the
+    latent {'c','kr'}): returns (x, entry)."""
+    if cfg.attn_kind == "mla":
+        x, entry = _cached_latent_attention(cfg, bp, x, positions, entry,
+                                            cache_index)
+    else:
+        x, k_cache, v_cache = _cached_self_attention(
+            cfg, bp, x, positions, entry["k"], entry["v"], cache_index)
+        entry = {"k": k_cache, "v": v_cache}
+    return _mlp_residual(cfg, bp, x, stats), entry
 
 
 @jax.named_scope("embed")
@@ -348,7 +297,8 @@ def _embed_tokens(p: tp.Dict, tokens: jax.Array, dtype) -> jax.Array:
 
 def _head_logits(p: tp.Dict, x: jax.Array, cfg: TransformerConfig
                  ) -> jax.Array:
-    """Final norm + tied LM head: [B, S, D] -> f32 logits [B, S, V].
+    """Final norm + LM head (the tied embedding, or the `head` table of
+    an untied model): [B, S, D] -> f32 logits [B, S, V].
 
     Head operands in the compute dtype + f32 accumulation — must match
     TransformerLM.__call__'s head exactly (the decode-vs-uncached-
@@ -359,19 +309,21 @@ def _head_logits(p: tp.Dict, x: jax.Array, cfg: TransformerConfig
     with jax.named_scope("norm"):
         x = _rmsnorm(x, p["norm_f"]["scale"], cfg.dtype)
     with jax.named_scope("head"):
-        if is_quantized(p["embed"]):
+        table = p["head"] if "head" in p else p["embed"]
+        if is_quantized(table):
             logits = jnp.einsum("btd,vd->btv", x,
-                                p["embed"]["q"].astype(cfg.dtype),
+                                table["q"].astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)
-            return logits * p["embed"]["scale"][:, 0]
-        return jnp.einsum("btd,vd->btv", x, p["embed"].astype(cfg.dtype),
+            return logits * table["scale"][:, 0]
+        return jnp.einsum("btd,vd->btv", x, table.astype(cfg.dtype),
                           preferred_element_type=jnp.float32)
 
 
 def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,
                 positions: jax.Array, cache: tp.Dict, cache_index: jax.Array,
                 *, token_mask: tp.Optional[jax.Array] = None,
-                state_mask: tp.Optional[jax.Array] = None):
+                state_mask: tp.Optional[jax.Array] = None,
+                stats: tp.Optional[tp.List] = None):
     """Forward `tokens` [B, S] at `positions`, reading+writing the cache.
 
     Re-implements the block stack against cached K/V (the training
@@ -382,7 +334,9 @@ def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,
     already ignore padded/parked rows through the positions-derived
     mask and out-of-range-dropped cache writes). Weights are read from
     the same parameter tree; the scan-stacked layout runs the layer
-    loop as a lax.scan over the stacked params + stacked cache.
+    loop as a lax.scan over the stacked params + stacked cache. Each
+    expert layer of an unstacked model appends its (assignments, experts
+    hit) counts to `stats` when a list is given.
     """
     p = params["params"]
     x = _embed_tokens(p, tokens, cfg.dtype)
@@ -400,9 +354,9 @@ def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,
         else:
             def body(x, layer_in):
                 bp, k_c, v_c = layer_in
-                x, k_c, v_c = _layer_forward(cfg, bp, x, positions, k_c,
-                                             v_c, cache_index)
-                return x, (k_c, v_c)
+                x, entry = _layer_forward(cfg, bp, x, positions,
+                                          {"k": k_c, "v": v_c}, cache_index)
+                return x, (entry["k"], entry["v"])
 
             x, (k_cache, v_cache) = jax.lax.scan(
                 body, x, (stacked, cache["k"], cache["v"]))
@@ -417,10 +371,9 @@ def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,
                     state_mask)
                 new_cache[name] = {"ssd": state}
             else:
-                x, k_cache, v_cache = _layer_forward(
-                    cfg, p[name], x, positions,
-                    cache[name]["k"], cache[name]["v"], cache_index)
-                new_cache[name] = {"k": k_cache, "v": v_cache}
+                x, new_cache[name] = _layer_forward(
+                    cfg, p[name], x, positions, cache[name], cache_index,
+                    stats)
 
     return _head_logits(p, x, cfg), new_cache
 
@@ -573,7 +526,7 @@ def generate(model, params, prompt: jax.Array, *, max_new_tokens: int,
     Args:
         model: a TransformerLM (its config drives shapes). All layer
             layouts are supported: per-layer params, scan-stacked, and
-            MoE blocks (decoded dropless — see `_moe_forward`).
+            MoE blocks (decoded dropless — see `moe.expert_layer`).
         params: the model's variables ({'params': ...}).
         prompt: [B, P] int32 prompt tokens.
         max_new_tokens: tokens to append.

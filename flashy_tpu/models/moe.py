@@ -5,12 +5,15 @@
 # SPMD partitioner turns into all-to-alls over ICI — the
 # Switch-Transformer/GShard construction, compiler-scheduled instead of
 # hand-written.
-"""MoEMLP: top-1/top-2 routed experts with capacity-based dense dispatch."""
+"""MoEMLP: top-1/top-2 routed experts with capacity-based dense dispatch;
+`expert_layer`: the one dropless expert layer of the serving path."""
 import typing as tp
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from .quantize import is_quantized, kernel_operand, postscale
 
 
 def moe_aux_loss(mutated_collections: tp.Mapping[str, tp.Any]) -> jax.Array:
@@ -267,3 +270,238 @@ class MoEMLP(nn.Module):
                 mode="fill", fill_value=0).astype(jnp.float32)
             out = out + (y_sorted * gate_kept[:, None])[jnp.argsort(order)]
         return out.astype(self.dtype)
+
+
+# ----------------------------------------------------------------------
+# The one expert layer of the decode / paged steps (and of the
+# full-sequence forward of a `n_routed > 0` config): functions over raw
+# parameters. Dropless: every assignment that lands on an expert held
+# HERE is computed, sorted by expert, through a grouped matrix product;
+# an assignment to an expert held elsewhere contributes nothing (its
+# chip adds that part). Two routers share it: the softmax router of
+# MoEMLP above (gelu experts, all held) and the sigmoid group-limited
+# router with a correction bias (gated-silu experts, a held range, a
+# shared expert).
+# ----------------------------------------------------------------------
+def softmax_route(logits: jax.Array, top_k: int
+                  ) -> tp.Tuple[jax.Array, jax.Array]:
+    """MoEMLP's rule (`parallel.moe_ep._topk_route`): f32 softmax, per
+    round the best unused expert at its raw probability. Returns
+    (ids [N, k], gates [N, k])."""
+    from ..parallel.moe_ep import _topk_route
+    probs = jax.nn.softmax(logits, axis=-1)
+    ids, gates, _ = _topk_route(probs, probs.shape[-1], top_k)
+    return ids.T, gates.T
+
+
+def sigmoid_group_route(logits: jax.Array, bias: jax.Array, *, top_k: int,
+                        n_group: int, topk_group: int, scale: float
+                        ) -> tp.Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores s; choice scores s + bias; the experts in
+    `n_group` equal groups, a group's score the sum of its two best
+    choice scores; the `topk_group` best groups stay; the `top_k` best
+    choice scores among them win (ties to the lower index, groups and
+    experts alike). Gates are s (not s + bias) at the winners, divided
+    by their sum, times `scale`. f32. Returns (ids [N, k], gates)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores + bias.astype(jnp.float32)
+    tokens, experts = scores.shape
+    grouped = choice.reshape(tokens, n_group, experts // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)            # [N, g]
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    masked = jnp.where(keep[:, :, None], grouped, -jnp.inf)
+    _, ids = jax.lax.top_k(masked.reshape(tokens, experts), top_k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * scale
+    return ids, gates
+
+
+def _tile(size: int, most: int) -> int:
+    """The largest power-of-two tile up to `most` that divides `size`
+    (the whole dimension when none of at least 128 does)."""
+    tile = most
+    while tile >= 128:
+        if size % tile == 0:
+            return tile
+        tile //= 2
+    return size
+
+
+def _grouped_matmul(rows: jax.Array, w: tp.Any, group_sizes: jax.Array,
+                    row_group: jax.Array, dtype) -> jax.Array:
+    """rows [m, K], sorted by group, times w [G, K, N] group by group
+    (f32 out). Rows past sum(group_sizes) belong to no group: their
+    output is unspecified and the caller masks it. On a TPU the Pallas
+    megablox `gmm` (visits only (group, row tile) pairs that exist);
+    elsewhere XLA's `ragged_dot`. A quantized w ({"q", "scale"}) gives
+    its int8 payload to the product and its per-output-channel scale to
+    the result, row by row."""
+    rhs, scale = (w["q"], w["scale"]) if is_quantized(w) else (w, None)
+    rhs = rhs.astype(dtype)
+    m = rows.shape[0]
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        tm = min(128, -(-m // 16) * 16)
+        pad = (-m) % tm
+        if pad:
+            rows = jnp.concatenate(
+                [rows, jnp.zeros((pad, rows.shape[1]), rows.dtype)])
+        out = megablox.gmm(rows, rhs, group_sizes, jnp.float32,
+                           (tm, _tile(rhs.shape[1], 1024),
+                            _tile(rhs.shape[2], 1024)))[:m]
+    else:
+        out = jax.lax.ragged_dot(rows, rhs, group_sizes,
+                                 preferred_element_type=jnp.float32)
+    if scale is not None:
+        out = out * jnp.take(scale[:, 0, :], row_group, axis=0)
+    return out
+
+
+def routed_experts(x_flat: jax.Array, ids: jax.Array, gates: jax.Array,
+                   w_up: tp.Any, w_down: tp.Any, *, first: int,
+                   gated: bool, dtype
+                   ) -> tp.Tuple[jax.Array, tp.Tuple[jax.Array, jax.Array]]:
+    """sum_k gate_k expert_k(x) over the experts held here.
+
+    `ids`, `gates` [N, k] are the router's picks over ALL experts;
+    `w_up` [count, D, F or 2F] and `w_down` [count, F, D] are experts
+    `first .. first + count - 1`. Assignments sort by expert (those to
+    experts held elsewhere last, outside every group), run the two
+    grouped products and return to their tokens. `gated`: the up
+    product is [gate | value] and the hidden silu(gate) * value;
+    otherwise gelu(up). Returns (y [N, D] f32, (assignments that landed
+    here, held experts that got at least one))."""
+    tokens, top_k = ids.shape
+    count = (w_up["q"] if is_quantized(w_up) else w_up).shape[0]
+    local = ids.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+        jnp.int32)
+    landed = jnp.sum(group_sizes)
+    row_group = jnp.minimum(key[order], count - 1)
+    rows = x_flat[order // top_k].astype(dtype)
+    up = _grouped_matmul(rows, w_up, group_sizes, row_group, dtype)
+    if gated:
+        gate, value = jnp.split(up, 2, axis=-1)
+        hidden = jax.nn.silu(gate) * value
+    else:
+        hidden = jax.nn.gelu(up)
+    y = _grouped_matmul(hidden.astype(dtype), w_down, group_sizes,
+                        row_group, dtype)
+    y = jnp.where((jnp.arange(y.shape[0]) < landed)[:, None], y, 0.0)
+    y = y[jnp.argsort(order)].reshape(tokens, top_k, -1)
+    out = jnp.sum(y * gates.astype(jnp.float32)[:, :, None], axis=1)
+    return out, (landed, jnp.sum(group_sizes > 0))
+
+
+def expert_layer(cfg, mp: tp.Dict, x: jax.Array
+                 ) -> tp.Tuple[jax.Array, tp.Tuple[jax.Array, jax.Array]]:
+    """The block's expert layer on pre-normed x [B, T, D]: router,
+    routed experts held here, shared expert. `mp` is MoEMLP's tree
+    (`cfg.moe_experts`: softmax router, gelu experts, all held) or
+    ExpertMLP's (`cfg.n_routed`: sigmoid group-limited router, gated
+    experts `cfg.held_experts`, `cfg.n_shared` shared). Returns
+    (y [B, T, D] in cfg.dtype, (assignments, experts hit))."""
+    batch, seq, dim = x.shape
+    x_flat = x.reshape(batch * seq, dim)
+    with jax.named_scope("router"):
+        logits = (x_flat.astype(jnp.float32)
+                  @ mp["router"]["kernel"].astype(jnp.float32))
+        if cfg.n_routed > 0:
+            ids, gates = sigmoid_group_route(
+                logits, mp["router_bias"], top_k=cfg.expert_top_k,
+                n_group=cfg.expert_groups, topk_group=cfg.expert_topk_groups,
+                scale=cfg.expert_scale)
+        else:
+            ids, gates = softmax_route(logits, cfg.moe_top_k)
+    with jax.named_scope("experts"):
+        out, stats = routed_experts(
+            x_flat, ids, gates, mp["w_up"], mp["w_down"],
+            first=cfg.held_experts[0] if cfg.n_routed > 0 else 0,
+            gated=cfg.n_routed > 0, dtype=cfg.dtype)
+    out = out.reshape(batch, seq, dim).astype(cfg.dtype)
+    if "shared" in mp:
+        with jax.named_scope("shared_expert"):
+            out = out + gated_mlp(mp["shared"], x, cfg.dtype)
+    return out, stats
+
+
+def gated_mlp(mp: tp.Dict, normed: jax.Array, dtype) -> jax.Array:
+    """SwiGLU MLP on pre-normed input from the raw `up` [D, 2F] (gate |
+    value) and `down` [F, D] kernels (quantized kernels supported): the
+    dense block's MLP and the shared expert."""
+    up_w, up_s = kernel_operand(mp["up"]["kernel"], dtype)
+    up = postscale(jnp.einsum("btd,df->btf", normed, up_w), up_s)
+    gate, value = jnp.split(up, 2, axis=-1)
+    down_w, down_s = kernel_operand(mp["down"]["kernel"], dtype)
+    return postscale(
+        jnp.einsum("btf,fd->btd", jax.nn.silu(gate) * value, down_w),
+        down_s)
+
+
+class Leaf(nn.Module):
+    """One named parameter leaf, `<module name>/<leaf>`, for modules
+    whose arithmetic is a function over the raw tree."""
+
+    @nn.compact
+    def __call__(self, leaf, init, shape, dtype):
+        return self.param(leaf, init, shape, dtype)
+
+
+class GatedLeaves(nn.Module):
+    """The `up` [D, 2F] (gate | value) and `down` [F, D] kernels of a
+    gated MLP as a raw tree (`gated_mlp` reads it)."""
+
+    @nn.compact
+    def __call__(self, dim: int, hidden: int, dtype):
+        dense = nn.initializers.lecun_normal()
+        return {"up": {"kernel": Leaf(name="up")(
+                    "kernel", dense, (dim, 2 * hidden), dtype)},
+                "down": {"kernel": Leaf(name="down")(
+                    "kernel", dense, (hidden, dim), dtype)}}
+
+
+class ExpertMLP(nn.Module):
+    """The expert layer of a `n_routed > 0` config as a Flax module: it
+    declares the parameters (`router/kernel` [D, n_routed],
+    `router_bias` [n_routed], `w_up` [count, D, 2F], `w_down`
+    [count, F, D], `shared/{up,down}/kernel`) and calls `expert_layer`,
+    the same function the decode and paged steps call."""
+
+    config: tp.Any
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.config
+        first, count = cfg.held_experts
+        count = count or cfg.n_routed  # count 0: every expert is held
+        width, pd = cfg.expert_hidden, cfg.param_dtype
+        if not (0 <= first and first + count <= cfg.n_routed
+                and cfg.n_routed % cfg.expert_groups == 0):
+            raise ValueError(
+                f"held_experts {cfg.held_experts} must lie inside the "
+                f"{cfg.n_routed} routed experts, which {cfg.expert_groups} "
+                f"groups must divide")
+        per_expert = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", batch_axis=(0,))
+        dense = nn.initializers.lecun_normal()
+        mp = {
+            "router": {"kernel": Leaf(name="router")(
+                "kernel", dense, (cfg.dim, cfg.n_routed), pd)},
+            # zeros as published; a learned balance term in deployment:
+            # drawn small so that it takes part in the choice
+            "router_bias": self.param(
+                "router_bias", nn.initializers.normal(0.01),
+                (cfg.n_routed,), jnp.float32),
+            "w_up": self.param("w_up", per_expert,
+                               (count, cfg.dim, 2 * width), pd),
+            "w_down": self.param("w_down", per_expert,
+                                 (count, width, cfg.dim), pd),
+        }
+        if cfg.n_shared:
+            mp["shared"] = GatedLeaves(name="shared")(
+                cfg.dim, width * cfg.n_shared, pd)
+        return expert_layer(cfg, mp, x)[0]

@@ -26,6 +26,10 @@ def _chunked_stage(model: TransformerLM, variables: tp.Mapping,
     cfg = model.config
     if not cfg.scan_layers:
         raise ValueError("pipelined_apply needs TransformerConfig.scan_layers=True")
+    if cfg.attn_kind != "mha" or cfg.n_routed > 0 or not cfg.tie_head:
+        raise ValueError("pipelined_apply stacks one MHA block body: latent "
+                         "attention, n_routed expert layers and an untied "
+                         "head are not pipelined")
     layers_per_chunk = cfg.num_layers // num_chunks
     moe = cfg.moe_experts > 0
 

@@ -37,6 +37,26 @@ def is_quantized(leaf: tp.Any) -> bool:
             and getattr(leaf.get("q"), "dtype", None) == jnp.int8)
 
 
+def kernel_operand(w, dtype):
+    """Matmul operand + output scale for a (possibly int8) kernel leaf.
+
+    Quantized leaves ({"q", "scale"}) contribute the raw int8 payload
+    converted to the compute dtype — a pure elementwise convert XLA
+    fuses into the dot's operand read — and the per-output-channel
+    scale to apply to the einsum RESULT. Dense leaves scale by None.
+    """
+    if is_quantized(w):
+        return w["q"].astype(dtype), w["scale"]
+    return w.astype(dtype), None
+
+
+def postscale(out: jax.Array, scale) -> jax.Array:
+    """Apply a kernel's output scale (broadcast over leading dims)."""
+    if scale is None:
+        return out
+    return out * scale.astype(out.dtype)
+
+
 def _quantize(w: jax.Array, contract_axes: tp.Sequence[int]) -> tp.Dict:
     """Symmetric absmax int8 over `contract_axes` (scale per out-channel)."""
     w = w.astype(jnp.float32)

@@ -229,16 +229,18 @@ def _dec_step(cfg: Seq2SeqConfig, p: tp.Dict, tokens: jax.Array,
     kernels included); only the cross sublayer is seq2seq-specific.
     Returns (logits, new_cache).
     """
-    from .decoding import _cached_self_attention, _gated_mlp
+    from .decoding import _cached_self_attention
+    from .moe import gated_mlp
 
     x = jnp.take(p["embed"], tokens, axis=0).astype(cfg.dtype)
     scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     new_cache = {}
+    block_cfg = cfg._block_config(causal=True)  # states the rotary keys
     for i in range(cfg.dec_layers):
         name = f"dec_blocks_{i}"
         bp = p[name]
         x, k_cache, v_cache = _cached_self_attention(
-            cfg, bp, x, positions, cache[name]["k"], cache[name]["v"],
+            block_cfg, bp, x, positions, cache[name]["k"], cache[name]["v"],
             cache_index)
         new_cache[name] = {"k": k_cache, "v": v_cache}
 
@@ -254,7 +256,7 @@ def _dec_step(cfg: Seq2SeqConfig, p: tp.Dict, tokens: jax.Array,
         x = x + jnp.einsum("bqhd,hdD->bqD", xa,
                            bp["xattn"]["out"]["kernel"].astype(cfg.dtype))
 
-        x = x + _gated_mlp(bp["mlp"],
+        x = x + gated_mlp(bp["mlp"],
                            rmsnorm(x, bp["norm3"]["scale"], cfg.dtype),
                            cfg.dtype)
 

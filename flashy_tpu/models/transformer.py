@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import (dot_product_attention, flash_attention,
                              sharded_flash_attention)
-from .moe import MoEMLP
+from .moe import ExpertMLP, MoEMLP
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,10 +70,55 @@ class TransformerConfig:
                                  # largest divisor (ops.ssd_scan)
     ssd_kernel: str = "auto"     # 'auto' | 'gather' | 'fused' — the
                                  # ops.ssd_scan chunked-kernel seam
+    # What a published config states beyond the block above. None of it
+    # is tunable, and the defaults are the model this file always built
+    # (full multi-head attention, base-10000 rotary over the whole head,
+    # one MLP kind in every block, tied head, float32 leaves).
+    attn_kind: str = "mha"       # 'mha' | 'mla': latent attention
+                                 # (models/mla.py), whose cache entry is
+                                 # the latent, not per-head K and V
+    q_lora_rank: int = 0         # mla: width of the query latent
+    kv_lora_rank: int = 0        # mla: width of the cached latent
+    qk_nope_head_dim: int = 0    # mla: per head, unrotated / rotated
+    qk_rope_head_dim: int = 0    #      key-query widths,
+    v_head_dim: int = 0          #      and the value width
+    rope_theta: float = 10000.0
+    rope_interleaved: bool = False  # rotary pairs (2i, 2i+1), not
+                                    # (i, i + D/2)
+    yarn_factor: float = 1.0     # > 1: yarn-scaled frequencies
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    dense_layers: int = 0        # with n_routed: leading layers that
+                                 # keep the dense MLP
+    dense_hidden: int = 0        # dense MLP width; 0 = dim * mlp_ratio
+    n_routed: int = 0            # > 0: expert layers with the sigmoid
+                                 # group-limited router (moe.ExpertMLP)
+                                 # after the leading dense ones
+    held_experts: tp.Tuple[int, int] = (0, 0)  # (first, count) of the
+                                 # routed experts THIS chip holds; the
+                                 # router keeps n_routed outputs.
+                                 # count 0 = all of them
+    expert_top_k: int = 0        # experts per token
+    expert_groups: int = 1       # router groups, and how many of them
+    expert_topk_groups: int = 1  # a token may choose from
+    expert_scale: float = 1.0    # routed_scaling_factor
+    n_shared: int = 0            # shared experts (one MLP of that width)
+    expert_hidden: int = 0       # width of one expert
+    tie_head: bool = True        # False: an output table `head` [V, D]
+    param_dtype: tp.Any = jnp.float32  # dtype of the matrix leaves
+                                 # (norm scales and the router's bias
+                                 # stay float32)
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.num_heads
+
+    @property
+    def mlp_hidden(self) -> int:
+        return self.dense_hidden or self.dim * self.mlp_ratio
 
 
 def mixer_pattern(cfg: "TransformerConfig") -> tp.Tuple[str, ...]:
@@ -92,6 +137,26 @@ def mixer_pattern(cfg: "TransformerConfig") -> tp.Tuple[str, ...]:
     return tuple(names[i % len(names)] for i in range(cfg.num_layers))
 
 
+def expert_layers(cfg: "TransformerConfig") -> tp.Tuple[bool, ...]:
+    """Per layer: True where the block's MLP is the `n_routed` expert
+    layer (every layer after the `dense_layers` leading ones). Checks
+    what the new kinds cannot be combined with."""
+    if cfg.attn_kind not in ("mha", "mla"):
+        raise ValueError(f"config.attn_kind must be 'mha' or 'mla', got "
+                         f"{cfg.attn_kind!r}")
+    if cfg.n_routed > 0 and cfg.moe_experts > 0:
+        raise ValueError("config.n_routed (sigmoid group-limited experts) "
+                         "and config.moe_experts (softmax MoEMLP) are two "
+                         "expert layers: state one")
+    if cfg.scan_layers and (cfg.attn_kind == "mla" or cfg.n_routed > 0
+                            or not cfg.tie_head):
+        raise ValueError("scan_layers stacks one block body: latent "
+                         "attention, n_routed expert layers and an untied "
+                         "head are not stacked (scan_layers=False)")
+    return tuple(cfg.n_routed > 0 and layer >= cfg.dense_layers
+                 for layer in range(cfg.num_layers))
+
+
 def rmsnorm(x: jax.Array, scale: jax.Array, dtype: tp.Any) -> jax.Array:
     """Functional RMSNorm matching nn.RMSNorm's math (f32 accumulation,
     eps 1e-6); used by the decode/pipelined paths that read raw params."""
@@ -100,8 +165,17 @@ def rmsnorm(x: jax.Array, scale: jax.Array, dtype: tp.Any) -> jax.Array:
     return (h * scale.astype(jnp.float32)).astype(dtype)
 
 
-def _rotary(x: jax.Array, positions: jax.Array) -> jax.Array:
-    """Apply rotary embeddings to [B, T, H, D] at the given positions."""
+def _rotary(x: jax.Array, positions: jax.Array,
+            cfg: tp.Optional[TransformerConfig] = None) -> jax.Array:
+    """Apply rotary embeddings to [B, T, H, D] at the given positions.
+    With a `cfg` that states another base, yarn or the interleaved
+    pairing, its frequencies (models/mla.py) instead of the table below."""
+    if cfg is not None:
+        from .mla import (plain_rotary, rope_cos_sin_scale, rope_inv_freq,
+                          rotate)
+        if not plain_rotary(cfg):
+            return rotate(x, positions, rope_inv_freq(cfg, x.shape[-1]),
+                          cfg.rope_interleaved, rope_cos_sin_scale(cfg))
     half = x.shape[-1] // 2
     freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B, T, half]
@@ -149,13 +223,14 @@ class Attention(nn.Module):
                  segment_ids: tp.Optional[jax.Array] = None) -> jax.Array:
         cfg = self.config
         qkv = nn.DenseGeneral((3, cfg.num_heads, cfg.head_dim), axis=-1,
-                              use_bias=False, dtype=cfg.dtype, name="qkv")(x)
+                              use_bias=False, dtype=cfg.dtype,
+                              param_dtype=cfg.param_dtype, name="qkv")(x)
         # column-parallel output: heads stay split over 'tensor' so the
         # whole attention body is head-local — no collective here
         qkv = _tp_boundary(qkv, self.mesh, None, "tensor", None)
         q, k, v = (qkv[:, :, i] for i in range(3))  # [B, T, H, Dh]
-        q = _rotary(q, positions)
-        k = _rotary(k, positions)
+        q = _rotary(q, positions, cfg)
+        k = _rotary(k, positions, cfg)
 
         if segment_ids is not None:
             # Packed batches (datapipe.SequencePacker): tokens may only
@@ -197,7 +272,8 @@ class Attention(nn.Module):
             out = dot_product_attention(q, k, v, causal=cfg.causal)
 
         out = nn.DenseGeneral(cfg.dim, axis=(-2, -1), use_bias=False,
-                              dtype=cfg.dtype, name="out")(out)
+                              dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                              name="out")(out)
         # row-parallel output: the contraction over 'tensor'-sharded
         # heads left partial sums — this boundary IS the all-reduce
         out = _tp_boundary(out, self.mesh)
@@ -213,16 +289,17 @@ class MLPBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         cfg = self.config
-        hidden = cfg.dim * cfg.mlp_ratio
+        hidden = cfg.mlp_hidden
         # Gated (SwiGLU-style) MLP: one fused up-projection, split in two.
-        up = nn.Dense(2 * hidden, use_bias=False, dtype=cfg.dtype, name="up")(x)
+        up = nn.Dense(2 * hidden, use_bias=False, dtype=cfg.dtype,
+                      param_dtype=cfg.param_dtype, name="up")(x)
         gate, value = jnp.split(up, 2, axis=-1)
         # Constrain the gated product, not `up`: gate/value are each F
         # wide and tensor-shard cleanly, whereas pinning the fused 2F
         # output would put the split boundary mid-shard (an all-to-all).
         h = _tp_boundary(nn.silu(gate) * value, self.mesh, "tensor")
         out = nn.Dense(cfg.dim, use_bias=False, dtype=cfg.dtype,
-                       name="down")(h)
+                       param_dtype=cfg.param_dtype, name="down")(h)
         out = _tp_boundary(out, self.mesh)  # row-parallel: the MLP all-reduce
         if cfg.dropout > 0.0:
             out = nn.Dropout(cfg.dropout, deterministic=not train)(out)
@@ -233,6 +310,7 @@ class Block(nn.Module):
     config: TransformerConfig
     mesh: tp.Any = None
     mixer: str = "attention"  # this layer's entry from mixer_pattern
+    experts: bool = False     # this layer's entry from expert_layers
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -242,13 +320,18 @@ class Block(nn.Module):
         if self.mixer == "ssd":
             from .ssd import SSDMixer
             mix: nn.Module = SSDMixer(cfg, mesh=self.mesh, name="ssd")
+        elif cfg.attn_kind == "mla":
+            from .mla import LatentAttention
+            mix = LatentAttention(cfg, name="attn")
         else:
             mix = Attention(cfg, mesh=self.mesh, name="attn")
         x = x + mix(
             nn.RMSNorm(dtype=cfg.dtype, name="norm1")(x), positions, train,
             segment_ids)
         normed = nn.RMSNorm(dtype=cfg.dtype, name="norm2")(x)
-        if cfg.moe_experts > 0:
+        if self.experts:
+            x = x + ExpertMLP(cfg, name="moe")(normed)
+        elif cfg.moe_experts > 0:
             x = x + MoEMLP(dim=cfg.dim, hidden=cfg.dim * cfg.mlp_ratio,
                            num_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
                            capacity_factor=cfg.moe_capacity_factor,
@@ -327,8 +410,9 @@ class TransformerLM(nn.Module):
         # head); activations drop to the compute dtype right after lookup.
         embedding = self.param(
             "embed", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.dim),
-            jnp.float32)
+            cfg.param_dtype)
         x = jnp.take(embedding, tokens, axis=0).astype(cfg.dtype)
+        experts = expert_layers(cfg)
         if cfg.scan_layers:
             # One compiled block body, scanned over a stacked [L, ...]
             # parameter dim — the idiomatic deep-model layout on TPU.
@@ -353,9 +437,14 @@ class TransformerLM(nn.Module):
             block = _remat(cfg) if cfg.remat else Block
             for layer in range(cfg.num_layers):
                 x = block(cfg, mesh=self.mesh, mixer=pattern[layer],
-                          name=f"block_{layer}")(
+                          experts=experts[layer], name=f"block_{layer}")(
                     x, positions, train, segment_ids)
         x = nn.RMSNorm(dtype=cfg.dtype, name="norm_f")(x)
+        if not cfg.tie_head:
+            # an output table of its own, laid out like the embedding
+            embedding = self.param(
+                "head", nn.initializers.normal(0.02),
+                (cfg.vocab_size, cfg.dim), cfg.param_dtype)
         if return_hidden:
             # Skip the head: the caller contracts against the tied
             # embedding itself (e.g. ops.losses.chunked_softmax_

@@ -64,6 +64,45 @@ def pool_spec(num_blocks: int, block_size: int, num_heads: int,
     return {"k": (shape, dtype), "v": (shape, dtype)}
 
 
+# A TPU stores an array in (8, 128) tiles. A leaf whose minor dimension
+# is not whole lanes gets another dimension on the lanes ([N, 16, 64]
+# and [N, 16, 576] are laid out with N minor: seen by compiling for the
+# v5e), and every step then pays relayout copies around the gather.
+LANES = 128
+
+
+def latent_pool_spec(num_blocks: int, block_size: int, rank: int,
+                     rope_dim: int, dtype
+                     ) -> tp.Dict[str, tp.Tuple[tp.Tuple[int, ...], tp.Any]]:
+    """ONE layer's entry of a latent pool (models/mla.py): `c`
+    [N, bs, rank], the normed latent (key AND value of every head),
+    and `kr` [N, bs, rope_dim rounded up to whole lanes], the rotated
+    shared key, zero beyond rope_dim. Two leaves so that a block of each
+    is whole (8, 128) tiles a kernel can copy, and the value is a leaf
+    of its own, not a slice of a 576-wide row. The padding is stored,
+    so the spec — and `pool_bytes` from it — counts it."""
+    return {"c": ((num_blocks, block_size, rank), dtype),
+            "kr": ((num_blocks, block_size, -(-rope_dim // LANES) * LANES),
+                   dtype)}
+
+
+def cfg_pool_spec(cfg, num_blocks: int, block_size: int, kv_dtype: str
+                  ) -> tp.Dict[str, tp.Tuple[tp.Tuple[int, ...], tp.Any]]:
+    """The pool entry of one layer of `cfg`: latent for
+    `attn_kind='mla'`, per-head K/V otherwise. Everything that sizes,
+    allocates or copies a pool follows this spec."""
+    if getattr(cfg, "attn_kind", "mha") == "mla":
+        if kv_dtype != "model":
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} keeps a scale per row and head; a "
+                f"latent pool (attn_kind='mla') has no heads in its rows: "
+                f"use kv_dtype='model'")
+        return latent_pool_spec(num_blocks, block_size, cfg.kv_lora_rank,
+                                cfg.qk_rope_head_dim, cfg.dtype)
+    return pool_spec(num_blocks, block_size, cfg.num_heads, cfg.head_dim,
+                     cfg.dtype, kv_dtype)
+
+
 def init_pool(cfg, num_blocks: int, block_size: int,
               kv_dtype: str = "model") -> tp.Dict:
     """Allocate the block-pool cache pytree for a TransformerLM config.
@@ -75,8 +114,7 @@ def init_pool(cfg, num_blocks: int, block_size: int,
     sentinel (ops-level convention; serve/paged.BlockPool never hands
     it out).
     """
-    spec = pool_spec(num_blocks, block_size, cfg.num_heads, cfg.head_dim,
-                     cfg.dtype, kv_dtype)
+    spec = cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
     if cfg.scan_layers:
         return {name: jnp.zeros((cfg.num_layers,) + shape, dt)
                 for name, (shape, dt) in spec.items()}
@@ -102,6 +140,19 @@ def _physical(table: jax.Array, positions: jax.Array, block_size: int
         table, jnp.minimum(index, table.shape[-1] - 1), axis=-1)
     block = jnp.where(index >= table.shape[-1], SENTINEL_BLOCK, block)
     return block, positions % block_size
+
+
+def latent_paged_write(entry: tp.Dict, c_kv: jax.Array, k_rope: jax.Array,
+                       table: jax.Array, positions: jax.Array) -> tp.Dict:
+    """`paged_write` for a latent pool ({c, kr}): the rows' normed
+    latents `[B, T, rank]` and rotated shared keys `[B, T, rope]`, the
+    key padded to the lanes it is stored on."""
+    block, offset = _physical(table, positions, entry["c"].shape[-2])
+    lanes = entry["kr"].shape[-1] - k_rope.shape[-1]
+    k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, lanes)))
+    return {name: entry[name].at[block, offset].set(
+                new.astype(entry[name].dtype))
+            for name, new in (("c", c_kv), ("kr", k_rope))}
 
 
 def paged_write(entry: tp.Dict, new_k: jax.Array, new_v: jax.Array,
@@ -213,6 +264,30 @@ def paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dtype), v_view)
 
 
+def latent_paged_attention(cfg, q_lat: jax.Array, q_rope: jax.Array,
+                           entry: tp.Dict, table: jax.Array,
+                           positions: jax.Array) -> jax.Array:
+    """The cached form of latent attention against a latent pool: each
+    row's table gathers its blocks into the logical views c [B, L, rank]
+    and kr [B, L, rope] (sentinel entries past every causal horizon, as
+    above), and `models.mla.cached_attention` attends them — the same
+    function the dense slab calls, query tiles and all. The rotated
+    queries are zero-padded to the `kr` leaf's stored width (the scores
+    are the same; the gathered view is not sliced). This step's rows
+    are already written. Returns o_lat [B, T, H, rank]."""
+    from ..models.mla import cached_attention
+    batch, entries = table.shape
+
+    def view(name):
+        g = entry[name][table]              # [B, E, bs, width]
+        return g.reshape(batch, entries * g.shape[2], g.shape[3])
+
+    lanes = entry["kr"].shape[-1] - q_rope.shape[-1]
+    q_rope = jnp.pad(q_rope, ((0, 0),) * 3 + ((0, lanes),))
+    return cached_attention(cfg, q_lat, q_rope, view("c"), view("kr"),
+                            positions)
+
+
 def slot_kv(entry: tp.Dict, table_row, length: int, dtype=jnp.float32
             ) -> tp.Tuple[jax.Array, jax.Array]:
     """Read back one slot's logical K/V rows [length, H, Dh].
@@ -235,8 +310,7 @@ def pool_bytes(cfg, num_blocks: int, block_size: int,
     import math
 
     import numpy as np
-    spec = pool_spec(num_blocks, block_size, cfg.num_heads, cfg.head_dim,
-                     cfg.dtype, kv_dtype)
+    spec = cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
     per_layer = sum(np.dtype(dt).itemsize * math.prod(shape)
                     for shape, dt in spec.values())
     return per_layer * cfg.num_layers

@@ -98,13 +98,18 @@ QUERY_TILE = 128      # query rows a grid step keeps when T must split
 FLAT_ROWS = 128       # at most this many (query, head) rows: flat layout
 
 
-def fused_kernel_unsupported_reason() -> tp.Optional[str]:
+def fused_kernel_unsupported_reason(cfg: tp.Any = None) -> tp.Optional[str]:
     """None when the fused kernel can genuinely RUN here (compiled on
-    TPU, interpret mode on CPU); else the human-readable reason. The
-    engine consults this to reject an explicit `kernel='fused'` LOUDLY
-    instead of letting the gather fallback masquerade as the kernel —
-    a demo/bench gate that reports 'fused' must have run it.
+    TPU, interpret mode on CPU) for `cfg`'s pool; else the
+    human-readable reason. The engine consults this to reject an
+    explicit `kernel='fused'` LOUDLY instead of letting the gather
+    fallback masquerade as the kernel — a demo/bench gate that reports
+    'fused' must have run it.
     """
+    if getattr(cfg, "attn_kind", "mha") == "mla":
+        return ("the fused kernel walks per-head K and V blocks; a latent "
+                "pool (attn_kind='mla') holds one shared row a token and "
+                "is read by the XLA table gather")
     backend = jax.default_backend()
     if backend in ("gpu", "cuda", "rocm"):
         return (f"the fused kernel is TPU-targeted and the backend is "
@@ -112,12 +117,12 @@ def fused_kernel_unsupported_reason() -> tp.Optional[str]:
     return None
 
 
-def default_kernel() -> str:
+def default_kernel(cfg: tp.Any = None) -> str:
     """The engine's `kernel='auto'` resolution: 'fused' on TPU (or TPU
-    PJRT plugins under other names), 'gather' on cpu/gpu — CPU runs
-    opt in to the fused kernel explicitly (interpret mode), the way
-    the demo and the parity tests do."""
-    if fused_kernel_unsupported_reason() is not None \
+    PJRT plugins under other names), 'gather' on cpu/gpu and for a
+    latent pool — CPU runs opt in to the fused kernel explicitly
+    (interpret mode), the way the demo and the parity tests do."""
+    if fused_kernel_unsupported_reason(cfg) is not None \
             or jax.default_backend() == "cpu":
         return "gather"
     return "fused"

@@ -33,6 +33,10 @@ SPAN_ADMIT = "serve/admit"
 SPAN_TABLE_UPLOAD = "serve/table_upload"
 SPAN_DISPATCH = "/dispatch"   # suffixes under decode / verify /
 SPAN_READBACK = "/readback"   # prefill_chunk
+# a span's stats are fixed when it opens, and the expert layers' counts
+# come back with the step's tokens: they ride on this empty child,
+# opened after the read-back, last inside its parent
+SPAN_MOE = "/moe"
 
 
 def _zero_ssd_leaves(cache: tp.Any, fresh: tp.Any) -> tp.Any:
@@ -72,7 +76,12 @@ def state_bytes_per_slot(cfg: tp.Any, max_seq_len: int, cache_layout: str,
     from ..models.transformer import mixer_pattern
     pattern = mixer_pattern(cfg)
     act_itemsize = jnp.dtype(cfg.dtype).itemsize
-    kv_slab = 2 * max_seq_len * cfg.num_heads * cfg.head_dim * act_itemsize
+    if cfg.attn_kind == "mla":  # one latent row a token, not K and V
+        from ..models.mla import latent_width
+        kv_slab = max_seq_len * latent_width(cfg) * act_itemsize
+    else:
+        kv_slab = (2 * max_seq_len * cfg.num_heads * cfg.head_dim
+                   * act_itemsize)
     ssd_state = cfg.num_heads * cfg.head_dim * cfg.ssd_state_dim * 4
     if cache_layout == "dense":
         return kv_slab * cfg.num_layers
@@ -255,6 +264,15 @@ class DecodeEngine:
             keying `pool` reservations. Engines sharing one pool MUST
             use disjoint `[base, base + slots)` ranges — otherwise two
             engines' slot 0 would collide on one reservation key.
+        keep_logits: a tap for a check that compares this engine's own
+            logits with a reference (paged only). The paged decode and
+            prefill-slice executables then also return the float32
+            logits they sampled from ([S, V]; [1, V] of a slice's last
+            used row), which stay on the device in `tapped['decode']`
+            and `tapped['prefill_chunk']` until the next such step
+            replaces them; the engine never reads them. The verify step
+            is not tapped. Off (the default), every executable is the
+            one it always was.
     """
 
     # every compiled step takes the cache as operand 1, donated so XLA
@@ -282,11 +300,17 @@ class DecodeEngine:
                  tracer: tp.Optional[Tracer] = None,
                  pool: tp.Optional[tp.Any] = None,
                  cache_box: tp.Optional[tp.Any] = None,
-                 pool_slot_base: int = 0):
+                 pool_slot_base: int = 0,
+                 keep_logits: bool = False):
         import jax
         import jax.numpy as jnp
         from ..models.decoding import init_cache
 
+        if keep_logits and cache_layout != "paged":
+            raise ValueError("keep_logits taps the paged executables only; "
+                             f"got cache_layout={cache_layout!r}")
+        self.keep_logits = bool(keep_logits)
+        self.tapped: tp.Dict[str, tp.Any] = {}
         self._model = model
         self._params = params
         self._cfg = model.config
@@ -327,6 +351,9 @@ class DecodeEngine:
         if kv_dtype == "int8" and cache_layout != "paged":
             raise ValueError("kv_dtype='int8' requires the paged cache "
                              "layout (scales live beside the block pool)")
+        # (a latent pool refuses int8 where its spec is made:
+        # ops.paged_attention.cfg_pool_spec)
+        self._latent = self._cfg.attn_kind == "mla"
         if kernel not in ("auto", "gather", "fused"):
             raise ValueError(f"kernel must be 'auto', 'gather' or "
                              f"'fused', got {kernel!r}")
@@ -339,7 +366,7 @@ class DecodeEngine:
             # it cannot (no pallas, GPU backend), the silent gather
             # fallback would let every fused gate/label false-pass
             from ..ops.paged_decode import fused_kernel_unsupported_reason
-            reason = fused_kernel_unsupported_reason()
+            reason = fused_kernel_unsupported_reason(self._cfg)
             if reason is not None:
                 raise ValueError(f"kernel='fused' cannot run here: "
                                  f"{reason}; use kernel='gather' (or "
@@ -347,7 +374,7 @@ class DecodeEngine:
         if kernel == "auto":
             if cache_layout == "paged":
                 from ..ops.paged_decode import default_kernel
-                kernel = default_kernel()
+                kernel = default_kernel(self._cfg)
             else:
                 kernel = "gather"
         self.kernel = kernel
@@ -487,6 +514,13 @@ class DecodeEngine:
         self._positions_host = np.full((slots,), self.max_seq_len, np.int64)
         self._active_host = np.zeros((slots,), bool)
         self._kv_walks: tp.Dict[int, tp.Any] = {}  # queries -> Walk
+        # the paged steps of a model with expert layers also return the
+        # layers' summed (assignments, experts hit), packed behind what
+        # the host reads back anyway (a scanned stack cannot hand them out)
+        self._moe_stats = (cache_layout == "paged"
+                           and not self._cfg.scan_layers
+                           and (self._cfg.n_routed > 0
+                                or self._cfg.moe_experts > 0))
 
     # ------------------------------------------------------------------
     # compiled steps
@@ -537,6 +571,35 @@ class DecodeEngine:
             return jax.random.categorical(
                 key, logits / self.temperature, axis=-1).astype(jnp.int32)
 
+    def _moe_list(self) -> tp.Optional[tp.List]:
+        """What a paged step hands `paged_apply_step(stats=)`: a list the
+        expert layers append their counts to, or None (no expert layer:
+        the program is the one it always was)."""
+        return [] if self._moe_stats else None
+
+    @staticmethod
+    def _with_moe(read, stats: tp.Optional[tp.List]):
+        """`read` (what the host reads back of a step, any int32 shape)
+        flattened, with the layers' summed (assignments, experts hit)
+        behind it: one transfer carries both."""
+        import jax.numpy as jnp
+        counts = jnp.stack([sum(s[0] for s in stats),
+                            sum(s[1] for s in stats)]).astype(jnp.int32)
+        return jnp.concatenate([read.reshape(-1).astype(jnp.int32), counts])
+
+    def _tap(self, logits) -> tp.Tuple:
+        """What `keep_logits` adds to a paged step's outputs: the
+        logits it sampled from, or nothing."""
+        import jax.numpy as jnp
+        return (logits.astype(jnp.float32),) if self.keep_logits else ()
+
+    def _moe_span(self, parent: str, counts) -> None:
+        """The empty `<parent>/moe` span that carries a step's counts."""
+        with span(parent + SPAN_MOE, self.tracer, category="serve",
+                  moe_assignments=int(counts[0]),
+                  moe_experts_hit=int(counts[1])):
+            pass
+
     def _table(self):
         """Device copy of the block tables, refreshed only when the host
         tables changed (admission / COW / retirement — never mid-decode,
@@ -567,12 +630,16 @@ class DecodeEngine:
                              active, key):
                 # identical contract to the dense step; the table is
                 # one more INPUT (contents never change the shape)
+                stats = self._moe_list()
                 logits, cache = paged_apply_step(
                     model, params, cfg, tokens[:, None],
                     positions[:, None], cache, table,
-                    kernel=self.kernel)
+                    kernel=self.kernel, stats=stats)
                 nxt = self._sample(logits[:, -1], key)
-                return jnp.where(active, nxt, jnp.int32(pad)), cache
+                nxt = jnp.where(active, nxt, jnp.int32(pad))
+                out = (nxt, cache) if stats is None else (
+                    nxt, cache, self._with_moe(nxt, stats))
+                return out + self._tap(logits[:, -1])
 
             return jax.jit(decode_paged, donate_argnums=self._donate)
 
@@ -646,12 +713,16 @@ class DecodeEngine:
                 row = jax.lax.dynamic_slice(
                     table, (slot, 0), (1, table.shape[1]))
                 positions = (start + jnp.arange(size, dtype=jnp.int32))[None]
+                stats = self._moe_list()
                 logits, cache = paged_apply_step(
                     model, params, cfg, tokens, positions, cache, row,
-                    kernel=self.kernel)
+                    kernel=self.kernel, stats=stats)
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], used - 1, axis=0, keepdims=True)
-                return self._sample(last, key)[0], cache
+                first = self._sample(last, key)[0]
+                out = (first, cache) if stats is None else (
+                    first, cache, self._with_moe(first, stats))
+                return out + self._tap(last)
 
             return jax.jit(chunk_paged, donate_argnums=self._donate)
 
@@ -715,9 +786,10 @@ class DecodeEngine:
                 toks = jnp.concatenate([tokens[:, None], drafts], axis=1)
                 pos = positions[:, None] \
                     + jnp.arange(k + 1, dtype=jnp.int32)[None]
+                stats = self._moe_list()
                 logits, cache = paged_apply_step(
                     model, params, cfg, toks, pos, cache, table,
-                    kernel=self.kernel)
+                    kernel=self.kernel, stats=stats)
                 out, accepted = speculative_acceptance(
                     drafts, logits, temperature=self.temperature,
                     rng=key if self.temperature > 0.0 else None,
@@ -729,6 +801,8 @@ class DecodeEngine:
                 new_tokens = jnp.where(active, last, jnp.int32(pad))
                 new_positions = jnp.where(active, positions + accepted + 1,
                                           positions)
+                if stats is not None:  # the counts ride behind `accepted`
+                    accepted = self._with_moe(accepted, stats)
                 return out, accepted, new_tokens, new_positions, cache
 
             return jax.jit(verify_paged, donate_argnums=self._donate)
@@ -768,7 +842,7 @@ class DecodeEngine:
         Scalars are inputs, so one compiled copy serves every fork."""
         import jax
         from .paged import copy_block_fn
-        copy = copy_block_fn(self._cfg.scan_layers)
+        copy = copy_block_fn(self._cfg, self.kv_dtype)
         return jax.jit(lambda cache, src, dst: copy(cache, src, dst),
                        donate_argnums=(0,))
 
@@ -805,7 +879,7 @@ class DecodeEngine:
             # can never touch a real one.
             for size in sorted({self.chunk, self.tail_bucket}):
                 dummy = jnp.full((1, size), self.pad_token, jnp.int32)
-                _, self._cache = self.compile_cache.warm(
+                _, self._cache, *_ = self.compile_cache.warm(
                     self._key("prefill_chunk", size),
                     lambda: self._build_prefill_chunk(size),
                     self._params, self._cache, *layout, dummy,
@@ -823,7 +897,7 @@ class DecodeEngine:
                     self._params, self._cache, dummy, jnp.int32(1),
                     jnp.int32(0), self._next_key())
                 warmed.append(f"prefill/{bucket}")
-        _, self._cache = self.compile_cache.warm(
+        _, self._cache, *_ = self.compile_cache.warm(
             self._key("decode", self.slots), self._build_decode,
             self._params, self._cache, *layout, self._tokens,
             self._positions, self._active, self._next_key())
@@ -1049,20 +1123,25 @@ class DecodeEngine:
             self._key("prefill_chunk", size),
             lambda: self._build_prefill_chunk(size))
         stats = {} if uid is None else {"uid": uid}
-        stats.update(self._kv_walk_stats(size, [start]))
+        stats.update(self._kv_read_stats(size, [start]))
         with span(SPAN_PREFILL_CHUNK, self.tracer, category="serve",
                   slot=slot, size=size, offset=start, length=length,
                   final=final, **stats):
-            first, self._cache = fn(self._params, self._cache,
-                                    *self._layout_args(),
-                                    jnp.asarray(padded), jnp.int32(start),
-                                    jnp.int32(used), jnp.int32(slot),
-                                    self._next_key())
+            first, self._cache, *packed = fn(
+                self._params, self._cache, *self._layout_args(),
+                jnp.asarray(padded), jnp.int32(start), jnp.int32(used),
+                jnp.int32(slot), self._next_key())
+            if self.keep_logits:
+                self.tapped["prefill_chunk"] = packed.pop()
             if not final:
+                # nothing of this slice is read back, its counts neither
                 return start + used, None
             with span(SPAN_PREFILL_CHUNK + SPAN_READBACK, self.tracer,
                       category="serve"):
-                first = int(first)
+                read = np.asarray(packed[0] if packed else first).reshape(-1)
+                first = int(read[0])
+            if packed:
+                self._moe_span(SPAN_PREFILL_CHUNK, read[1:])
             if self._pool is not None:
                 # prompt fully written: index its full blocks so later
                 # admissions share them instead of re-prefilling
@@ -1073,6 +1152,18 @@ class DecodeEngine:
             self._positions_host[slot] = length
             self._active_host[slot] = True
         return start + used, first
+
+    def _kv_read_stats(self, queries: int, bases) -> tp.Dict[str, int]:
+        """Span stats of one paged read of `queries` rows per slot from
+        first positions `bases` (host mirrors; no device work): the
+        fused kernel's walk counts, or for a latent pool `kv_bytes`, the
+        bytes as stored, over all layers, of the latent rows the live
+        slots' queries attend (parked slots sit at max_seq_len)."""
+        if not self._latent or self._pool is None:
+            return self._kv_walk_stats(queries, bases)
+        bases = np.asarray(bases)
+        rows = int((bases[bases < self.max_seq_len] + queries).sum())
+        return {"kv_bytes": rows * (self._block_bytes // self.block_size)}
 
     def _kv_walk_stats(self, queries: int, bases) -> tp.Dict[str, int]:
         """Span stats of one fused paged read of `queries` rows per slot
@@ -1104,16 +1195,21 @@ class DecodeEngine:
         with span(SPAN_DECODE, self.tracer, category="serve",
                   live=self.allocator.live_count,
                   running=int(self._active_host.sum()),
-                  **self._kv_walk_stats(1, self._positions_host)):
+                  **self._kv_read_stats(1, self._positions_host)):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_DECODE + SPAN_DISPATCH, self.tracer,
                       category="serve"):
-                tokens, self._cache = fn(self._params, self._cache, *layout,
-                                         self._tokens, self._positions,
-                                         self._active, key)
+                tokens, self._cache, *packed = fn(
+                    self._params, self._cache, *layout, self._tokens,
+                    self._positions, self._active, key)
+                if self.keep_logits:
+                    self.tapped["decode"] = packed.pop()
             with span(SPAN_DECODE + SPAN_READBACK, self.tracer,
                       category="serve"):
-                out = np.asarray(tokens)
+                out = np.asarray(packed[0] if packed else tokens)
+            if packed:
+                self._moe_span(SPAN_DECODE, out[self.slots:])
+                out = out[:self.slots]
             # feed each live slot its own token back; lengths advance by 1
             self._tokens = tokens
             self._positions = self._positions + self._active.astype(
@@ -1153,7 +1249,7 @@ class DecodeEngine:
         with span(SPAN_VERIFY, self.tracer, category="serve", k=k,
                   live=self.allocator.live_count,
                   running=int(self._active_host.sum()),
-                  **self._kv_walk_stats(k + 1, self._positions_host)):
+                  **self._kv_read_stats(k + 1, self._positions_host)):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_VERIFY + SPAN_DISPATCH, self.tracer,
                       category="serve"):
@@ -1165,6 +1261,9 @@ class DecodeEngine:
                       category="serve"):
                 out_np = np.asarray(out)
                 accepted_np = np.asarray(accepted)
+            if self._moe_stats:
+                self._moe_span(SPAN_VERIFY, accepted_np[self.slots:])
+                accepted_np = accepted_np[:self.slots]
         self._positions_host += np.where(self._active_host,
                                          accepted_np.astype(np.int64) + 1, 0)
         return out_np, accepted_np

@@ -196,6 +196,12 @@ class PrefixIndex:
                   if e.children == 0 and refcount[e.block] == 0]
         return sorted(leaves, key=lambda e: e.last_use)
 
+    def parent(self, entry: _IndexEntry) -> tp.Optional[_IndexEntry]:
+        """The entry one block up `entry`'s chain (None at the root)."""
+        if entry.parent_key is _ROOT:
+            return None
+        return self._entries[entry.parent_key]
+
     def evict(self, entry: _IndexEntry) -> int:
         """Drop a (leaf) entry; returns its freed pool block id."""
         assert entry.children == 0, "evict leaves first"
@@ -368,19 +374,40 @@ class BlockPool:
         return plan.fresh_needed <= self._headroom_for(plan)
 
     def _evict_for(self, need: int, protect: tp.Set[int]) -> None:
-        """Free cached blocks (LRU leaves first) until `need` are free."""
+        """Free cached blocks (LRU leaves first) until `need` are free.
+
+        One scan of the index, then a heap: evicting a leaf may make its
+        parent the next leaf, which joins the heap by its own last use —
+        the same victims in the same order as picking the globally
+        least recently used evictable leaf afresh each time (a retired
+        4,000-token prompt is ONE chain of 250 entries whose leaves
+        appear one at a time: rescanning the index per block cost
+        0.1-1.6 s an admission once the pool was full)."""
+        if len(self._free) >= need:
+            return
+
+        def usable(entry: _IndexEntry) -> bool:
+            return (entry.children == 0 and self.refcount[entry.block] == 0
+                    and entry.block not in protect)
+
+        # last_use values are unique (one clock tick each): no ties
+        heap = [(e.last_use, e) for e in self.index.evictable(self.refcount)
+                if e.block not in protect]
+        heapq.heapify(heap)
         while len(self._free) < need:
-            candidates = [e for e in self.index.evictable(self.refcount)
-                          if e.block not in protect]
-            if not candidates:
+            if not heap:
                 raise PoolExhausted(
                     f"pool over-committed: need {need} free blocks, have "
                     f"{len(self._free)} free + "
                     f"{self.cached_blocks} evictable")
-            block = self.index.evict(candidates[0])
+            _, entry = heapq.heappop(heap)
+            parent = self.index.parent(entry)
+            block = self.index.evict(entry)
             self.evictions += 1
             self._cached.discard(block)
             heapq.heappush(self._free, block)
+            if parent is not None and usable(parent):
+                heapq.heappush(heap, (parent.last_use, parent))
 
     def commit(self, plan: AdmissionPlan, slot: int
                ) -> tp.Tuple[np.ndarray, int,
@@ -566,7 +593,7 @@ class BlockPool:
 # the paged model step (device side)
 # ----------------------------------------------------------------------
 def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
-                     kernel: str = "gather"):
+                     kernel: str = "gather", stats=None):
     """Forward `tokens` [B, T] at `positions` [B, T] against the pool.
 
     The paged twin of models/decoding._apply_step: same embed, MLP/MoE,
@@ -580,13 +607,20 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     (ops/paged_decode.py) — legal here because every engine read path
     queries consecutive positions per row, the fused kernel's one
     extra contract. The write stays `paged_write` either way (a
-    per-row scatter XLA already fuses).
+    per-row scatter XLA already fuses). A latent block
+    (`attn_kind='mla'`) writes and gathers its latent pool entry and
+    attends in the cached form (models/mla.py); its read is always the
+    gather. Each expert layer of an unstacked model appends its
+    (assignments, experts hit) counts to `stats` when a list is given.
     """
     import jax
 
     from ..models.decoding import (_attn_residual, _embed_tokens,
-                                   _head_logits, _mlp_residual, _qkv_heads)
-    from ..ops.paged_attention import paged_attention, paged_write
+                                   _head_logits, _mlp_residual, _qkv_heads,
+                                   latent_projections, latent_residual)
+    from ..ops.paged_attention import (latent_paged_attention,
+                                       latent_paged_write, paged_attention,
+                                       paged_write)
     from ..ops.paged_decode import fused_paged_attention
 
     if kernel not in ("gather", "fused"):
@@ -594,7 +628,19 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
                          f"got {kernel!r}")
     attend = fused_paged_attention if kernel == "fused" else paged_attention
 
-    def layer(bp, x, entry):
+    def latent_layer(bp, x, entry):
+        (q_lat, q_rope), (c_kv, k_rope) = latent_projections(cfg, bp, x,
+                                                             positions)
+        with jax.named_scope("kv_write"):
+            entry = latent_paged_write(entry, c_kv, k_rope, table,
+                                       positions)
+        with jax.named_scope("attn"):
+            o_lat = latent_paged_attention(cfg, q_lat, q_rope, entry, table,
+                                           positions)
+        x = latent_residual(cfg, bp, x, o_lat)
+        return _mlp_residual(cfg, bp, x, stats), entry
+
+    def kv_layer(bp, x, entry):
         # the dense step's scopes (models/decoding.py), same names
         q, k, v = _qkv_heads(cfg, bp, x, positions)
         with jax.named_scope("kv_write"):
@@ -603,8 +649,9 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
             attn = attend(q, entry, table, positions,
                           head_dim=cfg.head_dim, dtype=cfg.dtype)
         x = _attn_residual(cfg, bp, x, attn)
-        return _mlp_residual(cfg, bp, x), entry
+        return _mlp_residual(cfg, bp, x, stats), entry
 
+    layer = latent_layer if cfg.attn_kind == "mla" else kv_layer
     p = params["params"]
     x = _embed_tokens(p, tokens, cfg.dtype)
     if cfg.scan_layers:
@@ -625,25 +672,29 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     return _head_logits(p, x, cfg), new_cache
 
 
-def copy_block_fn(scan_layers: bool) -> tp.Callable:
+def copy_block_fn(cfg, kv_dtype: str) -> tp.Callable:
     """Build the COW device copy: `(cache, src, dst) -> cache` with
     block `src`'s rows duplicated onto block `dst` across every layer
-    and leaf (int8 payloads AND their scales). One fixed-shape
-    executable per engine — warmed with the decode/verify steps so a
-    fork never compiles mid-traffic."""
+    and leaf (K/V payloads and their scales, or a latent pool's `c` and
+    `kr`). The block axis of a leaf follows the pool's spec
+    (`ops.paged_attention.cfg_pool_spec`): a layer-stacked leaf has it
+    one further in. One fixed-shape executable per engine — warmed with
+    the decode/verify steps so a fork never compiles mid-traffic."""
     import jax.numpy as jnp
+
+    from ..ops.paged_attention import cfg_pool_spec
+    spec = cfg_pool_spec(cfg, 1, 1, kv_dtype)
 
     def copy_entry(entry, src, dst):
         out = {}
         for name, leaf in entry.items():
-            # k/v leaves are [..., N, bs, H, Dh]; scales [..., N, bs, H]
-            axis = leaf.ndim - (4 if name in ("k", "v") else 3)
+            axis = leaf.ndim - len(spec[name][0])
             row = jnp.take(leaf, src, axis=axis)
             idx = (slice(None),) * axis + (dst,)
             out[name] = leaf.at[idx].set(row)
         return out
 
-    if scan_layers:
+    if cfg.scan_layers:
         return copy_entry
 
     def copy(cache, src, dst):

@@ -131,13 +131,13 @@ def test_greedy_generate_moe_scan_stacked():
 
 
 @pytest.mark.slow
-def test_moe_prefill_expert_stream_path():
-    # long prompts take the expert-streaming branch (N > gather cutoff);
-    # it must agree with the training forward exactly like the gather path.
+def test_moe_prefill_of_a_long_prompt():
+    # a prefill-sized token count through the one expert layer
+    # (`moe.expert_layer`: sorted by expert, grouped product) must agree
+    # with the training forward exactly like a decode step's few tokens.
     model, params = _moe_model()
     prompt = jnp.asarray(
         np.random.default_rng(4).integers(0, 64, (2, 40)), jnp.int32)
-    assert 2 * 40 > 64  # exercises the lax.scan-over-experts branch
     out = generate(model, params, prompt, max_new_tokens=2)
 
     tokens = prompt

@@ -7,6 +7,7 @@
 # draft rows beyond the accepted position are rewritten identically by
 # a fresh prefill), refcounted free-on-retire, and the pool/prefix
 # metrics fan-out into summary/serve.json/info.
+import heapq
 import json
 import logging
 
@@ -195,6 +196,75 @@ def test_block_pool_never_evicts_its_own_matched_chain():
     assert start == 8  # both cached blocks served from the index
     assert pool.index.match(shared, 4)[0]
     pool.check()
+
+
+def _evict_afresh(pool, need, protect):
+    """The loop `BlockPool._evict_for` replaced: pick the least recently
+    used evictable leaf afresh, by a whole scan, for every block."""
+    while pool.free_blocks < need:
+        candidates = [e for e in pool.index.evictable(pool.refcount)
+                      if e.block not in protect]
+        if not candidates:
+            raise PoolExhausted("nothing left to evict")
+        block = pool.index.evict(candidates[0])
+        pool._cached.discard(block)
+        heapq.heappush(pool._free, block)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_eviction_takes_the_victims_the_rescanning_loop_took(seed,
+                                                              monkeypatch):
+    """One scan and a heap against a fresh scan per block, on what the
+    heap could get wrong: long chains whose leaves appear one at a time,
+    chains that fork (a parent becomes a leaf only when its LAST child
+    goes), prefixes matched again so that a parent was used after its
+    child, a live slot holding part of a chain, and protected blocks in
+    the middle of one. Same victims, in the same order."""
+    rng = np.random.default_rng(seed)
+
+    def build():
+        pool = BlockPool(num_blocks=641, block_size=4, max_seq_len=256)
+        draws = np.random.default_rng(seed)
+        trunk = draws.integers(0, 50, 80).astype(np.int32)
+        prompts = []
+        for _ in range(10):
+            keep = 4 * int(draws.integers(0, 20))  # fork off the trunk
+            own = draws.integers(50, 99, int(draws.integers(40, 240 - keep)))
+            prompts.append(np.concatenate([trunk[:keep],
+                                           own.astype(np.int32)]))
+        for slot, prompt in enumerate(prompts):
+            pool.commit(pool.plan(prompt, 2), slot)
+            pool.on_live(slot)
+        for slot in draws.permutation(10)[:8]:  # two stay live
+            pool.release(int(slot))
+        for prompt in draws.permutation(10)[:3]:  # used again, parents
+            pool.index.match(prompts[prompt][:12], 4)  # after children
+        return pool, prompts
+
+    old, prompts = build()
+    new, _ = build()
+    matched = prompts[int(rng.integers(10))]
+    protect = {e.block for e in new.index.match(matched, 4)[0][:3]}
+    old.index.match(matched, 4)  # the same clock ticks on both sides
+    victims = {id(old): [], id(new): []}
+    evict = PrefixIndex.evict
+
+    def spy(index, entry):
+        owner = old if index is old.index else new
+        victims[id(owner)].append(entry.block)
+        return evict(index, entry)
+
+    monkeypatch.setattr(PrefixIndex, "evict", spy)
+    need = new.free_blocks + new.cached_blocks // 2
+    assert need >= new.free_blocks + 80, "long enough to walk whole chains"
+    new._evict_for(need, protect)
+    _evict_afresh(old, need, protect)
+    assert victims[id(new)] == victims[id(old)]
+    assert len(victims[id(new)]) == len(set(victims[id(new)])) >= 80
+    assert not protect & set(victims[id(new)])
+    new.check()
+    with pytest.raises(PoolExhausted):
+        new._evict_for(new.free_blocks + new.cached_blocks + 1, protect)
 
 
 def test_block_pool_ttl_expired_request_leaks_nothing():
