@@ -274,7 +274,10 @@ def latent_paged_attention(cfg, q_lat: jax.Array, q_rope: jax.Array,
     function the dense slab calls, query tiles and all. The rotated
     queries are zero-padded to the `kr` leaf's stored width (the scores
     are the same; the gathered view is not sliced). This step's rows
-    are already written. Returns o_lat [B, T, H, rank]."""
+    are already written. Returns o_lat [B, T, H, rank]. The XLA read
+    of a latent pool: what `kernel='gather'` runs, the oracle of
+    `ops.paged_decode.fused_latent_attention` (which never builds the
+    views or the scores) and the read of pools that kernel refuses."""
     from ..models.mla import cached_attention
     batch, entries = table.shape
 

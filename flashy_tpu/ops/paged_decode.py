@@ -73,7 +73,19 @@
 # so `key_pos <= q_pos` masks it — and all-sentinel warm-up tables
 # attend nothing real by construction.
 #
-# The gather implementation stays as the interpret-mode oracle (the
+# A LATENT pool (models/mla.py: one normed latent `c` [rank] a token, key
+# AND value of every head, and one rotated key `kr`) takes the same walk
+# with other operands (`fused_latent_attention`, kernel
+# `latent_decode_fused`): table and bases prefetched, whole `c`
+# [bs, rank] and `kr` [bs, 128] blocks copied into double-buffered
+# tiles, `ceil(live_blocks / group)` steps, the same online softmax. All
+# heads share the key row, so a slot brings H query rows a position and
+# a step is two 2-D dots for the scores and one for the values — no
+# per-head loop, no layout choice, no "same head" mask. It has only this
+# walk: a latent pool whose blocks are not whole tiles is refused
+# (`fused_kernel_unsupported_reason`) and read by the XLA table gather.
+#
+# The gather implementations stay as the interpret-mode oracles (the
 # ops/attention.py convention: pallas interpret mode on CPU, XLA
 # gather as the reference): token-exactness tests drive both through
 # the same engine and compare streams.
@@ -98,18 +110,28 @@ QUERY_TILE = 128      # query rows a grid step keeps when T must split
 FLAT_ROWS = 128       # at most this many (query, head) rows: flat layout
 
 
-def fused_kernel_unsupported_reason(cfg: tp.Any = None) -> tp.Optional[str]:
+def fused_kernel_unsupported_reason(cfg: tp.Any = None,
+                                    block_size: tp.Optional[int] = None
+                                    ) -> tp.Optional[str]:
     """None when the fused kernel can genuinely RUN here (compiled on
     TPU, interpret mode on CPU) for `cfg`'s pool; else the
     human-readable reason. The engine consults this to reject an
     explicit `kernel='fused'` LOUDLY instead of letting the gather
     fallback masquerade as the kernel — a demo/bench gate that reports
-    'fused' must have run it.
+    'fused' must have run it. A latent pool (`attn_kind='mla'`) has no
+    walk but the one whose copies the kernel issues itself, so its `c`
+    and `kr` blocks must be whole (sublanes, 128) tiles: `block_size`,
+    when the caller knows it, is held to the sublanes of `cfg.dtype`.
     """
     if getattr(cfg, "attn_kind", "mha") == "mla":
-        return ("the fused kernel walks per-head K and V blocks; a latent "
-                "pool (attn_kind='mla') holds one shared row a token and "
-                "is read by the XLA table gather")
+        sublanes = SUBLANES * 4 // jnp.dtype(cfg.dtype).itemsize
+        if cfg.kv_lora_rank % LANES or (block_size or 0) % sublanes:
+            return (f"the fused kernel copies whole ({sublanes}, {LANES}) "
+                    f"tiles of a latent pool's blocks and this one's are "
+                    f"[{block_size or 'block_size'}, {cfg.kv_lora_rank}] "
+                    f"(kv_lora_rank must be a multiple of {LANES}, "
+                    f"block_size of {sublanes}); the XLA table gather "
+                    f"reads any latent pool")
     backend = jax.default_backend()
     if backend in ("gpu", "cuda", "rocm"):
         return (f"the fused kernel is TPU-targeted and the backend is "
@@ -117,12 +139,15 @@ def fused_kernel_unsupported_reason(cfg: tp.Any = None) -> tp.Optional[str]:
     return None
 
 
-def default_kernel(cfg: tp.Any = None) -> str:
+def default_kernel(cfg: tp.Any = None,
+                   block_size: tp.Optional[int] = None) -> str:
     """The engine's `kernel='auto'` resolution: 'fused' on TPU (or TPU
-    PJRT plugins under other names), 'gather' on cpu/gpu and for a
-    latent pool — CPU runs opt in to the fused kernel explicitly
-    (interpret mode), the way the demo and the parity tests do."""
-    if fused_kernel_unsupported_reason(cfg) is not None \
+    PJRT plugins under other names) for every pool the kernel can walk
+    — K/V pools, and latent pools whose blocks are whole tiles —
+    'gather' on cpu/gpu and for the rest. CPU runs opt in to the fused
+    kernel explicitly (interpret mode), the way the demo and the parity
+    tests do."""
+    if fused_kernel_unsupported_reason(cfg, block_size) is not None \
             or jax.default_backend() == "cpu":
         return "gather"
     return "fused"
@@ -688,6 +713,254 @@ def fused_speculative_verify(q: jax.Array, entry: tp.Dict,
                                  head_dim=head_dim, dtype=dtype,
                                  head_block=head_block,
                                  interpret=interpret)
+
+
+# ----------------------------------------------------------------------
+# the same walk over a latent pool (models/mla.py's cached form)
+# ----------------------------------------------------------------------
+LATENT_ROWS = 2048       # (query, head) rows a grid step keeps
+LATENT_SCORES = 2 ** 20  # score-tile elements a compute step aims at
+LATENT_KEYS = 1024       # at most this many keys a compute step
+# The slice's walk is bound by the MXU and loses a fifth of its speed in
+# the 16 MiB every kernel gets by default (rows x keys 1024 x 256 against
+# 2048 x 512: 5.0 against 4.0 ms a layer at offset 3,584; PERF.md, PR
+# 28), so this kernel states its own limit — of the v5e's 128 MiB.
+LATENT_VMEM_LIMIT = 48 * 2 ** 20
+
+
+def _latent_vmem_estimate(queries: int, heads: int, rank: int, lanes: int,
+                          block_size: int, group: int, itemsize: int) -> int:
+    """`_vmem_estimate` for `_latent_walk_body`, from its own buffers:
+    the pipelined q_lat, q_rope and o blocks, the softmax state, the
+    double-buffered `c` and `kr` tiles, the score-shaped temporaries
+    (the mask's distances, scores, probs and their cast) and the value
+    product. High by a fifth at the cell's slice (35.9 MB where Mosaic
+    counts 29.4): an estimate to choose by."""
+    rows, keys = queries * heads, group * block_size
+    total = 2 * rows * (2 * rank + lanes) * itemsize      # q_lat, q_rope, o
+    total += rows * (2 * LANES + rank) * 4                # m, l, acc
+    total += 2 * keys * (rank + lanes) * itemsize         # c, kr tiles x2
+    total += rows * keys * (3 * 4 + itemsize)             # score-shaped
+    total += rows * rank * 4                              # probs . c
+    return total
+
+
+def latent_walk_shape(queries: int, heads: int, rank: int, lanes: int,
+                      block_size: int, entries: int, *, itemsize: int
+                      ) -> Walk:
+    """The walk of one latent read, from its shapes alone. Every head
+    shares the key row, so a slot brings `heads` query rows a position:
+    the MXU has its rows at T=1 and the group is what grows, to
+    `LATENT_KEYS` keys a step; a slice's T splits into query tiles of
+    at most `LATENT_ROWS` rows, each walking its own causal prefix,
+    against fewer keys a step so that a score tile stays
+    `LATENT_SCORES` elements. Then group and tile halve in turn until
+    `_latent_vmem_estimate` fits `LATENT_VMEM_LIMIT`. `head_block` is
+    all the heads, the layout flat, the copies the kernel's own."""
+    tile = max(1, min(queries, LATENT_ROWS // heads))
+    while queries % tile:
+        tile -= 1
+
+    def group_for(tile):
+        keys = min(LATENT_KEYS, LATENT_SCORES // (tile * heads))
+        return max(1, min(keys // block_size, entries))
+
+    def fits(group, tile):
+        return _latent_vmem_estimate(tile, heads, rank, lanes, block_size,
+                                     group, itemsize) <= LATENT_VMEM_LIMIT
+
+    group = group_for(tile)
+    while not fits(group, tile):
+        if group > 1:
+            group //= 2
+        elif tile > 1 and tile % 2 == 0:
+            tile //= 2
+            group = group_for(tile)
+        else:
+            break  # the smallest walk there is; Mosaic has the last word
+    return Walk(group, heads, tile, True, True)
+
+
+def latent_call_walk(queries: int, heads: int, entry: tp.Dict, *,
+                     entries: int) -> Walk:
+    """The walk `fused_latent_attention` takes for `queries` rows a slot
+    against a latent pool `entry` — its `c` and `kr` leaves, or anything
+    with their `shape` and `dtype` (the engine asks too, for its
+    `kv_steps` counter, with `jax.ShapeDtypeStruct`s of the pool's
+    spec)."""
+    c, kr = entry["c"], entry["kr"]
+    return latent_walk_shape(queries, heads, c.shape[-1], kr.shape[-1],
+                             c.shape[-2], entries,
+                             itemsize=jnp.dtype(c.dtype).itemsize)
+
+
+def _latent_walk_body(table_ref, base_ref, ql_ref, qr_ref, c_hbm, kr_hbm,
+                      o_ref, c_buf, kr_buf, sems, m_scr, l_scr, acc_scr, *,
+                      block_size: int, group: int, entries: int,
+                      scale: float):
+    """One (slot, query-tile) grid step: the whole walk, `_dma_walk_body`
+    for a pool whose row is one latent `c` [rank] — key AND value of
+    every head — and one rotated key `kr` [lanes]. The `[tq * H, rank]`
+    and `[tq * H, lanes]` queries attend a `[group * bs, rank | lanes]`
+    tile in two 2-D dots whose sum is the scores, and the value product
+    reads the `c` half again: no per-head loop, no "same head" mask —
+    only `key_pos <= q_pos`, which also hides sentinel entries and the
+    padding of a partial last group. Every query sees key 0, so no row
+    is ever without a visible key and the softmax needs no guard."""
+    slot, qtile = pl.program_id(0), pl.program_id(1)
+    _, tq, heads, rank = ql_ref.shape
+    rows, keys = tq * heads, group * block_size
+
+    first = base_ref[slot] + qtile * tq        # this tile's first q pos
+    live = _live_blocks(base_ref[slot], first + tq - 1, block_size,
+                        entries, jnp)
+    steps = (live + group - 1) // group
+
+    pools = ((c_hbm, c_buf, 0), (kr_hbm, kr_buf, 1))
+
+    def start_copies(step, half):
+        for g in range(group):
+            # as in `_dma_walk_body`: a partial last group re-reads the
+            # last live block, never an entry past the live range
+            block = table_ref[slot, jnp.minimum(step * group + g, live - 1)]
+            for src, dst, sem in pools:
+                pltpu.make_async_copy(src.at[block], dst.at[half, g],
+                                      sems.at[sem, half]).start()
+
+    def wait_copies(half):
+        # one wait an array: a DMA semaphore counts bytes, so a copy
+        # the size of the whole half — never started, the half lends it
+        # both shapes — waits for the group's (decode: 1.06 -> 0.97 ms
+        # a layer; PERF.md, PR 28)
+        for _, dst, sem in pools:
+            pltpu.make_async_copy(dst.at[half], dst.at[half],
+                                  sems.at[sem, half]).wait()
+
+    _init_state(m_scr, l_scr, acc_scr)
+    start_copies(0, 0)
+
+    # rows (t, h), columns (block, row-in-block): how far each pair is
+    # from the causal diagonal when the walk is at step 0
+    shape = (rows, keys)
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 0) // heads)
+    q_lat = ql_ref[0].reshape(rows, rank)
+    q_rope = qr_ref[0].reshape(rows, qr_ref.shape[-1])
+    nt = (((1,), (1,)), ((), ()))
+
+    def attend(step, carry):
+        half = step % 2
+
+        @pl.when(step + 1 < steps)
+        def _prefetch():
+            start_copies(step + 1, 1 - half)
+
+        wait_copies(half)
+        c = c_buf[half].reshape(keys, rank)
+        kr = kr_buf[half].reshape(keys, kr_buf.shape[-1])
+        scores = (jax.lax.dot_general(q_lat, c, nt,
+                                      preferred_element_type=jnp.float32)
+                  + jax.lax.dot_general(q_rope, kr, nt,
+                                        preferred_element_type=jnp.float32))
+        scores = jnp.where(ahead <= first - step * keys, scores * scale,
+                           NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.exp(scores - m_new)
+        l_new = l_scr[:, :1] * alpha + probs.sum(axis=-1, keepdims=True)
+        # P cast to the pool's dtype for the MXU, f32 accumulation
+        pv = jnp.dot(probs.astype(c.dtype), c,
+                     preferred_element_type=jnp.float32)
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    jax.lax.fori_loop(0, steps, attend, 0)
+    out = acc_scr[:] / l_scr[:, :1]
+    o_ref[0] = out.reshape(tq, heads, rank).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("walk", "scale", "interpret"))
+def _latent_call(q_lat, q_rope, entry, table, base, walk: Walk, *,
+                 scale: float, interpret: bool):
+    # jitted for the reason `_fused_call` is: the layers trace it once
+    batch, queries, heads, rank = q_lat.shape
+    lanes = entry["kr"].shape[-1]
+    block_size = entry["c"].shape[-2]
+    group, _, tq, _, _ = walk
+    dtype = entry["c"].dtype
+
+    def q_spec(width):
+        return pl.BlockSpec((1, tq, heads, width),
+                            lambda b, t, *_: (b, t, 0, 0))
+
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # the block table + the base positions
+        grid=(batch, queries // tq),
+        in_specs=[q_spec(rank), q_spec(lanes), hbm, hbm],
+        out_specs=q_spec(rank),
+        scratch_shapes=[
+            pltpu.VMEM((2, group, block_size, rank), dtype),
+            pltpu.VMEM((2, group, block_size, lanes), dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((heads * tq, LANES), jnp.float32),  # running max
+            pltpu.VMEM((heads * tq, LANES), jnp.float32),  # normalizer
+            pltpu.VMEM((heads * tq, rank), jnp.float32)],  # accumulator
+    )
+    kernel = functools.partial(
+        _latent_walk_body, block_size=block_size, group=group,
+        entries=table.shape[1], scale=scale)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_lat.shape, q_lat.dtype,
+                                       vma=jax.typeof(q_lat).vma),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=LATENT_VMEM_LIMIT),
+        name="latent_decode_fused",
+    )(table, base, q_lat, q_rope, entry["c"], entry["kr"])
+
+
+def fused_latent_attention(cfg, q_lat: jax.Array, q_rope: jax.Array,
+                           entry: tp.Dict, table: jax.Array,
+                           positions: jax.Array, *,
+                           interpret: tp.Optional[bool] = None
+                           ) -> jax.Array:
+    """`ops.paged_attention.latent_paged_attention`'s contract, one
+    kernel: the absorbed queries q_lat [B, T, H, rank] and rotated
+    q_rope [B, T, H, rope] against one layer's latent pool `entry`
+    ({c, kr}, this step's rows already written) through `[B,
+    max_blocks]` tables, causal by `positions` [B, T], which must be
+    CONSECUTIVE per row as for `fused_paged_attention`. Neither the
+    gathered `[B, L, rank]` view nor the `[B, H, T, L]` scores exist: a
+    slot's live blocks are copied once a query tile and attended under
+    an online softmax. Precision is the gather read's: operands in the
+    pool's dtype, float32 scores and softmax state, probabilities cast
+    to the pool's dtype for the value product, float32 accumulation,
+    o_lat [B, T, H, rank] in `cfg.dtype`. `interpret=None` resolves as
+    `fused_paged_attention` does."""
+    from ..models.mla import softmax_scale
+    from .paged_attention import latent_paged_attention
+    if interpret is None:
+        backend = jax.default_backend()
+        if backend in ("gpu", "cuda", "rocm"):
+            return latent_paged_attention(cfg, q_lat, q_rope, entry, table,
+                                          positions)
+        interpret = backend == "cpu"
+    dtype = entry["c"].dtype
+    lanes = entry["kr"].shape[-1] - q_rope.shape[-1]
+    q_rope = jnp.pad(q_rope, ((0, 0),) * 3 + ((0, lanes),))
+    walk = latent_call_walk(q_lat.shape[1], q_lat.shape[2], entry,
+                            entries=table.shape[1])
+    base = jax.lax.slice_in_dim(positions, 0, 1, axis=1)[:, 0]
+    out = _latent_call(q_lat.astype(dtype), q_rope.astype(dtype), entry,
+                       table, base.astype(jnp.int32), walk,
+                       scale=float(softmax_scale(cfg)), interpret=interpret)
+    return out.astype(cfg.dtype)
 
 
 def decode_read_bytes_per_token(cfg, context_len: int,

@@ -227,10 +227,13 @@ class DecodeEngine:
             decode, speculative verify and chunked prefill through the
             Pallas paged-decode kernel (ops/paged_decode.py — block
             iteration straight off the table, in-kernel int8 dequant
-            under the FT203 scale fold, online softmax). 'auto' (the
+            under the FT203 scale fold, online softmax; a latent pool
+            through the same module's latent walk). 'auto' (the
             default) resolves to 'fused' on TPU and 'gather'
-            elsewhere; on CPU an explicit kernel='fused' runs in
-            interpret mode (what the demo and the parity tests do).
+            elsewhere, and to 'gather' for a latent pool whose blocks
+            the kernel cannot copy (`ops.paged_decode.default_kernel`);
+            on CPU an explicit kernel='fused' runs in interpret mode
+            (what the demo and the parity tests do).
         prefix_cache: enable cross-request prefix sharing (paged only).
         cache_scope: prefix for this engine's compile-cache keys (and
             therefore its RecompileWatchdog entry names). REQUIRED
@@ -366,7 +369,7 @@ class DecodeEngine:
             # it cannot (no pallas, GPU backend), the silent gather
             # fallback would let every fused gate/label false-pass
             from ..ops.paged_decode import fused_kernel_unsupported_reason
-            reason = fused_kernel_unsupported_reason(self._cfg)
+            reason = fused_kernel_unsupported_reason(self._cfg, block_size)
             if reason is not None:
                 raise ValueError(f"kernel='fused' cannot run here: "
                                  f"{reason}; use kernel='gather' (or "
@@ -374,7 +377,7 @@ class DecodeEngine:
         if kernel == "auto":
             if cache_layout == "paged":
                 from ..ops.paged_decode import default_kernel
-                kernel = default_kernel(self._cfg)
+                kernel = default_kernel(self._cfg, block_size)
             else:
                 kernel = "gather"
         self.kernel = kernel
@@ -1155,36 +1158,46 @@ class DecodeEngine:
 
     def _kv_read_stats(self, queries: int, bases) -> tp.Dict[str, int]:
         """Span stats of one paged read of `queries` rows per slot from
-        first positions `bases` (host mirrors; no device work): the
-        fused kernel's walk counts, or for a latent pool `kv_bytes`, the
-        bytes as stored, over all layers, of the latent rows the live
-        slots' queries attend (parked slots sit at max_seq_len)."""
-        if not self._latent or self._pool is None:
-            return self._kv_walk_stats(queries, bases)
-        bases = np.asarray(bases)
-        rows = int((bases[bases < self.max_seq_len] + queries).sum())
-        return {"kv_bytes": rows * (self._block_bytes // self.block_size)}
-
-    def _kv_walk_stats(self, queries: int, bases) -> tp.Dict[str, int]:
-        """Span stats of one fused paged read of `queries` rows per slot
-        from first positions `bases` (host mirrors; no device work):
-        `kv_blocks`, the pool blocks the kernel attends in one layer, and
-        `kv_steps`, the compute steps it runs for them — their ratio is
-        how many blocks a step carries (ops/paged_decode.walk_counts).
-        Empty unless the read is the fused kernel's."""
-        if self._pool is None or self.kernel != "fused":
+        first positions `bases` (host mirrors; no device work). When the
+        read is the fused kernel's: `kv_blocks`, the pool blocks it
+        attends in one layer, and `kv_steps`, the compute steps it runs
+        for them — their ratio is how many blocks a step carries
+        (ops/paged_decode.walk_counts). For a latent pool, whichever
+        read serves it: `kv_bytes`, the bytes as stored, over all
+        layers, of the latent rows the live slots' queries attend
+        (parked slots sit at max_seq_len)."""
+        if self._pool is None:
             return {}
-        from ..ops.paged_decode import call_walk, walk_counts
+        stats = {}
+        if self._latent:
+            bases = np.asarray(bases)
+            rows = int((bases[bases < self.max_seq_len] + queries).sum())
+            stats["kv_bytes"] = rows * (self._block_bytes // self.block_size)
+        if self.kernel != "fused":
+            return stats
+        from ..ops.paged_decode import (call_walk, latent_call_walk,
+                                        walk_counts)
         walk = self._kv_walks.get(queries)
         if walk is None:
-            cfg = self._cfg
-            walk = self._kv_walks[queries] = call_walk(
-                len(bases), queries, cfg.num_heads, cfg.head_dim,
-                block_size=self.block_size, entries=self._pool.max_blocks,
-                quantized=self.kv_dtype == "int8", dtype=cfg.dtype)
-        blocks, steps = walk_counts(bases, queries, walk, self.block_size,
-                                    self._pool.max_blocks)
-        return {"kv_blocks": blocks, "kv_steps": steps}
+            cfg, entries = self._cfg, self._pool.max_blocks
+            if self._latent:
+                import jax
+
+                from ..ops.paged_attention import cfg_pool_spec
+                spec = cfg_pool_spec(cfg, 1, self.block_size, self.kv_dtype)
+                walk = latent_call_walk(
+                    queries, cfg.num_heads,
+                    {name: jax.ShapeDtypeStruct(*leaf)
+                     for name, leaf in spec.items()}, entries=entries)
+            else:
+                walk = call_walk(
+                    len(bases), queries, cfg.num_heads, cfg.head_dim,
+                    block_size=self.block_size, entries=entries,
+                    quantized=self.kv_dtype == "int8", dtype=cfg.dtype)
+            self._kv_walks[queries] = walk
+        stats["kv_blocks"], stats["kv_steps"] = walk_counts(
+            bases, queries, walk, self.block_size, self._pool.max_blocks)
+        return stats
 
     def decode(self) -> np.ndarray:
         """One [S, 1] decode step over every slot; returns the [S] next

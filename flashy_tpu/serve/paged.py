@@ -608,10 +608,12 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     queries consecutive positions per row, the fused kernel's one
     extra contract. The write stays `paged_write` either way (a
     per-row scatter XLA already fuses). A latent block
-    (`attn_kind='mla'`) writes and gathers its latent pool entry and
-    attends in the cached form (models/mla.py); its read is always the
-    gather. Each expert layer of an unstacked model appends its
-    (assignments, experts hit) counts to `stats` when a list is given.
+    (`attn_kind='mla'`) writes its latent pool entry and attends in the
+    cached form (models/mla.py) by the same choice: 'gather' is
+    `latent_paged_attention`, 'fused' the latent walk of the same
+    kernel module (`fused_latent_attention`). Each expert layer of an
+    unstacked model appends its (assignments, experts hit) counts to
+    `stats` when a list is given.
     """
     import jax
 
@@ -621,12 +623,15 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     from ..ops.paged_attention import (latent_paged_attention,
                                        latent_paged_write, paged_attention,
                                        paged_write)
-    from ..ops.paged_decode import fused_paged_attention
+    from ..ops.paged_decode import (fused_latent_attention,
+                                    fused_paged_attention)
 
     if kernel not in ("gather", "fused"):
         raise ValueError(f"kernel must be 'gather' or 'fused', "
                          f"got {kernel!r}")
-    attend = fused_paged_attention if kernel == "fused" else paged_attention
+    attend, attend_latent = (
+        (fused_paged_attention, fused_latent_attention) if kernel == "fused"
+        else (paged_attention, latent_paged_attention))
 
     def latent_layer(bp, x, entry):
         (q_lat, q_rope), (c_kv, k_rope) = latent_projections(cfg, bp, x,
@@ -635,8 +640,8 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
             entry = latent_paged_write(entry, c_kv, k_rope, table,
                                        positions)
         with jax.named_scope("attn"):
-            o_lat = latent_paged_attention(cfg, q_lat, q_rope, entry, table,
-                                           positions)
+            o_lat = attend_latent(cfg, q_lat, q_rope, entry, table,
+                                  positions)
         x = latent_residual(cfg, bp, x, o_lat)
         return _mlp_residual(cfg, bp, x, stats), entry
 

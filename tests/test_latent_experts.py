@@ -232,7 +232,7 @@ def test_latent_pool_spec_bytes_and_kernel_choice():
     assert block_bytes(cfg, 4, "model") == 3 * 4 * per_token
     assert pool_bytes(cfg, 5, 4, "model") == 5 * block_bytes(cfg, 4, "model")
     engine = _engine(model, params)
-    assert engine.kernel == "gather"  # auto, for a latent pool
+    assert engine.kernel == "gather"  # auto, on the CPU
     assert engine.cache_bytes() == pool_bytes(cfg, engine.num_blocks, 4,
                                               "model")
     assert engine.state_bytes_per_slot() == 16 * block_bytes(cfg, 4, "model")
@@ -243,11 +243,23 @@ def test_refusals_name_their_reason():
     with pytest.raises(ValueError, match="no heads in its rows"):
         DecodeEngine(model, {"params": params}, slots=2, max_seq_len=64,
                      cache_layout="paged", kv_dtype="int8")
-    with pytest.raises(ValueError, match="latent pool"):
+    # the fused walk copies whole (sublanes, 128) tiles of a latent
+    # pool's blocks: the toy's 8-wide latent is refused by its shape...
+    with pytest.raises(ValueError, match=r"whole \(8, 128\) tiles.*"
+                                         r"\[8, 8\]"):
         DecodeEngine(model, {"params": params}, slots=2, max_seq_len=64,
-                     cache_layout="paged", kernel="fused")
+                     cache_layout="paged", block_size=8, kernel="fused")
     from flashy_tpu.ops.paged_decode import fused_kernel_unsupported_reason
-    assert "latent" in fused_kernel_unsupported_reason(cfg)
+    assert "kv_lora_rank" in fused_kernel_unsupported_reason(cfg)
+    # ... and so is a block that is not whole sublanes of the dtype,
+    # while whole-lane ranks in whole-sublane blocks are accepted
+    wide = dataclasses.replace(cfg, kv_lora_rank=128)
+    assert fused_kernel_unsupported_reason(wide) is None
+    assert fused_kernel_unsupported_reason(wide, 8) is None
+    assert "[4, 128]" in fused_kernel_unsupported_reason(wide, 4)
+    half = dataclasses.replace(wide, dtype=jnp.bfloat16)
+    assert "(16, 128)" in fused_kernel_unsupported_reason(half, 8)
+    assert fused_kernel_unsupported_reason(half, 16) is None
     assert fused_kernel_unsupported_reason(TransformerConfig()) is None
     with pytest.raises(ValueError, match="not stacked"):
         TransformerLM(dataclasses.replace(cfg, scan_layers=True)).init(
@@ -381,11 +393,17 @@ def test_speculative_verify_and_handoff_work_on_a_latent_pool():
     assert packet["position"] == prompt.size and packet["blocks"]
 
 
-def test_spans_carry_the_expert_counts_and_the_latent_bytes():
+@pytest.mark.parametrize("kernel", ["gather", "fused"])
+def test_spans_carry_the_expert_counts_and_the_latent_bytes(kernel):
     from flashy_tpu.observability import Tracer
-    config, cfg, model, params = _toy(held=(4, 8))
+    # a latent 128 wide in blocks of 8: a pool either read can serve
+    config, cfg, model, params = _toy(held=(4, 8), kv_lora_rank=128)
     tracer = Tracer()
-    engine = _engine(model, params, tracer=tracer)
+    engine = DecodeEngine(model, {"params": params}, slots=3, max_seq_len=64,
+                          cache_layout="paged", block_size=8, chunk=8,
+                          kernel=kernel, tracer=tracer)
+    engine.warmup()
+    assert engine.kernel == kernel
     scheduler = ContinuousBatchingScheduler(engine, max_queue=4)
     scheduler.submit(np.arange(20, dtype=np.int32), 3)
     scheduler.run()
@@ -393,15 +411,25 @@ def test_spans_carry_the_expert_counts_and_the_latent_bytes():
     by_name = {}
     for event in events:
         by_name.setdefault(event["name"], []).append(event["args"])
-    per_token = block_bytes(cfg, 4, "model") // 4
+    per_token = block_bytes(cfg, 8, "model") // 8
     decode = by_name["serve/decode"]
-    assert decode[0]["kv_bytes"] == (20 + 1) * per_token
-    assert "kv_blocks" not in decode[0]  # the fused walk's, not ours
     chunk = by_name["serve/prefill_chunk"]
+    # `kv_bytes` whichever read serves the pool ...
+    assert decode[0]["kv_bytes"] == (20 + 1) * per_token
     assert chunk[0]["kv_bytes"] == 8 * per_token
+    # ... and the walk's counts exactly when the read is the fused one:
+    # 21 tokens are 3 blocks of 8 in one step (two parked slots walk one
+    # block each); the first slice's 8 rows are one block
+    for spans in (decode, chunk, by_name["serve/prefill_chunk"][1:]):
+        assert all(("kv_blocks" in s) == ("kv_steps" in s)
+                   == (kernel == "fused") for s in spans)
+    if kernel == "fused":
+        assert (decode[0]["kv_blocks"], decode[0]["kv_steps"]) == (5, 3)
+        assert [s["kv_blocks"] for s in chunk] == [1, 2, 3]
     counts = by_name["serve/decode/moe"]
     assert len(counts) == len(decode)
-    assert all(0 <= c["moe_experts_hit"] <= c["moe_assignments"] <= 2 * 4
+    # three slots' rows (parked ones route too), two expert layers, top 4
+    assert all(0 <= c["moe_experts_hit"] <= c["moe_assignments"] <= 3 * 2 * 4
                for c in counts)
     # only the final slice's token is read back, its counts with it
     assert len(by_name["serve/prefill_chunk/moe"]) == 1
