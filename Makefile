@@ -2,10 +2,10 @@ default: linter tests
 
 linter:
 	@if python -m flake8 --version >/dev/null 2>&1; then \
-		python -m flake8 --max-line-length=120 flashy_tpu tests examples bench.py __graft_entry__.py; \
+		python -m flake8 --max-line-length=120 flashy_tpu tests examples __graft_entry__.py; \
 	else \
 		echo "flake8 not installed; running syntax check only"; \
-		python -m compileall -q flashy_tpu tests examples bench.py __graft_entry__.py; \
+		python -m compileall -q flashy_tpu tests examples __graft_entry__.py; \
 	fi
 
 # Fast lane (default): everything but the `slow` marker — interpret-mode
@@ -66,9 +66,6 @@ tests-all:
 
 coverage:
 	coverage run -m pytest tests -q && coverage report -m --include='flashy_tpu/*'
-
-bench:
-	python bench.py
 
 # Continuous-batching serving smoke demo on CPU, all three legs: 32
 # staggered requests through an 8-slot engine (token-exact against
@@ -244,4 +241,4 @@ native:
 dist:
 	python -m build --sdist
 
-.PHONY: default linter tests tests-all analyze analyze-trace analyze-numerics analyze-all coverage bench serve-demo serve-spec-demo serve-paged-demo serve-slo-demo ssd-demo fleet-demo chaos-demo chaos-campaign elastic-demo zero-demo pipeline-demo tp-demo datapipe-demo docs native dist
+.PHONY: default linter tests tests-all analyze analyze-trace analyze-numerics analyze-all coverage serve-demo serve-spec-demo serve-paged-demo serve-slo-demo ssd-demo fleet-demo chaos-demo chaos-campaign elastic-demo zero-demo pipeline-demo tp-demo datapipe-demo docs native dist

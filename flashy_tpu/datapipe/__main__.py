@@ -318,7 +318,7 @@ def run_drill(epochs: int = 3, steps: int = 6, batch_size: int = 4,
 def run_packing_bench(batches: int = 200, batch_size: int = 8,
                       seq_len: int = 512,
                       root: tp.Optional[str] = None) -> tp.Dict[str, tp.Any]:
-    """Packing-throughput leg (host-only; used by bench.py): stream +
+    """Packing-throughput leg (host-only): stream +
     mix + pack `batches` fixed [B, L] batches, report tokens/s and the
     packing efficiency (non-padding fraction)."""
     workdir = Path(root) if root else Path(

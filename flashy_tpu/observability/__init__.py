@@ -1,5 +1,5 @@
 # Runtime telemetry for flashy_tpu — the profiler subsystem the
-# reference never shipped (SURVEY §5). Four pieces, one switch:
+# reference never shipped (SURVEY §5). Six pieces, one switch:
 #
 #  * Tracer            host-side spans -> Perfetto trace + telemetry.jsonl
 #  * span              one host interval -> the profiler's clock + the Tracer
@@ -7,7 +7,6 @@
 #  * RecompileWatchdog WARN when a jitted fn recompiles after warm-up
 #  * Heartbeat         per-rank liveness files + cross-host straggler report
 #  * SLOEngine         declarative latency budgets + burn-rate alerting
-#  * RooflineProfiler  per-executable FLOPs/bytes -> MFU / GB/s verdicts
 #
 # `enable_telemetry()` (or `solver.enable_telemetry()`) turns everything
 # on; the solver's stage loop, LogProgressBar and DataLoader then feed
@@ -38,7 +37,6 @@ from .slo import (  # noqa
     COUNTER_SLO_BURN, DEFAULT_SLO_BUDGETS, SLOBudget, SLOEngine,
     engine_budget_sets, format_slo_report,
 )
-from .roofline import RooflineProfiler, device_peaks  # noqa
 from .telemetry import (  # noqa
     Telemetry, enable_telemetry, disable_telemetry, get_telemetry,
     TELEMETRY_NAME, TRACE_NAME, HEARTBEAT_DIR_NAME,
@@ -52,6 +50,5 @@ __all__ = [
     "format_straggler_report",
     "SLOBudget", "SLOEngine", "DEFAULT_SLO_BUDGETS", "format_slo_report",
     "engine_budget_sets", "COUNTER_SLO_BURN",
-    "RooflineProfiler", "device_peaks",
     "TELEMETRY_NAME", "TRACE_NAME", "HEARTBEAT_DIR_NAME",
 ]

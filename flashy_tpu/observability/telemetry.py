@@ -9,7 +9,6 @@ from pathlib import Path
 import typing as tp
 
 from .heartbeat import Heartbeat
-from .roofline import RooflineProfiler
 from .steptimer import StepTimer
 from .tracer import Tracer
 from .watchdog import RecompileWatchdog
@@ -38,24 +37,17 @@ class Telemetry:
       with recompile detection.
     * `heartbeat` — per-rank liveness files under `heartbeats/`,
       beaten at step boundaries (throttled) and stage edges (forced).
-    * `roofline` — per-executable FLOPs/bytes + wall time -> realized
-      MFU / HBM GB/s (OFF unless `roofline=True`: resolving the costs
-      of jit-registered executables lowers+compiles them once more at
-      report time, a price an un-asked-for profiler must not charge).
 
     Args:
         max_journal_bytes: size cap on `telemetry.jsonl` (rotates to
             `.1..N` siblings past it); None keeps it unbounded.
-        roofline: enable the RooflineProfiler (`wrap()` and the serve
-            CompileCache register their executables into it).
     """
 
     def __init__(self, folder: tp.Union[str, Path], rank: int = 0,
                  world_size: int = 1, heartbeat_interval: float = 10.0,
                  recompile_warmup: int = 1, max_events: int = 200_000,
                  with_device_stats: bool = True,
-                 max_journal_bytes: tp.Optional[int] = None,
-                 roofline: bool = False):
+                 max_journal_bytes: tp.Optional[int] = None):
         self.folder = Path(folder)
         self.rank = rank
         self.tracer = Tracer(
@@ -69,8 +61,6 @@ class Telemetry:
                                    world_size=world_size,
                                    interval=heartbeat_interval,
                                    with_device_stats=with_device_stats)
-        self.roofline = RooflineProfiler(tracer=self.tracer,
-                                         enabled=roofline)
 
     @classmethod
     def from_xp(cls, **kwargs: tp.Any) -> "Telemetry":
@@ -117,8 +107,6 @@ class Telemetry:
         return self.tracer.export_chrome_trace()
 
     def close(self) -> None:
-        if self.roofline.enabled and self.roofline.profiles:
-            self.roofline.record()
         self.tracer.close()
 
 

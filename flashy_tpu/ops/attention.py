@@ -21,6 +21,17 @@
 #    other (tests pin it), so the split path doubles as the
 #    interpret-mode oracle for the fused one.
 #
+# Tile choice lives here and nowhere else: a call's blocks are its
+# `block_q` / `block_k` arguments (how parity tests and a builder's
+# sweep script reach other tilings), else DEFAULT_BLOCK, clamped to the
+# sequence and made to divide it (`_dividing_block`). No environment
+# variable, file or process-wide cache decides which kernel compiles.
+# 256 x 256 is what every training run in PERF_LEDGER.jsonl compiled
+# (PERF.md section 5: `flash_fwd` 113.5 ms and `flash_bwd_fused` 71.1 ms
+# a step at [8, 2048, 16, 128]); no sweep of other tiles has run on the
+# chip. One that does fixes its winner here as a rule over shapes, its
+# numbers in PERF.md (the `paged_decode.walk_shape` precedent).
+#
 # Array convention: [batch, time, heads, head_dim] (flax-style).
 # The logsumexp rows are carried broadcast across a 128-wide lane dim
 # ([BH, T, 128]) — the layout the public TPU kernels use, native to the
@@ -37,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
+DEFAULT_BLOCK = 256  # query and key rows a tile (header: tile choice)
 
 
 def _guarded_probs(scores: jax.Array, ref: jax.Array) -> jax.Array:
@@ -568,23 +580,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, fused):
 def _flash_bwd(causal, block_q, block_k, interpret, fused, residuals,
                grad_out):
     q, k, v, out, lse = residuals
-    t_q, t_k = q.shape[1], k.shape[1]
-    bq, bk = block_q, block_k
-    if t_q == t_k:
-        # Training tiles tuned separately from the forward's: the
-        # backward runs 2-3 matmuls per block pair against the
-        # forward's two, so its VMEM sweet spot differs. Cache-only at
-        # trace time (the lookup_tuned_blocks convention); a tuned pair
-        # that does not divide this sequence keeps the forward's tiles.
-        from .tuning import lookup_tuned_bwd_blocks
-        tuned = lookup_tuned_bwd_blocks(q.shape[0], t_q, q.shape[2],
-                                        q.shape[3], causal=causal,
-                                        dtype=q.dtype)
-        if tuned is not None and t_q % tuned[0] == 0 and t_k % tuned[1] == 0:
-            bq, bk = tuned
     backward = _flash_backward_fused if fused else _flash_backward
     return backward(q, k, v, out, lse, grad_out, causal=causal,
-                    block_q=bq, block_k=bk, interpret=interpret)
+                    block_q=block_q, block_k=block_k, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -615,25 +613,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     fused one-pass kernel (`fused_backward=None` -> True: each
     Q/K/V/dO block read from HBM once); `fused_backward=False` selects
     the split two-kernel path, kept as the bit-identical oracle (the
-    paged-decode `--kernel gather` convention). Block sizes default to
-    a winner recorded by `ops.tune_flash_blocks` for this (device,
-    shape) when one exists, else 256; they are clamped to the sequence
-    length, and when the requested block does not divide T, the largest
-    dividing multiple of 128 (up to 512) is used instead, so e.g. T=384
-    runs the kernel at 384 rather than falling back. Only when no
+    paged-decode `--kernel gather` convention). Blocks are the caller's
+    `block_q` / `block_k`, else `DEFAULT_BLOCK`, and the backward runs
+    at the forward's; they are clamped to the sequence length, and when
+    the requested block does not divide T, the largest dividing
+    multiple of 128 (up to 512) is used instead, so e.g. T=384 runs the
+    kernel at 384 rather than falling back. Only when no
     128-multiple divides T (T not 128-aligned), or on a GPU backend
     (the kernel is TPU-targeted), does `dot_product_attention` run
     instead.
     """
     t_q, t_k = q.shape[1], k.shape[1]
-    if block_q is None and block_k is None and t_q == t_k:
-        from .tuning import lookup_tuned_blocks
-        tuned = lookup_tuned_blocks(q.shape[0], t_q, q.shape[2], q.shape[3],
-                                    causal=causal, dtype=q.dtype)
-        if tuned is not None:
-            block_q, block_k = tuned
-    block_q = min(block_q or 256, t_q)
-    block_k = min(block_k or 256, t_k)
+    block_q = min(block_q or DEFAULT_BLOCK, t_q)
+    block_k = min(block_k or DEFAULT_BLOCK, t_k)
     if t_q % block_q:
         block_q = _dividing_block(t_q) or block_q
     if t_k % block_k:

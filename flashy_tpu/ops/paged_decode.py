@@ -33,7 +33,13 @@
 #    tile transposed to `[H, group * bs, Dh]`), its T rows split into
 #    query tiles when the score tile would outgrow VMEM. `walk_shape`
 #    picks group, query tile and layout from the shapes alone, under an
-#    explicit VMEM budget.
+#    explicit VMEM budget. That rule (and `latent_walk_shape` for a
+#    latent pool) is the one owner of tile choice: its constants below
+#    were fixed from a builder's sweeps on the chip (PERF.md section 6,
+#    PR 26 and PR 28), `head_block` is the caller's argument (how the
+#    parity tests and a sweep script reach other tilings) else
+#    `_default_head_block`, and no environment variable, file or
+#    process-wide cache decides which kernel compiles.
 #  * A copy the kernel issues needs whole (8, 128) tiles (Mosaic), so
 #    pools of narrower heads (`head_dim % 128 != 0`) or of fewer than 8
 #    heads a step keep the walk the GRID makes: one block a grid step through a BlockSpec index map
@@ -154,9 +160,10 @@ def default_kernel(cfg: tp.Any = None,
 
 
 def _default_head_block(num_heads: int, quantized: bool = False) -> int:
-    """Heads per grid step when nothing was tuned. int8 pools take every
-    head in one step: their `[block_size, H]` scale rows carry the heads
-    in the LANE position, where a copy window must be the whole dimension.
+    """Heads per grid step when the caller names none. int8 pools take
+    every head in one step: their `[block_size, H]` scale rows carry the
+    heads in the LANE position, where a copy window must be the whole
+    dimension.
     Dense pools take the largest power-of-two divisor of H not above 8,
     so the row block lands on the 8-sublane tile boundary."""
     if quantized:
@@ -252,22 +259,12 @@ def walk_shape(queries: int, heads: int, head_dim: int, block_size: int,
     return Walk(group, head_block, tile, flat(tile), True)
 
 
-def call_walk(batch: int, queries: int, heads: int, head_dim: int, *,
+def call_walk(queries: int, heads: int, head_dim: int, *,
               block_size: int, entries: int, quantized: bool, dtype,
               head_block: tp.Optional[int] = None) -> Walk:
     """The walk `fused_paged_attention` takes for a call of these shapes
     (the engine asks too, for its `kv_steps` counter): `walk_shape` at
-    the tuned `head_block` when `ops.tuning.tune_paged_blocks` has
-    recorded one for this device, else at the default."""
-    if head_block is None:
-        from .tuning import lookup_tuned_paged_blocks
-        head_block = lookup_tuned_paged_blocks(
-            batch, queries, heads, head_dim, block_size=block_size,
-            entries=entries, quantized=quantized, dtype=dtype)
-        if head_block is not None and heads % head_block:
-            # a corrupt cache entry: keep the default — a tuned pick
-            # must never be able to break correctness
-            head_block = None
+    the caller's `head_block`, else at `_default_head_block`."""
     itemsize = jnp.dtype(dtype).itemsize
     return walk_shape(queries, heads, head_dim, block_size, entries,
                       quantized=quantized, q_itemsize=itemsize,
@@ -662,9 +659,9 @@ def fused_paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
     close numbers.
 
     `head_block` tiles heads per grid step (VMEM scratch vs pipeline
-    depth); defaults to the per-`device_kind` tuned winner when
-    `ops.tuning.tune_paged_blocks` has recorded one, else a divisor of
-    H capped at 8. `interpret=None` resolves like `flash_attention`:
+    depth); defaults to `_default_head_block`: every head of an int8
+    pool, else a divisor of H capped at 8. `interpret=None` resolves
+    like `flash_attention`:
     interpret mode on CPU, the real kernel on TPU, and the gather
     fallback on GPU (the kernel is TPU-targeted).
     """
@@ -681,7 +678,7 @@ def fused_paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
     if head_block is not None and heads % head_block:
         raise ValueError(f"head_block {head_block} must divide "
                          f"num_heads {heads}")
-    walk = call_walk(q.shape[0], q.shape[1], heads, head_dim,
+    walk = call_walk(q.shape[1], heads, head_dim,
                      block_size=entry["k"].shape[-3], entries=table.shape[1],
                      quantized="k_scale" in entry, dtype=dtype,
                      head_block=head_block)
@@ -961,17 +958,3 @@ def fused_latent_attention(cfg, q_lat: jax.Array, q_rope: jax.Array,
                        table, base.astype(jnp.int32), walk,
                        scale=float(softmax_scale(cfg)), interpret=interpret)
     return out.astype(cfg.dtype)
-
-
-def decode_read_bytes_per_token(cfg, context_len: int,
-                                kv_dtype: str = "model") -> int:
-    """HBM bytes ONE decode token must stream from the KV pools.
-
-    Decode is bandwidth-bound: each step reads every live K/V byte of
-    the slot's context (plus the int8 scales) across all layers, and
-    tok/s is capped at ~bandwidth / this number. Pure host arithmetic
-    (the bench records it beside the measured tok/s so the
-    bandwidth-bound story is a number, not an assertion).
-    """
-    from .paged_attention import block_bytes
-    return block_bytes(cfg, 1, kv_dtype) * context_len

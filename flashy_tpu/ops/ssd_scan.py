@@ -33,8 +33,14 @@
 # (ops/paged_decode.py): kernel='auto'|'gather'|'fused', where 'gather'
 # names the XLA chunked reference (the interpret-mode bit-oracle the
 # fused kernel is tested against), an explicit 'fused' refuses to run
-# where it cannot (fused_ssd_unsupported_reason), and the tuned chunk
-# size lives in ops/tuning.py under a cache key leading "ssd_scan".
+# where it cannot (fused_ssd_unsupported_reason). The chunk is the
+# caller's `chunk` argument (the model passes `cfg.ssd_chunk`; parity
+# tests and a builder's sweep script pass others), else the rule over
+# the sequence length in `default_chunk`: no environment variable, file
+# or process-wide cache decides which kernel compiles. No cell of the
+# benchmark has an SSD layer, so this kernel has not been timed on the
+# chip and CHUNK_CANDIDATES has no sweep behind it yet; one that runs
+# fixes its winner in `default_chunk`, its numbers in PERF.md.
 """SSD/linear-attention dual forms: chunked scan + recurrent step."""
 import functools
 import typing as tp
@@ -51,8 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 # every decay product spanning a boundary is exactly zero.
 SSD_LOG_RESET = -1e30
 
-# Fused-kernel chunk candidates (ops/tuning.py sweeps these); the
-# default picks the largest one dividing T.
+# Chunk lengths `default_chunk` chooses among: the largest one
+# dividing T.
 CHUNK_CANDIDATES: tp.Tuple[int, ...] = (16, 32, 64, 128, 256)
 
 
@@ -299,9 +305,8 @@ def ssd_chunked_scan(c: jax.Array, b: jax.Array, v: jax.Array,
             state exactly).
         state: optional [B, H, Dh, Dstate] f32 carried-in state (a
             streaming prefill's previous chunks); zeros when None.
-        chunk: intra-chunk length. Defaults to the tuned winner
-            (ops/tuning.lookup_tuned_ssd_chunk) when one is recorded,
-            else `default_chunk(T)`. T need not be a multiple: the
+        chunk: intra-chunk length. Defaults to `default_chunk(T)`. T
+            need not be a multiple: the
             tail shorter than `chunk` is evaluated as one final chunk
             against the carried state, which chains EXACTLY (the scan
             carry IS the chained state) — so any partitioning of a
@@ -331,13 +336,7 @@ def ssd_chunked_scan(c: jax.Array, b: jax.Array, v: jax.Array,
     dim = v.shape[-1]
     b, log_decay = _masked_inputs(b, log_decay, token_mask)
     if chunk is None:
-        from .tuning import lookup_tuned_ssd_chunk
-        chunk = lookup_tuned_ssd_chunk(batch, seq, heads, dim, dstate,
-                                       dtype=v.dtype)
-        if chunk is None or chunk <= 0:
-            # no winner (or a corrupt cache entry): a tuned pick must
-            # never be able to break correctness
-            chunk = default_chunk(seq)
+        chunk = default_chunk(seq)
     elif chunk <= 0:
         raise ValueError(f"chunk must be positive, got {chunk}")
     chunk = min(int(chunk), seq)

@@ -21,7 +21,7 @@ from .accounting import (collective_stats, compare_collective_stats,
 # flashy_tpu.parallel.pipeline` is benign (the module holds no mutable
 # state; the schedule cache lives in .schedules, imported once) and is
 # silenced at the invocation sites with
-# `-W ignore::RuntimeWarning:runpy` (Makefile pipeline-demo, bench.py).
+# `-W ignore::RuntimeWarning:runpy` (Makefile pipeline-demo).
 from .pipeline import pipeline, pipeline_1f1b
 
 # ZeRO exports resolve lazily (PEP 562): `python -m

@@ -338,13 +338,6 @@ def wrap(step_fn: tp.Optional[tp.Callable] = None, *,
         last_watchdog[0] = wd
         return wd
 
-    def resolve_roofline():
-        from .. import observability
-        telemetry = observability.get_telemetry()
-        if telemetry is not None and telemetry.roofline.enabled:
-            return telemetry.roofline
-        return None
-
     def wrapped(state, batch, *rest):
         # Key on structure AND leaf shapes/dtypes: resolved shardings
         # depend on leaf shapes (fsdp picks the dim to split), so a state
@@ -385,15 +378,6 @@ def wrap(step_fn: tp.Optional[tp.Callable] = None, *,
                 donate_argnums=(0,) if donate_state else (),
                 static_argnums=static_argnums)
         fn = compiled_cache[key]
-        roofline = resolve_roofline()
-        if roofline is not None:
-            # Cost registration is keyed by watch_name (one entry per
-            # wrap — the first state shape seen prices it; register_jit
-            # is idempotent) and is deferred: the lower+compile for
-            # cost_analysis happens at report time, never on this path.
-            roofline.register_jit(watch_name, fn, (state, batch) + tuple(rest),
-                                  static_argnums=static_argnums)
-            roofline.note_call(watch_name)
         # Count ACTUAL XLA compiles via the inner jit's cache growth
         # (the same hook RecompileWatchdog.watch polls): a state-shape
         # miss above compiles on this first call, but so does a changed

@@ -667,8 +667,8 @@ def default_overlap(packed: bool, interleave: int = 1,
 
 
 # ---------------------------------------------------------------------------
-# Measurement harness: `python -m flashy_tpu.parallel.pipeline` and the
-# bench.py `pipeline` leg both run this — GPipe vs 1F1B vs interleaved
+# Measurement harness: `python -m flashy_tpu.parallel.pipeline` (`make
+# pipeline-demo`) runs this — GPipe vs 1F1B vs interleaved
 # vs packed 1F1B on a small (MoE) LM over a virtual-device 'pipe' mesh.
 # Gates: 1F1B gradients allclose to the GPipe oracle (MoE aux
 # included), packed gradients BIT-identical to unpacked at equal
@@ -758,7 +758,7 @@ def _pipeline_leg(*, moe: bool, mesh, pipe: int, steps: int, num_micro: int,
             # FT104's scalar: the FLOP-priced idle-lane fraction (the
             # SPMD body pays both lanes every tick; masked lanes are
             # real matmuls on zeros). Packing exists to narrow this —
-            # the demo gate and the bench leg both track it.
+            # the demo gate tracks it.
             from ..analysis.trace.dead_compute import dead_compute_stats
             from .schedules import build_1f1b_schedule
             stats["dead_compute_frac"] = round(dead_compute_stats(

@@ -604,8 +604,8 @@ def schedule_stats(num_stages: int, num_micro: int, interleave: int = 1, *,
                    microbatch_shape: tp.Optional[tp.Sequence[int]] = None,
                    dtype_size: int = 4) -> tp.Dict[str, tp.Any]:
     """Stats of the (cached) schedule — the host-side numbers the stage
-    metrics, the `pipeline/bubble` tracer track, the demo gates and the
-    bench leg all report. Degenerate single-stage pipelines have no
+    metrics, the `pipeline/bubble` tracer track and the demo gates all
+    report. Degenerate single-stage pipelines have no
     schedule (and no bubble)."""
     if num_stages <= 1:
         out: tp.Dict[str, tp.Any] = {

@@ -148,8 +148,8 @@ def flash_bwd_parity(*, interpret: tp.Optional[bool] = None,
 
 
 # ---------------------------------------------------------------------------
-# Measurement harness: `python -m flashy_tpu.parallel.tensor` and the
-# bench.py `tp` subleg both run this — step time, achieved TFLOP/s and
+# Measurement harness: `python -m flashy_tpu.parallel.tensor` (`make
+# tp-demo`) runs this — step time, achieved TFLOP/s and
 # per-chip optimizer HBM at tensor widths {1, 2, 4} on one small LM,
 # gradients checked against a replicated single-chip oracle, with every
 # compile reported through one RecompileWatchdog.

@@ -292,8 +292,8 @@ def describe_state_sharding(state: tp.Any) -> tp.Dict[str, tp.Any]:
 
 
 # ---------------------------------------------------------------------------
-# Measurement harness: `python -m flashy_tpu.parallel.zero` and the
-# bench.py `zero` leg both run this — step time + per-chip optimizer
+# Measurement harness: `python -m flashy_tpu.parallel.zero` (`make
+# zero-demo`) runs this — step time + per-chip optimizer
 # HBM for replicated vs ZeRO-1 vs FSDP on a small Transformer LM, with
 # every compile reported through one RecompileWatchdog so "zero
 # post-warm-up recompiles" is an asserted property, not a hope.

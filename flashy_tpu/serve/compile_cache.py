@@ -56,20 +56,13 @@ class CompileCache:
             accounting always works.
         tracer: optional Tracer — each miss (a real XLA build) lands in
             the journal as a `compile_cache` record and an instant event.
-        roofline: optional enabled `observability.RooflineProfiler` (or
-            via `attach_roofline()`); every executable built AFTER the
-            attach is registered into it (cost_analysis deferred to its
-            report) and timed-to-completion per call. Attach before
-            `DecodeEngine.warmup()` so the warm-up builds are covered.
     """
 
     def __init__(self, watchdog: tp.Optional[RecompileWatchdog] = None,
                  tracer: tp.Optional[Tracer] = None,
-                 record_signatures: bool = True,
-                 roofline: tp.Optional[tp.Any] = None):
+                 record_signatures: bool = True):
         self.watchdog = watchdog or RecompileWatchdog(warmup=1)
         self.tracer = tracer
-        self.roofline = roofline
         self.hits = 0
         self.misses = 0
         self._fns: tp.Dict[Key, tp.Callable] = {}
@@ -108,12 +101,9 @@ class CompileCache:
             return fn
         self.misses += 1
         name = self._name(key)
-        raw = build()
-        fn = self.watchdog.watch(raw, name=name)
+        fn = self.watchdog.watch(build(), name=name)
         if self.record_signatures:
             fn = self._with_signature_log(fn, name)
-        if self.roofline is not None and self.roofline.enabled:
-            fn = self._with_roofline(fn, raw, name)
         self._fns[key] = fn
         logger.debug("compile cache miss: built %s", name)
         if self.tracer is not None:
@@ -155,46 +145,6 @@ class CompileCache:
         recorded.watchdog_name = getattr(  # type: ignore[attr-defined]
             fn, "watchdog_name", name)
         return recorded
-
-    def attach_roofline(self, roofline: tp.Any) -> None:
-        """Attach a RooflineProfiler; executables built from now on are
-        registered + timed into it (existing entries are not rewrapped —
-        attach before the engine's `warmup()`)."""
-        self.roofline = roofline
-
-    def _with_roofline(self, fn: tp.Callable, raw: tp.Callable,
-                       name: str) -> tp.Callable:
-        """Per-call wall timing + deferred cost registration.
-
-        The first call registers `raw` (the unwrapped jit callable —
-        the only layer with `.lower`) against its concrete arguments;
-        every call is timed to COMPLETION via `block_until_ready`. The
-        engine materializes each step's outputs to numpy immediately
-        after the call anyway, so the block moves the sync into the
-        measurement, it does not add one.
-        """
-        import functools
-        import time
-
-        profiler = self.roofline
-
-        @functools.wraps(fn)
-        def profiled(*args: tp.Any, **kwargs: tp.Any) -> tp.Any:
-            import jax
-            if name not in profiler.profiles:
-                profiler.register_jit(name, raw, args, kwargs)
-            start = time.perf_counter()
-            out = fn(*args, **kwargs)
-            # the engine materializes these outputs immediately after
-            # the call; the profiler's sync only MOVES that block into
-            # the measurement window (and only runs when attached)
-            jax.block_until_ready(out)  # flashy: noqa[FT001]
-            profiler.observe(name, time.perf_counter() - start)
-            return out
-
-        profiled.watchdog_name = getattr(  # type: ignore[attr-defined]
-            fn, "watchdog_name", name)
-        return profiled
 
     def executables(self) -> tp.Dict[str, tp.Callable]:
         """{name: watched function} — the audit registry: every compiled

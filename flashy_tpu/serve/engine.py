@@ -425,10 +425,7 @@ class DecodeEngine:
             if compile_cache is None:
                 compile_cache = CompileCache(
                     watchdog=telemetry.watchdog if telemetry else None,
-                    tracer=tracer,
-                    roofline=(telemetry.roofline
-                              if telemetry is not None
-                              and telemetry.roofline.enabled else None))
+                    tracer=tracer)
         self.tracer = tracer
         self.compile_cache = compile_cache
 
@@ -1007,13 +1004,6 @@ class DecodeEngine:
         re-trace from."""
         return self.compile_cache.executables()
 
-    def attach_roofline(self, roofline: tp.Any) -> None:
-        """Attach an `observability.RooflineProfiler` to the compile
-        cache: every executable built from now on is cost-registered
-        and timed per call. Call BEFORE `warmup()` — already-built
-        entries are not rewrapped."""
-        self.compile_cache.attach_roofline(roofline)
-
     def pool_stats(self) -> tp.Optional[tp.Dict[str, float]]:
         """Block-pool occupancy/prefix counters plus bytes-per-token
         (None on the dense layout). `kv_bytes_per_token` is the pool
@@ -1191,7 +1181,7 @@ class DecodeEngine:
                      for name, leaf in spec.items()}, entries=entries)
             else:
                 walk = call_walk(
-                    len(bases), queries, cfg.num_heads, cfg.head_dim,
+                    queries, cfg.num_heads, cfg.head_dim,
                     block_size=self.block_size, entries=entries,
                     quantized=self.kv_dtype == "int8", dtype=cfg.dtype)
             self._kv_walks[queries] = walk

@@ -805,11 +805,6 @@ class BaseSolver:
         from . import observability
         telemetry = observability.get_telemetry()
         begin = time.time()
-        # call-count snapshot so the roofline stage summary prices only
-        # THIS stage's executions, not the whole run so far
-        roofline_mark = (telemetry.roofline.mark()
-                         if telemetry is not None
-                         and telemetry.roofline.enabled else None)
         try:
             if telemetry is not None:
                 telemetry.heartbeat.beat(epoch=self.epoch, stage=stage_name,
@@ -831,14 +826,6 @@ class BaseSolver:
                     timer.finish()
                     for key, value in timer.summary().items():
                         metrics.setdefault(key, value)
-                    if roofline_mark is not None:
-                        # realized MFU / HBM GB/s over the stage: summed
-                        # executable costs (this stage's calls only)
-                        # divided by the timer's summed device seconds
-                        device = sum(r["device"] for r in timer.records)
-                        for key, value in telemetry.roofline.stage_summary(
-                                device, since=roofline_mark).items():
-                            metrics.setdefault(key, value)
                 # per-stage delta, not the run-wide total: one recompile
                 # long ago must not read as "recompiling every stage"
                 recompiles = sum(telemetry.watchdog.summary().values())

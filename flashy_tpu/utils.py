@@ -115,8 +115,8 @@ def configure_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a fixed directory.
 
     Called by the entry points (`@flashy_tpu.main`, `python -m
-    flashy_tpu.serve`, `bench.py`, `chip_smoke.py`) before the first
-    compile. With `JAX_COMPILATION_CACHE_DIR` set, JAX reads it itself
+    flashy_tpu.serve`, `chip_smoke.py`, `benchmarks/run.py`) before the
+    first compile. With `JAX_COMPILATION_CACHE_DIR` set, JAX reads it itself
     and nothing is set in code; otherwise the cache goes to
     `<checkout>/.jax_compile_cache`, derived from this package's
     location so every working directory and every process of one

@@ -1,0 +1,211 @@
+# What decides which kernel each benchmark cell compiles, pinned on the
+# CPU from shapes alone: no OLMo-1B or dots array is ever built. A tile
+# comes from the kernel's arguments or from a rule over shapes in the
+# kernel's own module — never from the environment, a file or the home
+# directory (a run-time tuner once replayed winners from the file the
+# variable below names; the first test holds that door shut) — and the
+# walks of the serving cells are the ones the ledger's numbers were
+# measured with: a change of a constant in ops/paged_decode.py, or of a
+# cell's `chunk`, shows here which cell's kernel it changes.
+"""Tile choice per benchmark cell, from shapes, without a chip."""
+import functools
+import importlib
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from flashy_tpu.ops import attention, paged_decode, ssd_scan
+from flashy_tpu.ops.paged_attention import cfg_pool_spec
+from flashy_tpu.ops.paged_decode import Walk
+from tests.test_spans import pallas_calls
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+SERVING_CELLS = ("olmo1b-chat-closed", "olmo1b-fullctx-closed",
+                 "dotsvlm1-doc-closed")
+
+
+# ----------------------------------------------------------------------
+# (a) nothing outside the call decides a tile
+# ----------------------------------------------------------------------
+def _flash(grad):
+    q = jax.ShapeDtypeStruct((1, 256, 2, 16), jnp.bfloat16)
+
+    def forward(q, k, v):
+        return attention.flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return forward(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else forward
+    return jax.jit(fn).lower(q, q, q).as_text()
+
+
+def _paged():
+    batch, heads, dim, bs, entries = 2, 4, 8, 4, 3
+    q = jax.ShapeDtypeStruct((batch, 1, heads, dim), jnp.float32)
+    leaf = jax.ShapeDtypeStruct((7, bs, heads, dim), jnp.float32)
+    table = jax.ShapeDtypeStruct((batch, entries), jnp.int32)
+    positions = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    return jax.jit(lambda q, k, v, table, positions: (
+        paged_decode.fused_paged_attention(
+            q, {"k": k, "v": v}, table, positions, head_dim=dim,
+            dtype=jnp.float32))).lower(q, leaf, leaf, table,
+                                       positions).as_text()
+
+
+def _ssd():
+    batch, seq, heads, dim, dstate = 1, 64, 2, 16, 16
+    cb = jax.ShapeDtypeStruct((batch, seq, heads, dstate), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((batch, seq, heads, dim), jnp.bfloat16)
+    decay = jax.ShapeDtypeStruct((batch, seq, heads), jnp.float32)
+    return jax.jit(lambda c, b, v, a: ssd_scan.ssd_chunked_scan(
+        c, b, v, a, kernel="fused")).lower(cb, cb, v, decay).as_text()
+
+
+# toy call -> the entry the deleted tuner would have replayed for it:
+# its key after (kernel, jax, jaxlib, device_kind), and another tile
+LOWERINGS = {
+    "flash_fwd": (lambda: _flash(False), "flash",
+                  (1, 256, 2, 16, True, "bfloat16", True), [128, 128]),
+    "flash_bwd": (lambda: _flash(True), "flash_bwd",
+                  (1, 256, 2, 16, True, "bfloat16"), [128, 128]),
+    "paged_decode": (_paged, "paged_decode",
+                     (2, 1, 4, 8, 4, 3, False, "float32"), 2),
+    "ssd_scan": (_ssd, "ssd_scan", (1, 64, 2, 16, 16, "bfloat16"), 16),
+}
+
+
+@pytest.mark.parametrize("kernel", list(LOWERINGS))
+def test_kernel_tiles_come_from_shapes_alone(kernel, tmp_path, monkeypatch):
+    import jaxlib
+    lower, name, parts, other_tile = LOWERINGS[kernel]
+    monkeypatch.delenv("FLASHY_TPU_TUNE_CACHE", raising=False)
+    plain = lower()
+    key = "/".join(str(part) for part in (
+        name, f"jax-{jax.__version__}", f"jaxlib-{jaxlib.__version__}",
+        jax.devices()[0].device_kind) + parts)
+    home = tmp_path / "home"
+    winners = home / ".cache" / "flashy_tpu" / "attn_tune.json"
+    winners.parent.mkdir(parents=True)
+    winners.write_text(json.dumps({key: other_tile}))
+    monkeypatch.setenv("HOME", str(home))
+    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(winners))
+    assert lower() == plain
+
+
+# ----------------------------------------------------------------------
+# (b) the serving cells' walks are the recorded ones
+# ----------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _cell(name):
+    """(TransformerConfig, engine block) of a serving cell, from the
+    benchmark's own files (read only) through its own config mapping."""
+    manifest = json.loads((ROOT / "BENCHMARK.json").read_text())
+    workload, = (w for w in manifest["workloads"] if w["name"] == name)
+    config_file, = (c["file"] for c in manifest["configs"]
+                    if c["name"] == workload["config"])
+    config = json.loads((ROOT / config_file).read_text())
+    engine = json.loads(
+        (ROOT / "benchmarks" / "workloads" / f"{name}.json").read_text()
+    )["engine"]
+    harness = importlib.import_module(
+        "benchmarks.harness."
+        + config.get("harness", {}).get("model", "model"))
+    return harness.transformer_config(config, attention="dense",
+                                      dtype=jnp.bfloat16), engine
+
+
+def _walk_and_vmem(cfg, engine, queries):
+    """The walk of one read of `queries` rows a slot, as
+    `DecodeEngine._kv_read_stats` asks for it, its VMEM estimate and
+    the budget its module states."""
+    block_size = engine["block_size"]
+    entries = engine["max_seq_len"] // block_size
+    if cfg.attn_kind == "mla":
+        spec = cfg_pool_spec(cfg, 1, block_size, engine["kv_dtype"])
+        c, kr = (jax.ShapeDtypeStruct(*spec[leaf]) for leaf in ("c", "kr"))
+        walk = paged_decode.latent_call_walk(
+            queries, cfg.num_heads, {"c": c, "kr": kr}, entries=entries)
+        need = paged_decode._latent_vmem_estimate(
+            walk.query_tile, cfg.num_heads, c.shape[-1], kr.shape[-1],
+            block_size, walk.group, jnp.dtype(c.dtype).itemsize)
+        return walk, need, paged_decode.LATENT_VMEM_LIMIT
+    quantized = engine["kv_dtype"] == "int8"
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    walk = paged_decode.call_walk(
+        queries, cfg.num_heads, cfg.head_dim, block_size=block_size,
+        entries=entries, quantized=quantized, dtype=cfg.dtype)
+    need = paged_decode._vmem_estimate(
+        walk.query_tile, walk.head_block, cfg.head_dim, block_size,
+        walk.group, flat=walk.flat, q_itemsize=itemsize,
+        pool_itemsize=1 if quantized else itemsize)
+    return walk, need, paged_decode.VMEM_BUDGET
+
+
+# Recorded at commit a4433f0 (PR 28), the tree whose numbers the ledger
+# holds: (group, head_block, query_tile, flat, dma). The OLMo cells: 16
+# blocks = 256 keys a decode step, a 256-token slice in two query tiles
+# of 128 against 8 blocks (PERF.md section 6, PR 26); the latent cell:
+# 128 rows x 1,024 keys a decode step, 2,048 rows x 512 keys a slice
+# step (PR 28). Steps: decode T=1, a whole slice T=`chunk`, the tail
+# slice T=4 (the engine's `tail_bucket`), speculative verify T=5.
+OLMO_WALKS = {"decode": Walk(16, 16, 1, True, True),
+              "slice": Walk(8, 16, 128, False, True),
+              "tail": Walk(16, 16, 4, True, True),
+              "verify": Walk(16, 16, 5, True, True)}
+RECORDED = {
+    "olmo1b-chat-closed": OLMO_WALKS,
+    "olmo1b-fullctx-closed": OLMO_WALKS,
+    "dotsvlm1-doc-closed": {"decode": Walk(64, 128, 1, True, True),
+                            "slice": Walk(32, 128, 16, True, True),
+                            "tail": Walk(64, 128, 4, True, True),
+                            "verify": Walk(64, 128, 5, True, True)},
+}
+
+
+@pytest.mark.parametrize("step", ["decode", "slice", "tail", "verify"])
+@pytest.mark.parametrize("cell", SERVING_CELLS)
+def test_cell_walk_is_the_recorded_one(cell, step):
+    cfg, engine = _cell(cell)
+    queries = {"decode": 1, "slice": engine["chunk"],
+               "tail": min(4, engine["chunk"]), "verify": 5}[step]
+    walk, need, budget = _walk_and_vmem(cfg, engine, queries)
+    assert walk == RECORDED[cell][step]
+    assert need <= budget, (need, budget)
+
+
+# ----------------------------------------------------------------------
+# (c) the training cell's flash kernels run 256 x 256 blocks
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_fused"],
+                         ids=["fwd", "bwd"])
+def test_train_cell_flash_tiles(kernel):
+    # olmo1b-train-2k: 8 sequences of 2,048 tokens, 16 heads of 128, in
+    # bfloat16 — a grid of (batch x heads, 2048 / 256, 2048 / 256)
+    x = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return attention.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr
+    grids = {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
+             for eqn in pallas_calls(jaxpr)}
+    assert grids[kernel] == (8 * 16, 8, 8), grids
+
+
+# ----------------------------------------------------------------------
+# (d) `kernel: auto` in a serving cell is the fused walk on a TPU
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("cell", SERVING_CELLS)
+def test_cells_resolve_auto_to_the_fused_walk(cell, monkeypatch):
+    cfg, engine = _cell(cell)
+    assert engine["kernel"] == "auto"
+    assert paged_decode.default_kernel(cfg, engine["block_size"]) == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged_decode.fused_kernel_unsupported_reason(
+        cfg, engine["block_size"]) is None
+    assert paged_decode.default_kernel(cfg, engine["block_size"]) == "fused"

@@ -189,121 +189,6 @@ def test_flash_backward_bf16_dtype_and_close():
                                    np.asarray(b), rtol=0.1, atol=0.05)
 
 
-def test_tune_flash_blocks_sweeps_and_caches(tmp_path, monkeypatch):
-    # mechanism test (CPU interpret mode; timings are irrelevant, the
-    # sweep/caching behavior is what matters)
-    import flashy_tpu.ops.tuning as tuning
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "cache.json"))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tuning._cache.clear()
-
-    calls = []
-    real = tuning._time_call
-
-    def counting(fn, reps=1):
-        calls.append(1)
-        return real(fn, reps=1)
-
-    monkeypatch.setattr(tuning, "_time_call", counting)
-    best = tuning.tune_flash_blocks(
-        1, 256, 2, 16, candidates=[(128, 128), (256, 256)],
-        include_backward=False, interpret=True)
-    assert best in [(128, 128), (256, 256)]
-    assert len(calls) == 2  # both viable candidates measured
-
-    # second call: memory cache, no sweeping
-    best2 = tuning.tune_flash_blocks(
-        1, 256, 2, 16, candidates=[(128, 128), (256, 256)],
-        include_backward=False, interpret=True)
-    assert best2 == best and len(calls) == 2
-
-    # fresh process simulation: memory cache cleared, disk cache hits
-    tuning._cache.clear()
-    best3 = tuning.tune_flash_blocks(
-        1, 256, 2, 16, candidates=[(128, 128), (256, 256)],
-        include_backward=False, interpret=True)
-    assert best3 == best and len(calls) == 2
-
-
-def test_tuned_tiles_ignore_the_home_directory(tmp_path, monkeypatch):
-    """A winners file decides which kernel gets compiled, so by default
-    nothing outside the checkout is read or written: a file at the old
-    ~/.cache location is ignored, and FLASHY_TPU_TUNE_CACHE is the
-    explicit opt-in that brings it back."""
-    import json
-
-    import flashy_tpu.ops.tuning as tuning
-
-    legacy = tmp_path / "home" / ".cache" / "flashy_tpu" / "attn_tune.json"
-    legacy.parent.mkdir(parents=True)
-    key = tuning._flash_key(2, 256, 2, 32, True, jnp.bfloat16, True)
-    legacy.write_text(json.dumps(
-        {"/".join(str(part) for part in key): [128, 128]}))
-    monkeypatch.setenv("HOME", str(tmp_path / "home"))
-    monkeypatch.delenv("FLASHY_TPU_TUNE_CACHE", raising=False)
-    tuning._cache.clear()
-
-    assert tuning._cache_path() is None
-    assert tuning.lookup_tuned_blocks(2, 256, 2, 32) is None
-    tuning._store_disk_cache("some/key", [256, 256])  # nowhere to go
-    assert [p.name for p in tmp_path.rglob("*") if p.is_file()] \
-        == ["attn_tune.json"]
-    assert tuning.main(["--show"]) == 0  # says so, touches nothing
-
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(legacy))
-    assert tuning.lookup_tuned_blocks(2, 256, 2, 32) == (128, 128)
-    tuning._cache.clear()
-
-
-def test_tune_flash_blocks_cpu_returns_default():
-    from flashy_tpu.ops.tuning import tune_flash_blocks
-    assert tune_flash_blocks(1, 256, 2, 16) == (256, 256)
-
-
-def test_tune_cache_key_pins_runtime_and_device():
-    # A persisted block-size winner is a measurement of one compiled
-    # kernel on one chip generation: the cache key must pin the
-    # jax/jaxlib versions AND device_kind so a runtime upgrade (or a
-    # cache file shared across heterogeneous fleets) can never replay
-    # a stale winner — and it must be STABLE across calls, or the
-    # cache would never hit.
-    import jax
-    import jax.numpy as jnp
-
-    import flashy_tpu.ops.tuning as tuning
-
-    key = tuning._flash_key(1, 256, 2, 16, True, jnp.bfloat16, True)
-    assert key == tuning._flash_key(1, 256, 2, 16, True, jnp.bfloat16, True)
-    assert key[0] == "flash"  # the kernel name LEADS every key
-    assert f"jax-{jax.__version__}" in key
-    assert any(str(part).startswith("jaxlib-") for part in key)
-    assert jax.devices()[0].device_kind in key
-    # every shape/config argument still participates
-    assert key != tuning._flash_key(2, 256, 2, 16, True, jnp.bfloat16, True)
-    assert key != tuning._flash_key(1, 256, 2, 16, False, jnp.bfloat16, True)
-    assert key != tuning._flash_key(1, 256, 2, 16, True, jnp.float32, True)
-    # the disk spelling round-trips through one json cache entry
-    disk_key = "/".join(str(part) for part in key)
-    assert disk_key.count("jax-") >= 1 and "jaxlib-" in disk_key
-    assert disk_key.startswith("flash/")
-
-
-def test_tune_cache_keys_disjoint_across_kernels():
-    # Flash and paged-decode tunings must live in disjoint key spaces:
-    # a (block_q, block_k) pair is meaningless to the paged kernel and
-    # a head_block int would corrupt a flash lookup — the cache is one
-    # shared json file, so the kernel name is the namespace.
-    import jax.numpy as jnp
-
-    import flashy_tpu.ops.tuning as tuning
-
-    flash = tuning._flash_key(1, 256, 2, 16, True, jnp.bfloat16, True)
-    paged = tuning._paged_key(1, 256, 2, 16, 16, 4, True, jnp.bfloat16)
-    assert flash[0] == "flash" and paged[0] == "paged_decode"
-    assert flash != paged
-    assert "/".join(map(str, flash)) != "/".join(map(str, paged))
-
-
 def test_flash_auto_block_for_384():
     # 384 = 3*128 divides none of the default blocks; the auto-pick must
     # run the kernel at 384 instead of falling back to dense, and a
@@ -321,50 +206,6 @@ def test_flash_auto_block_for_384():
     ref = dot_product_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
-
-
-def test_lookup_tuned_blocks_cache_only(tmp_path, monkeypatch):
-    # lookup never sweeps: a cache miss is None, a seeded disk cache hits
-    import flashy_tpu.ops.tuning as tuning
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "cache.json"))
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_blocks(1, 256, 2, 16) is None
-
-    key = tuning._flash_key(1, 256, 2, 16, True, jnp.bfloat16, True)
-    tuning._store_disk_cache("/".join(str(p) for p in key), (128, 256))
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_blocks(1, 256, 2, 16) == (128, 256)
-    # memory-cached after the disk hit
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "other.json"))
-    assert tuning.lookup_tuned_blocks(1, 256, 2, 16) == (128, 256)
-
-
-def test_flash_attention_uses_tuned_blocks(tmp_path, monkeypatch):
-    # flash_attention with default block sizes picks up the tuned table
-    import flashy_tpu.ops.attention as attention
-    import flashy_tpu.ops.tuning as tuning
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "cache.json"))
-    tuning._cache.clear()
-    key = tuning._flash_key(1, 256, 2, 16, True, jnp.bfloat16, True)
-    tuning._store_disk_cache("/".join(str(p) for p in key), (128, 128))
-
-    seen = []
-    real = attention._flash
-
-    def spy(q, k, v, causal, block_q, block_k, interpret, fused_backward):
-        seen.append((block_q, block_k))
-        return real(q, k, v, causal, block_q, block_k, interpret,
-                    fused_backward)
-
-    monkeypatch.setattr(attention, "_flash", spy)
-    q = jnp.ones((1, 256, 2, 16), jnp.bfloat16)
-    attention.flash_attention(q, q, q, causal=True)
-    assert seen == [(128, 128)]
-
-    # explicit block sizes always win over the table
-    seen.clear()
-    attention.flash_attention(q, q, q, causal=True, block_q=256, block_k=256)
-    assert seen == [(256, 256)]
 
 
 class TestChunkedCrossEntropy:
@@ -457,112 +298,3 @@ def test_flash_fused_backward_bit_identical_to_split(shape_q, shape_k,
     for a, b in zip(fused, split):
         assert a.dtype == b.dtype
         assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_tune_flash_bwd_blocks_sweeps_and_caches(tmp_path, monkeypatch):
-    # mechanism test, the tune_flash_blocks convention: sweep once,
-    # then memory cache, then (cleared) the disk cache — and the
-    # cache-only lookup the custom-vjp backward consults must see the
-    # recorded winner without ever sweeping itself
-    import flashy_tpu.ops.tuning as tuning
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "cache.json"))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tuning._cache.clear()
-
-    calls = []
-    real = tuning._time_call
-
-    def counting(fn, reps=1):
-        calls.append(1)
-        return real(fn, reps=1)
-
-    monkeypatch.setattr(tuning, "_time_call", counting)
-    # cache-only lookup on a cold cache: miss, no sweep
-    assert tuning.lookup_tuned_bwd_blocks(1, 128, 2, 16, causal=True,
-                                          dtype=jnp.float32) is None
-    assert not calls
-
-    best = tuning.tune_flash_bwd_blocks(
-        1, 128, 2, 16, causal=True, dtype=jnp.float32,
-        candidates=[(64, 64), (128, 128)], interpret=True)
-    assert best in [(64, 64), (128, 128)]
-    assert len(calls) == 2  # both viable candidates measured
-
-    # the lookup now returns the winner (and still never sweeps)
-    assert tuning.lookup_tuned_bwd_blocks(
-        1, 128, 2, 16, causal=True, dtype=jnp.float32) == best
-    assert len(calls) == 2
-
-    # second tune call: memory cache, no sweeping
-    best2 = tuning.tune_flash_bwd_blocks(
-        1, 128, 2, 16, causal=True, dtype=jnp.float32,
-        candidates=[(64, 64), (128, 128)], interpret=True)
-    assert best2 == best and len(calls) == 2
-
-    # fresh process simulation: memory cache cleared, disk cache hits
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_bwd_blocks(
-        1, 128, 2, 16, causal=True, dtype=jnp.float32) == best
-    assert len(calls) == 2
-
-
-def test_tune_flash_bwd_blocks_cpu_returns_default():
-    from flashy_tpu.ops.tuning import tune_flash_bwd_blocks
-    # no interpret flag on CPU: unswept default, the forward convention
-    assert tune_flash_bwd_blocks(1, 256, 2, 16) == (256, 256)
-
-
-def test_search_remat_policy_records_winner(tmp_path, monkeypatch):
-    import flashy_tpu.ops.tuning as tuning
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "cache.json"))
-    tuning._cache.clear()
-
-    swept = []
-
-    def fake_time(fn, reps=1):
-        swept.append(fn.policy)
-        return {"full": 3.0, "dots": 1.0, "dots_no_batch": 2.0}[fn.policy]
-
-    monkeypatch.setattr(tuning, "_time_call", fake_time)
-
-    def build_step(policy):
-        def thunk():
-            return None
-        thunk.policy = policy
-        return thunk
-
-    # cache-only lookup on a cold cache: miss
-    assert tuning.lookup_remat_policy("lm", 128, 4) is None
-    # allow_cpu=True forces the sweep on the CPU backend (mechanism
-    # test; the production path skips it and returns 'dots' unswept)
-    best = tuning.search_remat_policy(build_step, "lm", 128, 4,
-                                      allow_cpu=True)
-    assert best == "dots" and sorted(swept) == sorted(tuning.REMAT_POLICIES)
-
-    # the winner is recorded for the cache-only lookup, and a second
-    # search returns it without re-timing
-    assert tuning.lookup_remat_policy("lm", 128, 4) == "dots"
-    swept.clear()
-    assert tuning.search_remat_policy(build_step, "lm", 128, 4,
-                                      allow_cpu=True) == "dots"
-    assert not swept
-
-    # disk round trip: memory cache cleared, the lookup still hits
-    tuning._cache.clear()
-    assert tuning.lookup_remat_policy("lm", 128, 4) == "dots"
-
-
-def test_search_remat_policy_rejects_unknown_policy():
-    from flashy_tpu.ops.tuning import search_remat_policy
-    with pytest.raises(ValueError, match="unknown remat policies"):
-        search_remat_policy(lambda p: (lambda: None), "lm",
-                            policies=("dots", "bogus"))
-
-
-def test_search_remat_policy_cpu_skips_sweep(monkeypatch):
-    import flashy_tpu.ops.tuning as tuning
-    tuning._cache.clear()
-    monkeypatch.setattr(tuning, "_time_call",
-                        lambda fn, reps=1: pytest.fail("swept on CPU"))
-    assert tuning.search_remat_policy(
-        lambda p: (lambda: None), "lm_cpu_skip", 1) == "dots"

@@ -3,11 +3,10 @@
 # (model dtype and int8, decode/verify/chunk row counts, sentinel
 # tables) and token-exactness through the SAME engine on both kernels
 # across block-boundary prompt lengths, COW-forked tables, speculative
-# verify and all-sentinel warm-up — plus the satellites: kernel-named
-# tuning cache + CLI, the ops namespace shadowing regression, the
-# models/audit registry entries and the FT203 gate anchoring INSIDE
-# the pallas_call body (a double-scaling rewrite must be caught, not
-# vacuously clean).
+# verify and all-sentinel warm-up — plus the satellites: the ops
+# namespace shadowing regression, the models/audit registry entries and
+# the FT203 gate anchoring INSIDE the pallas_call body (a double-scaling
+# rewrite must be caught, not vacuously clean).
 import numpy as np
 import pytest
 
@@ -87,7 +86,7 @@ def _ragged_fixture(kv_dtype, queries, seed=5):
     from flashy_tpu.ops.paged_decode import call_walk
 
     bs, heads, dim, entries = 16, 8, 128, 40
-    walk = call_walk(6, queries, heads, dim, block_size=bs, entries=entries,
+    walk = call_walk(queries, heads, dim, block_size=bs, entries=entries,
                      quantized=kv_dtype == "int8", dtype=jnp.float32)
     span = walk.group * bs
     assert walk.dma, walk
@@ -333,7 +332,7 @@ def test_fused_engine_token_exact_on_the_grouped_walk(kv_dtype):
     import jax.numpy as jnp
 
     model, params = _wide_model()
-    walk = call_walk(2, 1, 8, 128, block_size=16, entries=32,
+    walk = call_walk(1, 8, 128, block_size=16, entries=32,
                      quantized=kv_dtype == "int8", dtype=jnp.float32)
     assert walk.dma and walk.flat and walk.group == 16, walk
     rng = np.random.default_rng(8)
@@ -445,7 +444,7 @@ def test_engine_kernel_validation_and_auto():
 
 
 # ----------------------------------------------------------------------
-# satellites: ops namespace, tuning cache + CLI, audit registry
+# satellites: ops namespace, audit registry
 # ----------------------------------------------------------------------
 def test_ops_namespace_module_vs_function_shadowing():
     # the PR-8 hazard, pinned for the new module: importing the ops
@@ -467,141 +466,12 @@ def test_ops_namespace_module_vs_function_shadowing():
     assert callable(ops.fused_paged_attention)
     assert callable(ops.fused_speculative_verify)
     assert ops.fused_paged_attention is pd_mod.fused_paged_attention
-    # tuning exports resolve lazily (PEP 562) so the CLI module never
-    # double-executes; the names still work, the SUBMODULE attribute
-    # the eager import used to bind survives, and both show in dir()
-    assert callable(ops.tune_paged_blocks)
-    assert callable(ops.lookup_tuned_blocks)
-    assert isinstance(ops.tuning, types.ModuleType)
-    assert ops.tuning.tune_paged_blocks is ops.tune_paged_blocks
-    assert "tune_paged_blocks" in dir(ops) and "tuning" in dir(ops)
     with pytest.raises(AttributeError):
         ops.no_such_export
     # and a fresh import of the submodule does not flip the attribute
     importlib.reload(ops)
     assert isinstance(ops.paged_attention, types.ModuleType)
     assert isinstance(ops.paged_decode, types.ModuleType)
-
-
-def test_tune_paged_blocks_sweeps_and_caches(tmp_path, monkeypatch):
-    import jax
-
-    import flashy_tpu.ops.tuning as tuning
-
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(tmp_path / "c.json"))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    tuning._cache.clear()
-    calls = []
-    real = tuning._time_call
-
-    def counting(fn, reps=1):
-        calls.append(1)
-        return real(fn, reps=1)
-
-    monkeypatch.setattr(tuning, "_time_call", counting)
-    best = tuning.tune_paged_blocks(2, 1, 2, 8, block_size=4, entries=3,
-                                    candidates=[1, 2], interpret=True,
-                                    dtype=np.float32)
-    assert best in (1, 2) and len(calls) == 2
-    # memory cache, then disk cache after a simulated fresh process
-    assert tuning.tune_paged_blocks(2, 1, 2, 8, block_size=4, entries=3,
-                                    candidates=[1, 2], interpret=True,
-                                    dtype=np.float32) == best
-    assert len(calls) == 2
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_paged_blocks(
-        2, 1, 2, 8, block_size=4, entries=3, quantized=True,
-        dtype=np.float32) == best
-    assert len(calls) == 2
-
-
-def test_tuning_corrupt_cache_entries_read_as_misses(tmp_path,
-                                                     monkeypatch):
-    # the cache file is hand-editable (the CLI points users at it) and
-    # may live on shared storage: garbage values must read as a MISS —
-    # never raise at trace time, never replay as a winner
-    import json
-
-    import jax.numpy as jnp
-
-    import flashy_tpu.ops.tuning as tuning
-
-    path = tmp_path / "cache.json"
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(path))
-    tuning._cache.clear()
-    flash_key = "/".join(map(str, tuning._flash_key(
-        1, 256, 2, 16, True, jnp.bfloat16, True)))
-    paged_key = "/".join(map(str, tuning._paged_key(
-        2, 1, 2, 8, 4, 3, True, jnp.float32)))
-    path.write_text(json.dumps({
-        flash_key: "garbage", paged_key: [128, 128],  # wrong shapes
-    }))
-    assert tuning.lookup_tuned_blocks(1, 256, 2, 16) is None
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_paged_blocks(
-        2, 1, 2, 8, block_size=4, entries=3, quantized=True,
-        dtype=jnp.float32) is None
-    # a DIGIT string is indexable — "128"[0]/"128"[1] would coerce to
-    # the bogus winner (1, 2) instead of reading as corruption
-    path.write_text(json.dumps({flash_key: "128", paged_key: "8"}))
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_blocks(1, 256, 2, 16) is None
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_paged_blocks(
-        2, 1, 2, 8, block_size=4, entries=3, quantized=True,
-        dtype=jnp.float32) is None
-    # and the fused entry point survives the corrupt winner end-to-end
-    tuning._cache.clear()
-    import jax
-
-    from flashy_tpu.ops.paged_decode import fused_paged_attention
-    entry, table = _pool_fixture("int8")
-    q = jnp.ones((2, 1, 2, 8), jnp.float32)
-    out = fused_paged_attention(q, entry, table,
-                                jnp.asarray([[5], [2]], jnp.int32),
-                                head_dim=8, dtype=jnp.float32,
-                                interpret=True)
-    assert np.isfinite(np.asarray(out)).all()
-    del jax
-    # a cache written before the walk grouped blocks holds a scalar
-    # `head_block` winner: still a valid winner, and the walk it picks
-    # is the default one at that head_block
-    from flashy_tpu.ops.paged_decode import call_walk, walk_shape
-    path.write_text(json.dumps({paged_key: 1}))
-    tuning._cache.clear()
-    assert tuning.lookup_tuned_paged_blocks(
-        2, 1, 2, 8, block_size=4, entries=3, quantized=True,
-        dtype=jnp.float32) == 1
-    shapes = dict(block_size=4, entries=3, quantized=True)
-    assert call_walk(2, 1, 2, 8, dtype=jnp.float32, **shapes) \
-        == walk_shape(1, 2, 8, pool_itemsize=1, q_itemsize=4, head_block=1,
-                      **shapes)
-    # one that does not divide the heads reads as a miss, not an error
-    path.write_text(json.dumps({paged_key: 3}))
-    tuning._cache.clear()
-    assert call_walk(2, 1, 2, 8, dtype=jnp.float32, **shapes).head_block == 2
-    tuning._cache.clear()
-
-
-def test_tune_paged_blocks_never_sweeps_without_a_runnable_kernel(
-        monkeypatch):
-    # the gpu backend (gather fallback ignores head_block) must return
-    # the default WITHOUT timing anything — a sweep there persists a
-    # noise winner other hosts could replay
-    import jax
-
-    import flashy_tpu.ops.paged_decode as paged_decode
-    import flashy_tpu.ops.tuning as tuning
-
-    tuning._cache.clear()
-    calls = []
-    monkeypatch.setattr(tuning, "_time_call",
-                        lambda fn, reps=1: calls.append(1) or 0.0)
-    default = paged_decode._default_head_block(4)
-    monkeypatch.setattr(jax, "default_backend", lambda: "cuda")
-    assert tuning.tune_paged_blocks(2, 1, 4, 8, block_size=4,
-                                    entries=3) == default
-    assert not calls
 
 
 def test_engine_rejects_fused_where_the_kernel_cannot_run(monkeypatch):
@@ -621,37 +491,6 @@ def test_engine_rejects_fused_where_the_kernel_cannot_run(monkeypatch):
                           block_size=4, kernel="auto",
                           cache_scope="gpu_auto")
     assert engine.kernel == "gather"
-
-
-def test_tune_paged_blocks_cpu_returns_default():
-    from flashy_tpu.ops.paged_decode import _default_head_block
-    from flashy_tpu.ops.tuning import tune_paged_blocks
-
-    assert tune_paged_blocks(2, 1, 4, 8, block_size=4,
-                             entries=3) == _default_head_block(4)
-    assert _default_head_block(16) == 8
-    assert _default_head_block(6) == 2
-    assert _default_head_block(1) == 1
-
-
-def test_tuning_cli_show_and_clear(tmp_path, monkeypatch, capsys):
-    import flashy_tpu.ops.tuning as tuning
-
-    path = tmp_path / "cache.json"
-    monkeypatch.setenv("FLASHY_TPU_TUNE_CACHE", str(path))
-    tuning._cache.clear()
-    tuning._store_disk_cache("flash/jax-x/jaxlib-y/cpu/1/256", (128, 128))
-    tuning._store_disk_cache("paged_decode/jax-x/jaxlib-y/cpu/2/1", 2)
-    assert tuning.main(["--show"]) == 0
-    out = capsys.readouterr().out
-    assert "2 entries" in out and "[flash]" in out \
-        and "[paged_decode]" in out
-    assert tuning.main(["--clear"]) == 0
-    assert not path.exists()
-    assert tuning.main(["--show", "--clear"]) == 0  # idempotent
-    assert "0 entries" in capsys.readouterr().out
-    with pytest.raises(SystemExit):
-        tuning.main([])  # must pick an action
 
 
 def test_models_audit_registers_fused_programs():
@@ -727,19 +566,3 @@ def test_ft203_catches_double_scaled_fused_rewrite():
                               example_args=(q, entry, table, positions))
     keys = {f.key for f in QuantScaleAuditor().audit(program)}
     assert "double-scale:v" in keys, keys
-
-
-def test_decode_read_bytes_per_token_arithmetic():
-    from flashy_tpu.ops.paged_decode import decode_read_bytes_per_token
-
-    model, _ = _tiny_model()
-    cfg = model.config  # 2 layers, 2 heads, head_dim 8, f32
-    # model dtype: K+V rows = 2 * H * Dh * 4 bytes, per layer
-    assert decode_read_bytes_per_token(cfg, 1, "model") \
-        == 2 * 2 * 8 * 4 * 2
-    # int8: payload byte per element + one f32 scale per (row, head)
-    assert decode_read_bytes_per_token(cfg, 1, "int8") \
-        == (2 * 2 * 8 * 1 + 2 * 2 * 4) * 2
-    # linear in context
-    assert decode_read_bytes_per_token(cfg, 10, "int8") \
-        == 10 * decode_read_bytes_per_token(cfg, 1, "int8")

@@ -365,15 +365,20 @@ def test_compile_cache_setup_keeps_scopes_and_one_key_per_program(
             jax.config.update(flag, value)
 
 
-def _kernel_names(jaxpr, found):
+def pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr, nested ones included."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.append(eqn.params["name"])
+            yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    _kernel_names(inner, found)
+                    yield from pallas_calls(inner)
+
+
+def _kernel_names(jaxpr, found):
+    found.extend(eqn.params["name"] for eqn in pallas_calls(jaxpr))
     return found
 
 

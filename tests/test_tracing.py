@@ -1,10 +1,9 @@
-# Request-scoped tracing, SLO burn-rate alerting, and the roofline
-# profiler: lifecycle completeness (every submitted request reaches a
-# terminal journal event with named phases, whatever its fate), the
-# crash-closes-spans convention, deterministic sampling + the slow-tail
-# retroactive capture, burn-rate alerts under injected latency (and
-# silence on a clean run), cost_analysis-vs-analytic roofline sanity,
-# and requests.jsonl rotation.
+# Request-scoped tracing and SLO burn-rate alerting: lifecycle
+# completeness (every submitted request reaches a terminal journal
+# event with named phases, whatever its fate), the crash-closes-spans
+# convention, deterministic sampling + the slow-tail retroactive
+# capture, burn-rate alerts under injected latency (and silence on a
+# clean run), and requests.jsonl rotation.
 import json
 import time
 
@@ -12,9 +11,7 @@ import numpy as np
 import pytest
 
 from flashy_tpu import observability
-from flashy_tpu.observability import (
-    RooflineProfiler, SLOBudget, SLOEngine, Tracer,
-)
+from flashy_tpu.observability import SLOBudget, SLOEngine, Tracer
 from flashy_tpu.resilience import chaos
 from flashy_tpu.serve import ContinuousBatchingScheduler, DecodeEngine
 from flashy_tpu.serve.metrics import ServeMetrics
@@ -268,81 +265,6 @@ def test_slo_engine_multiwindow_rule_is_deterministic():
         slo.observe("ttft", 5.0, now=25.0 + i)
     entry = slo.evaluate(now=45.0)["budgets"]["ttft"]
     assert entry["alerting"]
-
-
-# ----------------------------------------------------------------------
-# roofline profiler
-# ----------------------------------------------------------------------
-def test_roofline_matmul_flops_match_analytic_and_mfu():
-    import jax
-    import jax.numpy as jnp
-
-    n = 128
-    fn = jax.jit(lambda a, b: a @ b)
-    a = jnp.ones((n, n), jnp.float32)
-    compiled = fn.lower(a, a).compile()
-    # a synthetic machine model with a LOW balance point so the matmul
-    # (intensity n/6 flops/byte) classifies compute-bound
-    profiler = RooflineProfiler(peak_flops=1e12, peak_bytes_per_sec=1e11)
-    profiler.register_compiled("test/matmul", compiled)
-    timed = profiler.timed("test/matmul", compiled)
-    for _ in range(3):
-        np.asarray(timed(a, a))
-
-    entry = profiler.summarize("test/matmul")
-    analytic = 2.0 * n ** 3
-    # cost_analysis counts the same dominant matmul term the analytic
-    # model does; anything outside 2x means the wrong executable (or a
-    # broken cost model) was priced
-    assert entry["source"] == "cost_analysis"
-    assert 0.5 <= entry["flops_per_call"] / analytic <= 2.0
-    assert entry["calls"] == 3
-    assert entry["wall_ms_per_call"] > 0
-    realized = entry["realized_flops_per_sec"]
-    assert entry["mfu"] == pytest.approx(realized / 1e12)
-    assert 0.0 < entry["mfu"] < 1.0
-    assert entry["intensity"] == pytest.approx(
-        entry["flops_per_call"] / entry["bytes_per_call"])
-    assert entry["verdict"] == "compute-bound"  # intensity > balance 10
-
-    report = profiler.report()
-    assert report["balance_flops_per_byte"] == pytest.approx(10.0)
-    assert "test/matmul" in report["executables"]
-
-
-def test_roofline_register_jit_defers_cost_to_report():
-    import jax
-    import jax.numpy as jnp
-
-    calls = {"lower": 0}
-    fn = jax.jit(lambda x: x * 2.0)
-
-    class Spy:
-        def lower(self, *args, **kwargs):
-            calls["lower"] += 1
-            return fn.lower(*args, **kwargs)
-
-    x = jnp.ones((8,), jnp.float32)
-    profiler = RooflineProfiler()
-    profiler.register_jit("test/double", Spy(), (x,))
-    profiler.observe("test/double", 1e-3)
-    assert calls["lower"] == 0  # nothing priced yet — off the hot path
-    entry = profiler.summarize("test/double")
-    assert calls["lower"] == 1
-    assert entry["bytes_per_call"] is not None
-    # registration abstracted the args: no live buffer is retained
-    profile = profiler.profiles["test/double"]
-    assert profile.flops is not None or profile.cost_error
-
-
-def test_roofline_disabled_is_inert():
-    profiler = RooflineProfiler(enabled=False)
-    profiler.register_costs("x", flops=1.0)
-    profiler.observe("x", 1.0)
-    assert profiler.profiles == {}
-    assert profiler.summarize("x") is None
-    fn = profiler.timed("x", lambda v: v)
-    assert fn(3) == 3  # pass-through, unwrapped
 
 
 # ----------------------------------------------------------------------

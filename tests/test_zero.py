@@ -387,9 +387,8 @@ def test_solver_zero_checkpoint_roundtrip_and_info(tmp_path, capsys):
 
 @pytest.mark.slow
 def test_run_zero_bench_record():
-    # The bench `zero` leg's harness end-to-end on the virtual mesh:
-    # ratio ~1/N, numerics tight, zero recompiles (what `make zero-demo`
-    # asserts in CI, and what bench.py records in the BENCH json).
+    # `run_zero_bench` end-to-end on the virtual mesh: ratio ~1/N,
+    # numerics tight, zero recompiles (what `make zero-demo` asserts).
     from flashy_tpu.parallel.zero import run_zero_bench
 
     result = run_zero_bench(steps=3, seq=32)
