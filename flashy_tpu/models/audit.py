@@ -32,20 +32,20 @@ def _attention_entries() -> tp.List[tp.Dict[str, tp.Any]]:
     import jax
     import jax.numpy as jnp
 
-    from ..ops.paged_attention import paged_attention, paged_write
+    from ..ops.paged_attention import (paged_attention, paged_write,
+                                       pool_spec)
 
     num_blocks, block_size, heads, head_dim = 4, 4, 2, 8
     batch, queries, entries = 2, 1, 3
     key = jax.random.PRNGKey(0)
-    shape = (num_blocks, block_size, heads, head_dim)
+    # the pool's own leaves: random int8 payloads, every scale 1 / 127
     entry = {
-        "k": jax.random.randint(key, shape, -127, 127, jnp.int32
-                                ).astype(jnp.int8),
-        "v": jax.random.randint(key, shape, -127, 127, jnp.int32
-                                ).astype(jnp.int8),
-        "k_scale": jnp.ones(shape[:-1], jnp.float32) / 127.0,
-        "v_scale": jnp.ones(shape[:-1], jnp.float32) / 127.0,
-    }
+        name: (jax.random.randint(key, shape, -127, 127, jnp.int32
+                                  ).astype(dtype) if dtype == jnp.int8
+               else jnp.ones(shape, dtype) / 127.0)
+        for name, (shape, dtype) in pool_spec(
+            num_blocks, block_size, heads, head_dim, jnp.float32,
+            "int8").items()}
     q = jax.random.normal(key, (batch, queries, heads, head_dim),
                           jnp.float32)
     table = jnp.asarray([[1, 2, 0], [3, 0, 0]], jnp.int32)

@@ -54,13 +54,21 @@ def pool_spec(num_blocks: int, block_size: int, num_heads: int,
 
     `kv_dtype='int8'` stores int8 payloads plus per-(row, head) f32
     scales beside them (models/quantize.quantize_kv); any other value
-    stores dense K/V in the model's compute dtype.
+    stores dense K/V in the model's compute dtype. A block's scales are
+    ONE row of `block_size * num_heads` values in (row-in-block, head)
+    order, `[N, 1, block_size * H]`: the rows the fused read copies
+    beside its K/V blocks, as stored. `[N, block_size, H]` put N on the
+    lanes of the v5e (LANES below) and every decode step and prefill
+    slice paid three whole-leaf relayout copies a scale leaf a layer
+    (PERF.md section 6, PR 30); `scale_rows` / `write_scale_rows` are
+    the only readers and writers of the order.
     """
     shape = (num_blocks, block_size, num_heads, head_dim)
     if kv_dtype == "int8":
+        rows = (num_blocks, 1, block_size * num_heads)
         return {"k": (shape, jnp.int8), "v": (shape, jnp.int8),
-                "k_scale": (shape[:-1], jnp.float32),
-                "v_scale": (shape[:-1], jnp.float32)}
+                "k_scale": (rows, jnp.float32),
+                "v_scale": (rows, jnp.float32)}
     return {"k": (shape, dtype), "v": (shape, dtype)}
 
 
@@ -155,6 +163,105 @@ def latent_paged_write(entry: tp.Dict, c_kv: jax.Array, k_rope: jax.Array,
             for name, new in (("c", c_kv), ("kr", k_rope))}
 
 
+def scale_rows(scales: jax.Array, blocks: jax.Array, num_heads: int
+               ) -> jax.Array:
+    """The scales of pool blocks `blocks` [...] out of a scale leaf
+    `[N, 1, block_size * H]` (`pool_spec`), as `[..., block_size, H]`.
+    Each block's row is a `dynamic_slice` naming all three dimensions:
+    the v5e's compiler gathers those from the leaf as stored, where
+    `scales[blocks]` (an index for the first dimension alone) had it
+    relay the whole leaf out to (8, 128) tiles first, a slice's copy a
+    layer (seen by compiling `chunk_paged` for the chip, PR 30)."""
+    rows = jax.vmap(lambda block: jax.lax.dynamic_slice_in_dim(
+        scales, block, 1))(blocks.reshape(-1))
+    return rows.reshape(blocks.shape + (-1, num_heads))
+
+
+@jax.jit
+def write_scale_rows(scales: jax.Array, new: jax.Array, block: jax.Array,
+                     offset: jax.Array) -> jax.Array:
+    """Write fresh rows' scales `new` [B, T, H] into a scale leaf
+    `[N, 1, block_size * H]`: row (block, offset) owns the H values from
+    `offset * H` of its block's row. The unit the device can move
+    without relaying the leaf out is a block's whole row, so this is a
+    read-modify-write of rows: each fresh row gathers its block's row,
+    takes into it EVERY row this call writes to that block (a chunk puts
+    `block_size` rows in one block, so the rows written for them are
+    equal and their order cannot matter), and the rows go back whole
+    (`_scatter_rows`). A scatter of the H-wide windows themselves is a
+    loop of B * T updates of 3 us on the v5e: it made a 256-token slice
+    27 ms longer (PERF.md section 6, PR 30). Blocks written by two batch
+    rows at once are the sentinel's alone: garbage by design. Jitted
+    for the reason `paged_decode._fused_call` is: a model's layers trace
+    and lower the kernel once between them (unrolled, 32 lowerings an
+    executable were 2 s of the chat cell's 7 s engine set-up)."""
+    heads = new.shape[-1]
+    old = scale_rows(scales, block, heads)              # [B, T, bs, H]
+    in_block = jnp.arange(old.shape[2], dtype=offset.dtype)
+    # hit[b, t, j, u]: row u of slot b writes offset j of row t's block
+    hit = ((block[:, :, None, None] == block[:, None, None, :])
+           & (offset[:, None, None, :] == in_block[None, None, :, None]))
+    fresh = jax.vmap(lambda rows, at: rows[at])(new.astype(scales.dtype),
+                                                jnp.argmax(hit, axis=-1))
+    rows = jnp.where(hit.any(axis=-1)[..., None], fresh, old)
+    return _scatter_rows(scales, rows.reshape((-1,) + scales.shape[1:]),
+                         block.reshape(-1))
+
+
+def _scatter_rows(leaf: jax.Array, rows: jax.Array, blocks: jax.Array
+                  ) -> jax.Array:
+    """`leaf[blocks[r]] = rows[r]` for whole rows `[R, 1, W]` of a scale
+    leaf `[N, 1, W]`, in place. On a TPU the leaf lies in (1, 128) tiles
+    (`pool_spec`), where XLA has no scatter of its own: it relays the
+    whole leaf out to (8, 128) tiles and back around one, or runs a
+    loop of updates. A kernel that copies the rows does neither
+    (`_row_copies`); elsewhere XLA's scatter is the write."""
+    if jax.default_backend() in ("cpu", "gpu", "cuda", "rocm"):
+        return leaf.at[blocks].set(rows)
+    return _row_copies(leaf, rows, blocks, interpret=False)
+
+
+def _row_copies(leaf: jax.Array, rows: jax.Array, blocks: jax.Array, *,
+                interpret: bool) -> jax.Array:
+    """`_scatter_rows` as a Pallas TPU kernel: the leaf stays in HBM,
+    aliased to the output, and every row is one async copy out of VMEM,
+    all in flight together. Rows that name one block are equal or the
+    sentinel's (`write_scale_rows`), so the copies need no order."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(block_ref, rows_ref, leaf_ref, out_ref, sem):
+        del leaf_ref  # aliased to out_ref: rows no copy names keep theirs
+
+        def copy(row):
+            return pltpu.make_async_copy(
+                rows_ref.at[row], out_ref.at[block_ref[row]], sem)
+
+        def start(row, carry):
+            copy(row).start()
+            return carry
+
+        def wait(row, carry):
+            copy(row).wait()
+            return carry
+
+        jax.lax.fori_loop(0, rows_ref.shape[0], start, 0)
+        jax.lax.fori_loop(0, rows_ref.shape[0], wait, 0)
+
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # the rows' blocks
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), hbm],
+        out_specs=hbm, scratch_shapes=[pltpu.SemaphoreType.DMA(())])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(leaf.shape, leaf.dtype),
+        input_output_aliases={2: 0}, interpret=interpret,
+        name="paged_scale_write",
+    )(blocks.astype(jnp.int32), rows, leaf)
+
+
 def paged_write(entry: tp.Dict, new_k: jax.Array, new_v: jax.Array,
                 table: jax.Array, positions: jax.Array) -> tp.Dict:
     """Write fresh K/V rows `[B, T, H, Dh]` through the block tables.
@@ -173,8 +280,8 @@ def paged_write(entry: tp.Dict, new_k: jax.Array, new_v: jax.Array,
         if f"{name}_scale" in entry:
             q, scale = quantize_kv(new)
             out[name] = entry[name].at[block, offset].set(q)
-            out[f"{name}_scale"] = \
-                entry[f"{name}_scale"].at[block, offset].set(scale)
+            out[f"{name}_scale"] = write_scale_rows(
+                entry[f"{name}_scale"], scale, block, offset)
         else:
             out[name] = entry[name].at[block, offset].set(
                 new.astype(entry[name].dtype))
@@ -197,7 +304,8 @@ def gather_kv(entry: tp.Dict, table: jax.Array, dtype
     def view(name):
         g = entry[name][table]              # [B, E, bs, H, Dh]
         if f"{name}_scale" in entry:
-            g = dequantize_kv(g, entry[f"{name}_scale"][table], dtype)
+            g = dequantize_kv(g, scale_rows(entry[f"{name}_scale"], table,
+                                            g.shape[3]), dtype)
         return g.astype(dtype).reshape(batch, entries * g.shape[2],
                                        *g.shape[3:])
 
@@ -244,7 +352,8 @@ def paged_attention(q: jax.Array, entry: tp.Dict, table: jax.Array,
         s = entry.get(f"{name}_scale")
         if s is not None:
             # [B, E, bs, H] -> [B, H, 1, L] to broadcast over scores
-            s = s[table].reshape(batch, g.shape[1], g.shape[2])
+            s = scale_rows(s, table, g.shape[2]).reshape(
+                batch, g.shape[1], g.shape[2])
             s = s.transpose(0, 2, 1)[:, :, None, :]
         return g.astype(dtype), s
 

@@ -44,11 +44,15 @@
 #    pools of narrower heads (`head_dim % 128 != 0`) or of fewer than 8
 #    heads a step keep the walk the GRID makes: one block a grid step through a BlockSpec index map
 #    that reads the table, every table entry a step, dead ones clamped
-#    and skipped. For the same reason int8 scales reach the kernel as
-#    lane-dense `[bs * H]` rows copied beside their block (flat layout)
-#    or gathered through the table beforehand, a step's `[H, keys]`
-#    slab at a time (per-head layout). Same arithmetic in all of them
-#    (`_attend_tile`).
+#    and skipped. For the same reason an int8 pool STORES a block's
+#    scales as one lane-dense `[bs * H]` row (`ops.paged_attention.
+#    pool_spec`: the leaf is `[N, 1, bs * H]`, whole (1, 128) tiles on
+#    the device, never reshaped or relaid out): the flat layout copies
+#    that row beside its block, as stored; the per-head layout and the
+#    grid's walk take the rows gathered through the table beforehand, a
+#    step's `[H, keys]` slab at a time (`_step_scales`; Mosaic has no
+#    `[1, bs * H] -> [bs, H]` reshape to do it in the kernel). Same
+#    arithmetic in all of them (`_attend_tile`).
 #  * int8 pools dequantize IN the kernel under the FT203 scale-folding
 #    identity: the per-(row, head) K scales multiply the SCORES between
 #    the q.k contraction and the softmax, the V scales multiply the
@@ -105,7 +109,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import paged_attention
+from .paged_attention import paged_attention, scale_rows
 
 NEG_INF = -1e30
 LANES = 128  # native f32 lane width; row stats ride it (attention.py)
@@ -161,9 +165,9 @@ def default_kernel(cfg: tp.Any = None,
 
 def _default_head_block(num_heads: int, quantized: bool = False) -> int:
     """Heads per grid step when the caller names none. int8 pools take
-    every head in one step: their `[block_size, H]` scale rows carry the
-    heads in the LANE position, where a copy window must be the whole
-    dimension.
+    every head in one step: a block's scales are one `[block_size * H]`
+    row (row-in-block major, head minor), and the flat layout's score
+    columns have that order only with every head in the step.
     Dense pools take the largest power-of-two divisor of H not above 8,
     so the row block lands on the 8-sublane tile boundary."""
     if quantized:
@@ -508,9 +512,8 @@ def _grid_walk_body(table_ref, base_ref, q_ref, k_ref, v_ref, ks_ref,
                    + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
                    <= base + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
 
-        def scales(ref):  # [bs, hb] -> [hb, 1, bs]
-            return None if ref is None \
-                else ref[0].transpose(1, 0)[:, None, :]
+        def scales(ref):  # this entry's [hb, bs] of the gathered scales
+            return None if ref is None else ref[0, 0][:, None, :]
 
         _attend_tile(q_ref[0].transpose(1, 0, 2), k_ref[0], v_ref[0],
                      scales(ks_ref), scales(vs_ref), visible[None], m_scr,
@@ -534,20 +537,20 @@ def _kernel(body, quant: bool, **static):
     return kernel
 
 
-def _step_scales(scales: jax.Array, table: jax.Array, group: int
-                 ) -> jax.Array:
-    """`[N, bs, H]` pool scales -> `[B, steps, H, group * bs]`: each
-    slot's scales gathered through its table and laid out a compute step
+def _step_scales(scales: jax.Array, table: jax.Array, group: int,
+                 heads: int) -> jax.Array:
+    """`[N, 1, bs * H]` pool scales -> `[B, steps, H, group * bs]`: each
+    slot's rows gathered through its table and laid out a compute step
     at a time, heads on sublanes and keys on lanes, the way the per-head
-    score tile wants them. (A pool row of H scales is too narrow a window
-    for the kernel to copy itself; the scales are 3% of the bytes.) A
-    table `group` does not divide is padded with the sentinel."""
+    score tile wants them. (The kernel can copy a block's row but not
+    turn it into `[H, bs]`; the scales are 3% of the bytes.) A table
+    `group` does not divide is padded with the sentinel."""
     batch, entries = table.shape
     steps = -(-entries // group)
     table = jnp.pad(table, ((0, 0), (0, steps * group - entries)))
-    gathered = scales[table.reshape(batch, steps, group)]
+    gathered = scale_rows(scales, table.reshape(batch, steps, group), heads)
     return gathered.transpose(0, 1, 4, 2, 3).reshape(
-        batch, steps, scales.shape[-1], group * scales.shape[-2])
+        batch, steps, heads, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("walk", "interpret"))
@@ -580,15 +583,14 @@ def _fused_call(q, entry, table, base, walk: Walk, *, interpret: bool):
                    pltpu.VMEM(tile, entry["v"].dtype),
                    pltpu.SemaphoreType.DMA((4, 2))] + state
         if quant and flat:
-            # one lane-dense row per pool block, in the (row, head) order
-            # of the flat score columns, copied beside its K/V block
-            row = (1, block_size * heads)
-            for i in (1, 3):
-                operands[i] = operands[i].reshape((-1,) + row)
+            # the leaf as stored: one lane-dense row per pool block, in
+            # the (row, head) order of the flat score columns, copied
+            # beside its K/V block
+            row = operands[1].shape[1:]
             scratch += [pltpu.VMEM((2, group) + row, jnp.float32)] * 2
         elif quant:
             for i in (1, 3):
-                operands[i] = _step_scales(operands[i], table, group)
+                operands[i] = _step_scales(operands[i], table, group, heads)
                 specs[i] = pl.BlockSpec(
                     (1, operands[i].shape[1], hb, group * block_size),
                     lambda b, h, t, *_: (b, 0, h, 0))
@@ -599,22 +601,31 @@ def _fused_call(q, entry, table, base, walk: Walk, *, interpret: bool):
         def q_index(b, h, e, *_):
             return (b, 0, h, 0)
 
-        def block_index(b, h, e, table_ref, base_ref):
-            # Clamp dead entries onto the last live block: the pipeline
+        def live_entry(b, e, base_ref):
+            # Clamp dead entries onto the last live one: the pipeline
             # recognizes an unchanged block index and skips the copy.
             # Parked slots (base == max_seq_len) clamp to the table's
             # end like the gather path attends their all-sentinel view.
             last = jnp.minimum(
                 jnp.maximum(base_ref[b] + queries - 1, 0) // block_size,
                 entries - 1)
-            return (table_ref[b, jnp.minimum(e, last)], 0, h, 0)
+            return jnp.minimum(e, last)
+
+        def block_index(b, h, e, table_ref, base_ref):
+            return (table_ref[b, live_entry(b, e, base_ref)], 0, h, 0)
+
+        def scale_index(b, h, e, table_ref, base_ref):
+            return (b, live_entry(b, e, base_ref), h, 0)
 
         grid = (batch, heads // hb, entries)
-        specs = [pl.BlockSpec((1, block_size, hb, dim), block_index)
-                 if operand.ndim == 4 else
-                 pl.BlockSpec((1, block_size, hb),
-                              lambda *args: block_index(*args)[:3])
-                 for operand in operands]
+        blocks = pl.BlockSpec((1, block_size, hb, dim), block_index)
+        specs = [blocks, blocks]
+        if quant:
+            # each entry's `[hb, bs]` of the slot's gathered scales
+            for i in (1, 3):
+                operands[i] = _step_scales(operands[i], table, 1, heads)
+            scales = pl.BlockSpec((1, 1, hb, block_size), scale_index)
+            specs = [blocks, scales, blocks, scales]
         scratch = state
         kernel = _kernel(_grid_walk_body, quant, block_size=block_size,
                          scale=scale)

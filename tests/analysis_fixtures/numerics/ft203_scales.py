@@ -33,6 +33,7 @@ def _attention_variant(mode):
         def view(name):
             g = entry[name][table]
             g = g.reshape(batch, entries * g.shape[2], *g.shape[3:])
+            # a block's scales are one (row-in-block, head) row
             s = entry[f"{name}_scale"][table].reshape(
                 batch, g.shape[1], g.shape[2])
             return g.astype(jnp.float32), s  # payload [B,L,H,Dh], s [B,L,H]
@@ -62,17 +63,18 @@ def _attention_variant(mode):
 
 
 def programs():
+    from flashy_tpu.ops.paged_attention import pool_spec
+
     num_blocks, block_size, heads = 4, 4, 2
     key = jax.random.PRNGKey(0)
-    shape = (num_blocks, block_size, heads, _HEAD_DIM)
+    # the pool's own leaves: random int8 payloads, every scale 0.01
     entry = {
-        "k": jax.random.randint(key, shape, -127, 127, jnp.int32
-                                ).astype(jnp.int8),
-        "v": jax.random.randint(key, shape, -127, 127, jnp.int32
-                                ).astype(jnp.int8),
-        "k_scale": jnp.full(shape[:-1], 0.01, jnp.float32),
-        "v_scale": jnp.full(shape[:-1], 0.01, jnp.float32),
-    }
+        name: (jax.random.randint(key, shape, -127, 127, jnp.int32
+                                  ).astype(dtype) if dtype == jnp.int8
+               else jnp.full(shape, 0.01, dtype))
+        for name, (shape, dtype) in pool_spec(
+            num_blocks, block_size, heads, _HEAD_DIM, jnp.float32,
+            "int8").items()}
     q = jax.random.normal(key, (2, 1, heads, _HEAD_DIM), jnp.float32)
     table = jnp.asarray([[1, 2, 0], [3, 0, 0]], jnp.int32)
     positions = jnp.asarray([[5], [2]], jnp.int32)

@@ -227,14 +227,13 @@ def test_ft203_seeded_scale_misplacements():
 
 
 def test_ft203_live_paged_attention_is_clean():
-    from flashy_tpu.ops.paged_attention import paged_attention
+    from flashy_tpu.ops.paged_attention import paged_attention, pool_spec
 
-    shape = (4, 4, 2, 8)
     key = jax.random.PRNGKey(0)
-    entry = {"k": jnp.zeros(shape, jnp.int8),
-             "v": jnp.zeros(shape, jnp.int8),
-             "k_scale": jnp.ones(shape[:-1], jnp.float32),
-             "v_scale": jnp.ones(shape[:-1], jnp.float32)}
+    # the pool's own leaves: zero payloads, unit scales
+    entry = {name: jnp.full(shape, name.endswith("_scale"), dtype)
+             for name, (shape, dtype) in pool_spec(
+                 4, 4, 2, 8, jnp.float32, "int8").items()}
     program = NumericsProgram(
         label="live/paged-attention",
         fn=lambda q, e, t, p: paged_attention(q, e, t, p, head_dim=8,

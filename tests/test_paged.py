@@ -410,14 +410,202 @@ def test_paged_chunked_prefill_boundary_exact():
 
 
 # ----------------------------------------------------------------------
+# the int8 pool's scale rows (PR 30)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("scan_layers", [False, True])
+def test_int8_pool_keeps_a_blocks_scales_in_one_row(scan_layers):
+    # one lane-dense row of block_size * H float32 values a block, the
+    # shape the fused read copies as stored; the payloads and a pool in
+    # the model's dtype are what they were, and the bytes are counted
+    # from the same spec
+    import jax.numpy as jnp
+    from flashy_tpu.ops.paged_attention import (init_pool, pool_bytes,
+                                                pool_spec)
+
+    spec = pool_spec(9, 16, 4, 8, jnp.bfloat16, "int8")
+    assert spec["k"] == spec["v"] == ((9, 16, 4, 8), jnp.int8)
+    assert spec["k_scale"] == spec["v_scale"] == ((9, 1, 64), jnp.float32)
+    assert pool_spec(9, 16, 4, 8, jnp.bfloat16, "model") == {
+        "k": ((9, 16, 4, 8), jnp.bfloat16),
+        "v": ((9, 16, 4, 8), jnp.bfloat16)}
+    model, _ = _tiny_model(scan_layers=scan_layers)
+    cfg = model.config
+    pool = init_pool(cfg, 5, 4, "int8")
+    entry = pool if scan_layers else pool["block_0"]
+    lead = (cfg.num_layers,) if scan_layers else ()
+    assert entry["k_scale"].shape == lead + (5, 1, 4 * cfg.num_heads)
+    assert entry["k"].shape == lead + (5, 4, cfg.num_heads, cfg.head_dim)
+    per_block = 2 * 4 * cfg.num_heads * (cfg.head_dim + 4)
+    assert pool_bytes(cfg, 5, 4, "int8") == 5 * per_block * cfg.num_layers
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_paged_write_then_gather_round_trips_scattered_rows(kv_dtype):
+    # rows written at scattered (block, offset) pairs through
+    # out-of-order tables come back at their logical positions, every
+    # other row stays as it was, and a position past the table's
+    # coverage lands in the sentinel block — the payload AND, for int8,
+    # the H scales at `offset * H` of the block's row
+    import jax.numpy as jnp
+    from flashy_tpu.models.quantize import dequantize_kv, quantize_kv
+    from flashy_tpu.ops.paged_attention import (gather_kv, paged_write,
+                                                pool_spec)
+
+    blocks, bs, heads, dim = 9, 4, 3, 8
+    entry = {name: jnp.zeros(shape, dtype) for name, (shape, dtype)
+             in pool_spec(blocks, bs, heads, dim, jnp.float32,
+                          kv_dtype).items()}
+    table = jnp.asarray([[7, 2, 5], [3, 8, 1]], jnp.int32)
+    # slot 0: first row, a block's last row, mid-block, past the table;
+    # slot 1: the table's last row, two neighbours, past the table
+    positions = jnp.asarray([[0, 7, 9, 12], [11, 4, 5, 100]], jnp.int32)
+    rng = np.random.default_rng(3)
+    new_k = jnp.asarray(rng.normal(size=(2, 4, heads, dim)), jnp.float32)
+    new_v = jnp.asarray(rng.normal(size=(2, 4, heads, dim)), jnp.float32)
+    # the overshoot rows both land on the sentinel's (0, 0): make them
+    # one row, so the duplicate write has one answer
+    new_k = new_k.at[1, 3].set(new_k[0, 3])
+    new_v = new_v.at[1, 3].set(new_v[0, 3])
+    out = paged_write(entry, new_k, new_v, table, positions)
+
+    def stored(x):
+        return dequantize_kv(*quantize_kv(x)) if kv_dtype == "int8" else x
+
+    want_k = np.zeros((2, 3 * bs, heads, dim), np.float32)
+    want_v = np.zeros_like(want_k)
+    for slot in range(2):
+        for row in range(3):
+            pos = int(positions[slot, row])
+            want_k[slot, pos] = stored(new_k[slot, row])
+            want_v[slot, pos] = stored(new_v[slot, row])
+    got_k, got_v = gather_kv(out, table, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    # the sentinel block took the overshoot rows at offset 0, and
+    # nothing else in the pool moved
+    sent_k, _ = gather_kv(out, jnp.zeros((1, 1), jnp.int32), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(sent_k)[0, 0],
+                                  stored(new_k[0, 3]))
+    assert not np.asarray(sent_k)[0, 1:].any()
+    assert not np.asarray(out["k"])[[4, 6]].any()
+    if kv_dtype == "int8":
+        # physical placement: position 9 of slot 0 is block 5, offset 1
+        _, scale = quantize_kv(new_k[0, 2])
+        row = np.asarray(out["k_scale"])[5, 0]
+        np.testing.assert_array_equal(row[heads:2 * heads], scale)
+        assert not row[:heads].any() and not row[2 * heads:].any()
+        assert out["k_scale"].shape == entry["k_scale"].shape
+
+
+@pytest.mark.parametrize("rows", [1, 5, 40])
+def test_scale_row_copies_match_the_xla_scatter(rows):
+    # the TPU's write of whole scale rows (a kernel of async copies, in
+    # interpret mode here) against the scatter every other backend runs:
+    # scattered blocks, rows that name one block twice with equal
+    # content (a chunk's rows of one block), every other row untouched
+    import jax.numpy as jnp
+    from flashy_tpu.ops.paged_attention import _row_copies
+
+    rng = np.random.default_rng(rows)
+    leaf = jnp.asarray(rng.normal(size=(23, 1, 128)), jnp.float32)
+    blocks = rng.permutation(np.arange(1, 23)).astype(np.int32)
+    blocks = np.resize(blocks[:-(-rows // 2)], rows)  # each block twice
+    fresh = rng.normal(size=(23, 1, 128)).astype(np.float32)[blocks]
+    want = leaf.at[jnp.asarray(blocks)].set(jnp.asarray(fresh))
+    got = _row_copies(leaf, jnp.asarray(fresh), jnp.asarray(blocks),
+                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    untouched = np.setdiff1d(np.arange(23), blocks)
+    np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                  np.asarray(leaf)[untouched])
+
+
+@pytest.mark.parametrize("kernel", ["gather", "fused"])
+@pytest.mark.parametrize("how", ["handoff", "preempt"])
+def test_int8_slot_survives_handoff_and_preemption(how, kernel):
+    # the scale rows travel with their blocks: a slot handed from a
+    # prefill engine to a decode engine over one pool, and a slot
+    # evicted mid-decode and admitted again, emit the tokens an int8
+    # engine that did neither emits
+    model, params = _tiny_model()
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 32, n).astype(np.int32) for n in (5, 9, 6)]
+    alone = _paged_engine(model, params, slots=2, kv_dtype="int8",
+                          kernel=kernel, cache_scope=f"alone_{how}_{kernel}")
+    scheduler = ContinuousBatchingScheduler(alone)
+    want = [scheduler.submit(p, 6) for p in prompts]
+    scheduler.run()
+    if how == "handoff":
+        from flashy_tpu.serve.fleet import DisaggregatedPair
+        pair = DisaggregatedPair(model, params, prefill_slots=1,
+                                 decode_slots=2, block_size=4,
+                                 kernel=kernel, kv_dtype="int8")
+        pair.warmup(prompt_lengths=[len(p) for p in prompts])
+        outputs = pair.serve(prompts, max_new_tokens=6)
+        assert len(pair.handoffs) == len(prompts)
+        for handle, out in zip(want, outputs):
+            np.testing.assert_array_equal(handle.generated, out)
+        pair.pool.check()
+        return
+    engine = _paged_engine(model, params, slots=2, kv_dtype="int8",
+                           kernel=kernel,
+                           cache_scope=f"preempted_{kernel}")
+    engine.warmup(prompt_lengths=range(4, 16))
+    scheduler = ContinuousBatchingScheduler(engine)
+    handles = [scheduler.submit(p, 6) for p in prompts]
+    for _ in range(3):
+        scheduler.step()
+    victim = scheduler.preempt(handles[0].slot)
+    assert victim is handles[0] and 0 < len(victim.generated) < 6
+    scheduler.run()
+    assert victim.preemptions == 1
+    for handle, got in zip(want, handles):
+        np.testing.assert_array_equal(handle.output, got.output)
+    engine._pool.check()
+
+
+# ----------------------------------------------------------------------
+# pools without scale leaves compile to the programs they were (PR 30)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def untouched_programs():
+    from tests.data.record_untouched_pool_programs import programs
+    return programs()
+
+
+@pytest.mark.parametrize("engine", ["latent/gather", "latent/fused",
+                                    "kv-toy/gather", "kv-toy/fused",
+                                    "kv-wide/fused"])
+def test_programs_of_pools_without_scales_are_untouched(untouched_programs,
+                                                        engine):
+    # the lowered text of decode, both prefill slices, verify and the
+    # COW copy of a latent engine and of K/V engines in the model's
+    # dtype, byte-equal (by sha256) to what commit 61bbbe0 lowered —
+    # recorded before PR 30's first edit by
+    # tests/data/record_untouched_pool_programs.py
+    import os
+    recorded = os.path.join(os.path.dirname(__file__), "data",
+                            "untouched_pool_programs.json")
+    with open(recorded) as f:
+        want = {name: digest for name, digest in json.load(f).items()
+                if name.startswith(engine + "/")}
+    got = {name: digest for name, digest in untouched_programs.items()
+           if name.startswith(engine + "/")}
+    assert len(want) == 5 and got == want
+
+
+# ----------------------------------------------------------------------
 # COW fork isolation
 # ----------------------------------------------------------------------
-def test_cow_fork_never_mutates_the_shared_block():
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_cow_fork_never_mutates_the_shared_block(kv_dtype):
     """Two slots sharing a prefix: the second slot's COW fork and all
     its later writes leave the first slot's (and the index's) block
-    bytes untouched — asserted on the raw pool arrays."""
+    bytes untouched — asserted on the raw pool arrays, an int8 pool's
+    scale rows among them (`copy_block_fn` follows the pool's spec)."""
     model, params = _tiny_model()
-    engine = _paged_engine(model, params, slots=2, block_size=4)
+    engine = _paged_engine(model, params, slots=2, block_size=4,
+                           kv_dtype=kv_dtype)
     pool = engine._pool
     base = np.asarray([1, 2, 3, 4, 5, 6, 7, 8], np.int32)  # 2 full blocks
 
@@ -445,10 +633,21 @@ def test_cow_fork_never_mutates_the_shared_block():
                            if name in ("k", "v")
                            else leaf[..., shared_block, :, :])
         np.testing.assert_array_equal(before[name], after)
-    # and both outputs stayed exact
-    for h in (first, second):
-        want = _generate(model, params, h.prompt, h.max_new_tokens)
-        np.testing.assert_array_equal(h.output, want)
+    # and both outputs stayed exact: the forked copy holds what a pool
+    # that never shared a block holds (int8 rounds both alike)
+    if kv_dtype == "model":
+        want = [_generate(model, params, h.prompt, h.max_new_tokens)
+                for h in (first, second)]
+    else:
+        alone = ContinuousBatchingScheduler(_paged_engine(
+            model, params, slots=2, block_size=4, kv_dtype=kv_dtype,
+            prefix_cache=False, cache_scope="cow_alone"))
+        want = [alone.submit(h.prompt, h.max_new_tokens)
+                for h in (first, second)]
+        alone.run()
+        want = [h.output for h in want]
+    for h, tokens in zip((first, second), want):
+        np.testing.assert_array_equal(h.output, tokens)
 
 
 def test_paged_admission_backpressure_under_tiny_pool():

@@ -28,24 +28,35 @@ def _tiny_model(vocab=32, max_seq_len=32, scan_layers=False):
     return model, params
 
 
+def _pool_entry(k, v, kv_dtype):
+    """One layer's pool entry holding rows `k`, `v` [N, bs, H, Dh], every
+    leaf in the shape and dtype `pool_spec` gives it: an int8 pool's
+    per-(row, head) scales are one (row-in-block, head) row a block."""
+    import jax.numpy as jnp
+    from flashy_tpu.models.quantize import quantize_kv
+    from flashy_tpu.ops.paged_attention import pool_spec
+
+    leaves = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+    if kv_dtype == "int8":
+        for name in ("k", "v"):
+            leaves[name], leaves[f"{name}_scale"] = quantize_kv(leaves[name])
+    spec = pool_spec(*k.shape, jnp.float32, kv_dtype)
+    assert set(spec) == set(leaves)
+    return {name: leaves[name].reshape(shape).astype(dtype)
+            for name, (shape, dtype) in spec.items()}
+
+
 def _pool_fixture(kv_dtype="model", num_blocks=6, block_size=4, heads=2,
                   head_dim=8, seed=0):
     """A random pool + tables + consecutive positions for direct calls."""
     import jax.numpy as jnp
-    from flashy_tpu.models.quantize import quantize_kv
 
     rng = np.random.default_rng(seed)
     shape = (num_blocks, block_size, heads, head_dim)
     k = rng.normal(size=shape).astype(np.float32)
     v = rng.normal(size=shape).astype(np.float32)
-    if kv_dtype == "int8":
-        kq, ks = quantize_kv(jnp.asarray(k))
-        vq, vs = quantize_kv(jnp.asarray(v))
-        entry = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        entry = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
     table = jnp.asarray([[1, 2, 3, 0, 0], [4, 5, 0, 0, 0]], jnp.int32)
-    return entry, table
+    return _pool_entry(k, v, kv_dtype), table
 
 
 def _serve_stream(model, params, workload, kernel, *, kv_dtype="model",
@@ -75,19 +86,19 @@ def _serve_stream(model, params, workload, kernel, *, kv_dtype="model",
 # ----------------------------------------------------------------------
 # direct kernel parity vs the gather oracle
 # ----------------------------------------------------------------------
-def _ragged_fixture(kv_dtype, queries, seed=5):
+def _ragged_fixture(kv_dtype, queries, seed=5, heads=8, head_block=None):
     """Wide pool rows (the walk that copies its own blocks) and a ragged
     batch the walk must get right: contexts of 1, one short of a group,
     a group, one past it and the full table, a parked slot, tables whose
     live entries are scattered over the pool out of order, and a table
     width the group does not divide."""
     import jax.numpy as jnp
-    from flashy_tpu.models.quantize import quantize_kv
     from flashy_tpu.ops.paged_decode import call_walk
 
-    bs, heads, dim, entries = 16, 8, 128, 40
+    bs, dim, entries = 16, 128, 40
     walk = call_walk(queries, heads, dim, block_size=bs, entries=entries,
-                     quantized=kv_dtype == "int8", dtype=jnp.float32)
+                     quantized=kv_dtype == "int8", dtype=jnp.float32,
+                     head_block=head_block)
     span = walk.group * bs
     assert walk.dma, walk
     max_seq_len = entries * bs
@@ -103,11 +114,7 @@ def _ragged_fixture(kv_dtype, queries, seed=5):
     shape = (sum(blocks) + 9, bs, heads, dim)
     k = jnp.asarray(rng.normal(size=shape), jnp.float32)
     v = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    if kv_dtype == "int8":
-        (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
-        entry = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        entry = {"k": k, "v": v}
+    entry = _pool_entry(k, v, kv_dtype)
     q = jnp.asarray(rng.normal(size=(len(base), queries, heads, dim)),
                     jnp.float32)
     return entry, jnp.asarray(table), q, jnp.asarray(base, jnp.int32), walk
@@ -115,18 +122,35 @@ def _ragged_fixture(kv_dtype, queries, seed=5):
 
 @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
 @pytest.mark.parametrize("queries", [1, 3, 5, "ragged-1", "ragged-3",
-                                     "ragged-5", "ragged-chunk"])
+                                     "ragged-5", "ragged-chunk",
+                                     "grid-3-hb1", "ragged-3-hb8"])
 def test_fused_kernel_matches_gather_oracle(kv_dtype, queries):
+    # every entry is made from `pool_spec` (`_pool_entry`): an int8
+    # pool's scales are the stored rows, read by the grid's walk (the
+    # toy pool), the flat layout (ragged decode and verify rows) and the
+    # per-head layout (a chunk), at every head a step and, '-hb', at a
+    # caller's smaller head block
     import jax.numpy as jnp
     from flashy_tpu.ops.paged_attention import paged_attention
     from flashy_tpu.ops.paged_decode import fused_paged_attention
 
-    live = slice(None)
-    if isinstance(queries, int):
+    live, head_block = slice(None), None
+    if isinstance(queries, int) or queries == "grid-3-hb1":
+        if queries == "grid-3-hb1":
+            queries, head_block = 3, 1
         entry, table = _pool_fixture(kv_dtype)
         rng = np.random.default_rng(1)
         q = jnp.asarray(rng.normal(size=(2, queries, 2, 8)), jnp.float32)
         base = jnp.asarray([9, 2], jnp.int32)
+    elif queries == "ragged-3-hb8":
+        # two head blocks of a 16-head pool: few rows, but an int8
+        # pool's scale rows are flat only with every head in a step
+        queries, head_block = 3, 8
+        entry, table, q, base, walk = _ragged_fixture(
+            kv_dtype, queries, heads=16, head_block=head_block)
+        assert walk.dma and walk.head_block == 8, walk
+        assert walk.flat == (kv_dtype == "model"), walk
+        live = slice(0, -1)
     else:
         queries = {"1": 1, "3": 3, "5": 5, "chunk": 32}[queries[7:]]
         entry, table, q, base, walk = _ragged_fixture(kv_dtype, queries)
@@ -139,7 +163,7 @@ def test_fused_kernel_matches_gather_oracle(kv_dtype, queries):
                            head_dim=q.shape[-1], dtype=jnp.float32)
     got = fused_paged_attention(q, entry, table, positions,
                                 head_dim=q.shape[-1], dtype=jnp.float32,
-                                interpret=True)
+                                head_block=head_block, interpret=True)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                rtol=2e-5, atol=2e-6)
@@ -553,7 +577,8 @@ def test_ft203_catches_double_scaled_fused_rewrite():
             "k": entry_in["k"],
             # pre-scaled dense V copy, scales still handed to the fold
             "v": (entry_in["v"].astype(jnp.float32)
-                  * entry_in["v_scale"][..., None]),
+                  * entry_in["v_scale"].reshape(
+                      entry_in["v"].shape[:-1])[..., None]),
             "k_scale": entry_in["k_scale"],
             "v_scale": entry_in["v_scale"],
         }

@@ -189,7 +189,7 @@ def test_paged_attention_finite_over_all_zero_pool():
     # the inf/NaN scales this guards against would poison the softmax
     # even though masked positions contribute no weight
     from flashy_tpu.ops.paged_attention import (init_pool, paged_attention,
-                                                paged_write)
+                                                paged_write, scale_rows)
 
     cfg = TransformerConfig(vocab_size=32, dim=16, num_layers=1,
                             num_heads=2, attention="dense",
@@ -206,7 +206,8 @@ def test_paged_attention_finite_over_all_zero_pool():
     zero_row = jnp.zeros((1, 1, 2, 8), jnp.float32)
     entry = paged_write(entry, zero_row, zero_row, table,
                         jnp.asarray([[1]], jnp.int32))
-    assert np.asarray(entry["k_scale"])[1, 1].min() == 1.0
+    rows = scale_rows(entry["k_scale"], jnp.asarray(1), 2)  # block 1
+    assert np.asarray(rows)[1].min() == 1.0
     out = paged_attention(new, entry, table, positions, head_dim=8,
                           dtype=jnp.float32)
     assert np.all(np.isfinite(np.asarray(out)))
