@@ -22,13 +22,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.ssd_scan import ssd_chunked_scan, ssd_recurrent_scan
-from . import mla
+from . import gqa, mla
 from .moe import expert_layer, gated_mlp
 from .transformer import (TransformerConfig, _rotary, expert_layers,
                           mixer_pattern, rmsnorm as _rmsnorm)
 from .quantize import (is_quantized, kernel_operand as _kernel,
                        postscale as _postscale)
 from .ssd import ssd_log_decay
+
+
+def _norm(cfg, x: jax.Array, scale: jax.Array) -> jax.Array:
+    """RMSNorm at the config's epsilon (a config of another family that
+    shares these bodies, models/seq2seq.py, states none: 1e-6)."""
+    return _rmsnorm(x, scale, cfg.dtype, getattr(cfg, "norm_eps", 1e-6))
 
 
 def _split_heads(qkv: jax.Array) -> tp.Tuple[jax.Array, jax.Array, jax.Array]:
@@ -55,6 +61,15 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> tp.Dict:
     sshape = (batch, cfg.num_heads, cfg.head_dim, cfg.ssd_state_dim)
     pattern = mixer_pattern(cfg)
     expert_layers(cfg)  # refuses what the new kinds cannot combine with
+    if cfg.attn_kind == "gqa":
+        # every layer a slab of its own kind's heads, a window layer's
+        # whole too: the dense layout masks a window, it bounds nothing
+        return {f"block_{i}": {
+                    "k": jnp.zeros((batch, max_len, kind.kv_heads,
+                                    gqa.key_dim(cfg)), cfg.dtype),
+                    "v": jnp.zeros((batch, max_len, kind.kv_heads,
+                                    gqa.value_dim(cfg)), cfg.dtype)}
+                for i, kind in enumerate(gqa.layer_kinds(cfg))}
     if cfg.attn_kind == "mla":
         attn = {"c": (batch, max_len, 1, cfg.kv_lora_rank),
                 "kr": (batch, max_len, 1, cfg.qk_rope_head_dim)}
@@ -88,7 +103,7 @@ def _qkv_heads(cfg, bp: tp.Dict, x: jax.Array, positions: jax.Array
     """Pre-norm, fused QKV projection and rotary: (q, k, v), each
     [B, S, H, Dh] (quantized kernels supported)."""
     with jax.named_scope("norm"):
-        normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
+        normed = _norm(cfg, x, bp["norm1"]["scale"])
     with jax.named_scope("qkv"):
         qkv_w, qkv_s = _kernel(bp["attn"]["qkv"]["kernel"], cfg.dtype)
         qkv = _postscale(jnp.einsum("btd,dchk->btchk", normed, qkv_w), qkv_s)
@@ -111,7 +126,7 @@ def _mlp_residual(cfg, bp: tp.Dict, x: jax.Array,
     """x + the block's pre-normed MLP (gated, or the expert layer, whose
     (assignments, experts hit) pair is appended to `stats` if given)."""
     with jax.named_scope("norm"):
-        normed = _rmsnorm(x, bp["norm2"]["scale"], cfg.dtype)
+        normed = _norm(cfg, x, bp["norm2"]["scale"])
     with jax.named_scope("mlp"):
         if "moe" in bp:
             out, counts = expert_layer(cfg, bp["moe"], normed)
@@ -192,7 +207,7 @@ def _ssd_mixer_forward(cfg, bp: tp.Dict, x: jax.Array, state: jax.Array,
     a mid-chunked-prefill slot must not have its accumulated state
     advanced by decode ticks it is not part of).
     """
-    normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
+    normed = _norm(cfg, x, bp["norm1"]["scale"])
     nstate = cfg.ssd_state_dim
     cbv_w, cbv_s = _kernel(bp["ssd"]["cbv"]["kernel"], cfg.dtype)
     cbv = _postscale(jnp.einsum("btd,dhp->bthp", normed, cbv_w), cbv_s)
@@ -230,7 +245,7 @@ def latent_projections(cfg, bp: tp.Dict, x: jax.Array, positions: jax.Array):
     and the paged step: (q_lat, q_rope) — the queries with W_kvb's key
     half absorbed — and (c_kv, k_rope), the token's cache row."""
     with jax.named_scope("norm"):
-        normed = _rmsnorm(x, bp["norm1"]["scale"], cfg.dtype)
+        normed = _norm(cfg, x, bp["norm1"]["scale"])
     with jax.named_scope("mla_q"):
         q_nope, q_rope = mla.queries(cfg, bp["attn"], normed, positions)
         q_lat = mla.absorb_queries(cfg, bp["attn"], q_nope)
@@ -265,13 +280,56 @@ def _cached_latent_attention(cfg, bp: tp.Dict, x: jax.Array,
     return latent_residual(cfg, bp, x, o_lat), entry
 
 
+def grouped_projections(cfg, kind: gqa.LayerKind, bp: tp.Dict,
+                        x: jax.Array, positions: jax.Array):
+    """A grouped-attention block's pre-norm and projections, shared by
+    the dense and the paged step: (q, k, v) of models/gqa.py."""
+    with jax.named_scope("norm"):
+        normed = _norm(cfg, x, bp["norm1"]["scale"])
+    return gqa.project(cfg, kind, bp["attn"], normed, positions)
+
+
+def grouped_residual(cfg, bp: tp.Dict, x: jax.Array, heads_out: jax.Array
+                     ) -> jax.Array:
+    """x + W_o of the attended heads [B, S, H, Dv]."""
+    with jax.named_scope("out_proj"):
+        return x + gqa.output(cfg, bp["attn"], heads_out)
+
+
+def _cached_grouped_attention(cfg, kind: gqa.LayerKind, bp: tp.Dict,
+                              x: jax.Array, positions: jax.Array,
+                              entry: tp.Dict, cache_index: jax.Array):
+    """Pre-norm grouped attention of one layer kind against the dense
+    {'k','v'} slabs, row s of which holds position s: returns
+    (x + attn_out, entry)."""
+    q, k, v = grouped_projections(cfg, kind, bp, x, positions)
+    with jax.named_scope("kv_write"):
+        entry = {"k": _cache_write(entry["k"], k.astype(cfg.dtype),
+                                   cache_index),
+                 "v": _cache_write(entry["v"], v.astype(cfg.dtype),
+                                   cache_index)}
+    with jax.named_scope("attn"), jax.named_scope(kind.scope):
+        rows = jnp.broadcast_to(jnp.arange(entry["k"].shape[1]),
+                                entry["k"].shape[:2])
+        side_by_side = lambda slab: slab.reshape(slab.shape[:2] + (-1,))
+        heads_out = gqa.attend(cfg, kind, bp["attn"], q,
+                               side_by_side(entry["k"]),
+                               side_by_side(entry["v"]), rows, positions)
+    return grouped_residual(cfg, bp, x, heads_out), entry
+
+
 def _layer_forward(cfg: TransformerConfig, bp: tp.Dict, x: jax.Array,
                    positions: jax.Array, entry: tp.Dict,
                    cache_index: jax.Array,
-                   stats: tp.Optional[tp.List] = None):
+                   stats: tp.Optional[tp.List] = None, layer: int = 0):
     """One block against its cache entry ({'k','v'} slabs, or the
-    latent {'c','kr'}): returns (x, entry)."""
-    if cfg.attn_kind == "mla":
+    latent {'c','kr'}): returns (x, entry). `layer` picks the layer's
+    kind where the config has one a layer (`attn_kind='gqa'`)."""
+    if cfg.attn_kind == "gqa":
+        x, entry = _cached_grouped_attention(
+            cfg, gqa.layer_kinds(cfg)[layer], bp, x, positions, entry,
+            cache_index)
+    elif cfg.attn_kind == "mla":
         x, entry = _cached_latent_attention(cfg, bp, x, positions, entry,
                                             cache_index)
     else:
@@ -307,7 +365,7 @@ def _head_logits(p: tp.Dict, x: jax.Array, cfg: TransformerConfig
     and paged apply steps.
     """
     with jax.named_scope("norm"):
-        x = _rmsnorm(x, p["norm_f"]["scale"], cfg.dtype)
+        x = _norm(cfg, x, p["norm_f"]["scale"])
     with jax.named_scope("head"):
         table = p["head"] if "head" in p else p["embed"]
         if is_quantized(table):
@@ -373,7 +431,7 @@ def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,
             else:
                 x, new_cache[name] = _layer_forward(
                     cfg, p[name], x, positions, cache[name], cache_index,
-                    stats)
+                    stats, layer)
 
     return _head_logits(p, x, cfg), new_cache
 

@@ -77,11 +77,30 @@ class TransformerConfig:
     attn_kind: str = "mha"       # 'mha' | 'mla': latent attention
                                  # (models/mla.py), whose cache entry is
                                  # the latent, not per-head K and V
+                                 # | 'gqa': grouped KV heads, a layer
+                                 # kind PER LAYER (full or window), key
+                                 # and value widths of their own
+                                 # (models/gqa.py; the keys below)
     q_lora_rank: int = 0         # mla: width of the query latent
     kv_lora_rank: int = 0        # mla: width of the cached latent
     qk_nope_head_dim: int = 0    # mla: per head, unrotated / rotated
     qk_rope_head_dim: int = 0    #      key-query widths,
-    v_head_dim: int = 0          #      and the value width
+    v_head_dim: int = 0          #      and the value width (gqa: 0 =
+                                 #      the key width)
+    qk_head_dim: int = 0         # gqa: key/query width a head
+    rotary_dim: int = 0          # gqa: leading dimensions of a head that
+                                 # rotate (0 = the whole head)
+    num_kv_heads: int = 0        # gqa: KV heads of a full layer
+    window_layers: tp.Tuple[int, ...] = ()  # gqa: per layer 1 = window
+                                 # attention, 0 = full; () = all full
+    window: int = 0              # gqa: a window layer's query sees itself
+                                 # and the window - 1 positions before it
+    window_kv_heads: int = 0     # gqa: KV heads of a window layer
+    window_rope_theta: float = 0.0  # gqa: its rotary base (0 = rope_theta)
+    window_sink: bool = False    # gqa: a learned scalar a head joins the
+                                 # window layers' softmax denominator
+    value_scale: float = 1.0     # gqa: the values are multiplied by it
+    norm_eps: float = 1e-6       # every RMSNorm's epsilon
     rope_theta: float = 10000.0
     rope_interleaved: bool = False  # rotary pairs (2i, 2i+1), not
                                     # (i, i + D/2)
@@ -141,27 +160,30 @@ def expert_layers(cfg: "TransformerConfig") -> tp.Tuple[bool, ...]:
     """Per layer: True where the block's MLP is the `n_routed` expert
     layer (every layer after the `dense_layers` leading ones). Checks
     what the new kinds cannot be combined with."""
-    if cfg.attn_kind not in ("mha", "mla"):
-        raise ValueError(f"config.attn_kind must be 'mha' or 'mla', got "
-                         f"{cfg.attn_kind!r}")
+    if cfg.attn_kind not in ("mha", "mla", "gqa"):
+        raise ValueError(f"config.attn_kind must be 'mha', 'mla' or 'gqa', "
+                         f"got {cfg.attn_kind!r}")
     if cfg.n_routed > 0 and cfg.moe_experts > 0:
         raise ValueError("config.n_routed (sigmoid group-limited experts) "
                          "and config.moe_experts (softmax MoEMLP) are two "
                          "expert layers: state one")
-    if cfg.scan_layers and (cfg.attn_kind == "mla" or cfg.n_routed > 0
+    if cfg.scan_layers and (cfg.attn_kind != "mha" or cfg.n_routed > 0
                             or not cfg.tie_head):
         raise ValueError("scan_layers stacks one block body: latent "
-                         "attention, n_routed expert layers and an untied "
-                         "head are not stacked (scan_layers=False)")
+                         "attention, grouped attention by layer kind, "
+                         "n_routed expert layers and an untied head are not "
+                         "stacked (scan_layers=False)")
     return tuple(cfg.n_routed > 0 and layer >= cfg.dense_layers
                  for layer in range(cfg.num_layers))
 
 
-def rmsnorm(x: jax.Array, scale: jax.Array, dtype: tp.Any) -> jax.Array:
+def rmsnorm(x: jax.Array, scale: jax.Array, dtype: tp.Any,
+            eps: float = 1e-6) -> jax.Array:
     """Functional RMSNorm matching nn.RMSNorm's math (f32 accumulation,
-    eps 1e-6); used by the decode/pipelined paths that read raw params."""
+    eps 1e-6 unless the config states another: `norm_eps`); used by the
+    decode/pipelined paths that read raw params."""
     h = jnp.asarray(x, jnp.float32)
-    h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + 1e-6)
+    h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + eps)
     return (h * scale.astype(jnp.float32)).astype(dtype)
 
 
@@ -311,6 +333,7 @@ class Block(nn.Module):
     mesh: tp.Any = None
     mixer: str = "attention"  # this layer's entry from mixer_pattern
     experts: bool = False     # this layer's entry from expert_layers
+    layer: int = 0            # its index (gqa: picks the layer's kind)
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
@@ -323,12 +346,16 @@ class Block(nn.Module):
         elif cfg.attn_kind == "mla":
             from .mla import LatentAttention
             mix = LatentAttention(cfg, name="attn")
+        elif cfg.attn_kind == "gqa":
+            from .gqa import GroupedAttention
+            mix = GroupedAttention(cfg, layer=self.layer, name="attn")
         else:
             mix = Attention(cfg, mesh=self.mesh, name="attn")
         x = x + mix(
-            nn.RMSNorm(dtype=cfg.dtype, name="norm1")(x), positions, train,
-            segment_ids)
-        normed = nn.RMSNorm(dtype=cfg.dtype, name="norm2")(x)
+            nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                       name="norm1")(x), positions, train, segment_ids)
+        normed = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                            name="norm2")(x)
         if self.experts:
             x = x + ExpertMLP(cfg, name="moe")(normed)
         elif cfg.moe_experts > 0:
@@ -437,9 +464,11 @@ class TransformerLM(nn.Module):
             block = _remat(cfg) if cfg.remat else Block
             for layer in range(cfg.num_layers):
                 x = block(cfg, mesh=self.mesh, mixer=pattern[layer],
-                          experts=experts[layer], name=f"block_{layer}")(
+                          experts=experts[layer], layer=layer,
+                          name=f"block_{layer}")(
                     x, positions, train, segment_ids)
-        x = nn.RMSNorm(dtype=cfg.dtype, name="norm_f")(x)
+        x = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                       name="norm_f")(x)
         if not cfg.tie_head:
             # an output table of its own, laid out like the embedding
             embedding = self.param(

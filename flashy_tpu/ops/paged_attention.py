@@ -111,24 +111,77 @@ def cfg_pool_spec(cfg, num_blocks: int, block_size: int, kv_dtype: str
                      cfg.dtype, kv_dtype)
 
 
+def grouped_pool_spec(num_blocks: int, block_size: int, kv_heads: int,
+                      key_dim: int, value_dim: int, dtype
+                      ) -> tp.Dict[str, tp.Tuple[tp.Tuple[int, ...], tp.Any]]:
+    """ONE layer's entry of a grouped-attention pool (models/gqa.py):
+    `k` [N, bs, Hkv * Dk] and `v` [N, bs, Hkv * Dv], a row's heads side
+    by side on the lanes. The layer's own KV head count, and a key width
+    that need not be the value's nor whole lanes: 4 heads of 192 are 768
+    lanes, whole (8, 128) tiles as stored, where [N, bs, 4, 192] would
+    be padded to 256 a head or relaid out (`LANES` above)."""
+    return {"k": ((num_blocks, block_size, kv_heads * key_dim), dtype),
+            "v": ((num_blocks, block_size, kv_heads * value_dim), dtype)}
+
+
+def ring_blocks(window: int, rows: int, block_size: int) -> int:
+    """Blocks of one slot's ring in a window layer: room for the
+    `window - 1` positions a query sees behind it and the `rows`
+    positions the largest step writes (a prefill slice, the verify
+    step's drafts), so that no row a query of the step still sees is
+    overwritten by a later row of the same step."""
+    return -(-(window - 1 + rows) // block_size)
+
+
+def layer_pool_specs(cfg, num_blocks: int, block_size: int, kv_dtype: str,
+                     *, slots: int = 0, ring: int = 0) -> tp.List[tp.Dict]:
+    """Per layer, the pool entry `cfg_pool_spec` gives every layer
+    alike — or, for `attn_kind='gqa'`, the entry of the layer's kind:
+    a full-attention layer pages through the block table as every K/V
+    layer does (`num_blocks` blocks of its own head count), a window
+    layer holds `[1 + slots, ring * block_size, F]`: a sentinel and one
+    static ring of `ring` blocks' rows a slot (`ring_address`),
+    whatever the context."""
+    if getattr(cfg, "attn_kind", "mha") != "gqa":
+        return [cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
+                ] * cfg.num_layers
+    from ..models import gqa
+    if kv_dtype != "model":
+        raise ValueError(
+            f"kv_dtype={kv_dtype!r} keeps a scale per row and head in rows "
+            f"laid out for one head count and one head width; a grouped "
+            f"pool (attn_kind='gqa') has a head count a layer kind and "
+            f"unequal key and value widths: use kv_dtype='model'")
+    return [grouped_pool_spec(
+                *((1 + slots, ring * block_size) if kind.window
+                  else (num_blocks, block_size)),
+                kind.kv_heads, gqa.key_dim(cfg), gqa.value_dim(cfg),
+                cfg.dtype)
+            for kind in gqa.layer_kinds(cfg)]
+
+
 def init_pool(cfg, num_blocks: int, block_size: int,
-              kv_dtype: str = "model") -> tp.Dict:
+              kv_dtype: str = "model", *, slots: int = 0,
+              ring: int = 0) -> tp.Dict:
     """Allocate the block-pool cache pytree for a TransformerLM config.
 
     Mirrors models/decoding.init_cache's structure so the rest of the
     decode step is layout-agnostic: per-layer models get one entry per
-    block_i; scan-stacked models get stacked [L, N, bs, H, Dh] leaves
-    scanned together with the stacked parameters. Block 0 is the
+    block_i (`layer_pool_specs`: a window layer's is `slots` rings of
+    `ring` blocks); scan-stacked models get stacked [L, N, bs, H, Dh]
+    leaves scanned together with the stacked parameters. Block 0 is the
     sentinel (ops-level convention; serve/paged.BlockPool never hands
     it out).
     """
-    spec = cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
     if cfg.scan_layers:
+        spec = cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
         return {name: jnp.zeros((cfg.num_layers,) + shape, dt)
                 for name, (shape, dt) in spec.items()}
+    specs = layer_pool_specs(cfg, num_blocks, block_size, kv_dtype,
+                             slots=slots, ring=ring)
     return {f"block_{i}": {name: jnp.zeros(shape, dt)
                            for name, (shape, dt) in spec.items()}
-            for i in range(cfg.num_layers)}
+            for i, spec in enumerate(specs)}
 
 
 def _physical(table: jax.Array, positions: jax.Array, block_size: int
@@ -161,6 +214,66 @@ def latent_paged_write(entry: tp.Dict, c_kv: jax.Array, k_rope: jax.Array,
     return {name: entry[name].at[block, offset].set(
                 new.astype(entry[name].dtype))
             for name, new in (("c", c_kv), ("kr", k_rope))}
+
+
+def ring_address(slots: jax.Array, positions: jax.Array, cells: int
+                 ) -> tp.Tuple[jax.Array, jax.Array]:
+    """Positions [B, T] of slots [B] -> (ring [B, T], cell [B, T]) in a
+    window layer's entry `[1 + slots, cells, F]`: position p of slot s
+    lies in ring `1 + s` at cell `p % cells`, from the positions the
+    step already has — no allocation, no free, no table. A row that
+    belongs to no slot (-1: a parked row of the decode step, whose slot
+    may be mid-prefill) goes to the sentinel, ring 0."""
+    return (jnp.broadcast_to(1 + slots[:, None], positions.shape),
+            positions % cells)
+
+
+def ring_view(entry: tp.Dict, slots: jax.Array, positions: jax.Array
+              ) -> tp.Tuple[jax.Array, jax.Array, jax.Array]:
+    """The rings of `slots` [B] out of a window layer's entry, as
+    (k [B, cells, Hkv * Dk], v [B, cells, Hkv * Dv], the position each
+    cell holds [B, cells]) once the step at `positions` is written:
+    cell c holds the positions p = c (mod cells), `ring_positions` says
+    which."""
+    k_view = entry["k"][1 + slots]
+    return k_view, entry["v"][1 + slots], ring_positions(
+        positions, k_view.shape[1])
+
+
+def ring_positions(positions: jax.Array, cells: int) -> jax.Array:
+    """The position each of a ring's `cells` rows holds once the step
+    whose rows are at `positions` [B, T] is written: the largest
+    p <= the step's last row with p = c (mod cells); negative where the
+    slot's request has not come that far (what lies there is an earlier
+    request's). [B, cells]."""
+    last = positions[:, -1:]
+    return last - (last - jnp.arange(cells, dtype=positions.dtype)) % cells
+
+
+def grouped_write(entry: tp.Dict, new_k: jax.Array, new_v: jax.Array,
+                  block: jax.Array, offset: jax.Array) -> tp.Dict:
+    """Write fresh rows k [B, T, Hkv, Dk], v [B, T, Hkv, Dv] into a
+    grouped pool entry ({k, v}: `grouped_pool_spec`) at (block, offset)
+    [B, T] — or (ring, cell) of a window layer's — a row's heads side by
+    side."""
+    return {name: entry[name].at[block, offset].set(
+                new.reshape(new.shape[:2] + (-1,)).astype(entry[name].dtype))
+            for name, new in (("k", new_k), ("v", new_v))}
+
+
+def grouped_table_view(entry: tp.Dict, table: jax.Array
+                       ) -> tp.Tuple[jax.Array, jax.Array, jax.Array]:
+    """Each row's logical view of a full-attention layer's entry through
+    its block table [B, E]: (k [B, E * bs, Hkv * Dk], v [B, E * bs,
+    Hkv * Dv], the position each row holds [B, E * bs]: row s holds
+    position s); sentinel entries past every causal horizon, as
+    `gather_kv`'s."""
+    def view(leaf):
+        g = leaf[table]                                 # [B, E, bs, F]
+        return g.reshape(g.shape[0], -1, g.shape[-1])
+    k_view = view(entry["k"])
+    return k_view, view(entry["v"]), jnp.broadcast_to(
+        jnp.arange(k_view.shape[1], dtype=table.dtype), k_view.shape[:2])
 
 
 def scale_rows(scales: jax.Array, blocks: jax.Array, num_heads: int
@@ -413,8 +526,12 @@ def slot_kv(entry: tp.Dict, table_row, length: int, dtype=jnp.float32
 
 
 def pool_bytes(cfg, num_blocks: int, block_size: int,
-               kv_dtype: str = "model") -> int:
-    """Total HBM bytes of the pool across layers (capacity planning).
+               kv_dtype: str = "model", *, slots: int = 0,
+               ring: int = 0) -> int:
+    """Total HBM bytes of the pool across layers (capacity planning),
+    each layer by its kind: with `slots` rings of `ring` blocks, window
+    layers count those and their sentinel ring (`window_bytes`) and no
+    block of the `num_blocks` that grow.
 
     Pure host arithmetic — the scheduler consults it every step for
     the bytes-per-token gauge, so no jnp ops belong here.
@@ -422,12 +539,39 @@ def pool_bytes(cfg, num_blocks: int, block_size: int,
     import math
 
     import numpy as np
-    spec = cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
-    per_layer = sum(np.dtype(dt).itemsize * math.prod(shape)
-                    for shape, dt in spec.values())
-    return per_layer * cfg.num_layers
+    return sum(np.dtype(dt).itemsize * math.prod(shape)
+               for spec in layer_pool_specs(cfg, num_blocks, block_size,
+                                            kv_dtype, slots=slots, ring=ring)
+               for shape, dt in spec.values())
+
+
+def window_bytes(cfg, block_size: int, *, slots: int, ring: int) -> int:
+    """HBM bytes of the window layers' rings (and sentinels): fixed,
+    whatever the contexts. 0 for a config without window layers."""
+    return (pool_bytes(cfg, 0, block_size, slots=slots, ring=ring)
+            if slots * ring else 0)
+
+
+def token_bytes(cfg, kv_dtype: str = "model") -> tp.Tuple[int, int]:
+    """Bytes as stored that ONE cached token costs (the layers whose
+    pool grows with the context, the window layers): a block of one
+    row, and one row `[Hkv * width]` of each leaf of each window
+    layer's entry."""
+    import numpy as np
+
+    from ..models import gqa
+    rows = 0
+    if gqa.has_window(cfg):
+        specs = layer_pool_specs(cfg, 1, 1, kv_dtype, slots=1, ring=1)
+        rows = sum(np.dtype(dt).itemsize * shape[-1]
+                   for spec, kind in zip(specs, gqa.layer_kinds(cfg))
+                   if kind.window for shape, dt in spec.values())
+    return block_bytes(cfg, 1, kv_dtype), rows
 
 
 def block_bytes(cfg, block_size: int, kv_dtype: str = "model") -> int:
-    """HBM bytes ONE block costs across layers (admission accounting)."""
-    return pool_bytes(cfg, 1, block_size, kv_dtype)
+    """HBM bytes ONE block of the pool that grows costs across the
+    layers that page through the table (admission accounting): every
+    layer, but for a window layer, which is counted by `window_bytes`."""
+    return (pool_bytes(cfg, 1, block_size, kv_dtype)
+            - pool_bytes(cfg, 0, block_size, kv_dtype))

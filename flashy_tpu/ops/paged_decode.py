@@ -132,7 +132,19 @@ def fused_kernel_unsupported_reason(cfg: tp.Any = None,
     walk but the one whose copies the kernel issues itself, so its `c`
     and `kr` blocks must be whole (sublanes, 128) tiles: `block_size`,
     when the caller knows it, is held to the sublanes of `cfg.dtype`.
+    A grouped pool (`attn_kind='gqa'`) has no walk yet: `walk_shape`
+    and `_fused_call` take one head width for K and V and as many KV
+    heads as query heads.
     """
+    if getattr(cfg, "attn_kind", "mha") == "gqa":
+        from ..models import gqa
+        heads = sorted({kind.kv_heads for kind in gqa.layer_kinds(cfg)})
+        return (f"the fused kernel walks K and V blocks of one head width "
+                f"with as many KV heads as query heads, and this pool has "
+                f"{' | '.join(map(str, heads))} KV heads of "
+                f"{gqa.key_dim(cfg)} | {gqa.value_dim(cfg)} under "
+                f"{cfg.num_heads} query heads; the XLA table gather reads "
+                f"it (its window layers' rings by the masked dense form)")
     if getattr(cfg, "attn_kind", "mha") == "mla":
         sublanes = SUBLANES * 4 // jnp.dtype(cfg.dtype).itemsize
         if cfg.kv_lora_rank % LANES or (block_size or 0) % sublanes:

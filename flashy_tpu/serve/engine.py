@@ -56,16 +56,22 @@ def _zero_ssd_leaves(cache: tp.Any, fresh: tp.Any) -> tp.Any:
 
 def state_bytes_per_slot(cfg: tp.Any, max_seq_len: int, cache_layout: str,
                          *, kv_dtype: str = "model",
-                         block_size: int = 16) -> int:
+                         block_size: int = 16, ring: int = 0) -> int:
     """Decode-state bytes ONE slot reserves at `max_seq_len`, by layout.
 
     Host arithmetic only (no allocation) — the capacity number
     `ServeMetrics.static_info` prints and the O(1)-state gate measures:
 
-      dense:  per-layer [max_seq_len, H, Dh] K+V slabs;
+      dense:  per-layer [max_seq_len, H, Dh] K+V slabs (a grouped
+              config's by its layer's kind, a window layer's whole
+              too: the dense layout bounds nothing);
       paged:  the slot's full block budget (max_seq_len / block_size
               blocks) at `block_bytes` — int8 pools count payload +
-              scales, exactly what admission reserves;
+              scales, exactly what admission reserves — and, where the
+              config has window layers, their rings of `ring` blocks
+              (the engine's; by default the least a paged engine has,
+              `ring_blocks` for a step of `block_size` rows), which do
+              NOT grow with `max_seq_len`;
       ssd:    SSD layers contribute the fixed [H, Dh, Dstate] f32
               state — NO max_seq_len term, the O(1) contract — while
               any attention layers in a hybrid stack keep their dense
@@ -76,24 +82,37 @@ def state_bytes_per_slot(cfg: tp.Any, max_seq_len: int, cache_layout: str,
     from ..models.transformer import mixer_pattern
     pattern = mixer_pattern(cfg)
     act_itemsize = jnp.dtype(cfg.dtype).itemsize
-    if cfg.attn_kind == "mla":  # one latent row a token, not K and V
+    if cfg.attn_kind == "gqa":
+        from ..models import gqa
+        widths = gqa.key_dim(cfg) + gqa.value_dim(cfg)
+        kv_slabs = [max_seq_len * kind.kv_heads * widths * act_itemsize
+                    for kind in gqa.layer_kinds(cfg)]
+    elif cfg.attn_kind == "mla":  # one latent row a token, not K and V
         from ..models.mla import latent_width
-        kv_slab = max_seq_len * latent_width(cfg) * act_itemsize
+        kv_slabs = [max_seq_len * latent_width(cfg) * act_itemsize
+                    ] * cfg.num_layers
     else:
-        kv_slab = (2 * max_seq_len * cfg.num_heads * cfg.head_dim
-                   * act_itemsize)
+        kv_slabs = [2 * max_seq_len * cfg.num_heads * cfg.head_dim
+                    * act_itemsize] * cfg.num_layers
     ssd_state = cfg.num_heads * cfg.head_dim * cfg.ssd_state_dim * 4
     if cache_layout == "dense":
-        return kv_slab * cfg.num_layers
+        return sum(kv_slabs)
     if cache_layout == "paged":
-        from ..ops.paged_attention import block_bytes
+        from ..models.gqa import has_window
+        from ..ops.paged_attention import (block_bytes, ring_blocks,
+                                           token_bytes)
         if max_seq_len % block_size:
             raise ValueError(f"block_size {block_size} must divide "
                              f"max_seq_len {max_seq_len}")
-        return (max_seq_len // block_size) * block_bytes(cfg, block_size,
-                                                         kv_dtype)
+        rings = 0
+        if has_window(cfg):
+            ring = ring or ring_blocks(cfg.window, block_size, block_size)
+            rings = ring * block_size * token_bytes(cfg, kv_dtype)[1]
+        return rings + (max_seq_len // block_size) * block_bytes(
+            cfg, block_size, kv_dtype)
     if cache_layout == "ssd":
-        return sum(ssd_state if m == "ssd" else kv_slab for m in pattern)
+        return sum(ssd_state if m == "ssd" else slab
+                   for m, slab in zip(pattern, kv_slabs))
     raise ValueError(f"unknown cache_layout {cache_layout!r}")
 
 
@@ -235,6 +254,8 @@ class DecodeEngine:
             on CPU an explicit kernel='fused' runs in interpret mode
             (what the demo and the parity tests do).
         prefix_cache: enable cross-request prefix sharing (paged only).
+            A model with window layers shares none, whatever is asked
+            (`BlockPool`: a hit would need rows a ring has overwritten).
         cache_scope: prefix for this engine's compile-cache keys (and
             therefore its RecompileWatchdog entry names). REQUIRED
             whenever two engines coexist in one process — different
@@ -357,6 +378,11 @@ class DecodeEngine:
         # (a latent pool refuses int8 where its spec is made:
         # ops.paged_attention.cfg_pool_spec)
         self._latent = self._cfg.attn_kind == "mla"
+        # grouped attention by layer kind (models/gqa.py): full layers
+        # page through the pool, window layers keep a ring a slot
+        from ..models.gqa import has_window
+        self._grouped = self._cfg.attn_kind == "gqa"
+        self._windowed = has_window(self._cfg)
         if kernel not in ("auto", "gather", "fused"):
             raise ValueError(f"kernel must be 'auto', 'gather' or "
                              f"'fused', got {kernel!r}")
@@ -444,9 +470,17 @@ class DecodeEngine:
                              "paged-layout sharing hooks; the dense "
                              "layout has no block pool to share")
         self._pool_base = int(pool_slot_base)
+        self.ring = 0  # blocks of a window layer's ring a slot
         if cache_layout == "paged":
-            from ..ops.paged_attention import block_bytes, init_pool
+            from ..ops.paged_attention import (block_bytes, init_pool,
+                                               token_bytes, window_bytes)
             from .paged import BlockPool, CacheBox
+            if self._windowed and (pool is not None
+                                   or cache_box is not None):
+                raise ValueError(
+                    "a model with window layers keeps a ring a slot beside "
+                    "the pool, which a block list does not hand over: no "
+                    "shared pool / cache_box (disaggregated hand-off) for it")
             if pool is not None:
                 if pool.block_size != self.block_size:
                     raise ValueError(
@@ -484,15 +518,24 @@ class DecodeEngine:
                     num_blocks=self.num_blocks, block_size=self.block_size,
                     max_seq_len=self.max_seq_len,
                     spec_overshoot=self.spec_k or 0,
-                    prefix_cache=prefix_cache)
+                    prefix_cache=prefix_cache,
+                    # a ring holds the largest step's rows behind a window
+                    window=self._cfg.window if self._windowed else 0,
+                    step_rows=max(self.chunk, (self.spec_k or 0) + 1))
+            self.ring = self._pool.ring
             self._cache_box = cache_box if cache_box is not None \
                 else CacheBox()
             if self._cache_box.value is None:
                 self._cache_box.value = init_pool(
                     self._cfg, self.num_blocks, self.block_size,
-                    self.kv_dtype)
+                    self.kv_dtype, slots=slots, ring=self.ring)
             self._block_bytes = block_bytes(self._cfg, self.block_size,
                                             self.kv_dtype)
+            # bytes a cached token costs the layers that grow | the
+            # window layers; the rings' bytes, fixed
+            self._token_bytes = token_bytes(self._cfg, self.kv_dtype)
+            self._window_bytes = window_bytes(
+                self._cfg, self.block_size, slots=slots, ring=self.ring)
             self._table_host = np.zeros(
                 (slots, self._pool.max_blocks), np.int32)
             self._table_dev = jnp.asarray(self._table_host)
@@ -571,6 +614,16 @@ class DecodeEngine:
             return jax.random.categorical(
                 key, logits / self.temperature, axis=-1).astype(jnp.int32)
 
+    def _row_slots(self, active):
+        """What a paged step over every slot hands `paged_apply_step(
+        slots=)`: row s is slot s, or -1 where the slot is parked (free,
+        or mid-prefill: its ring is the slices' to write). None without
+        window layers (the program is the one it always was)."""
+        import jax.numpy as jnp
+        if not self._windowed:
+            return None
+        return jnp.where(active, jnp.arange(self.slots, dtype=jnp.int32), -1)
+
     def _moe_list(self) -> tp.Optional[tp.List]:
         """What a paged step hands `paged_apply_step(stats=)`: a list the
         expert layers append their counts to, or None (no expert layer:
@@ -634,7 +687,8 @@ class DecodeEngine:
                 logits, cache = paged_apply_step(
                     model, params, cfg, tokens[:, None],
                     positions[:, None], cache, table,
-                    kernel=self.kernel, stats=stats)
+                    kernel=self.kernel, stats=stats,
+                    slots=self._row_slots(active))
                 nxt = self._sample(logits[:, -1], key)
                 nxt = jnp.where(active, nxt, jnp.int32(pad))
                 out = (nxt, cache) if stats is None else (
@@ -716,7 +770,8 @@ class DecodeEngine:
                 stats = self._moe_list()
                 logits, cache = paged_apply_step(
                     model, params, cfg, tokens, positions, cache, row,
-                    kernel=self.kernel, stats=stats)
+                    kernel=self.kernel, stats=stats,
+                    slots=slot[None] if self._windowed else None)
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], used - 1, axis=0, keepdims=True)
                 first = self._sample(last, key)[0]
@@ -789,7 +844,8 @@ class DecodeEngine:
                 stats = self._moe_list()
                 logits, cache = paged_apply_step(
                     model, params, cfg, toks, pos, cache, table,
-                    kernel=self.kernel, stats=stats)
+                    kernel=self.kernel, stats=stats,
+                    slots=self._row_slots(active))
                 out, accepted = speculative_acceptance(
                     drafts, logits, temperature=self.temperature,
                     rng=key if self.temperature > 0.0 else None,
@@ -1014,9 +1070,16 @@ class DecodeEngine:
         stats = self._pool.stats()
         per_block = self._block_bytes
         live_tokens = int(sum(self._positions_host[self._active_host]))
+        # `window_bytes`: the window layers' rings, fixed whatever the
+        # contexts (0 without window layers, which also share no prefix:
+        # `BlockPool`); capacity, in_use and peak_in_use stay the pool
+        # that grows, the full-attention layers' blocks. A live slot
+        # holds its rings beside its blocks.
+        stats["window_bytes"] = self._window_bytes
+        held = (stats["in_use"] * per_block + int(self._active_host.sum())
+                * (self._window_bytes // (1 + self.slots)))
         stats["kv_bytes_per_token"] = (
-            stats["in_use"] * per_block / live_tokens if live_tokens
-            else 0.0)
+            held / live_tokens if live_tokens else 0.0)
         return stats
 
     def state_bytes_per_slot(self) -> int:
@@ -1024,7 +1087,8 @@ class DecodeEngine:
         max_seq_len (see module-level `state_bytes_per_slot`)."""
         return state_bytes_per_slot(
             self._cfg, self.max_seq_len, self.cache_layout,
-            kv_dtype=self.kv_dtype, block_size=self.block_size)
+            kv_dtype=self.kv_dtype, block_size=self.block_size,
+            ring=self.ring)
 
     def cache_bytes(self) -> int:
         """Total HBM bytes this engine's KV cache occupies (the fixed
@@ -1032,7 +1096,7 @@ class DecodeEngine:
         if self._pool is not None:
             from ..ops.paged_attention import pool_bytes
             return pool_bytes(self._cfg, self.num_blocks, self.block_size,
-                              self.kv_dtype)
+                              self.kv_dtype, slots=self.slots, ring=self.ring)
         import jax
         return int(sum(leaf.size * leaf.dtype.itemsize
                        for leaf in jax.tree_util.tree_leaves(self._cache)))
@@ -1155,14 +1219,23 @@ class DecodeEngine:
         (ops/paged_decode.walk_counts). For a latent pool, whichever
         read serves it: `kv_bytes`, the bytes as stored, over all
         layers, of the latent rows the live slots' queries attend
-        (parked slots sit at max_seq_len)."""
+        (parked slots sit at max_seq_len). For a grouped pool likewise,
+        by layer kind: a full-attention layer counts a slot's live
+        context, a window layer the rows its queries see of it
+        (`min(context, window - 1 + queries)`), and `kv_bytes_window`
+        is the window layers' part."""
         if self._pool is None:
             return {}
         stats = {}
-        if self._latent:
+        if self._latent or self._grouped:
             bases = np.asarray(bases)
-            rows = int((bases[bases < self.max_seq_len] + queries).sum())
-            stats["kv_bytes"] = rows * (self._block_bytes // self.block_size)
+            rows = bases[bases < self.max_seq_len] + queries
+            grows, ring = self._token_bytes
+            stats["kv_bytes"] = int(rows.sum()) * grows
+            if self._windowed:
+                seen = np.minimum(rows, self._cfg.window - 1 + queries)
+                stats["kv_bytes_window"] = int(seen.sum()) * ring
+                stats["kv_bytes"] += stats["kv_bytes_window"]
         if self.kernel != "fused":
             return stats
         from ..ops.paged_decode import (call_walk, latent_call_walk,

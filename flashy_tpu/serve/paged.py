@@ -244,11 +244,23 @@ class BlockPool:
         prefix_cache: enable the PrefixIndex (sharing + COW); off, every
             admission allocates fresh blocks and retirement frees them
             all.
+        window, step_rows: a model with window layers (models/gqa.py)
+            keeps, beside this pool's blocks, a static ring a slot a
+            window layer of `ring` blocks (`ops.paged_attention.
+            ring_blocks`: the `window - 1` rows a query sees behind it
+            and the `step_rows` of the largest step) that nothing here
+            allocates or frees: the blocks counted here are the
+            full-attention layers' alone, and `check()` holds the ring
+            to its size. `plan` finds no prefix for such a model
+            (`prefix_cache` is off): a hit would need the window layers'
+            rows of the prefix's last `window - 1` tokens, which a ring
+            has overwritten.
     """
 
     def __init__(self, *, num_blocks: int, block_size: int,
                  max_seq_len: int, spec_overshoot: int = 0,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, window: int = 0,
+                 step_rows: int = 0):
         if num_blocks < 2:
             raise ValueError(f"need >= 2 blocks (sentinel + 1 real), "
                              f"got {num_blocks}")
@@ -260,7 +272,11 @@ class BlockPool:
         self.max_blocks = max_seq_len // block_size  # per-slot table entries
         self.max_seq_len = max_seq_len
         self.spec_overshoot = int(spec_overshoot)
-        self.prefix_cache = prefix_cache
+        self.prefix_cache = prefix_cache and not window
+        self.window, self.step_rows = int(window), int(step_rows)
+        from ..ops.paged_attention import ring_blocks
+        self.ring = (ring_blocks(self.window, self.step_rows, block_size)
+                     if window else 0)
         self.capacity = num_blocks - 1
         # min-heap: allocation pops the lowest free block (deterministic
         # tables for tests/traces) in O(log N), not via list sorts
@@ -550,6 +566,20 @@ class BlockPool:
         mutations keep O(touched) asserts inline instead."""
         if np.any(self.refcount < 0):
             raise AssertionError("negative block refcount")
+        if self.window:
+            # the window layers' half: nothing to leak (a ring is a
+            # slot's for good), but the ring must hold what the largest
+            # step writes behind a full window, and no prefix may have
+            # been shared past it
+            rows = max(self.step_rows, 1 + self.spec_overshoot)
+            if self.ring * self.block_size < self.window - 1 + rows:
+                raise AssertionError(
+                    f"a window layer's ring of {self.ring} blocks is "
+                    f"smaller than the window's {self.window - 1} rows "
+                    f"and a step's {rows}")
+            if len(self.index) or self.prefix_matched_tokens:
+                raise AssertionError("a prefix was shared under window "
+                                     "layers")
         if self._cached != self.index.blocks:
             raise AssertionError("cached-block mirror drifted from the "
                                  "index")
@@ -586,6 +616,9 @@ class BlockPool:
             "prefix_hit_rate": self.prefix_hit_rate,
             "preemptions": self.preemptions,
             "handoffs": self.handoffs,
+            # blocks a slot of a window layer's ring: static, in none
+            # of the counts above (0: no window layer)
+            "window_ring_blocks": self.ring,
         }
 
 
@@ -593,7 +626,7 @@ class BlockPool:
 # the paged model step (device side)
 # ----------------------------------------------------------------------
 def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
-                     kernel: str = "gather", stats=None):
+                     kernel: str = "gather", stats=None, slots=None):
     """Forward `tokens` [B, T] at `positions` [B, T] against the pool.
 
     The paged twin of models/decoding._apply_step: same embed, MLP/MoE,
@@ -611,18 +644,27 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     (`attn_kind='mla'`) writes its latent pool entry and attends in the
     cached form (models/mla.py) by the same choice: 'gather' is
     `latent_paged_attention`, 'fused' the latent walk of the same
-    kernel module (`fused_latent_attention`). Each expert layer of an
-    unstacked model appends its (assignments, experts hit) counts to
-    `stats` when a list is given.
+    kernel module (`fused_latent_attention`). A grouped-attention
+    block (`attn_kind='gqa'`, models/gqa.py) goes by its LAYER's kind:
+    a full-attention layer writes and reads its own entry through the
+    table (scope `attn/global`; the XLA gather, in query tiles), a
+    window layer the ring of each row's slot (`slots` [B], the engine
+    slot a row belongs to, -1 for a parked row; scope `attn/window`: the masked dense form
+    over the ring's view, whose row c holds the position
+    `ring_positions` says), which the table does not know. Each expert
+    layer of an unstacked model appends its (assignments, experts hit)
+    counts to `stats` when a list is given.
     """
     import jax
 
     from ..models.decoding import (_attn_residual, _embed_tokens,
                                    _head_logits, _mlp_residual, _qkv_heads,
+                                   grouped_projections, grouped_residual,
                                    latent_projections, latent_residual)
-    from ..ops.paged_attention import (latent_paged_attention,
+    from ..ops.paged_attention import (_physical, grouped_table_view,
+                                       grouped_write, latent_paged_attention,
                                        latent_paged_write, paged_attention,
-                                       paged_write)
+                                       paged_write, ring_address, ring_view)
     from ..ops.paged_decode import (fused_latent_attention,
                                     fused_paged_attention)
 
@@ -656,7 +698,36 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
         x = _attn_residual(cfg, bp, x, attn)
         return _mlp_residual(cfg, bp, x, stats), entry
 
-    layer = latent_layer if cfg.attn_kind == "mla" else kv_layer
+    def grouped_layer(kind):
+        from ..models import gqa
+
+        def layer(bp, x, entry):
+            q, k, v = grouped_projections(cfg, kind, bp, x, positions)
+            if kind.window and slots is None:
+                raise ValueError("a window layer's ring is addressed by "
+                                 "slot: paged_apply_step(slots=)")
+            with jax.named_scope("kv_write"):
+                if kind.window:
+                    at = ring_address(slots, positions, entry["k"].shape[1])
+                else:
+                    at = _physical(table, positions, entry["k"].shape[1])
+                entry = grouped_write(entry, k, v, *at)
+            with jax.named_scope("attn"), jax.named_scope(kind.scope):
+                view = (ring_view(entry, slots, positions) if kind.window
+                        else grouped_table_view(entry, table))
+                out = gqa.attend(cfg, kind, bp["attn"], q, *view, positions)
+            x = grouped_residual(cfg, bp, x, out)
+            return _mlp_residual(cfg, bp, x, stats), entry
+
+        return layer
+
+    # one layer body for the stack, or one a layer kind
+    if cfg.attn_kind == "gqa":
+        from ..models.gqa import layer_kinds
+        layers = [grouped_layer(kind) for kind in layer_kinds(cfg)]
+    else:
+        layers = [latent_layer if cfg.attn_kind == "mla" else kv_layer
+                  ] * cfg.num_layers
     p = params["params"]
     x = _embed_tokens(p, tokens, cfg.dtype)
     if cfg.scan_layers:
@@ -664,7 +735,7 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
 
         def body(x, layer_in):
             bp, entry = layer_in
-            x, entry = layer(bp, x, entry)
+            x, entry = layers[0](bp, x, entry)
             return x, entry
 
         x, new_cache = jax.lax.scan(body, x, (stacked, cache))
@@ -672,7 +743,7 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
         new_cache = {}
         for i in range(cfg.num_layers):
             name = f"block_{i}"
-            x, new_cache[name] = layer(p[name], x, cache[name])
+            x, new_cache[name] = layers[i](p[name], x, cache[name])
 
     return _head_logits(p, x, cfg), new_cache
 
@@ -682,15 +753,15 @@ def copy_block_fn(cfg, kv_dtype: str) -> tp.Callable:
     block `src`'s rows duplicated onto block `dst` across every layer
     and leaf (K/V payloads and their scales, or a latent pool's `c` and
     `kr`). The block axis of a leaf follows the pool's spec
-    (`ops.paged_attention.cfg_pool_spec`): a layer-stacked leaf has it
-    one further in. One fixed-shape executable per engine — warmed with
+    (`ops.paged_attention.layer_pool_specs`): a layer-stacked leaf has it
+    one further in. A window layer's rings are no blocks of the pool and
+    pass through. One fixed-shape executable per engine — warmed with
     the decode/verify steps so a fork never compiles mid-traffic."""
     import jax.numpy as jnp
 
-    from ..ops.paged_attention import cfg_pool_spec
-    spec = cfg_pool_spec(cfg, 1, 1, kv_dtype)
+    from ..ops.paged_attention import cfg_pool_spec, layer_pool_specs
 
-    def copy_entry(entry, src, dst):
+    def copy_entry(entry, spec, src, dst):
         out = {}
         for name, leaf in entry.items():
             axis = leaf.ndim - len(spec[name][0])
@@ -700,10 +771,19 @@ def copy_block_fn(cfg, kv_dtype: str) -> tp.Callable:
         return out
 
     if cfg.scan_layers:
-        return copy_entry
+        spec = cfg_pool_spec(cfg, 1, 1, kv_dtype)
+        return lambda entry, src, dst: copy_entry(entry, spec, src, dst)
+    specs = layer_pool_specs(cfg, 1, 1, kv_dtype)
+    rings = [False] * cfg.num_layers
+    if cfg.attn_kind == "gqa":
+        from ..models.gqa import layer_kinds
+        rings = [bool(kind.window) for kind in layer_kinds(cfg)]
 
     def copy(cache, src, dst):
-        return {name: copy_entry(entry, src, dst)
-                for name, entry in cache.items()}
+        return {f"block_{i}": (
+                    cache[f"block_{i}"] if rings[i] else
+                    copy_entry(cache[f"block_{i}"], specs[i], src, dst))
+                for i in range(cfg.num_layers)}
 
     return copy
+

@@ -209,3 +209,24 @@ def test_cells_resolve_auto_to_the_fused_walk(cell, monkeypatch):
     assert paged_decode.fused_kernel_unsupported_reason(
         cfg, engine["block_size"]) is None
     assert paged_decode.default_kernel(cfg, engine["block_size"]) == "fused"
+
+
+def test_the_grouped_cell_resolves_auto_to_the_gather_on_a_tpu(monkeypatch):
+    # the window/full cell: no walk takes 4 | 8 KV heads of 192 | 128
+    # under 64 query heads yet; `auto` is the XLA gather wherever it runs
+    # and the reason names the shape
+    cfg, engine = _cell("mimo25-doc16k-closed")
+    assert engine["kernel"] == "auto"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    reason = paged_decode.fused_kernel_unsupported_reason(
+        cfg, engine["block_size"])
+    assert "4 | 8 KV heads of 192 | 128 under 64 query heads" in reason
+    assert paged_decode.default_kernel(cfg, engine["block_size"]) == "gather"
+    # its pool leaves are whole 128-lane rows as stored
+    from flashy_tpu.ops.paged_attention import layer_pool_specs, ring_blocks
+    ring = ring_blocks(cfg.window, engine["chunk"], engine["block_size"])
+    specs = layer_pool_specs(cfg, 9, engine["block_size"], "model",
+                             slots=engine["slots"], ring=ring)
+    assert [spec["k"][0] for spec in specs] == (
+        [(9, 16, 768)] + [(33, 640, 1536)] * 5 + [(9, 16, 768)])
+    assert [spec["v"][0][-1] for spec in specs] == [512] + [1024] * 5 + [512]
