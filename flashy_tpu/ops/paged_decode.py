@@ -33,10 +33,11 @@
 #    tile transposed to `[H, group * bs, Dh]`), its T rows split into
 #    query tiles when the score tile would outgrow VMEM. `walk_shape`
 #    picks group, query tile and layout from the shapes alone, under an
-#    explicit VMEM budget. That rule (and `latent_walk_shape` for a
-#    latent pool) is the one owner of tile choice: its constants below
-#    were fixed from a builder's sweeps on the chip (PERF.md section 6,
-#    PR 26 and PR 28), `head_block` is the caller's argument (how the
+#    explicit VMEM budget. That rule (and `latent_walk_shape` /
+#    `grouped_walk_shape` for a latent / grouped pool) is the one owner
+#    of tile choice: its constants below were fixed from a builder's
+#    sweeps on the chip (PERF.md section 6, PR 26, PR 28 and PR 32),
+#    `head_block` is the caller's argument (how the
 #    parity tests and a sweep script reach other tilings) else
 #    `_default_head_block`, and no environment variable, file or
 #    process-wide cache decides which kernel compiles.
@@ -95,6 +96,22 @@
 # walk: a latent pool whose blocks are not whole tiles is refused
 # (`fused_kernel_unsupported_reason`) and read by the XLA table gather.
 #
+# A GROUPED pool (models/gqa.py: a full-attention layer's row holds its
+# Hkv KV heads side by side, `k` [Hkv * Dk] and `v` [Hkv * Dv], keys
+# wider than values, fewer KV heads than query heads) takes it too
+# (`fused_grouped_attention`, kernel `grouped_decode_fused`): whole K and
+# V blocks copied as stored, the same steps and softmax. Query head h
+# reads KV head h // (H / Hkv), by the form the step's rows pick
+# (`head_parts`): at most 8 rows a slot lay the query heads
+# block-diagonally over the row's lanes and keep their own KV head's
+# values of every head's — no `[.., Hkv, Dk]` relayout exists; a slice
+# has the rows to fill the MXU and attends a KV head at a time, its
+# queries over the window of whole 128 lanes that holds the head's keys.
+# `kernel='fused'` on such a pool means that the full-attention layers
+# walk: a window layer's ring (ops/paged_attention.py: `ring_view`) is
+# no table's view and keeps the masked dense read. As for a latent pool,
+# blocks that are not whole tiles are refused and read by the gather.
+#
 # The gather implementations stay as the interpret-mode oracles (the
 # ops/attention.py convention: pallas interpret mode on CPU, XLA
 # gather as the reference): token-exactness tests drive both through
@@ -132,19 +149,34 @@ def fused_kernel_unsupported_reason(cfg: tp.Any = None,
     walk but the one whose copies the kernel issues itself, so its `c`
     and `kr` blocks must be whole (sublanes, 128) tiles: `block_size`,
     when the caller knows it, is held to the sublanes of `cfg.dtype`.
-    A grouped pool (`attn_kind='gqa'`) has no walk yet: `walk_shape`
-    and `_fused_call` take one head width for K and V and as many KV
-    heads as query heads.
+    A grouped pool (`attn_kind='gqa'`) likewise: 'fused' there means
+    that the FULL-attention layers walk their tables
+    (`fused_grouped_attention`; a window layer's ring keeps the masked
+    dense read either way), and the kernel copies their K `[block_size,
+    Hkv * Dk]` and V `[block_size, Hkv * Dv]` blocks as stored, so both
+    rows must be whole lanes.
     """
     if getattr(cfg, "attn_kind", "mha") == "gqa":
         from ..models import gqa
-        heads = sorted({kind.kv_heads for kind in gqa.layer_kinds(cfg)})
-        return (f"the fused kernel walks K and V blocks of one head width "
-                f"with as many KV heads as query heads, and this pool has "
-                f"{' | '.join(map(str, heads))} KV heads of "
-                f"{gqa.key_dim(cfg)} | {gqa.value_dim(cfg)} under "
-                f"{cfg.num_heads} query heads; the XLA table gather reads "
-                f"it (its window layers' rings by the masked dense form)")
+        sublanes = SUBLANES * 4 // jnp.dtype(cfg.dtype).itemsize
+        dk, dv = gqa.key_dim(cfg), gqa.value_dim(cfg)
+        full = sorted({kind.kv_heads for kind in gqa.layer_kinds(cfg)
+                       if not kind.window})
+        if not full:
+            return ("the fused kernel walks a grouped pool's full-attention "
+                    "layers through their block tables and this one has "
+                    "only window layers, whose rings the masked dense form "
+                    "reads")
+        if (any(kv * dk % LANES or kv * dv % LANES for kv in full)
+                or (block_size or 0) % sublanes):
+            return (f"the fused kernel copies whole ({sublanes}, {LANES}) "
+                    f"tiles of a grouped pool's full-attention blocks and "
+                    f"this one's are [{block_size or 'block_size'}, "
+                    f"{' | '.join(map(str, full))} KV heads of {dk} | {dv}] "
+                    f"under {cfg.num_heads} query heads (a row's keys and "
+                    f"its values must each be a multiple of {LANES} wide, "
+                    f"block_size of {sublanes}); the XLA table gather reads "
+                    f"any grouped pool")
     if getattr(cfg, "attn_kind", "mha") == "mla":
         sublanes = SUBLANES * 4 // jnp.dtype(cfg.dtype).itemsize
         if cfg.kv_lora_rank % LANES or (block_size or 0) % sublanes:
@@ -165,8 +197,9 @@ def default_kernel(cfg: tp.Any = None,
                    block_size: tp.Optional[int] = None) -> str:
     """The engine's `kernel='auto'` resolution: 'fused' on TPU (or TPU
     PJRT plugins under other names) for every pool the kernel can walk
-    — K/V pools, and latent pools whose blocks are whole tiles —
-    'gather' on cpu/gpu and for the rest. CPU runs opt in to the fused
+    — K/V pools, and latent pools and grouped pools (their
+    full-attention layers) whose blocks are whole tiles — 'gather' on
+    cpu/gpu and for the rest. CPU runs opt in to the fused
     kernel explicitly (interpret mode), the way the demo and the parity
     tests do."""
     if fused_kernel_unsupported_reason(cfg, block_size) is not None \
@@ -814,6 +847,50 @@ def latent_call_walk(queries: int, heads: int, entry: tp.Dict, *,
                              itemsize=jnp.dtype(c.dtype).itemsize)
 
 
+def _walk_copies(table_ref, slot, live, group: int, pools, sems, window):
+    """(start_copies(step, half), wait_copies(half)) of a walk whose
+    kernel copies whole pool blocks itself: `pools` are (HBM array,
+    double-buffered VMEM tile, row of the DMA semaphores `sems`)
+    triples, and `window(dst, half, g)` is where block g of a group
+    lands."""
+    def start_copies(step, half):
+        for g in range(group):
+            # as in `_dma_walk_body`: a partial last group re-reads the
+            # last live block, never an entry past the live range
+            block = table_ref[slot, jnp.minimum(step * group + g, live - 1)]
+            for src, dst, sem in pools:
+                pltpu.make_async_copy(src.at[block], window(dst, half, g),
+                                      sems.at[sem, half]).start()
+
+    def wait_copies(half):
+        # one wait an array: a DMA semaphore counts bytes, so a copy
+        # the size of the whole half — never started, the half lends it
+        # both shapes — waits for the group's (decode: 1.06 -> 0.97 ms
+        # a layer; PERF.md, PR 28)
+        for _, dst, sem in pools:
+            pltpu.make_async_copy(dst.at[half], dst.at[half],
+                                  sems.at[sem, half]).wait()
+
+    return start_copies, wait_copies
+
+
+def _softmax_step(scores, values, m_ref, l_ref, acc_ref):
+    """One online-softmax step of a walk whose every query sees key 0
+    (no guard): masked float32 `scores` [rows, keys] against `values`
+    [keys, width] into the running max, normaliser and accumulator."""
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    probs = jnp.exp(scores - m_new)
+    l_new = l_ref[:, :1] * alpha + probs.sum(axis=-1, keepdims=True)
+    # P cast to the pool's dtype for the MXU, f32 accumulation
+    pv = jnp.dot(probs.astype(values.dtype), values,
+                 preferred_element_type=jnp.float32)
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
 def _latent_walk_body(table_ref, base_ref, ql_ref, qr_ref, c_hbm, kr_hbm,
                       o_ref, c_buf, kr_buf, sems, m_scr, l_scr, acc_scr, *,
                       block_size: int, group: int, entries: int,
@@ -836,25 +913,10 @@ def _latent_walk_body(table_ref, base_ref, ql_ref, qr_ref, c_hbm, kr_hbm,
                         entries, jnp)
     steps = (live + group - 1) // group
 
-    pools = ((c_hbm, c_buf, 0), (kr_hbm, kr_buf, 1))
-
-    def start_copies(step, half):
-        for g in range(group):
-            # as in `_dma_walk_body`: a partial last group re-reads the
-            # last live block, never an entry past the live range
-            block = table_ref[slot, jnp.minimum(step * group + g, live - 1)]
-            for src, dst, sem in pools:
-                pltpu.make_async_copy(src.at[block], dst.at[half, g],
-                                      sems.at[sem, half]).start()
-
-    def wait_copies(half):
-        # one wait an array: a DMA semaphore counts bytes, so a copy
-        # the size of the whole half — never started, the half lends it
-        # both shapes — waits for the group's (decode: 1.06 -> 0.97 ms
-        # a layer; PERF.md, PR 28)
-        for _, dst, sem in pools:
-            pltpu.make_async_copy(dst.at[half], dst.at[half],
-                                  sems.at[sem, half]).wait()
+    start_copies, wait_copies = _walk_copies(
+        table_ref, slot, live, group,
+        ((c_hbm, c_buf, 0), (kr_hbm, kr_buf, 1)), sems,
+        lambda dst, half, g: dst.at[half, g])
 
     _init_state(m_scr, l_scr, acc_scr)
     start_copies(0, 0)
@@ -884,17 +946,7 @@ def _latent_walk_body(table_ref, base_ref, ql_ref, qr_ref, c_hbm, kr_hbm,
                                         preferred_element_type=jnp.float32))
         scores = jnp.where(ahead <= first - step * keys, scores * scale,
                            NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)
-        l_new = l_scr[:, :1] * alpha + probs.sum(axis=-1, keepdims=True)
-        # P cast to the pool's dtype for the MXU, f32 accumulation
-        pv = jnp.dot(probs.astype(c.dtype), c,
-                     preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _softmax_step(scores, c, m_scr, l_scr, acc_scr)
         return carry
 
     jax.lax.fori_loop(0, steps, attend, 0)
@@ -981,3 +1033,317 @@ def fused_latent_attention(cfg, q_lat: jax.Array, q_rope: jax.Array,
                        table, base.astype(jnp.int32), walk,
                        scale=float(softmax_scale(cfg)), interpret=interpret)
     return out.astype(cfg.dtype)
+
+
+# ----------------------------------------------------------------------
+# the same walk over a grouped pool (models/gqa.py's full-attention
+# layers: a row's KV heads side by side, keys wider than values)
+# ----------------------------------------------------------------------
+GROUPED_ROWS = 1024  # (query, head) rows of one part a grid step keeps
+GROUPED_KEYS = 1024  # keys a compute step attends
+GROUPED_VMEM_LIMIT = 48 * 2 ** 20  # stated, as LATENT_VMEM_LIMIT is
+
+
+class HeadParts(tp.NamedTuple):
+    """How a grouped read lays its query heads over a pool row's lanes:
+    `len(k_starts)` parts of `heads` consecutive query heads each, a
+    part's queries laid over the `k_width` K lanes from its `k_starts`
+    and taking the values of the `v_width` V lanes from its `v_starts`
+    — windows of whole 128 lanes, so no copy or slice in the kernel is
+    narrower than the lanes."""
+    heads: int
+    k_width: int
+    v_width: int
+    k_starts: tp.Tuple[int, ...]
+    v_starts: tp.Tuple[int, ...]
+
+
+def head_parts(heads: int, kv_heads: int, dk: int, dv: int,
+               flat: bool) -> HeadParts:
+    """Query head h reads KV head `h // (heads / kv_heads)`, by one of
+    two forms (`models/gqa.py:FLAT_QUERY_ROWS` says which). Flat — few
+    rows, decode and verify: ONE part, every query head laid
+    block-diagonally over the row's `kv_heads * dk` lanes, taking every
+    KV head's values and keeping its own; the `kv_heads`-fold surplus of
+    products is nothing beside the bytes. Split — a slice, which has
+    the rows to fill the MXU: a part a KV head, its queries over the
+    narrowest window of whole lanes that holds the head's keys (4 heads
+    of 192: lanes 0-255, 128-383, 384-639, 512-767, the 64 lanes that
+    are a neighbour's met by zeros), and its own values' lanes when
+    those are whole (else the row's, kept as in the flat form)."""
+    if flat:
+        return HeadParts(heads, kv_heads * dk, kv_heads * dv, (0,), (0,))
+    first = [kv * dk // LANES * LANES for kv in range(kv_heads)]
+    width = max(-(-((kv + 1) * dk - start) // LANES) * LANES
+                for kv, start in enumerate(first))
+    k_starts = tuple(min(start, kv_heads * dk - width) for start in first)
+    if dv % LANES:
+        return HeadParts(heads // kv_heads, width, kv_heads * dv, k_starts,
+                         (0,) * kv_heads)
+    return HeadParts(heads // kv_heads, width, dv, k_starts,
+                     tuple(kv * dv for kv in range(kv_heads)))
+
+
+def _grouped_vmem_estimate(queries: int, parts: HeadParts, k_lanes: int,
+                           v_lanes: int, block_size: int, group: int,
+                           itemsize: int) -> int:
+    """`_vmem_estimate` for `_grouped_walk_body`, from its own buffers:
+    the pipelined q and o blocks of every part, the softmax state, the
+    double-buffered K and V tiles, one part's score-shaped temporaries
+    (the mask's distances, scores, probs and their cast) and its value
+    product."""
+    count = len(parts.k_starts)
+    rows, keys = queries * parts.heads, group * block_size
+    total = 2 * count * rows * (parts.k_width + parts.v_width) * itemsize
+    total += count * rows * (2 * LANES + parts.v_width) * 4   # m, l, acc
+    total += 2 * keys * (k_lanes + v_lanes) * itemsize        # K, V x2
+    total += rows * keys * (3 * 4 + itemsize)                 # score-shaped
+    total += rows * parts.v_width * 4                         # probs . v
+    return total
+
+
+def grouped_walk_shape(queries: int, heads: int, kv_heads: int, dk: int,
+                       dv: int, block_size: int, entries: int, *,
+                       itemsize: int) -> Walk:
+    """The walk of one grouped read, from its shapes alone, as
+    `latent_walk_shape`: at most `FLAT_QUERY_ROWS` query rows a slot
+    take the flat form whole; a slice splits the heads and its T into
+    query tiles of at most `GROUPED_ROWS` rows a part — whole sublanes
+    of them, each tile walking its own causal prefix. Either attends
+    `GROUPED_KEYS` keys a step (the cost of a decode read is per step
+    and per copy, not per byte; a slice's step pays its state's update
+    once, whatever the keys). Then group and tile halve in turn until
+    `_grouped_vmem_estimate` fits `GROUPED_VMEM_LIMIT`. `head_block` is
+    all the heads, the copies the kernel's own."""
+    from ..models.gqa import FLAT_QUERY_ROWS
+    flat = queries <= FLAT_QUERY_ROWS
+    parts = head_parts(heads, kv_heads, dk, dv, flat)
+    sublanes = SUBLANES * 4 // itemsize
+
+    def largest(tile):
+        # a q block is whole sublanes of rows, or all the rows
+        return next((t for t in range(tile, 0, -1) if queries % t == 0
+                     and (t * parts.heads) % sublanes == 0), queries)
+
+    def fits(group, tile):
+        return _grouped_vmem_estimate(
+            tile, parts, kv_heads * dk, kv_heads * dv, block_size, group,
+            itemsize) <= GROUPED_VMEM_LIMIT
+
+    tile = queries if flat else largest(
+        max(1, min(queries, GROUPED_ROWS // parts.heads)))
+    group = max(1, min(GROUPED_KEYS // block_size, entries))
+    while not fits(group, tile):
+        if group > 1:
+            group //= 2
+        elif not flat and largest(tile // 2) < tile:
+            tile = largest(tile // 2)
+        else:
+            break  # the smallest walk there is; Mosaic has the last word
+    return Walk(group, heads, tile, flat, True)
+
+
+def grouped_call_walk(cfg, kind, queries: int, *, block_size: int,
+                      entries: int) -> Walk:
+    """The walk `fused_grouped_attention` takes for `queries` rows a slot
+    against the pool entry of a full-attention layer of kind `kind`
+    (the engine asks too, for its `kv_steps` counter): a grouped pool is
+    stored in `cfg.dtype`, its rows `kind.kv_heads` heads of
+    `gqa.key_dim` | `gqa.value_dim`."""
+    from ..models import gqa
+    return grouped_walk_shape(
+        queries, cfg.num_heads, kind.kv_heads, gqa.key_dim(cfg),
+        gqa.value_dim(cfg), block_size, entries,
+        itemsize=jnp.dtype(cfg.dtype).itemsize)
+
+
+def _grouped_walk_body(table_ref, base_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *,
+                       block_size: int, group: int, entries: int,
+                       parts: HeadParts, scale: float):
+    """One (slot, query-tile) grid step: the whole walk, `_latent_walk_body`
+    for a pool whose row holds every KV head's key `[Hkv * Dk]` and value
+    `[Hkv * Dv]`. Whole K and V blocks land in `[group * bs, lanes]`
+    tiles as stored; each of `parts` attends its window of the K tile's
+    lanes with its `[tq * heads, k_width]` queries (rows (t, h), laid
+    over the window by `_lay_queries`) in one 2-D dot, keeps its own
+    online-softmax state and multiplies its window of the V tile. One
+    mask, `key_pos <= q_pos`, for every part: it also hides sentinel
+    entries and the padding of a partial last group. Every query sees
+    key 0, so the softmax needs no guard."""
+    slot, qtile = pl.program_id(0), pl.program_id(1)
+    rows, keys = q_ref.shape[2], group * block_size
+    tq = rows // parts.heads
+
+    first = base_ref[slot] + qtile * tq        # this tile's first q pos
+    live = _live_blocks(base_ref[slot], first + tq - 1, block_size,
+                        entries, jnp)
+    steps = (live + group - 1) // group
+
+    start_copies, wait_copies = _walk_copies(
+        table_ref, slot, live, group,
+        ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)), sems,
+        lambda dst, half, g: dst.at[half, pl.ds(g * block_size, block_size)])
+
+    _init_state(m_scr, l_scr, acc_scr)
+    start_copies(0, 0)
+
+    # rows (t, h), columns (block, row-in-block): how far each pair is
+    # from the causal diagonal when the walk is at step 0
+    shape = (rows, keys)
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 0) // parts.heads)
+    nt = (((1,), (1,)), ((), ()))
+
+    def attend(step, carry):
+        half = step % 2
+
+        @pl.when(step + 1 < steps)
+        def _prefetch():
+            start_copies(step + 1, 1 - half)
+
+        wait_copies(half)
+        visible = ahead <= first - step * keys
+        for part, (k_start, v_start) in enumerate(zip(parts.k_starts,
+                                                      parts.v_starts)):
+            k = k_buf[half, :, k_start:k_start + parts.k_width]
+            v = v_buf[half, :, v_start:v_start + parts.v_width]
+            scores = jax.lax.dot_general(
+                q_ref[0, part], k, nt,
+                preferred_element_type=jnp.float32) * scale
+            _softmax_step(jnp.where(visible, scores, NEG_INF), v,
+                          m_scr.at[part], l_scr.at[part], acc_scr.at[part])
+        return carry
+
+    jax.lax.fori_loop(0, steps, attend, 0)
+    for part in range(len(parts.k_starts)):
+        o_ref[0, part] = (acc_scr[part] / l_scr[part, :, :1]
+                          ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("walk", "parts", "scale",
+                                             "interpret"))
+def _grouped_call(q, entry, table, base, walk: Walk, parts: HeadParts, *,
+                  scale: float, interpret: bool):
+    # jitted for the reason `_fused_call` is: the layers trace it once
+    batch, count, rows, _ = q.shape
+    k_lanes, v_lanes = entry["k"].shape[-1], entry["v"].shape[-1]
+    block_size = entry["k"].shape[-2]
+    keys = walk.group * block_size
+    tile = walk.query_tile * parts.heads
+    dtype = entry["k"].dtype
+
+    def spec(width):
+        return pl.BlockSpec((1, count, tile, width),
+                            lambda b, t, *_: (b, 0, t, 0))
+
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # the block table + the base positions
+        grid=(batch, rows // tile),
+        in_specs=[spec(parts.k_width), hbm, hbm],
+        out_specs=spec(parts.v_width),
+        scratch_shapes=[
+            pltpu.VMEM((2, keys, k_lanes), dtype),
+            pltpu.VMEM((2, keys, v_lanes), dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((count, tile, LANES), jnp.float32),  # running max
+            pltpu.VMEM((count, tile, LANES), jnp.float32),  # normalizer
+            pltpu.VMEM((count, tile, parts.v_width), jnp.float32)],  # acc
+    )
+    kernel = functools.partial(
+        _grouped_walk_body, block_size=block_size, group=walk.group,
+        entries=table.shape[1], parts=parts, scale=scale)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (batch, count, rows, parts.v_width), q.dtype,
+            vma=jax.typeof(q).vma),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=GROUPED_VMEM_LIMIT),
+        name="grouped_decode_fused",
+    )(table, base, q, entry["k"], entry["v"])
+
+
+def _lay_queries(q: jax.Array, parts: HeadParts, kv_heads: int) -> jax.Array:
+    """q [B, T, H, Dk] -> [B, parts, T * heads a part, k_width]: each
+    head's query on the lanes its KV head's keys hold within its part's
+    window, zeros beside; rows (t, h)."""
+    batch, queries, heads, dk = q.shape
+    group = heads // kv_heads
+    each = parts.heads // group  # KV heads a part covers
+    laid = []
+    for part, start in enumerate(parts.k_starts):
+        kvs = range(part * each, (part + 1) * each)
+        laid.append(jnp.concatenate([jnp.pad(
+            q[:, :, kv * group:(kv + 1) * group],
+            ((0, 0),) * 3 + ((kv * dk - start,
+                              parts.k_width - (kv + 1) * dk + start),))
+            for kv in kvs], axis=2))
+    return jnp.stack(laid, axis=1).reshape(
+        batch, len(laid), queries * parts.heads, parts.k_width)
+
+
+def _own_values(out: jax.Array, parts: HeadParts, queries: int,
+                kv_heads: int, dv: int) -> jax.Array:
+    """The kernel's [B, parts, T * heads a part, v_width] -> [B, T, H,
+    Dv]: a part whose window was the whole row took every KV head's
+    values for every query head; its own are kept."""
+    batch, count = out.shape[:2]
+    heads = count * parts.heads
+    out = out.reshape(batch, count, queries, parts.heads, -1)
+    out = jnp.moveaxis(out, 1, 2).reshape(batch, queries, heads, -1)
+    if parts.v_width == dv:  # each part took its own lanes
+        return out
+    own = (jnp.arange(heads)[:, None] // (heads // kv_heads)
+           == jnp.arange(kv_heads)[None, :])                  # [H, Hkv]
+    every = out.reshape(batch, queries, heads, kv_heads, -1)
+    return jnp.sum(jnp.where(own[None, None, :, :, None], every,
+                             jnp.zeros((), every.dtype)), axis=3)
+
+
+def fused_grouped_attention(cfg, kind, q: jax.Array, entry: tp.Dict,
+                            table: jax.Array, positions: jax.Array, *,
+                            interpret: tp.Optional[bool] = None
+                            ) -> jax.Array:
+    """`models.gqa.attend` over `ops.paged_attention.grouped_table_view`
+    for a FULL-attention layer of kind `kind`, one kernel: q [B, T, H,
+    Dk] (rotated) against one layer's grouped pool `entry` ({k: [N, bs,
+    Hkv * Dk], v: [N, bs, Hkv * Dv]}, this step's rows already written)
+    through `[B, max_blocks]` tables, causal by `positions` [B, T],
+    which must be CONSECUTIVE per row as for `fused_paged_attention`.
+    Neither the gathered `[B, L, Hkv * Dk]` view nor the `[B, H, T, L]`
+    scores exist: a slot's live blocks are copied once a query tile and
+    attended under an online softmax, the heads laid out by
+    `head_parts`. Precision is the gather read's: operands in the pool's
+    dtype, float32 scores (scaled by `Dk ** -0.5` after the product) and
+    softmax state, probabilities cast to the pool's dtype for the value
+    product, float32 accumulation, [B, T, H, Dv] in `cfg.dtype`; the
+    values are read as stored (scaled before they were cached). A window
+    layer's ring is not a table's view: its read is `gqa.attend`'s.
+    `interpret=None` resolves as `fused_paged_attention` does."""
+    from ..models import gqa
+    from .paged_attention import grouped_table_view
+    if kind.window or kind.sink:
+        raise ValueError(f"the grouped walk reads a full-attention layer "
+                         f"through its block table, got {kind}")
+    if interpret is None:
+        backend = jax.default_backend()
+        if backend in ("gpu", "cuda", "rocm"):
+            return gqa.attend(cfg, kind, {}, q,
+                              *grouped_table_view(entry, table), positions)
+        interpret = backend == "cpu"
+    queries, heads, dk = q.shape[1:]
+    dv = gqa.value_dim(cfg)
+    walk = grouped_call_walk(cfg, kind, queries,
+                             block_size=entry["k"].shape[-2],
+                             entries=table.shape[1])
+    parts = head_parts(heads, kind.kv_heads, dk, dv, walk.flat)
+    base = jax.lax.slice_in_dim(positions, 0, 1, axis=1)[:, 0]
+    out = _grouped_call(
+        _lay_queries(q.astype(entry["k"].dtype), parts, kind.kv_heads),
+        entry, table, base.astype(jnp.int32), walk, parts,
+        scale=float(dk ** -0.5), interpret=interpret)
+    return _own_values(out, parts, queries, kind.kv_heads,
+                       dv).astype(cfg.dtype)

@@ -247,10 +247,13 @@ class DecodeEngine:
             Pallas paged-decode kernel (ops/paged_decode.py — block
             iteration straight off the table, in-kernel int8 dequant
             under the FT203 scale fold, online softmax; a latent pool
-            through the same module's latent walk). 'auto' (the
-            default) resolves to 'fused' on TPU and 'gather'
-            elsewhere, and to 'gather' for a latent pool whose blocks
-            the kernel cannot copy (`ops.paged_decode.default_kernel`);
+            through the same module's latent walk, a grouped pool's
+            full-attention layers through its grouped walk — the
+            window layers' rings keep the masked dense read). 'auto'
+            (the default) resolves to 'fused' on TPU and 'gather'
+            elsewhere, and to 'gather' for a latent or grouped pool
+            whose blocks the kernel cannot copy
+            (`ops.paged_decode.default_kernel`);
             on CPU an explicit kernel='fused' runs in interpret mode
             (what the demo and the parity tests do).
         prefix_cache: enable cross-request prefix sharing (paged only).
@@ -1216,7 +1219,9 @@ class DecodeEngine:
         read is the fused kernel's: `kv_blocks`, the pool blocks it
         attends in one layer, and `kv_steps`, the compute steps it runs
         for them — their ratio is how many blocks a step carries
-        (ops/paged_decode.walk_counts). For a latent pool, whichever
+        (ops/paged_decode.walk_counts; a grouped pool's 'fused' is the
+        walk of its full-attention layers, so the layer counted is one
+        of those, by `grouped_call_walk`). For a latent pool, whichever
         read serves it: `kv_bytes`, the bytes as stored, over all
         layers, of the latent rows the live slots' queries attend
         (parked slots sit at max_seq_len). For a grouped pool likewise,
@@ -1238,8 +1243,8 @@ class DecodeEngine:
                 stats["kv_bytes"] += stats["kv_bytes_window"]
         if self.kernel != "fused":
             return stats
-        from ..ops.paged_decode import (call_walk, latent_call_walk,
-                                        walk_counts)
+        from ..ops.paged_decode import (call_walk, grouped_call_walk,
+                                        latent_call_walk, walk_counts)
         walk = self._kv_walks.get(queries)
         if walk is None:
             cfg, entries = self._cfg, self._pool.max_blocks
@@ -1252,6 +1257,12 @@ class DecodeEngine:
                     queries, cfg.num_heads,
                     {name: jax.ShapeDtypeStruct(*leaf)
                      for name, leaf in spec.items()}, entries=entries)
+            elif self._grouped:
+                from ..models.gqa import layer_kinds
+                walk = grouped_call_walk(
+                    cfg, next(kind for kind in layer_kinds(cfg)
+                              if not kind.window),  # those layers walk
+                    queries, block_size=self.block_size, entries=entries)
             else:
                 walk = call_walk(
                     queries, cfg.num_heads, cfg.head_dim,

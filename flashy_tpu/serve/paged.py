@@ -647,11 +647,14 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     kernel module (`fused_latent_attention`). A grouped-attention
     block (`attn_kind='gqa'`, models/gqa.py) goes by its LAYER's kind:
     a full-attention layer writes and reads its own entry through the
-    table (scope `attn/global`; the XLA gather, in query tiles), a
-    window layer the ring of each row's slot (`slots` [B], the engine
-    slot a row belongs to, -1 for a parked row; scope `attn/window`: the masked dense form
-    over the ring's view, whose row c holds the position
-    `ring_positions` says), which the table does not know. Each expert
+    table (scope `attn/global`: 'gather' is the XLA gather of the
+    table's view and `gqa.attend` in query tiles, 'fused' the grouped
+    walk of the kernel module, `fused_grouped_attention`), a window
+    layer the ring of each row's slot (`slots` [B], the engine slot a
+    row belongs to, -1 for a parked row; scope `attn/window`: the
+    masked dense form over the ring's view, whose row c holds the
+    position `ring_positions` says, whichever `kernel`), which the
+    table does not know. Each expert
     layer of an unstacked model appends its (assignments, experts hit)
     counts to `stats` when a list is given.
     """
@@ -665,7 +668,8 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
                                        grouped_write, latent_paged_attention,
                                        latent_paged_write, paged_attention,
                                        paged_write, ring_address, ring_view)
-    from ..ops.paged_decode import (fused_latent_attention,
+    from ..ops.paged_decode import (fused_grouped_attention,
+                                    fused_latent_attention,
                                     fused_paged_attention)
 
     if kernel not in ("gather", "fused"):
@@ -713,9 +717,15 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
                     at = _physical(table, positions, entry["k"].shape[1])
                 entry = grouped_write(entry, k, v, *at)
             with jax.named_scope("attn"), jax.named_scope(kind.scope):
-                view = (ring_view(entry, slots, positions) if kind.window
-                        else grouped_table_view(entry, table))
-                out = gqa.attend(cfg, kind, bp["attn"], q, *view, positions)
+                if kernel == "fused" and not kind.window:
+                    out = fused_grouped_attention(cfg, kind, q, entry,
+                                                  table, positions)
+                else:
+                    view = (ring_view(entry, slots, positions)
+                            if kind.window
+                            else grouped_table_view(entry, table))
+                    out = gqa.attend(cfg, kind, bp["attn"], q, *view,
+                                     positions)
             x = grouped_residual(cfg, bp, x, out)
             return _mlp_residual(cfg, bp, x, stats), entry
 

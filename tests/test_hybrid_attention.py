@@ -324,13 +324,23 @@ def test_refusals_name_their_reason():
                  block_size=4)
     with pytest.raises(ValueError, match="a head count a layer kind"):
         DecodeEngine(model, variables, kv_dtype="int8", **paged)
-    with pytest.raises(ValueError, match=r"2 \| 4 KV heads of 24 \| 16 "
+    # the walk of the full-attention layers copies whole tiles: the
+    # toy's rows (2 KV heads of 24 | 16: 48 | 32 lanes) are refused by
+    # their shape, and so is a pool of rings alone
+    with pytest.raises(ValueError, match=r"\[4, 2 KV heads of 24 \| 16\] "
                                          r"under 8 query heads"):
         DecodeEngine(model, variables, kernel="fused", **paged)
     from flashy_tpu.ops.paged_decode import (default_kernel,
                                              fused_kernel_unsupported_reason)
     assert default_kernel(cfg, 16) == "gather"
     assert "table gather" in fused_kernel_unsupported_reason(cfg, 16)
+    rings = dataclasses.replace(cfg, window_layers=(1,) * cfg.num_layers)
+    assert "only window layers" in fused_kernel_unsupported_reason(rings, 16)
+    whole = dataclasses.replace(cfg, qk_head_dim=64, v_head_dim=64,
+                                rotary_dim=16)
+    assert fused_kernel_unsupported_reason(whole, 8) is None
+    assert "[4, 2 KV heads of 64 | 64]" in fused_kernel_unsupported_reason(
+        whole, 4)
     # the hand-off is a list of block ids: it knows no ring
     donor = DecodeEngine(model, variables, **paged)
     with pytest.raises(ValueError, match="a block list does not hand over"):

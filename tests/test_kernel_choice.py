@@ -24,7 +24,7 @@ from tests.test_spans import pallas_calls
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 SERVING_CELLS = ("olmo1b-chat-closed", "olmo1b-fullctx-closed",
-                 "dotsvlm1-doc-closed")
+                 "dotsvlm1-doc-closed", "mimo25-doc16k-closed")
 
 
 # ----------------------------------------------------------------------
@@ -133,6 +133,19 @@ def _walk_and_vmem(cfg, engine, queries):
             walk.query_tile, cfg.num_heads, c.shape[-1], kr.shape[-1],
             block_size, walk.group, jnp.dtype(c.dtype).itemsize)
         return walk, need, paged_decode.LATENT_VMEM_LIMIT
+    if cfg.attn_kind == "gqa":
+        # a full-attention layer: those are the layers that walk
+        from flashy_tpu.models import gqa
+        kind = next(k for k in gqa.layer_kinds(cfg) if not k.window)
+        dk, dv = gqa.key_dim(cfg), gqa.value_dim(cfg)
+        walk = paged_decode.grouped_call_walk(
+            cfg, kind, queries, block_size=block_size, entries=entries)
+        need = paged_decode._grouped_vmem_estimate(
+            walk.query_tile, paged_decode.head_parts(
+                cfg.num_heads, kind.kv_heads, dk, dv, walk.flat),
+            kind.kv_heads * dk, kind.kv_heads * dv, block_size, walk.group,
+            jnp.dtype(cfg.dtype).itemsize)
+        return walk, need, paged_decode.GROUPED_VMEM_LIMIT
     quantized = engine["kv_dtype"] == "int8"
     itemsize = jnp.dtype(cfg.dtype).itemsize
     walk = paged_decode.call_walk(
@@ -151,7 +164,11 @@ def _walk_and_vmem(cfg, engine, queries):
 # of 128 against 8 blocks (PERF.md section 6, PR 26); the latent cell:
 # 128 rows x 1,024 keys a decode step, 2,048 rows x 512 keys a slice
 # step (PR 28). Steps: decode T=1, a whole slice T=`chunk`, the tail
-# slice T=4 (the engine's `tail_bucket`), speculative verify T=5.
+# slice T=4 (the engine's `tail_bucket`), speculative verify T=5. The
+# window/full cell (PR 32, the walk of its two full-attention layers):
+# 64 rows x 1,024 keys a decode step, block-diagonal over the row's 768
+# key lanes; a slice's 16 heads of a KV head x 64 positions = 1,024 rows
+# a part against 1,024 keys (PERF.md section 6, PR 32).
 OLMO_WALKS = {"decode": Walk(16, 16, 1, True, True),
               "slice": Walk(8, 16, 128, False, True),
               "tail": Walk(16, 16, 4, True, True),
@@ -163,6 +180,10 @@ RECORDED = {
                             "slice": Walk(32, 128, 16, True, True),
                             "tail": Walk(64, 128, 4, True, True),
                             "verify": Walk(64, 128, 5, True, True)},
+    "mimo25-doc16k-closed": {"decode": Walk(64, 64, 1, True, True),
+                             "slice": Walk(64, 64, 64, False, True),
+                             "tail": Walk(64, 64, 4, True, True),
+                             "verify": Walk(64, 64, 5, True, True)},
 }
 
 
@@ -211,17 +232,21 @@ def test_cells_resolve_auto_to_the_fused_walk(cell, monkeypatch):
     assert paged_decode.default_kernel(cfg, engine["block_size"]) == "fused"
 
 
-def test_the_grouped_cell_resolves_auto_to_the_gather_on_a_tpu(monkeypatch):
-    # the window/full cell: no walk takes 4 | 8 KV heads of 192 | 128
-    # under 64 query heads yet; `auto` is the XLA gather wherever it runs
-    # and the reason names the shape
+def test_the_grouped_cell_resolves_auto_to_the_walk_on_a_tpu(monkeypatch):
+    # the window/full cell: `auto` is the walk of its full-attention
+    # layers on a TPU (4 KV heads of 192 | 128 are 768 | 512 lanes, whole
+    # tiles in blocks of 16 bf16 rows), the gather on the CPU; the same
+    # config in blocks the kernel cannot copy is refused by its shape
     cfg, engine = _cell("mimo25-doc16k-closed")
     assert engine["kernel"] == "auto"
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    reason = paged_decode.fused_kernel_unsupported_reason(
-        cfg, engine["block_size"])
-    assert "4 | 8 KV heads of 192 | 128 under 64 query heads" in reason
     assert paged_decode.default_kernel(cfg, engine["block_size"]) == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged_decode.fused_kernel_unsupported_reason(
+        cfg, engine["block_size"]) is None
+    assert paged_decode.default_kernel(cfg, engine["block_size"]) == "fused"
+    reason = paged_decode.fused_kernel_unsupported_reason(cfg, 8)
+    assert "[8, 4 KV heads of 192 | 128] under 64 query heads" in reason
+    assert paged_decode.default_kernel(cfg, 8) == "gather"
     # its pool leaves are whole 128-lane rows as stored
     from flashy_tpu.ops.paged_attention import layer_pool_specs, ring_blocks
     ring = ring_blocks(cfg.window, engine["chunk"], engine["block_size"])

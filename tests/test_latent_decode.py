@@ -3,7 +3,9 @@
 # the XLA table gather it replaces on a TPU
 # (ops/paged_attention.py:latent_paged_attention, the oracle), then
 # through the engine, then compiled — not run — for the v5e at the
-# benchmark cell's widths. Every tolerance states its reason.
+# benchmark cell's widths (the grouped pool's walk too: one file holds
+# the tests that load the TPU's compiler). Every tolerance states its
+# reason.
 """The latent pool's fused read against the gather read."""
 import numpy as np
 import pytest
@@ -12,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.harness import model_dots
-from flashy_tpu.models import TransformerConfig, TransformerLM
+from flashy_tpu.models import TransformerConfig, TransformerLM, gqa
 from flashy_tpu.ops import paged_decode
 from flashy_tpu.ops.paged_attention import (latent_paged_attention,
                                             latent_pool_spec)
@@ -183,20 +185,12 @@ def test_engine_streams_agree_between_the_fused_and_the_gather_read():
         np.testing.assert_array_equal(fused, gather)
 
 
-def test_the_latent_kernel_carries_its_name_under_the_attn_scope():
-    # what the trace readers find it by: `pallas_call(name=)` and the
-    # `attn` named scope of the paged step, once a layer, in the decode
-    # and in the slice executable
-    engine = _toy_engine("fused")
-    step = engine._build_decode()
-    args = (engine._params, engine._cache, *engine._layout_args(),
-            engine._tokens, engine._positions, engine._active,
-            jnp.zeros((2,), jnp.uint32))
-    layers = engine._cfg.num_layers
+def kernel_stacks(step, *args):
+    """(kernel name, name stack) of every `pallas_call` of `step`'s
+    jaxpr; a nested jit's equations carry their stack from its call on."""
     stacks = []
 
     def walk(jaxpr, outer=""):
-        # a nested jit's equations carry their stack from its call on
         for eqn in jaxpr.eqns:
             stack = f"{outer}/{eqn.source_info.name_stack}"
             if eqn.primitive.name == "pallas_call":
@@ -209,6 +203,22 @@ def test_the_latent_kernel_carries_its_name_under_the_attn_scope():
                         walk(inner, stack)
 
     walk(jax.make_jaxpr(step)(*args).jaxpr)
+    return stacks
+
+
+def decode_args(engine):
+    return (engine._params, engine._cache, *engine._layout_args(),
+            engine._tokens, engine._positions, engine._active,
+            jnp.zeros((2,), jnp.uint32))
+
+
+def test_the_latent_kernel_carries_its_name_under_the_attn_scope():
+    # what the trace readers find it by: `pallas_call(name=)` and the
+    # `attn` named scope of the paged step, once a layer, in the decode
+    # and in the slice executable
+    engine = _toy_engine("fused")
+    stacks = kernel_stacks(engine._build_decode(), *decode_args(engine))
+    layers = engine._cfg.num_layers
     assert [name for name, _ in stacks] == ["latent_decode_fused"] * layers
     assert all("attn/" in stack for _, stack in stacks), stacks
 
@@ -250,3 +260,38 @@ def test_the_cells_latent_reads_compile_for_the_v5e(one_chip, slots,
         sds((slots, queries, 128, 128), jnp.bfloat16), entry,
         sds((slots, 288), jnp.int32), sds((slots,), jnp.int32)).compile()
     assert "latent_decode_fused" in compiled.as_text()
+
+
+@pytest.mark.parametrize("slots,queries", [(32, 1), (32, 5), (1, 4),
+                                           (1, 512)])
+def test_the_cells_grouped_reads_compile_for_the_v5e(one_chip, slots,
+                                                     queries):
+    # the same for the walk `grouped_walk_shape` picks at the
+    # window/full cell's widths (64 heads over 4 KV heads of 192 | 128,
+    # blocks of 16, 1,088 entries, bf16, the [32, 1088] table whole in
+    # scalar memory); here because one file holds the tests that load
+    # the TPU's compiler (tests/test_grouped_decode.py has the rest)
+    from flashy_tpu.ops.paged_attention import grouped_pool_spec
+    from tests.test_grouped_decode import cell_cfg
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = cell_cfg(jnp.bfloat16, 1088 * 16)
+    kind, = gqa.layer_kinds(cfg)
+    entry = {name: sds(shape, dt) for name, (shape, dt) in grouped_pool_spec(
+        32 * 1088 + 1, 16, 4, 192, 128, jnp.bfloat16).items()}
+    walk = paged_decode.grouped_call_walk(cfg, kind, queries, block_size=16,
+                                          entries=1088)
+    parts = paged_decode.head_parts(64, 4, 192, 128, walk.flat)
+
+    def read(q, entry, table, base):
+        return paged_decode._grouped_call(q, entry, table, base, walk, parts,
+                                          scale=0.07, interpret=False)
+
+    compiled = jax.jit(read).lower(
+        sds((slots, len(parts.k_starts), queries * parts.heads,
+             parts.k_width), jnp.bfloat16),
+        entry, sds((slots, 1088), jnp.int32),
+        sds((slots,), jnp.int32)).compile()
+    assert "grouped_decode_fused" in compiled.as_text()
