@@ -22,10 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.ssd_scan import ssd_chunked_scan, ssd_recurrent_scan
-from . import gqa, mla
+from . import gqa, mamba2, mla
 from .moe import expert_layer, gated_mlp
 from .transformer import (TransformerConfig, _rotary, expert_layers,
-                          mixer_pattern, rmsnorm as _rmsnorm)
+                          mixer_pattern, pattern_kinds, rmsnorm as _rmsnorm)
 from .quantize import (is_quantized, kernel_operand as _kernel,
                        postscale as _postscale)
 from .ssd import ssd_log_decay
@@ -55,21 +55,35 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> tp.Dict:
     construction) get single stacked [L, ...] arrays, the layer dim
     scanned together with the stacked parameters. Both layouts keep the
     slot (batch) dim at position -4 on every leaf, which is what the
-    serving engine's slot take/merge slicing relies on.
+    serving engine's slot take/merge slicing relies on. Under a
+    `layer_pattern` a layer's entry is its one mixer's: a Mamba-2
+    layer's {'state','conv'} (`mamba2.state_spec`, no max_len either),
+    an attention layer's grouped slabs, an expert layer's nothing.
     """
     shape = (batch, max_len, cfg.num_heads, cfg.head_dim)
     sshape = (batch, cfg.num_heads, cfg.head_dim, cfg.ssd_state_dim)
     pattern = mixer_pattern(cfg)
     expert_layers(cfg)  # refuses what the new kinds cannot combine with
-    if cfg.attn_kind == "gqa":
-        # every layer a slab of its own kind's heads, a window layer's
-        # whole too: the dense layout masks a window, it bounds nothing
-        return {f"block_{i}": {
-                    "k": jnp.zeros((batch, max_len, kind.kv_heads,
+    if cfg.attn_kind == "gqa" or cfg.layer_pattern:
+        # every attention layer a slab of its own kind's heads, a window
+        # layer's whole too: the dense layout masks a window, it bounds
+        # nothing; under a `layer_pattern` the other layers hold their
+        # own mixer's entry
+        kinds = gqa.layer_kinds(cfg) if cfg.attn_kind == "gqa" else ()
+        pattern = pattern_kinds(cfg) or "*" * cfg.num_layers
+
+        def entry(layer):
+            if pattern[layer] == "E":
+                return {}
+            if pattern[layer] == "M":
+                return {name: jnp.zeros(*leaf) for name, leaf in
+                        mamba2.state_spec(cfg, batch).items()}
+            return {"k": jnp.zeros((batch, max_len, kinds[layer].kv_heads,
                                     gqa.key_dim(cfg)), cfg.dtype),
-                    "v": jnp.zeros((batch, max_len, kind.kv_heads,
+                    "v": jnp.zeros((batch, max_len, kinds[layer].kv_heads,
                                     gqa.value_dim(cfg)), cfg.dtype)}
-                for i, kind in enumerate(gqa.layer_kinds(cfg))}
+
+        return {f"block_{i}": entry(i) for i in range(cfg.num_layers)}
     if cfg.attn_kind == "mla":
         attn = {"c": (batch, max_len, 1, cfg.kv_lora_rank),
                 "kr": (batch, max_len, 1, cfg.qk_rope_head_dim)}
@@ -318,13 +332,41 @@ def _cached_grouped_attention(cfg, kind: gqa.LayerKind, bp: tp.Dict,
     return grouped_residual(cfg, bp, x, heads_out), entry
 
 
+def mamba_residual(cfg, bp: tp.Dict, x: jax.Array, state: jax.Array,
+                   tail: jax.Array, *, rows: tp.Optional[jax.Array] = None,
+                   used: tp.Optional[jax.Array] = None):
+    """x + a Mamba-2 layer's mixer on its pre-norm, shared by the dense
+    and the paged step: (x, state, tail) of `mamba2.mixer`."""
+    with jax.named_scope("norm"):
+        normed = _norm(cfg, x, bp["norm1"]["scale"])
+    out, state, tail = mamba2.mixer(cfg, bp["ssm"], normed, state, tail,
+                                    rows=rows, used=used)
+    return x + out, state, tail
+
+
 def _layer_forward(cfg: TransformerConfig, bp: tp.Dict, x: jax.Array,
                    positions: jax.Array, entry: tp.Dict,
                    cache_index: jax.Array,
-                   stats: tp.Optional[tp.List] = None, layer: int = 0):
+                   stats: tp.Optional[tp.List] = None, layer: int = 0,
+                   token_mask: tp.Optional[jax.Array] = None):
     """One block against its cache entry ({'k','v'} slabs, or the
     latent {'c','kr'}): returns (x, entry). `layer` picks the layer's
-    kind where the config has one a layer (`attn_kind='gqa'`)."""
+    kind where the config has one a layer (`attn_kind='gqa'`, and a
+    `layer_pattern`, whose layer is its one mixer: the experts, the
+    attention alone, or the Mamba-2 mixer against its {'state','conv'}
+    entry, `token_mask` keeping a slice's pads out of both)."""
+    if cfg.layer_pattern:
+        kind = pattern_kinds(cfg)[layer]
+        if kind == "E":
+            return _mlp_residual(cfg, bp, x, stats), entry
+        if kind == "*":
+            return _cached_grouped_attention(
+                cfg, gqa.layer_kinds(cfg)[layer], bp, x, positions, entry,
+                cache_index)
+        used = None if token_mask is None else jnp.sum(token_mask, axis=1)
+        x, state, tail = mamba_residual(cfg, bp, x, entry["state"],
+                                        entry["conv"], used=used)
+        return x, {"state": state, "conv": tail}
     if cfg.attn_kind == "gqa":
         x, entry = _cached_grouped_attention(
             cfg, gqa.layer_kinds(cfg)[layer], bp, x, positions, entry,
@@ -431,7 +473,7 @@ def _apply_step(model, params, cfg: TransformerConfig, tokens: jax.Array,
             else:
                 x, new_cache[name] = _layer_forward(
                     cfg, p[name], x, positions, cache[name], cache_index,
-                    stats, layer)
+                    stats, layer, token_mask)
 
     return _head_logits(p, x, cfg), new_cache
 

@@ -91,7 +91,11 @@ def has_window(cfg) -> bool:
 def _rotary(cfg, kind: LayerKind, x: jax.Array, positions: jax.Array
             ) -> jax.Array:
     """The first `rotary_dim` dimensions of every head of x [B,T,H,D]
-    rotated at the layer kind's base; the rest pass."""
+    rotated at the layer kind's base; the rest pass. `rotary_dim` 0 is
+    the whole head; a config with `rope=False` rotates nothing (its
+    attention has no position embedding)."""
+    if not cfg.rope:
+        return x
     width = cfg.rotary_dim or x.shape[-1]
     index = np.arange(width // 2, dtype=np.float64)
     inv_freq = (kind.theta ** (-2.0 * index / width)).astype(np.float32)

@@ -318,13 +318,13 @@ def sigmoid_group_route(logits: jax.Array, bias: jax.Array, *, top_k: int,
 
 
 def _tile(size: int, most: int) -> int:
-    """The largest power-of-two tile up to `most` that divides `size`
-    (the whole dimension when none of at least 128 does)."""
-    tile = most
-    while tile >= 128:
+    """The largest tile of whole 128-lane columns up to `most` that
+    divides `size` (the whole dimension when none does): 1,024 for the
+    widths that are powers of two times a small odd number, 896 for an
+    expert 2,688 = 21 x 128 wide, where a power of two leaves 128."""
+    for tile in range(most - most % 128, 0, -128):
         if size % tile == 0:
             return tile
-        tile //= 2
     return size
 
 
@@ -360,18 +360,20 @@ def _grouped_matmul(rows: jax.Array, w: tp.Any, group_sizes: jax.Array,
 
 def routed_experts(x_flat: jax.Array, ids: jax.Array, gates: jax.Array,
                    w_up: tp.Any, w_down: tp.Any, *, first: int,
-                   gated: bool, dtype
+                   act: str, dtype
                    ) -> tp.Tuple[jax.Array, tp.Tuple[jax.Array, jax.Array]]:
     """sum_k gate_k expert_k(x) over the experts held here.
 
     `ids`, `gates` [N, k] are the router's picks over ALL experts;
     `w_up` [count, D, F or 2F] and `w_down` [count, F, D] are experts
-    `first .. first + count - 1`. Assignments sort by expert (those to
-    experts held elsewhere last, outside every group), run the two
-    grouped products and return to their tokens. `gated`: the up
-    product is [gate | value] and the hidden silu(gate) * value;
-    otherwise gelu(up). Returns (y [N, D] f32, (assignments that landed
-    here, held experts that got at least one))."""
+    `first .. first + count - 1` (D the width of `x_flat`: the hidden
+    state, or the latent the experts live in). Assignments sort by
+    expert (those to experts held elsewhere last, outside every group),
+    run the two grouped products and return to their tokens. `act`:
+    'silu' = the up product is [gate | value] and the hidden
+    silu(gate) * value; 'gelu' and 'relu2' are not gated: gelu(up),
+    relu(up)^2. Returns (y [N, D] f32, (assignments that landed here,
+    held experts that got at least one))."""
     tokens, top_k = ids.shape
     count = (w_up["q"] if is_quantized(w_up) else w_up).shape[0]
     local = ids.reshape(-1) - first
@@ -384,11 +386,16 @@ def routed_experts(x_flat: jax.Array, ids: jax.Array, gates: jax.Array,
     row_group = jnp.minimum(key[order], count - 1)
     rows = x_flat[order // top_k].astype(dtype)
     up = _grouped_matmul(rows, w_up, group_sizes, row_group, dtype)
-    if gated:
+    if act == "silu":
         gate, value = jnp.split(up, 2, axis=-1)
         hidden = jax.nn.silu(gate) * value
-    else:
+    elif act == "relu2":
+        hidden = jnp.square(jax.nn.relu(up))
+    elif act == "gelu":
         hidden = jax.nn.gelu(up)
+    else:
+        raise ValueError(f"an expert's activation is 'silu' (gated), "
+                         f"'relu2' or 'gelu', got {act!r}")
     y = _grouped_matmul(hidden.astype(dtype), w_down, group_sizes,
                         row_group, dtype)
     y = jnp.where((jnp.arange(y.shape[0]) < landed)[:, None], y, 0.0)
@@ -402,9 +409,14 @@ def expert_layer(cfg, mp: tp.Dict, x: jax.Array
     """The block's expert layer on pre-normed x [B, T, D]: router,
     routed experts held here, shared expert. `mp` is MoEMLP's tree
     (`cfg.moe_experts`: softmax router, gelu experts, all held) or
-    ExpertMLP's (`cfg.n_routed`: sigmoid group-limited router, gated
-    experts `cfg.held_experts`, `cfg.n_shared` shared). Returns
-    (y [B, T, D] in cfg.dtype, (assignments, experts hit))."""
+    ExpertMLP's (`cfg.n_routed`: sigmoid group-limited router, experts
+    `cfg.held_experts` of activation `cfg.expert_act`, `cfg.n_shared`
+    shared). With `latent_down` / `latent_up` leaves (`cfg.
+    expert_latent`) the routed experts live in a latent: ONE
+    down-projection before the dispatch, one up-projection after the
+    weighted sum of this chip's experts; the router and the shared
+    expert read the full hidden state. Returns (y [B, T, D] in
+    cfg.dtype, (assignments, experts hit))."""
     batch, seq, dim = x.shape
     x_flat = x.reshape(batch * seq, dim)
     with jax.named_scope("router"):
@@ -417,15 +429,26 @@ def expert_layer(cfg, mp: tp.Dict, x: jax.Array
                 scale=cfg.expert_scale)
         else:
             ids, gates = softmax_route(logits, cfg.moe_top_k)
+    act = cfg.expert_act if cfg.n_routed > 0 else "gelu"
+    rows = x_flat
+    if "latent_down" in mp:
+        with jax.named_scope("latent_down"):
+            rows = jnp.dot(x_flat.astype(cfg.dtype),
+                           mp["latent_down"]["kernel"].astype(cfg.dtype))
     with jax.named_scope("experts"):
         out, stats = routed_experts(
-            x_flat, ids, gates, mp["w_up"], mp["w_down"],
+            rows, ids, gates, mp["w_up"], mp["w_down"],
             first=cfg.held_experts[0] if cfg.n_routed > 0 else 0,
-            gated=cfg.n_routed > 0, dtype=cfg.dtype)
+            act=act, dtype=cfg.dtype)
+    if "latent_up" in mp:
+        with jax.named_scope("latent_up"):
+            out = jnp.dot(out.astype(cfg.dtype),
+                          mp["latent_up"]["kernel"].astype(cfg.dtype))
     out = out.reshape(batch, seq, dim).astype(cfg.dtype)
     if "shared" in mp:
         with jax.named_scope("shared_expert"):
-            out = out + gated_mlp(mp["shared"], x, cfg.dtype)
+            shared = relu2_mlp if act == "relu2" else gated_mlp
+            out = out + shared(mp["shared"], x, cfg.dtype)
     return out, stats
 
 
@@ -442,6 +465,15 @@ def gated_mlp(mp: tp.Dict, normed: jax.Array, dtype) -> jax.Array:
         down_s)
 
 
+def relu2_mlp(mp: tp.Dict, normed: jax.Array, dtype) -> jax.Array:
+    """down(relu(up x)^2), not gated, from the raw `up` [D, F] and
+    `down` [F, D] kernels: the shared expert of an `expert_act='relu2'`
+    config."""
+    up = jnp.einsum("btd,df->btf", normed, mp["up"]["kernel"].astype(dtype))
+    return jnp.einsum("btf,fd->btd", jnp.square(jax.nn.relu(up)),
+                      mp["down"]["kernel"].astype(dtype))
+
+
 class Leaf(nn.Module):
     """One named parameter leaf, `<module name>/<leaf>`, for modules
     whose arithmetic is a function over the raw tree."""
@@ -453,13 +485,14 @@ class Leaf(nn.Module):
 
 class GatedLeaves(nn.Module):
     """The `up` [D, 2F] (gate | value) and `down` [F, D] kernels of a
-    gated MLP as a raw tree (`gated_mlp` reads it)."""
+    gated MLP as a raw tree (`gated_mlp` reads it); `up` [D, F] where
+    it is not gated (`relu2_mlp`)."""
 
     @nn.compact
-    def __call__(self, dim: int, hidden: int, dtype):
+    def __call__(self, dim: int, hidden: int, dtype, gated: bool = True):
         dense = nn.initializers.lecun_normal()
         return {"up": {"kernel": Leaf(name="up")(
-                    "kernel", dense, (dim, 2 * hidden), dtype)},
+                    "kernel", dense, (dim, (1 + gated) * hidden), dtype)},
                 "down": {"kernel": Leaf(name="down")(
                     "kernel", dense, (hidden, dim), dtype)}}
 
@@ -468,8 +501,11 @@ class ExpertMLP(nn.Module):
     """The expert layer of a `n_routed > 0` config as a Flax module: it
     declares the parameters (`router/kernel` [D, n_routed],
     `router_bias` [n_routed], `w_up` [count, D, 2F], `w_down`
-    [count, F, D], `shared/{up,down}/kernel`) and calls `expert_layer`,
-    the same function the decode and paged steps call."""
+    [count, F, D], `shared/{up,down}/kernel`; with `expert_latent` L
+    the experts' D is L and `latent_down/kernel` [D, L],
+    `latent_up/kernel` [L, D] stand around them; `expert_act='relu2'`
+    halves every `up`) and calls `expert_layer`, the same function the
+    decode and paged steps call."""
 
     config: tp.Any
 
@@ -479,6 +515,8 @@ class ExpertMLP(nn.Module):
         first, count = cfg.held_experts
         count = count or cfg.n_routed  # count 0: every expert is held
         width, pd = cfg.expert_hidden, cfg.param_dtype
+        gated = cfg.expert_act == "silu"
+        inner = cfg.expert_latent or cfg.dim  # what an expert reads
         if not (0 <= first and first + count <= cfg.n_routed
                 and cfg.n_routed % cfg.expert_groups == 0):
             raise ValueError(
@@ -497,11 +535,17 @@ class ExpertMLP(nn.Module):
                 "router_bias", nn.initializers.normal(0.01),
                 (cfg.n_routed,), jnp.float32),
             "w_up": self.param("w_up", per_expert,
-                               (count, cfg.dim, 2 * width), pd),
+                               (count, inner, (1 + gated) * width), pd),
             "w_down": self.param("w_down", per_expert,
-                                 (count, width, cfg.dim), pd),
+                                 (count, width, inner), pd),
         }
+        if cfg.expert_latent:
+            mp["latent_down"] = {"kernel": Leaf(name="latent_down")(
+                "kernel", dense, (cfg.dim, inner), pd)}
+            mp["latent_up"] = {"kernel": Leaf(name="latent_up")(
+                "kernel", dense, (inner, cfg.dim), pd)}
         if cfg.n_shared:
             mp["shared"] = GatedLeaves(name="shared")(
-                cfg.dim, width * cfg.n_shared, pd)
+                cfg.dim, cfg.shared_hidden or width * cfg.n_shared, pd,
+                gated)
         return expert_layer(cfg, mp, x)[0]

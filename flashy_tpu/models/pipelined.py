@@ -24,6 +24,10 @@ def _chunked_stage(model: TransformerLM, variables: tp.Mapping,
     """Mesh-independent stage plumbing: the per-chunk stage function and
     the [num_chunks, layers_per_chunk, ...] stacked block params."""
     cfg = model.config
+    if cfg.layer_pattern:
+        raise ValueError("pipelined_apply stacks one block body: the layers "
+                         "of a layer_pattern differ in kind and parameters "
+                         "and are not pipelined")
     if not cfg.scan_layers:
         raise ValueError("pipelined_apply needs TransformerConfig.scan_layers=True")
     if cfg.attn_kind != "mha" or cfg.n_routed > 0 or not cfg.tie_head:

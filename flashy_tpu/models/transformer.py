@@ -70,6 +70,21 @@ class TransformerConfig:
                                  # largest divisor (ops.ssd_scan)
     ssd_kernel: str = "auto"     # 'auto' | 'gather' | 'fused' — the
                                  # ops.ssd_scan chunked-kernel seam
+    layer_pattern: str = ""      # one residual mixer a layer, a kind a
+                                 # character (x <- x + mixer(norm(x)),
+                                 # nothing else in the layer): 'M' a
+                                 # Mamba-2 mixer (models/mamba2.py, the
+                                 # ssm_ keys below), 'E' the `n_routed`
+                                 # expert layer with no attention before
+                                 # it, '*' attention (`attn_kind='gqa'`)
+                                 # with no MLP after it. '' = every layer
+                                 # a mixer and an MLP, as above
+    ssm_heads: int = 0           # 'M': heads H, each `ssm_head_dim` P
+    ssm_head_dim: int = 0        # wide, a state [H, P, ssd_state_dim]
+                                 # float32 a sequence (`ssd_chunk` /
+                                 # `ssd_kernel`: the chunked form's)
+    ssm_groups: int = 1          # 'M': groups sharing one B and one C
+    ssm_conv: int = 4            # 'M': taps of the causal depthwise conv
     # What a published config states beyond the block above. None of it
     # is tunable, and the defaults are the model this file always built
     # (full multi-head attention, base-10000 rotary over the whole head,
@@ -90,6 +105,9 @@ class TransformerConfig:
     qk_head_dim: int = 0         # gqa: key/query width a head
     rotary_dim: int = 0          # gqa: leading dimensions of a head that
                                  # rotate (0 = the whole head)
+    rope: bool = True            # gqa: False = no position embedding in
+                                 # the attention at all (position comes
+                                 # through other layers)
     num_kv_heads: int = 0        # gqa: KV heads of a full layer
     window_layers: tp.Tuple[int, ...] = ()  # gqa: per layer 1 = window
                                  # attention, 0 = full; () = all full
@@ -126,6 +144,17 @@ class TransformerConfig:
     expert_scale: float = 1.0    # routed_scaling_factor
     n_shared: int = 0            # shared experts (one MLP of that width)
     expert_hidden: int = 0       # width of one expert
+    expert_act: str = "silu"     # 'silu': gated, silu(gate) * value;
+                                 # 'relu2': NOT gated, relu(up)^2 (the
+                                 # shared expert alike)
+    expert_latent: int = 0       # > 0: the routed experts live in a
+                                 # latent this wide: one down-projection
+                                 # shared by them before the dispatch,
+                                 # one up-projection after the weighted
+                                 # sum; the router and the shared expert
+                                 # see the full hidden state
+    shared_hidden: int = 0       # the shared expert's width (0 =
+                                 # expert_hidden * n_shared)
     tie_head: bool = True        # False: an output table `head` [V, D]
     param_dtype: tp.Any = jnp.float32  # dtype of the matrix leaves
                                  # (norm scales and the router's bias
@@ -156,10 +185,46 @@ def mixer_pattern(cfg: "TransformerConfig") -> tp.Tuple[str, ...]:
     return tuple(names[i % len(names)] for i in range(cfg.num_layers))
 
 
+def pattern_kinds(cfg: "TransformerConfig") -> tp.Tuple[str, ...]:
+    """`cfg.layer_pattern` as one kind a layer ('M' | 'E' | '*'), or ()
+    for a config whose layers are all a mixer and an MLP. Refuses what
+    a layer of one mixer cannot be combined with."""
+    pattern = tuple(cfg.layer_pattern)
+    if not pattern:
+        return ()
+    if len(pattern) != cfg.num_layers or set(pattern) - set("ME*"):
+        raise ValueError(
+            f"config.layer_pattern names each of the {cfg.num_layers} "
+            f"layers 'M' (Mamba-2), 'E' (experts) or '*' (attention); got "
+            f"{cfg.layer_pattern!r}")
+    if cfg.scan_layers or cfg.mixer != "attention" or cfg.moe_experts > 0:
+        raise ValueError(
+            "a layer_pattern's layers differ in kind and parameters: "
+            "scan_layers stacks one body, `mixer` cycles blocks of a "
+            "mixer and an MLP, and moe_experts is such a block's MLP "
+            "(scan_layers=False, mixer='attention', moe_experts=0)")
+    if cfg.attention != "dense":
+        raise ValueError(
+            f"attention={cfg.attention!r} is a kernel of blocks that are "
+            f"all attention: a config with a layer_pattern runs "
+            f"attention='dense'")
+    if "*" in pattern and cfg.attn_kind != "gqa":
+        raise ValueError("a layer_pattern's '*' layers are grouped "
+                         "attention: attn_kind='gqa'")
+    if "E" in pattern and cfg.n_routed <= 0:
+        raise ValueError("a layer_pattern's 'E' layers are the n_routed "
+                         "expert layer: state n_routed and its keys")
+    if "M" in pattern:
+        from . import mamba2
+        mamba2.check(cfg)
+    return pattern
+
+
 def expert_layers(cfg: "TransformerConfig") -> tp.Tuple[bool, ...]:
     """Per layer: True where the block's MLP is the `n_routed` expert
-    layer (every layer after the `dense_layers` leading ones). Checks
-    what the new kinds cannot be combined with."""
+    layer (every layer after the `dense_layers` leading ones; the 'E'
+    layers of a `layer_pattern`). Checks what the new kinds cannot be
+    combined with."""
     if cfg.attn_kind not in ("mha", "mla", "gqa"):
         raise ValueError(f"config.attn_kind must be 'mha', 'mla' or 'gqa', "
                          f"got {cfg.attn_kind!r}")
@@ -173,6 +238,9 @@ def expert_layers(cfg: "TransformerConfig") -> tp.Tuple[bool, ...]:
                          "attention, grouped attention by layer kind, "
                          "n_routed expert layers and an untied head are not "
                          "stacked (scan_layers=False)")
+    pattern = pattern_kinds(cfg)
+    if pattern:
+        return tuple(kind == "E" for kind in pattern)
     return tuple(cfg.n_routed > 0 and layer >= cfg.dense_layers
                  for layer in range(cfg.num_layers))
 
@@ -340,6 +408,23 @@ class Block(nn.Module):
                  train: bool = False,
                  segment_ids: tp.Optional[jax.Array] = None) -> jax.Array:
         cfg = self.config
+        if cfg.layer_pattern:
+            # one mixer on one norm, which keeps the name it has in a
+            # block of two (`norm1` before a sequence mixer, `norm2`
+            # before the experts): the decode steps read either tree
+            # with the same functions
+            kind = pattern_kinds(cfg)[self.layer]
+            norm = lambda name: nn.RMSNorm(
+                epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)(x)
+            if kind == "E":
+                return x + ExpertMLP(cfg, name="moe")(norm("norm2"))
+            if kind == "M":
+                from .mamba2 import Mamba2Mixer
+                one: nn.Module = Mamba2Mixer(cfg, name="ssm")
+            else:
+                from .gqa import GroupedAttention
+                one = GroupedAttention(cfg, layer=self.layer, name="attn")
+            return x + one(norm("norm1"), positions, train, segment_ids)
         if self.mixer == "ssd":
             from .ssd import SSDMixer
             mix: nn.Module = SSDMixer(cfg, mesh=self.mesh, name="ssd")

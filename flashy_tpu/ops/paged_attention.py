@@ -141,23 +141,39 @@ def layer_pool_specs(cfg, num_blocks: int, block_size: int, kv_dtype: str,
     layer does (`num_blocks` blocks of its own head count), a window
     layer holds `[1 + slots, ring * block_size, F]`: a sentinel and one
     static ring of `ring` blocks' rows a slot (`ring_address`),
-    whatever the context."""
-    if getattr(cfg, "attn_kind", "mha") != "gqa":
+    whatever the context. Under a `layer_pattern` only the '*' layers
+    hold K and V; a Mamba-2 layer ('M') holds the third kind of state a
+    slot: `state` f32 `[1 + slots, H, P, N]` and `conv` `[1 + slots,
+    taps - 1, channels]` (`models.mamba2.state_spec`), indexed by slot
+    and not by position, entry 0 the sentinel that parked rows write;
+    an expert layer ('E') holds nothing."""
+    pattern = getattr(cfg, "layer_pattern", "")
+    grouped = getattr(cfg, "attn_kind", "mha") == "gqa"
+    if not grouped and not pattern:
         return [cfg_pool_spec(cfg, num_blocks, block_size, kv_dtype)
                 ] * cfg.num_layers
-    from ..models import gqa
-    if kv_dtype != "model":
-        raise ValueError(
-            f"kv_dtype={kv_dtype!r} keeps a scale per row and head in rows "
-            f"laid out for one head count and one head width; a grouped "
-            f"pool (attn_kind='gqa') has a head count a layer kind and "
-            f"unequal key and value widths: use kv_dtype='model'")
-    return [grouped_pool_spec(
-                *((1 + slots, ring * block_size) if kind.window
-                  else (num_blocks, block_size)),
-                kind.kv_heads, gqa.key_dim(cfg), gqa.value_dim(cfg),
-                cfg.dtype)
-            for kind in gqa.layer_kinds(cfg)]
+    specs: tp.List[tp.Dict] = [{}] * cfg.num_layers
+    if grouped:
+        from ..models import gqa
+        if kv_dtype != "model":
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} keeps a scale per row and head in "
+                f"rows laid out for one head count and one head width; a "
+                f"grouped pool (attn_kind='gqa') has a head count a layer "
+                f"kind and unequal key and value widths: use "
+                f"kv_dtype='model'")
+        specs = [grouped_pool_spec(
+                     *((1 + slots, ring * block_size) if kind.window
+                       else (num_blocks, block_size)),
+                     kind.kv_heads, gqa.key_dim(cfg), gqa.value_dim(cfg),
+                     cfg.dtype)
+                 for kind in gqa.layer_kinds(cfg)]
+    if pattern:
+        from ..models import mamba2
+        specs = [spec if kind == "*" else
+                 mamba2.state_spec(cfg, 1 + slots) if kind == "M" else {}
+                 for spec, kind in zip(specs, pattern)]
+    return specs
 
 
 def init_pool(cfg, num_blocks: int, block_size: int,
@@ -536,12 +552,16 @@ def pool_bytes(cfg, num_blocks: int, block_size: int,
     Pure host arithmetic — the scheduler consults it every step for
     the bytes-per-token gauge, so no jnp ops belong here.
     """
+    return sum(map(_entry_bytes, layer_pool_specs(
+        cfg, num_blocks, block_size, kv_dtype, slots=slots, ring=ring)))
+
+
+def _entry_bytes(spec: tp.Dict) -> int:
+    """Bytes of one layer's entry, from its spec."""
     import math
 
     import numpy as np
     return sum(np.dtype(dt).itemsize * math.prod(shape)
-               for spec in layer_pool_specs(cfg, num_blocks, block_size,
-                                            kv_dtype, slots=slots, ring=ring)
                for shape, dt in spec.values())
 
 
@@ -549,7 +569,19 @@ def window_bytes(cfg, block_size: int, *, slots: int, ring: int) -> int:
     """HBM bytes of the window layers' rings (and sentinels): fixed,
     whatever the contexts. 0 for a config without window layers."""
     return (pool_bytes(cfg, 0, block_size, slots=slots, ring=ring)
-            if slots * ring else 0)
+            - state_bytes(cfg, slots) if slots * ring else 0)
+
+
+def state_bytes(cfg, slots: int) -> int:
+    """HBM bytes of the recurrent layers' entries (`state` and `conv`,
+    the sentinel's too) for `slots` slots: fixed, whatever the
+    contexts. 0 for a config without Mamba-2 layers."""
+    pattern = getattr(cfg, "layer_pattern", "")
+    if "M" not in pattern:
+        return 0
+    from ..models import mamba2
+    return pattern.count("M") * _entry_bytes(
+        mamba2.state_spec(cfg, 1 + slots))
 
 
 def token_bytes(cfg, kv_dtype: str = "model") -> tp.Tuple[int, int]:

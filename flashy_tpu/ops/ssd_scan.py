@@ -37,10 +37,26 @@
 # caller's `chunk` argument (the model passes `cfg.ssd_chunk`; parity
 # tests and a builder's sweep script pass others), else the rule over
 # the sequence length in `default_chunk`: no environment variable, file
-# or process-wide cache decides which kernel compiles. No cell of the
-# benchmark has an SSD layer, so this kernel has not been timed on the
-# chip and CHUNK_CANDIDATES has no sweep behind it yet; one that runs
-# fixes its winner in `default_chunk`, its numbers in PERF.md.
+# or process-wide cache decides which kernel compiles.
+#
+# b and c come BY GROUP, `[B, T, G, N]` with G dividing the heads (head
+# h reads group h // (H / G); G == H is a projection a head): the
+# kernel's index map names the group as stored and nothing is broadcast
+# to the heads in HBM; the XLA forms repeat them (a reference's cost).
+#
+# On the chip (v5e, PR 33, PERF.md section 6; H=128, P=64, N=128, G=8,
+# bf16 b and c, f32 v: one layer of the cell `nemotron3s-reason-closed`).
+# A 512-token slice: the kernel at chunk 64 | 128 | 256 | 512 takes
+# 0.694 | 0.450 | 0.425 | 0.557 ms, XLA's chunked form 0.424 (128) and
+# 0.403 (256): `default_chunk` takes the largest candidate that divides
+# T, 256 there, and the kernel is no faster than XLA at this shape — a
+# (head, chunk) grid step is four small float32 products, two of them
+# the triangular decay sums it rebuilds every step (ROADMAP S14); it
+# stays the TPU path because it never materialises b and c a head. One
+# decode token a row against 129 resident states (`ssd_state_update`):
+# XLA's gather, update and scatter 5.84 ms a layer, the kernel 2.25 |
+# 2.13 | 2.14 ms at 16 | 32 | 64 heads a step (128 exceed VMEM), where
+# the bytes alone take 1.31: `UPDATE_HEADS` 32.
 """SSD/linear-attention dual forms: chunked scan + recurrent step."""
 import functools
 import typing as tp
@@ -58,7 +74,7 @@ from jax.experimental.pallas import tpu as pltpu
 SSD_LOG_RESET = -1e30
 
 # Chunk lengths `default_chunk` chooses among: the largest one
-# dividing T.
+# dividing T (the header has the chip's sweep: 256 at T = 512).
 CHUNK_CANDIDATES: tp.Tuple[int, ...] = (16, 32, 64, 128, 256)
 
 
@@ -102,6 +118,19 @@ def default_chunk(seq_len: int) -> int:
 def _to_heads_first(x: jax.Array) -> jax.Array:
     """[B, T, H, *] -> [B, H, T, *] (the scan-internal layout)."""
     return jnp.swapaxes(x, 1, 2)
+
+
+def _per_head(x: jax.Array, heads: int) -> jax.Array:
+    """b or c heads-first [B, G, T, N] -> [B, H, T, N]: head h reads
+    group h // (H / G). The XLA forms only (the reference, the CPU): the
+    kernels index the group as stored."""
+    groups = x.shape[1]
+    if groups == heads:
+        return x
+    if heads % groups:
+        raise ValueError(f"{groups} groups of b and c do not divide "
+                         f"{heads} heads")
+    return jnp.repeat(x, heads // groups, axis=1)
 
 
 def _masked_inputs(b: jax.Array, log_a: jax.Array,
@@ -240,12 +269,18 @@ def _fused_ssd_body(c_ref, b_ref, v_ref, la_ref, s0_ref, y_ref, sout_ref,
 
 
 def _fused_call(c, b, v, la, state, *, chunk: int, interpret: bool):
-    batch, heads, seq, dstate = c.shape
-    dim = v.shape[-1]
+    batch, groups, seq, dstate = c.shape
+    heads, dim = v.shape[1], v.shape[-1]
     n_chunks = seq // chunk
+    share = heads // groups  # heads that read one group's b and c
 
     def tok_index(bi, hi, j):
         return (bi, hi, j, 0)
+
+    def group_index(bi, hi, j):
+        # head hi reads group hi // share as stored: b and c are never
+        # broadcast to the heads in HBM
+        return (bi, hi // share, j, 0)
 
     def state_index(bi, hi, j):
         return (bi, hi, 0, 0)
@@ -256,8 +291,8 @@ def _fused_call(c, b, v, la, state, *, chunk: int, interpret: bool):
         kernel,
         grid=(batch, heads, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, dstate), tok_index),
-            pl.BlockSpec((1, 1, chunk, dstate), tok_index),
+            pl.BlockSpec((1, 1, chunk, dstate), group_index),
+            pl.BlockSpec((1, 1, chunk, dstate), group_index),
             pl.BlockSpec((1, 1, chunk, dim), tok_index),
             # log-decays ride a trailing unit dim: a (1, 1, chunk) block
             # of the [B, H, T] array is a 1-row tile Mosaic refuses, and
@@ -297,8 +332,10 @@ def ssd_chunked_scan(c: jax.Array, b: jax.Array, v: jax.Array,
     token.
 
     Args:
-        c: [B, T, H, Dstate] output projections (the "C" of SSD).
-        b: [B, T, H, Dstate] state input projections (the "B").
+        c: [B, T, G, Dstate] output projections (the "C" of SSD); G
+            divides H and head h reads group h // (H / G) — G == H is a
+            projection a head.
+        b: [B, T, G, Dstate] state input projections (the "B").
         v: [B, T, H, Dh] values.
         log_decay: [B, T, H] per-token log decays, <= 0 (use
             `SSD_LOG_RESET` at segment boundaries to zero the carried
@@ -332,8 +369,8 @@ def ssd_chunked_scan(c: jax.Array, b: jax.Array, v: jax.Array,
                          f"got {kernel!r}")
     if kernel == "auto":
         kernel = default_ssd_kernel()
-    batch, seq, heads, dstate = c.shape
-    dim = v.shape[-1]
+    batch, seq, _, dstate = c.shape
+    heads, dim = v.shape[2], v.shape[-1]
     b, log_decay = _masked_inputs(b, log_decay, token_mask)
     if chunk is None:
         chunk = default_chunk(seq)
@@ -358,11 +395,18 @@ def ssd_chunked_scan(c: jax.Array, b: jax.Array, v: jax.Array,
         else:
             interpret = False
 
+    # compiled, the kernel's blocks are whole (sublanes, 128) tiles of
+    # the narrowest operand; a shorter piece (a sub-chunk tail, a tail
+    # slice of a few tokens, an init trace) is a few rows of work: XLA's
+    tile = 32 // min(jnp.dtype(x.dtype).itemsize for x in (c, b, v))
+
     def run(c_p, b_p, v_p, la_p, state_p, chunk_p):
-        if kernel == "fused":
+        if kernel == "fused" and (interpret or chunk_p % tile == 0):
             return _fused_call(c_p, b_p, v_p, la_p, state_p, chunk=chunk_p,
                                interpret=bool(interpret))
-        return _chunked_reference(c_p, b_p, v_p, la_p, state_p, chunk_p)
+        return _chunked_reference(_per_head(c_p, heads),
+                                  _per_head(b_p, heads), v_p, la_p,
+                                  state_p, chunk_p)
 
     # Full chunks first, then the sub-chunk tail as one final chunk
     # against the carried state — exact chaining (see `chunk` above).
@@ -387,14 +431,15 @@ def ssd_recurrent_scan(c: jax.Array, b: jax.Array, v: jax.Array,
                        ) -> tp.Tuple[jax.Array, jax.Array]:
     """The RECURRENT form: advance the state one token at a time.
 
-    Same argument shapes as `ssd_chunked_scan` plus the mandatory
+    Same argument shapes as `ssd_chunked_scan` (b and c by group) plus the mandatory
     [B, H, Dh, Dstate] f32 `state`; T is usually 1 (a decode step) but
     any T runs — a lax.scan over time with the f32 state as carry (the
     recurrent reference the dual-form parity gate compares against).
     Returns (y [B, T, H, Dh] in v's dtype, new state f32).
     """
-    ch = _to_heads_first(c).astype(jnp.float32)
-    bh = _to_heads_first(b).astype(jnp.float32)
+    heads = v.shape[2]
+    ch = _per_head(_to_heads_first(c), heads).astype(jnp.float32)
+    bh = _per_head(_to_heads_first(b), heads).astype(jnp.float32)
     vh = _to_heads_first(v).astype(jnp.float32)
     lah = _to_heads_first(log_decay[..., None])[..., 0].astype(jnp.float32)
     state = state.astype(jnp.float32)
@@ -412,6 +457,127 @@ def ssd_recurrent_scan(c: jax.Array, b: jax.Array, v: jax.Array,
         step, state, (to_time(ch), to_time(bh), to_time(vh), to_time(lah)))
     y = jnp.moveaxis(ys, 0, 2)                 # [B, H, T, Dh]
     return _to_heads_first(y).astype(v.dtype), state
+
+
+# ----------------------------------------------------------------------
+# one token a row against a table of resident states (the decode run)
+# ----------------------------------------------------------------------
+# Heads a grid step of the update kernel carries: 32 x [64, 128] f32 is
+# 1 MB in and 1 MB out a step, double-buffered 4 MB (PERF.md section 6,
+# PR 33, has the sweep).
+UPDATE_HEADS = 32
+
+
+def _update_body(rows_ref, decay_ref, v_ref, b_ref, c_ref, state_ref,
+                 y_ref, out_ref, *, heads: int, share: int):
+    """One (row, head block) grid step: `heads` states [P, N] of the
+    row's table entry advanced in place. decay and v arrive with the
+    state's P on the sublanes ([P, heads]: a head's column broadcasts
+    along the lanes), b and c as the groups' rows [1, N]."""
+    del rows_ref  # consumed by the index maps
+    decay, v = decay_ref[0, 0], v_ref[0, 0]                # [P, heads]
+    lane = jax.lax.broadcasted_iota(jnp.int32, decay.shape, 1)
+    y = jnp.zeros(decay.shape, jnp.float32)
+    for j in range(heads):
+        group = j // share  # within the block's groups (0: one group)
+        b_row, c_row = b_ref[0, group], c_ref[0, group]    # [1, N]
+        new = (decay[:, j:j + 1] * state_ref[0, j]
+               + v[:, j:j + 1] * b_row)                    # [P, N]
+        out_ref[0, j] = new
+        y = jnp.where(lane == j,
+                      jnp.sum(new * c_row, axis=1, keepdims=True), y)
+    y_ref[0, 0] = y
+
+
+def _update_call(state, rows, decay, v, b, c, *, heads_per_step: int,
+                 interpret: bool):
+    batch, heads, dim = v.shape
+    groups, dstate = b.shape[1], b.shape[2]
+    share = heads // groups
+    hb = min(heads_per_step, heads)
+    if heads % hb or (hb % share and share % hb):
+        raise ValueError(f"{hb} heads a step must divide {heads} heads and "
+                         f"be whole groups of {share} (or divide one)")
+    blocks, per_block = heads // hb, max(1, hb // share)
+
+    def columns(x):  # [B, H, P] -> [B, H / hb, P, hb]
+        return jnp.swapaxes(x.reshape(batch, blocks, hb, dim), 2, 3)
+
+    def group_index(bi, hi, rows):
+        return (bi, hi * hb // (share * per_block), 0, 0)
+
+    column = pl.BlockSpec((1, 1, dim, hb), lambda bi, hi, rows: (bi, hi, 0, 0))
+    group = pl.BlockSpec((1, per_block, 1, dstate), group_index)
+    entry = pl.BlockSpec((1, hb, dim, dstate),
+                         lambda bi, hi, rows: (rows[bi], hi, 0, 0))
+    y, state = pl.pallas_call(
+        functools.partial(_update_body, heads=hb, share=share),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(batch, blocks),
+            in_specs=[column, column, group, group, entry],
+            out_specs=[column, entry]),
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, blocks, dim, hb), jnp.float32),
+            jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        # operands count the prefetched rows: the table is operand 5
+        input_output_aliases={5: 1},
+        interpret=interpret,
+        name="ssd_state_update",
+    )(rows, columns(jnp.broadcast_to(decay[:, :, None], v.shape)),
+      columns(v), b[:, :, None, :], c[:, :, None, :], state)
+    return jnp.swapaxes(y, 2, 3).reshape(batch, heads, dim), state
+
+
+def ssd_state_update(state: jax.Array, rows: jax.Array, decay: jax.Array,
+                     v: jax.Array, b: jax.Array, c: jax.Array, *,
+                     kernel: str = "auto",
+                     heads_per_step: tp.Optional[int] = None,
+                     interpret: tp.Optional[bool] = None
+                     ) -> tp.Tuple[jax.Array, jax.Array]:
+    """The recurrence advanced ONE token a row, in place, against a
+    table of resident states: the serving engine's decode run.
+
+    Args:
+        state: [R, H, Dh, Dstate] f32, one entry a slot (and whatever
+            row parked rows are pointed at); donated by the caller, so
+            the update is in place.
+        rows: [B] int32, the entry each batch row advances. Rows that
+            name the same entry (parked rows at a sentinel) leave it
+            with one of their writes.
+        decay: [B, H] f32, a_t in (0, 1] (exp of the log decay).
+        v: [B, H, Dh] f32; b, c: [B, G, Dstate] f32 by group.
+        kernel: 'gather' = XLA (rows gathered, advanced, scattered
+            back), 'fused' = the Pallas kernel `ssd_state_update`, which
+            reads and writes each touched entry once through the
+            prefetched `rows`; 'auto' as `default_ssd_kernel()`.
+        heads_per_step: the kernel's heads a grid step (`UPDATE_HEADS`).
+
+    Returns (y [B, H, Dh] f32 = new state . c, the table).
+    """
+    if kernel not in ("auto", "gather", "fused"):
+        raise ValueError(f"kernel must be 'auto', 'gather' or 'fused', "
+                         f"got {kernel!r}")
+    if kernel == "auto":
+        kernel = default_ssd_kernel()
+    batch, heads, dim = v.shape
+    groups = b.shape[1]
+    if kernel == "fused":
+        if interpret is None:
+            interpret = jax.default_backend() == "cpu"
+        return _update_call(
+            state, rows.astype(jnp.int32), decay.astype(jnp.float32),
+            v.astype(jnp.float32), b.astype(jnp.float32),
+            c.astype(jnp.float32),
+            heads_per_step=heads_per_step or UPDATE_HEADS,
+            interpret=bool(interpret))
+    by_group = (batch, groups, heads // groups)
+    new = (decay.reshape(by_group + (1, 1))
+           * state[rows].reshape(by_group + state.shape[2:])
+           + v.reshape(by_group + (dim, 1)) * b[:, :, None, None, :])
+    y = jnp.einsum("bghpn,bgn->bghp", new, c,
+                   preferred_element_type=jnp.float32)
+    return (y.reshape(batch, heads, dim),
+            state.at[rows].set(new.reshape((batch,) + state.shape[1:])))
 
 
 def ssd_state_bytes(num_heads: int, head_dim: int, dstate: int) -> int:

@@ -71,7 +71,9 @@ def state_bytes_per_slot(cfg: tp.Any, max_seq_len: int, cache_layout: str,
               config has window layers, their rings of `ring` blocks
               (the engine's; by default the least a paged engine has,
               `ring_blocks` for a step of `block_size` rows), which do
-              NOT grow with `max_seq_len`;
+              NOT grow with `max_seq_len`, and, where it has Mamba-2
+              layers (`layer_pattern`), their state and conv tail, which
+              do not either (only its attention layers page);
       ssd:    SSD layers contribute the fixed [H, Dh, Dstate] f32
               state — NO max_seq_len term, the O(1) contract — while
               any attention layers in a hybrid stack keep their dense
@@ -100,7 +102,7 @@ def state_bytes_per_slot(cfg: tp.Any, max_seq_len: int, cache_layout: str,
     if cache_layout == "paged":
         from ..models.gqa import has_window
         from ..ops.paged_attention import (block_bytes, ring_blocks,
-                                           token_bytes)
+                                           state_bytes, token_bytes)
         if max_seq_len % block_size:
             raise ValueError(f"block_size {block_size} must divide "
                              f"max_seq_len {max_seq_len}")
@@ -108,8 +110,9 @@ def state_bytes_per_slot(cfg: tp.Any, max_seq_len: int, cache_layout: str,
         if has_window(cfg):
             ring = ring or ring_blocks(cfg.window, block_size, block_size)
             rings = ring * block_size * token_bytes(cfg, kv_dtype)[1]
-        return rings + (max_seq_len // block_size) * block_bytes(
-            cfg, block_size, kv_dtype)
+        return rings + state_bytes(cfg, 0) + (
+            max_seq_len // block_size) * block_bytes(cfg, block_size,
+                                                     kv_dtype)
     if cache_layout == "ssd":
         return sum(ssd_state if m == "ssd" else slab
                    for m, slab in zip(pattern, kv_slabs))
@@ -386,6 +389,21 @@ class DecodeEngine:
         from ..models.gqa import has_window
         self._grouped = self._cfg.attn_kind == "gqa"
         self._windowed = has_window(self._cfg)
+        # one mixer a layer (`layer_pattern`): its Mamba-2 layers keep a
+        # state and a conv tail a slot beside the pool, the third kind
+        # of per-slot state (ops.paged_attention.layer_pool_specs)
+        self._recurrent = "M" in self._cfg.layer_pattern
+        if self._cfg.layer_pattern and cache_layout != "paged":
+            raise ValueError(
+                f"a layer_pattern's layers keep their state a slot beside "
+                f"the block pool (attention layers page, Mamba-2 layers "
+                f"hold one entry a slot): serve it with "
+                f"cache_layout='paged' (got {cache_layout!r})")
+        if self._recurrent and spec_k is not None:
+            raise ValueError(
+                "speculative decoding is not supported under recurrent "
+                "layers: a verify step would have to roll a state back, "
+                "and a state is cumulative (spec_k=None)")
         if kernel not in ("auto", "gather", "fused"):
             raise ValueError(f"kernel must be 'auto', 'gather' or "
                              f"'fused', got {kernel!r}")
@@ -476,14 +494,17 @@ class DecodeEngine:
         self.ring = 0  # blocks of a window layer's ring a slot
         if cache_layout == "paged":
             from ..ops.paged_attention import (block_bytes, init_pool,
-                                               token_bytes, window_bytes)
+                                               state_bytes, token_bytes,
+                                               window_bytes)
             from .paged import BlockPool, CacheBox
-            if self._windowed and (pool is not None
-                                   or cache_box is not None):
+            if (self._windowed or self._recurrent) and (
+                    pool is not None or cache_box is not None
+                    or (self._recurrent and pool_slot_base)):
                 raise ValueError(
-                    "a model with window layers keeps a ring a slot beside "
-                    "the pool, which a block list does not hand over: no "
-                    "shared pool / cache_box (disaggregated hand-off) for it")
+                    "a model with window or recurrent layers keeps a ring "
+                    "or a state a slot beside the pool, which a block list "
+                    "does not hand over: no shared pool / cache_box / "
+                    "pool_slot_base (disaggregated hand-off) for it")
             if pool is not None:
                 if pool.block_size != self.block_size:
                     raise ValueError(
@@ -524,7 +545,8 @@ class DecodeEngine:
                     prefix_cache=prefix_cache,
                     # a ring holds the largest step's rows behind a window
                     window=self._cfg.window if self._windowed else 0,
-                    step_rows=max(self.chunk, (self.spec_k or 0) + 1))
+                    step_rows=max(self.chunk, (self.spec_k or 0) + 1),
+                    state_slots=slots if self._recurrent else 0)
             self.ring = self._pool.ring
             self._cache_box = cache_box if cache_box is not None \
                 else CacheBox()
@@ -539,6 +561,9 @@ class DecodeEngine:
             self._token_bytes = token_bytes(self._cfg, self.kv_dtype)
             self._window_bytes = window_bytes(
                 self._cfg, self.block_size, slots=slots, ring=self.ring)
+            # one slot's recurrent entries over all layers: what a row
+            # that advances reads, and writes again
+            self._state_row_bytes = state_bytes(self._cfg, 0)
             self._table_host = np.zeros(
                 (slots, self._pool.max_blocks), np.int32)
             self._table_dev = jnp.asarray(self._table_host)
@@ -621,9 +646,10 @@ class DecodeEngine:
         """What a paged step over every slot hands `paged_apply_step(
         slots=)`: row s is slot s, or -1 where the slot is parked (free,
         or mid-prefill: its ring is the slices' to write). None without
-        window layers (the program is the one it always was)."""
+        window or recurrent layers (the program is the one it always
+        was)."""
         import jax.numpy as jnp
-        if not self._windowed:
+        if not (self._windowed or self._recurrent):
             return None
         return jnp.where(active, jnp.arange(self.slots, dtype=jnp.int32), -1)
 
@@ -771,10 +797,16 @@ class DecodeEngine:
                     table, (slot, 0), (1, table.shape[1]))
                 positions = (start + jnp.arange(size, dtype=jnp.int32))[None]
                 stats = self._moe_list()
+                # a recurrent layer carries the slot's state across the
+                # slices: pads stay out of it, a first slice starts it
+                # from zeros (whatever the slot's last occupant left)
+                carried = ({"used": used[None], "fresh": (start == 0)[None]}
+                           if self._recurrent else {})
                 logits, cache = paged_apply_step(
                     model, params, cfg, tokens, positions, cache, row,
                     kernel=self.kernel, stats=stats,
-                    slots=slot[None] if self._windowed else None)
+                    slots=(slot[None] if self._windowed or self._recurrent
+                           else None), **carried)
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], used - 1, axis=0, keepdims=True)
                 first = self._sample(last, key)[0]
@@ -1079,6 +1111,10 @@ class DecodeEngine:
         # that grows, the full-attention layers' blocks. A live slot
         # holds its rings beside its blocks.
         stats["window_bytes"] = self._window_bytes
+        # `state_bytes`: the recurrent layers' entries, fixed likewise
+        # and in none of the block counts; `kv_bytes_per_token` stays
+        # the attention layers'
+        stats["state_bytes"] = (1 + self.slots) * self._state_row_bytes
         held = (stats["in_use"] * per_block + int(self._active_host.sum())
                 * (self._window_bytes // (1 + self.slots)))
         stats["kv_bytes_per_token"] = (
@@ -1184,6 +1220,7 @@ class DecodeEngine:
             lambda: self._build_prefill_chunk(size))
         stats = {} if uid is None else {"uid": uid}
         stats.update(self._kv_read_stats(size, [start]))
+        stats.update(self._state_stats(1))
         with span(SPAN_PREFILL_CHUNK, self.tracer, category="serve",
                   slot=slot, size=size, offset=start, length=length,
                   final=final, **stats):
@@ -1273,6 +1310,16 @@ class DecodeEngine:
             bases, queries, walk, self.block_size, self._pool.max_blocks)
         return stats
 
+    def _state_stats(self, rows: int) -> tp.Dict[str, int]:
+        """Span stat of a step that advances `rows` sequences under
+        recurrent layers: `ssm_state_bytes`, the bytes of state and conv
+        tail, all layers, that those rows read and write again (host
+        arithmetic on the slot mirror; parked rows' sentinel traffic is
+        not work). Nothing without such layers."""
+        if not self._recurrent:
+            return {}
+        return {"ssm_state_bytes": 2 * rows * self._state_row_bytes}
+
     def decode(self) -> np.ndarray:
         """One [S, 1] decode step over every slot; returns the [S] next
         tokens (pad_token on inactive slots). Always the same compiled
@@ -1282,7 +1329,8 @@ class DecodeEngine:
         with span(SPAN_DECODE, self.tracer, category="serve",
                   live=self.allocator.live_count,
                   running=int(self._active_host.sum()),
-                  **self._kv_read_stats(1, self._positions_host)):
+                  **self._kv_read_stats(1, self._positions_host),
+                  **self._state_stats(int(self._active_host.sum()))):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_DECODE + SPAN_DISPATCH, self.tracer,
                       category="serve"):

@@ -255,12 +255,21 @@ class BlockPool:
             (`prefix_cache` is off): a hit would need the window layers'
             rows of the prefix's last `window - 1` tokens, which a ring
             has overwritten.
+        state_slots: a model with recurrent layers (models/mamba2.py)
+            keeps, beside this pool's blocks, one fixed entry a slot a
+            layer (a state and a conv tail, `1 + state_slots` entries
+            with the sentinel), indexed by slot: nothing here allocates
+            or frees them, a reservation's key is its entry, and
+            `check()` holds every key inside the entries. `plan` finds
+            no prefix for such a model either: the state after a prefix
+            is no block's content, and a slot's first slice starts from
+            zeros.
     """
 
     def __init__(self, *, num_blocks: int, block_size: int,
                  max_seq_len: int, spec_overshoot: int = 0,
                  prefix_cache: bool = True, window: int = 0,
-                 step_rows: int = 0):
+                 step_rows: int = 0, state_slots: int = 0):
         if num_blocks < 2:
             raise ValueError(f"need >= 2 blocks (sentinel + 1 real), "
                              f"got {num_blocks}")
@@ -272,8 +281,9 @@ class BlockPool:
         self.max_blocks = max_seq_len // block_size  # per-slot table entries
         self.max_seq_len = max_seq_len
         self.spec_overshoot = int(spec_overshoot)
-        self.prefix_cache = prefix_cache and not window
+        self.prefix_cache = prefix_cache and not window and not state_slots
         self.window, self.step_rows = int(window), int(step_rows)
+        self.state_slots = int(state_slots)
         from ..ops.paged_attention import ring_blocks
         self.ring = (ring_blocks(self.window, self.step_rows, block_size)
                      if window else 0)
@@ -580,6 +590,17 @@ class BlockPool:
             if len(self.index) or self.prefix_matched_tokens:
                 raise AssertionError("a prefix was shared under window "
                                      "layers")
+        if self.state_slots:
+            # the recurrent layers' half: an entry a slot, for good
+            if len(self.index) or self.prefix_matched_tokens:
+                raise AssertionError("a prefix was shared under recurrent "
+                                     "layers")
+            outside = [s for s in self._slots
+                       if not 0 <= s < self.state_slots]
+            if outside:
+                raise AssertionError(
+                    f"reservations {outside} lie outside the "
+                    f"{self.state_slots} state entries")
         if self._cached != self.index.blocks:
             raise AssertionError("cached-block mirror drifted from the "
                                  "index")
@@ -626,7 +647,8 @@ class BlockPool:
 # the paged model step (device side)
 # ----------------------------------------------------------------------
 def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
-                     kernel: str = "gather", stats=None, slots=None):
+                     kernel: str = "gather", stats=None, slots=None,
+                     used=None, fresh=None):
     """Forward `tokens` [B, T] at `positions` [B, T] against the pool.
 
     The paged twin of models/decoding._apply_step: same embed, MLP/MoE,
@@ -654,16 +676,26 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
     row belongs to, -1 for a parked row; scope `attn/window`: the
     masked dense form over the ring's view, whose row c holds the
     position `ring_positions` says, whichever `kernel`), which the
-    table does not know. Each expert
+    table does not know. Under a `layer_pattern` a layer is its one
+    mixer: an expert layer holds no entry, an attention layer is the
+    full-attention grouped layer without an MLP, and a Mamba-2 layer
+    advances entry `1 + slots[i]` of its `state` / `conv` tables (entry
+    0, the sentinel, for a parked row): a decode run (`used` None) one
+    token a row in place, a prefill slice (`used` [B], the real tokens
+    of each right-padded row) through the chunked form with the slot's
+    state and conv tail going in and coming out, from zeros where
+    `fresh` [B] says the row begins a sequence. Each expert
     layer of an unstacked model appends its (assignments, experts hit)
     counts to `stats` when a list is given.
     """
     import jax
+    import jax.numpy as jnp
 
     from ..models.decoding import (_attn_residual, _embed_tokens,
                                    _head_logits, _mlp_residual, _qkv_heads,
                                    grouped_projections, grouped_residual,
-                                   latent_projections, latent_residual)
+                                   latent_projections, latent_residual,
+                                   mamba_residual)
     from ..ops.paged_attention import (_physical, grouped_table_view,
                                        grouped_write, latent_paged_attention,
                                        latent_paged_write, paged_attention,
@@ -702,7 +734,27 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
         x = _attn_residual(cfg, bp, x, attn)
         return _mlp_residual(cfg, bp, x, stats), entry
 
-    def grouped_layer(kind):
+    def mamba_layer(bp, x, entry):
+        if slots is None:
+            raise ValueError("a Mamba-2 layer's state is addressed by "
+                             "slot: paged_apply_step(slots=)")
+        rows = slots + 1  # a parked row (-1) advances the sentinel
+        state, tail = entry["state"], entry["conv"]
+        if used is None:  # a decode run: the tables, in place
+            x, state, tail = mamba_residual(cfg, bp, x, state, tail,
+                                            rows=rows)
+            return x, {"state": state, "conv": tail}
+        own, own_tail = state[rows], tail[rows]
+        if fresh is not None:
+            own = jnp.where(fresh[:, None, None, None], 0.0, own)
+            own_tail = jnp.where(fresh[:, None, None],
+                                 jnp.zeros((), own_tail.dtype), own_tail)
+        x, own, own_tail = mamba_residual(cfg, bp, x, own, own_tail,
+                                          used=used)
+        return x, {"state": state.at[rows].set(own),
+                   "conv": tail.at[rows].set(own_tail)}
+
+    def grouped_layer(kind, mlp=True):
         from ..models import gqa
 
         def layer(bp, x, entry):
@@ -727,12 +779,24 @@ def paged_apply_step(model, params, cfg, tokens, positions, cache, table,
                     out = gqa.attend(cfg, kind, bp["attn"], q, *view,
                                      positions)
             x = grouped_residual(cfg, bp, x, out)
-            return _mlp_residual(cfg, bp, x, stats), entry
+            return (_mlp_residual(cfg, bp, x, stats) if mlp else x), entry
 
         return layer
 
     # one layer body for the stack, or one a layer kind
-    if cfg.attn_kind == "gqa":
+    if cfg.layer_pattern:
+        from ..models.gqa import layer_kinds
+        from ..models.transformer import pattern_kinds
+        pattern = pattern_kinds(cfg)
+        kinds = layer_kinds(cfg) if "*" in pattern else None
+
+        def expert_only(bp, x, entry):
+            return _mlp_residual(cfg, bp, x, stats), entry
+
+        layers = [mamba_layer if kind == "M" else expert_only if kind == "E"
+                  else grouped_layer(kinds[i], mlp=False)
+                  for i, kind in enumerate(pattern)]
+    elif cfg.attn_kind == "gqa":
         from ..models.gqa import layer_kinds
         layers = [grouped_layer(kind) for kind in layer_kinds(cfg)]
     else:
@@ -764,8 +828,9 @@ def copy_block_fn(cfg, kv_dtype: str) -> tp.Callable:
     and leaf (K/V payloads and their scales, or a latent pool's `c` and
     `kr`). The block axis of a leaf follows the pool's spec
     (`ops.paged_attention.layer_pool_specs`): a layer-stacked leaf has it
-    one further in. A window layer's rings are no blocks of the pool and
-    pass through. One fixed-shape executable per engine — warmed with
+    one further in. A window layer's rings and a Mamba-2 layer's state
+    entries are no blocks of the pool and pass through. One fixed-shape
+    executable per engine — warmed with
     the decode/verify steps so a fork never compiles mid-traffic."""
     import jax.numpy as jnp
 
@@ -784,15 +849,18 @@ def copy_block_fn(cfg, kv_dtype: str) -> tp.Callable:
         spec = cfg_pool_spec(cfg, 1, 1, kv_dtype)
         return lambda entry, src, dst: copy_entry(entry, spec, src, dst)
     specs = layer_pool_specs(cfg, 1, 1, kv_dtype)
-    rings = [False] * cfg.num_layers
+    paged = [True] * cfg.num_layers
     if cfg.attn_kind == "gqa":
         from ..models.gqa import layer_kinds
-        rings = [bool(kind.window) for kind in layer_kinds(cfg)]
+        paged = [not kind.window for kind in layer_kinds(cfg)]
+    if cfg.layer_pattern:
+        paged = [kind == "*" and own for kind, own in
+                 zip(cfg.layer_pattern, paged)]
 
     def copy(cache, src, dst):
         return {f"block_{i}": (
-                    cache[f"block_{i}"] if rings[i] else
-                    copy_entry(cache[f"block_{i}"], specs[i], src, dst))
+                    copy_entry(cache[f"block_{i}"], specs[i], src, dst)
+                    if paged[i] else cache[f"block_{i}"])
                 for i in range(cfg.num_layers)}
 
     return copy

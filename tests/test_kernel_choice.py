@@ -24,7 +24,8 @@ from tests.test_spans import pallas_calls
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 SERVING_CELLS = ("olmo1b-chat-closed", "olmo1b-fullctx-closed",
-                 "dotsvlm1-doc-closed", "mimo25-doc16k-closed")
+                 "dotsvlm1-doc-closed", "mimo25-doc16k-closed",
+                 "nemotron3s-reason-closed")
 
 
 # ----------------------------------------------------------------------
@@ -168,7 +169,10 @@ def _walk_and_vmem(cfg, engine, queries):
 # window/full cell (PR 32, the walk of its two full-attention layers):
 # 64 rows x 1,024 keys a decode step, block-diagonal over the row's 768
 # key lanes; a slice's 16 heads of a KV head x 64 positions = 1,024 rows
-# a part against 1,024 keys (PERF.md section 6, PR 32).
+# a part against 1,024 keys (PERF.md section 6, PR 32). The Mamba-2 /
+# latent-expert cell (PR 33, the walk of its one attention layer, 32
+# query heads over 2 KV heads of 128 | 128: K and V blocks [16, 256]):
+# the same split, 16 heads of a KV head x 64 positions a part.
 OLMO_WALKS = {"decode": Walk(16, 16, 1, True, True),
               "slice": Walk(8, 16, 128, False, True),
               "tail": Walk(16, 16, 4, True, True),
@@ -184,6 +188,10 @@ RECORDED = {
                              "slice": Walk(64, 64, 64, False, True),
                              "tail": Walk(64, 64, 4, True, True),
                              "verify": Walk(64, 64, 5, True, True)},
+    "nemotron3s-reason-closed": {"decode": Walk(64, 32, 1, True, True),
+                                 "slice": Walk(64, 32, 64, False, True),
+                                 "tail": Walk(64, 32, 4, True, True),
+                                 "verify": Walk(64, 32, 5, True, True)},
 }
 
 
@@ -255,3 +263,28 @@ def test_the_grouped_cell_resolves_auto_to_the_walk_on_a_tpu(monkeypatch):
     assert [spec["k"][0] for spec in specs] == (
         [(9, 16, 768)] + [(33, 640, 1536)] * 5 + [(9, 16, 768)])
     assert [spec["v"][0][-1] for spec in specs] == [512] + [1024] * 5 + [512]
+
+
+def test_the_recurrent_cell_keeps_a_state_a_slot_beside_its_one_paged_layer():
+    # the Mamba-2 / latent-expert cell: five layers hold a float32 state
+    # [128, 64, 128] and a 3-row conv tail a slot (entry 0 the sentinel),
+    # five expert layers hold nothing, the one attention layer pages
+    # whole-tile blocks; the slice's scan runs the swept chunk and the
+    # decode update the swept heads a step
+    from flashy_tpu.ops.paged_attention import (block_bytes, layer_pool_specs,
+                                                state_bytes)
+    cfg, engine = _cell("nemotron3s-reason-closed")
+    assert cfg.layer_pattern == "MEMEMEMEM*E"
+    specs = layer_pool_specs(cfg, 9, engine["block_size"], "model",
+                             slots=engine["slots"])
+    shapes = [{name: leaf[0] for name, leaf in spec.items()}
+              for spec in specs]
+    state = {"state": (129, 128, 64, 128), "conv": (129, 3, 10240)}
+    assert shapes == [state, {}] * 4 + [state, {"k": (9, 16, 256),
+                                                "v": (9, 16, 256)}, {}]
+    assert specs[0]["state"][1] == jnp.float32
+    assert block_bytes(cfg, engine["block_size"]) == 16 * 1024
+    assert state_bytes(cfg, 0) == 5 * (128 * 64 * 128 * 4 + 3 * 10240 * 2)
+    assert state_bytes(cfg, engine["slots"]) == 129 * state_bytes(cfg, 0)
+    assert ssd_scan.default_chunk(engine["chunk"]) in ssd_scan.CHUNK_CANDIDATES
+    assert cfg.ssm_heads % ssd_scan.UPDATE_HEADS == 0
