@@ -39,6 +39,14 @@ SPAN_READBACK = "/readback"   # prefill_chunk
 SPAN_MOE = "/moe"
 
 
+class StepHandle(tp.NamedTuple):
+    """What a dispatched step left on the device until `collect()`
+    reads it: nothing in it has been waited for."""
+    span: str     # SPAN_DECODE | SPAN_PREFILL_CHUNK: whose children read it
+    read: tp.Any  # int32 device array: the tokens, the expert counts behind
+    tap: tp.Any   # the float32 logits they were taken from, or None
+
+
 def _zero_ssd_leaves(cache: tp.Any, fresh: tp.Any) -> tp.Any:
     """Zero the SSD state leaves of a cache pytree when `fresh` (a
     traced bool scalar) is set; attention K/V leaves pass through.
@@ -184,8 +192,14 @@ class DecodeEngine:
     Purely tensor-level: it owns the cache, the per-slot device-visible
     state (last token, length, active mask) and the CompileCache of
     executables; request semantics (queueing, retirement, metrics) live
-    in the scheduler. Greedy by default; `temperature > 0` samples with
-    a per-step split of `rng`.
+    in the scheduler. A step is two halves: `dispatch_decode()` /
+    `dispatch_prefill_chunk()` enqueue it and return a `StepHandle` at
+    once — the compiled steps advance the per-slot state on the device,
+    so the next one can be dispatched behind it — and `collect()` waits
+    for a handle and reads its tokens; `decode()` / `prefill_chunk()`
+    are one after the other. The host mirrors move at dispatch: they
+    describe every step dispatched so far. Greedy by default;
+    `temperature > 0` samples with a per-step split of `rng`.
 
     Args:
         model: a TransformerLM (its config drives shapes/dtype).
@@ -297,12 +311,14 @@ class DecodeEngine:
         keep_logits: a tap for a check that compares this engine's own
             logits with a reference (paged only). The paged decode and
             prefill-slice executables then also return the float32
-            logits they sampled from ([S, V]; [1, V] of a slice's last
-            used row), which stay on the device in `tapped['decode']`
-            and `tapped['prefill_chunk']` until the next such step
-            replaces them; the engine never reads them. The verify step
-            is not tapped. Off (the default), every executable is the
-            one it always was.
+            logits they sampled from ([S, V]; [1, V] of a final slice's
+            last used row), which stay on the device: in the step's
+            handle while it is in flight, then, from `collect()` on, in
+            `tapped['decode']` / `tapped['prefill_chunk']` until the
+            next collected step replaces them — the logits of the
+            tokens most recently READ; the engine never reads them. The
+            verify step is not tapped. Off (the default), every
+            executable is the one it always was.
     """
 
     # every compiled step takes the cache as operand 1, donated so XLA
@@ -566,7 +582,7 @@ class DecodeEngine:
             self._state_row_bytes = state_bytes(self._cfg, 0)
             self._table_host = np.zeros(
                 (slots, self._pool.max_blocks), np.int32)
-            self._table_dev = jnp.asarray(self._table_host)
+            self._table_dev = jnp.array(self._table_host)  # as `_table`
             self._table_dirty = False
         else:
             from .paged import CacheBox
@@ -675,6 +691,26 @@ class DecodeEngine:
         import jax.numpy as jnp
         return (logits.astype(jnp.float32),) if self.keep_logits else ()
 
+    # The per-slot state (last token, position, liveness) is advanced by
+    # the compiled steps themselves, so the next step can be dispatched
+    # before anything of this one has been read back.
+    @staticmethod
+    def _advanced(positions, active):
+        """A decode step's positions: every live slot one further."""
+        return positions + active.astype(positions.dtype)
+
+    @staticmethod
+    def _armed(state, slot, first, length, final):
+        """A prefill slice's `(tokens, positions, active)`: where `final`
+        (a traced bool: one executable serves every slice) row `slot`
+        goes live at the prompt's `length` with its own `first` token."""
+        import jax.numpy as jnp
+        tokens, positions, active = state
+        return (tokens.at[slot].set(jnp.where(final, first, tokens[slot])),
+                positions.at[slot].set(
+                    jnp.where(final, length, positions[slot])),
+                active.at[slot].set(active[slot] | final))
+
     def _moe_span(self, parent: str, counts) -> None:
         """The empty `<parent>/moe` span that carries a step's counts."""
         with span(parent + SPAN_MOE, self.tracer, category="serve",
@@ -690,7 +726,10 @@ class DecodeEngine:
         if self._table_dirty:
             with span(SPAN_TABLE_UPLOAD, self.tracer, category="serve",
                       bytes=int(self._table_host.nbytes)):
-                self._table_dev = jnp.asarray(self._table_host)
+                # a copy, never a view (the CPU backend would alias the
+                # host array): a step in flight reads the table it was
+                # dispatched with while the host edits the next one
+                self._table_dev = jnp.array(self._table_host)
             self._table_dirty = False
         return self._table_dev
 
@@ -720,8 +759,9 @@ class DecodeEngine:
                     slots=self._row_slots(active))
                 nxt = self._sample(logits[:, -1], key)
                 nxt = jnp.where(active, nxt, jnp.int32(pad))
-                out = (nxt, cache) if stats is None else (
-                    nxt, cache, self._with_moe(nxt, stats))
+                out = (nxt, cache, self._advanced(positions, active))
+                if stats is not None:
+                    out += (self._with_moe(nxt, stats),)
                 return out + self._tap(logits[:, -1])
 
             return jax.jit(decode_paged, donate_argnums=self._donate)
@@ -738,7 +778,8 @@ class DecodeEngine:
                 model, params, cfg, tokens[:, None], positions[:, None],
                 cache, positions, state_mask=active)
             nxt = self._sample(logits[:, -1], key)
-            return jnp.where(active, nxt, jnp.int32(pad)), cache
+            return (jnp.where(active, nxt, jnp.int32(pad)), cache,
+                    self._advanced(positions, active))
 
         return jax.jit(decode, donate_argnums=self._donate)
 
@@ -785,7 +826,7 @@ class DecodeEngine:
             from .paged import paged_apply_step
 
             def chunk_paged(params, cache, table, tokens, start, used,
-                            slot, key):
+                            slot, key, state, final):
                 # tokens: [1, size] at absolute positions start.. —
                 # attention reaches the slot's EARLIER blocks (its own
                 # previous chunks AND any prefix-shared blocks) through
@@ -810,13 +851,16 @@ class DecodeEngine:
                 last = jax.lax.dynamic_index_in_dim(
                     logits[0], used - 1, axis=0, keepdims=True)
                 first = self._sample(last, key)[0]
-                out = (first, cache) if stats is None else (
-                    first, cache, self._with_moe(first, stats))
+                out = (first, cache,
+                       self._armed(state, slot, first, start + used, final))
+                if stats is not None:
+                    out += (self._with_moe(first, stats),)
                 return out + self._tap(last)
 
             return jax.jit(chunk_paged, donate_argnums=self._donate)
 
-        def chunk_step(params, cache, tokens, start, used, slot, key):
+        def chunk_step(params, cache, tokens, start, used, slot, key,
+                       state, final):
             # tokens: [1, size] right-padded slice of the prompt whose
             # real tokens sit at absolute positions start..start+used-1.
             # Unlike the bucketed prefill (fresh mini cache), a chunk
@@ -852,7 +896,8 @@ class DecodeEngine:
                                                 axis=0, keepdims=True)
             first = self._sample(last, key)[0]
             cache = jax.tree_util.tree_map(merge, cache, mini)
-            return first, cache
+            return first, cache, self._armed(state, slot, first,
+                                             start + used, final)
 
         return jax.jit(chunk_step, donate_argnums=self._donate)
 
@@ -975,7 +1020,7 @@ class DecodeEngine:
                     lambda: self._build_prefill_chunk(size),
                     self._params, self._cache, *layout, dummy,
                     jnp.int32(0), jnp.int32(1), jnp.int32(0),
-                    self._next_key())
+                    self._next_key(), self._slot_state(), jnp.bool_(False))
                 warmed.append(f"prefill_chunk/{size}")
         else:
             buckets = {self.min_bucket}
@@ -1010,6 +1055,9 @@ class DecodeEngine:
                 self._key("copy_block"), self._build_copy,
                 self._cache, jnp.int32(0), jnp.int32(0))
             warmed.append("copy_block")
+        # the eager row writes of retire / preempt / hand-off lower on
+        # their first use too: here, not in the middle of traffic
+        self._park(0)
         # warm-up wrote scratch K/V at slot 0 position 0; a real prefill
         # overwrites it before that slot ever decodes, but reset the
         # host-visible state anyway so the engine starts pristine.
@@ -1179,13 +1227,28 @@ class DecodeEngine:
     def prefill_chunk(self, slot: int, prompt: np.ndarray, start: int,
                       uid: tp.Optional[int] = None
                       ) -> tp.Tuple[int, tp.Optional[int]]:
-        """Advance `slot`'s prefill by ONE fixed-size slice.
+        """Advance `slot`'s prefill by ONE fixed-size slice and wait for
+        it where it is the last: `dispatch_prefill_chunk()` followed by
+        `collect()`. Returns `(next_start, first_token)`; `first_token`
+        is None until the final slice."""
+        start, handle = self.dispatch_prefill_chunk(slot, prompt, start,
+                                                    uid=uid)
+        return start, None if handle is None else int(self.collect(handle)[0])
+
+    def dispatch_prefill_chunk(self, slot: int, prompt: np.ndarray,
+                               start: int, uid: tp.Optional[int] = None
+                               ) -> tp.Tuple[int, tp.Optional[StepHandle]]:
+        """Enqueue ONE fixed-size slice of `slot`'s prefill; waits for
+        nothing.
 
         Processes `prompt[start : start + size]` where size is `chunk`,
         or `tail_bucket` when the remainder fits it — so the compiled
         prefill set in chunked mode is exactly those two shapes.
-        Returns `(next_start, first_token)`; `first_token` is None
-        until the final slice, at which point the slot goes live. The
+        Returns `(next_start, handle)`; `handle` is None until the
+        final slice, whose executable itself puts the slot live on the
+        device (its first token, the prompt's length, active) so a
+        decode step dispatched next already carries the row;
+        `collect(handle)[0]` is that first token. The
         scheduler interleaves these ticks with decode steps, bounding
         the stall a long prompt can impose on live slots to one
         slice's compute. `uid` (the scheduler's request id) only rides
@@ -1224,31 +1287,41 @@ class DecodeEngine:
         with span(SPAN_PREFILL_CHUNK, self.tracer, category="serve",
                   slot=slot, size=size, offset=start, length=length,
                   final=final, **stats):
-            first, self._cache, *packed = fn(
+            first, self._cache, state, *packed = fn(
                 self._params, self._cache, *self._layout_args(),
                 jnp.asarray(padded), jnp.int32(start), jnp.int32(used),
-                jnp.int32(slot), self._next_key())
-            if self.keep_logits:
-                self.tapped["prefill_chunk"] = packed.pop()
+                jnp.int32(slot), self._next_key(), self._slot_state(),
+                jnp.bool_(final))
             if not final:
                 # nothing of this slice is read back, its counts neither
                 return start + used, None
-            with span(SPAN_PREFILL_CHUNK + SPAN_READBACK, self.tracer,
-                      category="serve"):
-                read = np.asarray(packed[0] if packed else first).reshape(-1)
-                first = int(read[0])
-            if packed:
-                self._moe_span(SPAN_PREFILL_CHUNK, read[1:])
-            if self._pool is not None:
-                # prompt fully written: index its full blocks so later
-                # admissions share them instead of re-prefilling
-                self._pool.on_live(self.pool_key(slot))
-            self._tokens = self._tokens.at[slot].set(first)
-            self._positions = self._positions.at[slot].set(length)
-            self._active = self._active.at[slot].set(True)
+            self._tokens, self._positions, self._active = state
             self._positions_host[slot] = length
             self._active_host[slot] = True
-        return start + used, first
+            if self._pool is not None:
+                # prompt fully written (in device order: whatever is
+                # enqueued after this slice sees it): index its full
+                # blocks so later admissions share them
+                self._pool.on_live(self.pool_key(slot))
+        tap = packed.pop() if self.keep_logits else None
+        return start + used, StepHandle(SPAN_PREFILL_CHUNK,
+                                        packed[0] if packed else first, tap)
+
+    def collect(self, handle: StepHandle) -> np.ndarray:
+        """Wait for a dispatched step and read it back: a decode step's
+        [S] tokens (pad_token on inactive slots), a final slice's first
+        token as [1]. The step's logits, where kept, become
+        `tapped[...]` now: `tapped` holds the logits of the tokens most
+        recently read, not of a step still in flight."""
+        with span(handle.span + SPAN_READBACK, self.tracer,
+                  category="serve"):
+            read = np.asarray(handle.read).reshape(-1)
+        if self._moe_stats:
+            self._moe_span(handle.span, read[-2:])
+            read = read[:-2]
+        if handle.tap is not None:
+            self.tapped[handle.span.rpartition("/")[2]] = handle.tap
+        return read
 
     def _kv_read_stats(self, queries: int, bases) -> tp.Dict[str, int]:
         """Span stats of one paged read of `queries` rows per slot from
@@ -1321,9 +1394,18 @@ class DecodeEngine:
         return {"ssm_state_bytes": 2 * rows * self._state_row_bytes}
 
     def decode(self) -> np.ndarray:
-        """One [S, 1] decode step over every slot; returns the [S] next
-        tokens (pad_token on inactive slots). Always the same compiled
-        executable, whatever the live mix."""
+        """One [S, 1] decode step over every slot, waited for:
+        `dispatch_decode()` followed by `collect()`. Returns the [S]
+        next tokens (pad_token on inactive slots)."""
+        return self.collect(self.dispatch_decode())
+
+    def dispatch_decode(self) -> StepHandle:
+        """Enqueue one [S, 1] decode step over every slot; waits for
+        nothing. Always the same compiled executable, whatever the live
+        mix. The step feeds each live slot its own token back and
+        advances its position on the device, so the next step (or the
+        next slice) can be dispatched before `collect(handle)` reads
+        this one's tokens."""
         fn = self.compile_cache.get(self._key("decode", self.slots),
                                     self._build_decode)
         with span(SPAN_DECODE, self.tracer, category="serve",
@@ -1334,23 +1416,13 @@ class DecodeEngine:
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_DECODE + SPAN_DISPATCH, self.tracer,
                       category="serve"):
-                tokens, self._cache, *packed = fn(
+                self._tokens, self._cache, self._positions, *packed = fn(
                     self._params, self._cache, *layout, self._tokens,
                     self._positions, self._active, key)
-                if self.keep_logits:
-                    self.tapped["decode"] = packed.pop()
-            with span(SPAN_DECODE + SPAN_READBACK, self.tracer,
-                      category="serve"):
-                out = np.asarray(packed[0] if packed else tokens)
-            if packed:
-                self._moe_span(SPAN_DECODE, out[self.slots:])
-                out = out[:self.slots]
-            # feed each live slot its own token back; lengths advance by 1
-            self._tokens = tokens
-            self._positions = self._positions + self._active.astype(
-                self._positions.dtype)
             self._positions_host += self._active_host
-        return out
+        tap = packed.pop() if self.keep_logits else None
+        return StepHandle(SPAN_DECODE, packed[0] if packed else self._tokens,
+                          tap)
 
     def decode_speculative(self, drafts: np.ndarray
                            ) -> tp.Tuple[np.ndarray, np.ndarray]:
@@ -1423,6 +1495,22 @@ class DecodeEngine:
         self._positions = self._positions.at[slot].set(int(position))
         self._positions_host[slot] = int(position)
 
+    def _slot_state(self) -> tp.Tuple:
+        """The device's `(tokens, positions, active)`, as the prefill
+        slices take and return them."""
+        return self._tokens, self._positions, self._active
+
+    def _park(self, slot: int) -> None:
+        """Deactivate row `slot` on the device and in the host mirrors:
+        inactive, at position `max_seq_len`, where its writes fall out
+        of range. Eager row writes of host constants, enqueued behind
+        every step dispatched so far: nothing is read, nothing waits."""
+        self._active = self._active.at[slot].set(False)
+        self._positions = self._positions.at[slot].set(self.max_seq_len)
+        self._tokens = self._tokens.at[slot].set(self.pad_token)
+        self._positions_host[slot] = self.max_seq_len
+        self._active_host[slot] = False
+
     def retire(self, slot: int) -> None:
         """Free `slot`: deactivate it and park its position out of range
         so pending decode writes drop instead of landing in the cache
@@ -1430,11 +1518,7 @@ class DecodeEngine:
         the paged layout the slot's block refcounts drop too — blocks
         no table references return to the free list, except prompt
         blocks the prefix index still caches for future admissions."""
-        self._active = self._active.at[slot].set(False)
-        self._positions = self._positions.at[slot].set(self.max_seq_len)
-        self._tokens = self._tokens.at[slot].set(self.pad_token)
-        self._positions_host[slot] = self.max_seq_len
-        self._active_host[slot] = False
+        self._park(slot)
         if self._pool is not None and self._pool.holds(self.pool_key(slot)):
             self._pool.release(self.pool_key(slot))
             self._table_host[slot] = 0
@@ -1457,11 +1541,7 @@ class DecodeEngine:
         """
         if slot not in self.allocator.live:
             raise ValueError(f"slot {slot} is not live")
-        self._active = self._active.at[slot].set(False)
-        self._positions = self._positions.at[slot].set(self.max_seq_len)
-        self._tokens = self._tokens.at[slot].set(self.pad_token)
-        self._positions_host[slot] = self.max_seq_len
-        self._active_host[slot] = False
+        self._park(slot)
         if self._pool is not None and self._pool.holds(self.pool_key(slot)):
             self._pool.evict_slot(self.pool_key(slot))
             self._table_host[slot] = 0
@@ -1480,7 +1560,9 @@ class DecodeEngine:
         reservation stays keyed to this engine's `pool_key(slot)` until
         the importer re-keys it (`BlockPool.transfer_slot`); this slot
         itself is deactivated and returned to the allocator. Paged
-        engines only.
+        engines only. The last token is read from the device, behind
+        every step dispatched so far: a caller that still holds such a
+        step's handle finds the same token in it.
         """
         if self._pool is None:
             raise ValueError("handoff requires the paged layout: the "
@@ -1492,11 +1574,7 @@ class DecodeEngine:
             "position": int(self._positions_host[slot]),
             "last_token": int(np.asarray(self._tokens)[slot]),
         }
-        self._active = self._active.at[slot].set(False)
-        self._positions = self._positions.at[slot].set(self.max_seq_len)
-        self._tokens = self._tokens.at[slot].set(self.pad_token)
-        self._positions_host[slot] = self.max_seq_len
-        self._active_host[slot] = False
+        self._park(slot)
         self._table_host[slot] = 0
         self._table_dirty = True
         self.allocator.release(slot)

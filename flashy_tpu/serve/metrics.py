@@ -92,6 +92,13 @@ class ServeMetrics:
         self.prefix_prompt_tokens = 0
         self.prefix_admissions = 0
         self.prefix_hits = 0
+        # the one-step pipeline: steps that started with a step still
+        # unread, rows the decode steps advanced, and those of them
+        # decoded for a request that had already ended (an EOS is seen
+        # one step late; that token is dropped)
+        self.steps_in_flight = 0
+        self.rows_decoded = 0
+        self.late_rows = 0
 
     # ------------------------------------------------------------------
     # scheduler hooks
@@ -200,6 +207,17 @@ class ServeMetrics:
                 self.tracer.counter(COUNTER_KV_BYTES,
                                     bytes=bytes_per_token)
 
+    def on_step(self, in_flight: int) -> None:
+        """One scheduler step began; `in_flight` (0 | 1): the step
+        before it was still unread on the device."""
+        self.steps_in_flight += in_flight
+
+    def on_rows(self, decoded: int, late: int) -> None:
+        """One decode step was read back: it advanced `decoded` rows,
+        `late` of them for requests that had already ended."""
+        self.rows_decoded += decoded
+        self.late_rows += late
+
     def on_gauges(self, queue_depth: int, live: int, capacity: int) -> None:
         """Sample the queue depth + slot occupancy (once per step)."""
         occupancy = live / capacity if capacity else 0.0
@@ -231,6 +249,10 @@ class ServeMetrics:
                                      ("occupancy", self.occupancy, 1)):
             for p in self.percentiles:
                 out[f"{name}_p{p:g}"] = percentile(samples, p) * scale
+        if self.rows_decoded:
+            out["steps_in_flight"] = self.steps_in_flight
+            out["late_rows"] = self.late_rows
+            out["late_row_share"] = self.late_rows / self.rows_decoded
         if self.pool_occupancy:
             for p in self.percentiles:
                 out[f"pool_occupancy_p{p:g}"] = percentile(

@@ -19,7 +19,7 @@ import numpy as np
 
 from ..observability import span
 from ..resilience import chaos
-from .engine import DecodeEngine
+from .engine import DecodeEngine, StepHandle
 from .metrics import ServeMetrics
 from .paged import PoolExhausted
 
@@ -27,7 +27,8 @@ logger = logging.getLogger(__name__)
 
 # One scheduler step as a span tree on the profiler's clock (the
 # engine's spans nest inside: serve/prefill_chunk, serve/table_upload,
-# serve/decode and its dispatch/readback children).
+# serve/decode and its dispatch child; the read-backs of the step before
+# it, serve/decode/readback and serve/prefill_chunk/readback, follow).
 SPAN_STEP = "serve/step"
 SPAN_ADMISSION = "serve/admission"
 SPAN_GAUGES = "serve/gauges"
@@ -70,6 +71,7 @@ class Request:
     finished_at: tp.Optional[float] = None
     finish_reason: tp.Optional[str] = None  # 'eos' | 'length' | 'expired'
     preemptions: int = 0  # times this request was evicted mid-flight
+    in_flight: int = 0  # tokens launched on the device, not yet delivered
 
     @property
     def done(self) -> bool:
@@ -98,6 +100,26 @@ class Request:
         budget a resumed admission still owes this request."""
         return self.max_new_tokens - len(self.generated)
 
+    @property
+    def launched_out(self) -> bool:
+        """Every token of the budget is delivered or on its way: known
+        by COUNT when the last step is dispatched, its values unread."""
+        return len(self.generated) + self.in_flight >= self.max_new_tokens
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """One launched step whose tokens nobody has read: the engine's
+    handles and the host's record of who owned which row AT LAUNCH — a
+    slot may have been handed to another request since."""
+    # (slot, request, the final slice's handle): first tokens, in order
+    firsts: tp.List[tp.Tuple[int, Request, StepHandle]] = dataclasses.field(
+        default_factory=list)
+    decode: tp.Optional[StepHandle] = None
+    # (slot, request) of every row the decode step advanced
+    rows: tp.List[tp.Tuple[int, Request]] = dataclasses.field(
+        default_factory=list)
+
 
 class ContinuousBatchingScheduler:
     """FIFO request queue feeding a DecodeEngine's slots.
@@ -107,6 +129,26 @@ class ContinuousBatchingScheduler:
     Decode never waits for admission and admission never waits for a
     batch boundary — capacity freed mid-stream is refilled on the next
     step while the other slots keep generating.
+
+    ONE STEP STAYS IN FLIGHT. A step launches its prefill slice and its
+    decode run on the device and only THEN reads the step before it
+    back (`engine.collect`): that one's device work ended while the
+    host admitted, planned and dispatched, and the device already has
+    its successor queued when it ends. So the tokens of step k reach
+    their requests during step k + 1 — to the requests that owned the
+    rows when step k was LAUNCHED (`_InFlight`), whoever holds the
+    slots by then. A request that ends by its budget is known to end by
+    count when its last step is launched: its slot is parked and freed
+    right then (`Request.launched_out`), no step later than in
+    lock-step. One that ends by EOS is seen a step late: its row
+    decodes once more, inside its own reservation, and that token is
+    dropped (`late_rows`). `flush()` reads what is in flight NOW, for a
+    caller that needs a request's tokens up to date between steps;
+    `preempt()`, `drain_for_reroute()` and `run()`'s end do it
+    themselves, and `idle` is False until it is done. With a `draft`
+    attached the step is lock-step instead: a draft proposes from the
+    last tokens' VALUES, so that step cannot be launched before they
+    are read.
 
     On a paged engine (`DecodeEngine(cache_layout='paged')`) admission
     additionally gates on BLOCK-POOL headroom: the queue head waits
@@ -203,6 +245,10 @@ class ContinuousBatchingScheduler:
         self.prefill_tokens_last_step = 0
         self.max_prefill_tokens_per_step = 0
         self.steps = 0  # scheduler steps taken (the `step` stat of SPAN_STEP)
+        self._in_flight: tp.Optional[_InFlight] = None  # launched, unread
+        self._step_start = time.perf_counter()  # top of the latest step
+        self._emitted = 0  # decode tokens delivered, all steps
+        self._late_rows = 0  # rows found late since the last step opened
 
     # ------------------------------------------------------------------
     # admission
@@ -218,7 +264,7 @@ class ContinuousBatchingScheduler:
     @property
     def idle(self) -> bool:
         return (not self._queue and not self._running
-                and not self._prefilling)
+                and not self._prefilling and self._in_flight is None)
 
     def submit(self, prompt: tp.Any, max_new_tokens: int,
                eos_token: tp.Optional[int] = None,
@@ -319,11 +365,13 @@ class ContinuousBatchingScheduler:
 
     def _first_token(self, slot: int, request: Request,
                      first: int) -> None:
-        """Prefill completed: record TTFT, seed the draft, and either
-        retire the request (EOS / budget of 1) or start decoding it.
-        A RESUMED request (preempted / re-routed after engine death)
-        lands here again when its prompt+generated re-prefill finishes;
-        its TTFT was already recorded, so only the token counts."""
+        """A first token reached its request: record TTFT, seed the
+        draft, and retire the request if this ends it (EOS / budget of
+        1). The caller has put the request in `_running` if it still
+        holds its slot. A RESUMED request (preempted / re-routed after
+        engine death) lands here again when its prompt+generated
+        re-prefill finishes; its TTFT was already recorded, so only the
+        token counts."""
         now = time.perf_counter()
         request.state = "running"
         request.generated.append(first)
@@ -338,11 +386,9 @@ class ContinuousBatchingScheduler:
             self._finish(request, "eos")
         elif len(request.generated) >= request.max_new_tokens:
             self._finish(request, "length")
-        else:
-            self._running[slot] = request
-            if self.draft is not None:
-                self.draft.begin(slot, request.prompt, first)
-                self._draft_slots.add(slot)
+        elif self.draft is not None:
+            self.draft.begin(slot, request.prompt, first)
+            self._draft_slots.add(slot)
 
     def _pop_next(self) -> Request:
         """Remove and return the next request to admit: the highest
@@ -362,6 +408,9 @@ class ContinuousBatchingScheduler:
         room for a blocked admission; returns whether a victim existed.
         The victim is the lowest-priority running request (most recent
         uid among ties — least sunk decode work by FIFO admission)."""
+        if all(r.priority >= priority for r in self._running.values()):
+            return False
+        self.flush()  # a candidate may just have finished
         victim: tp.Optional[Request] = None
         for request in self._running.values():
             if request.priority >= priority:
@@ -385,8 +434,12 @@ class ContinuousBatchingScheduler:
         at the front of its priority class and its next admission
         prefills `resume_prompt` with `remaining_budget` — token-exact
         continuation, since K/V rows are pure functions of
-        (token, position, params).
+        (token, position, params). What is in flight is read first, so
+        the victim keeps every token the device made for it; a slot
+        whose request that read just finished raises KeyError, like a
+        slot that runs nothing.
         """
+        self.flush()
         request = self._running.pop(slot)
         if slot in self._draft_slots:
             self._draft_slots.discard(slot)
@@ -454,7 +507,10 @@ class ContinuousBatchingScheduler:
         reset to 'queued' with generated tokens retained (running and
         prefilling first, by uid, then the queue in order); re-
         admission elsewhere prefills `resume_prompt`, which re-derives
-        the lost K/V exactly."""
+        the lost K/V exactly. Tokens the engine had already made when it
+        was declared dead are read first (`flush()`: a request they
+        complete is done, not drained)."""
+        self.flush()
         in_flight = sorted(
             list(self._running.values())
             + [entry[0] for entry in self._prefilling.values()],
@@ -534,7 +590,9 @@ class ContinuousBatchingScheduler:
             self.admitted_order.append(request.uid)
             admitted += 1
             if self.engine.chunk is None:
+                # the monolithic prefill waits for its own token
                 first = self.engine.prefill(slot, prompt)
+                self._running[slot] = request
                 self._first_token(slot, request, first)
             else:
                 # prefill resumes where the prefix cache left off
@@ -544,27 +602,41 @@ class ContinuousBatchingScheduler:
                 self._prefilling[slot] = [request, start, prompt]
         return admitted
 
-    def _advance_prefill(self) -> None:
+    def _advance_prefill(self, launched: _InFlight) -> None:
         """Advance chunked prefills by at most `prefill_chunks_per_step`
         slices across the in-progress prefills, oldest first (FIFO down
-        to the tick) — the bound on the stall a long prompt imposes."""
+        to the tick) — the bound on the stall a long prompt imposes. A
+        prompt's last slice joins `launched`: its first token is read
+        with the rest of this step, during the next."""
         self.prefill_tokens_last_step = 0
         budget = self.prefill_chunks_per_step
         for slot in list(self._prefilling):
             if budget <= 0:
                 break
             request, start, prompt = self._prefilling[slot]
-            new_start, first = self.engine.prefill_chunk(
+            new_start, handle = self.engine.dispatch_prefill_chunk(
                 slot, prompt, start, uid=request.uid)
             budget -= 1
             if self.tracing is not None:
                 self.tracing.on_prefill_chunk(request, start, new_start)
             self.prefill_tokens_last_step += new_start - start
-            if first is None:
+            if handle is None:
                 self._prefilling[slot][1] = new_start
+                continue
+            del self._prefilling[slot]
+            if self.draft is not None:  # lock-step: the draft needs it
+                self._running[slot] = request
+                self._first_token(slot, request,
+                                  int(self.engine.collect(handle)[0]))
+                continue
+            # the slice put the row live on the device: the decode run
+            # launched next carries it, unless its budget is this token
+            request.in_flight += 1
+            launched.firsts.append((slot, request, handle))
+            if request.launched_out:
+                self.engine.retire(slot)
             else:
-                del self._prefilling[slot]
-                self._first_token(slot, request, first)
+                self._running[slot] = request
         self.max_prefill_tokens_per_step = max(
             self.max_prefill_tokens_per_step, self.prefill_tokens_last_step)
 
@@ -575,10 +647,14 @@ class ContinuousBatchingScheduler:
         request.state = "done"
         request.finish_reason = reason
         request.finished_at = time.perf_counter()
-        self.engine.retire(request.slot)
-        if request.slot in self._draft_slots:
-            self._draft_slots.discard(request.slot)
-            self.draft.retire(request.slot)
+        if self._running.get(request.slot) is request:
+            # it still holds its slot (one launched out to its budget
+            # gave it back when its last step was dispatched)
+            del self._running[request.slot]
+            self.engine.retire(request.slot)
+            if request.slot in self._draft_slots:
+                self._draft_slots.discard(request.slot)
+                self.draft.retire(request.slot)
         self.metrics.on_done(request.finished_at - request.submitted_at,
                              reason, tenant=request.tenant,
                              tokens=len(request.generated))
@@ -588,7 +664,7 @@ class ContinuousBatchingScheduler:
                      request.uid, reason, request.prompt.size,
                      len(request.generated))
 
-    def _feed(self, slot: int, request: Request, tokens: tp.Sequence[int],
+    def _feed(self, request: Request, tokens: tp.Sequence[int],
               gap: float) -> tp.Tuple[int, bool]:
         """Append emitted tokens to a running request, stopping at EOS
         or the length budget; returns (#kept, finished). The first
@@ -602,18 +678,18 @@ class ContinuousBatchingScheduler:
             kept += 1
             self.metrics.on_token(gap if kept == 1 else 0.0)
             if request.eos_token is not None and token == request.eos_token:
-                del self._running[slot]
                 self._finish(request, "eos")
                 return kept, True
             if len(request.generated) >= request.max_new_tokens:
-                del self._running[slot]
                 self._finish(request, "length")
                 return kept, True
         return kept, False
 
     def step(self) -> int:
-        """Shed expired + admit/advance prefill + one decode (or
-        speculative verify) step + retire; returns #tokens emitted.
+        """Shed expired + admit/advance prefill + launch one decode step
+        + read the step before it back and retire what it finished (or,
+        with a draft, one lock-step speculative verify step); returns
+        the decode tokens it delivered to their requests.
 
         A crash anywhere in the step closes every in-flight request
         span first (`tracing.finalize('crashed')` — the finalize
@@ -627,20 +703,39 @@ class ContinuousBatchingScheduler:
                 self.tracing.finalize("crashed")
             raise
 
+    def flush(self) -> int:
+        """Read back the step in flight, if any, and deliver its tokens;
+        returns the decode tokens delivered. After it every request's
+        `generated` holds all the device has made for it and nothing is
+        in flight: the lock-step a caller gets by `step(); flush()`."""
+        before = self._emitted
+        self._collect()
+        return self._emitted - before
+
     def _step(self) -> int:
         # the ITL clock starts here: a token's gap is the whole step
-        # that produced it, the prefill slice the step carried included
-        step_start = time.perf_counter()
+        # that delivered it, the prefill slice the step carried included
+        self._step_start = time.perf_counter()
+        before = self._emitted
         tracer = self.engine.tracer
+        # `in_flight`: a step is unread as this one starts; `late_rows`:
+        # rows found late (decoded for a request that had ended) by the
+        # read-backs since the last step opened — a span's stats are
+        # fixed when it opens, and a read-back comes last in its step
+        in_flight = int(self._in_flight is not None)
+        late, self._late_rows = self._late_rows, 0
+        self.metrics.on_step(in_flight)
         with span(SPAN_STEP, tracer, category="serve", step=self.steps,
                   queued=len(self._queue), prefilling=len(self._prefilling),
-                  running=len(self._running)):
+                  running=len(self._running), in_flight=in_flight,
+                  late_rows=late):
             self.steps += 1
+            launched = _InFlight()
             with span(SPAN_ADMISSION, tracer, category="serve",
                       queued=len(self._queue)):
                 self._shed_expired()
                 self._admit()
-            self._advance_prefill()
+            self._advance_prefill(launched)
             with span(SPAN_GAUGES, tracer, category="serve"):
                 self.metrics.on_gauges(queue_depth=len(self._queue),
                                        live=self.engine.live_count,
@@ -653,38 +748,77 @@ class ContinuousBatchingScheduler:
                         capacity=int(pool["capacity"]),
                         cached=int(pool["cached"]),
                         bytes_per_token=pool["kv_bytes_per_token"])
-            if not self._running:
-                return 0
-            # inside the ITL-measured region on purpose: an injected
-            # delay here lands in the per-token `gap` the SLO engine
-            # samples, and an injected raise still unwinds through
-            # step()'s finalize
-            chaos.fault_point("serve.step", queue_depth=len(self._queue),
-                              live=len(self._running))
-            if self.draft is None:
-                tokens = self.engine.decode()
-                gap = time.perf_counter() - step_start
-                with span(SPAN_RETIRE, tracer, category="serve"):
-                    return self._retire_decoded(tokens, gap)
-            # speculative step: k drafted tokens per slot verified in
-            # ONE [S, k+1] call; each live slot emits accepted+1 tokens
-            # (EOS / budget may truncate the span — the engine slot is
-            # retired then, so the overshoot never lands anywhere).
-            drafts = self.draft.propose()
-            out, accepted = self.engine.decode_speculative(drafts)
-            gap = time.perf_counter() - step_start
-            with span(SPAN_RETIRE, tracer, category="serve"):
-                return self._retire_verified(drafts, out, accepted, gap)
+            if self._running:
+                # inside the ITL-measured region on purpose: an injected
+                # delay here lands in the per-token `gap` the SLO engine
+                # samples, and an injected raise still unwinds through
+                # step()'s finalize
+                chaos.fault_point("serve.step", queue_depth=len(self._queue),
+                                  live=len(self._running))
+                if self.draft is not None:
+                    return self._speculative_step()
+                self._launch_decode(launched)
+            # the step before this one ended on the device while the
+            # host did the above: read it, then leave this one in flight
+            self._collect()
+            if launched.firsts or launched.decode is not None:
+                self._in_flight = launched
+            return self._emitted - before
 
-    def _retire_decoded(self, tokens: np.ndarray, gap: float) -> int:
-        emitted = 0
-        for slot, request in list(self._running.items()):
-            kept, finished = self._feed(slot, request,
-                                        [int(tokens[slot])], gap)
-            emitted += kept
-            if not finished and self.tracing is not None:
-                self.tracing.on_step_tokens(request, kept)
-        return emitted
+    def _launch_decode(self, launched: _InFlight) -> None:
+        """Dispatch one decode step over the running rows and note who
+        owns them. A request whose budget this step completes gives its
+        slot back NOW, values unread: the parking is enqueued behind the
+        step, ahead of whatever the slot's next owner dispatches."""
+        launched.decode = self.engine.dispatch_decode()
+        launched.rows = list(self._running.items())
+        for slot, request in launched.rows:
+            request.in_flight += 1
+            if request.launched_out:
+                del self._running[slot]
+                self.engine.retire(slot)
+
+    def _collect(self) -> None:
+        """Read the step in flight back, if any, and deliver its tokens
+        to the requests that owned its rows at launch."""
+        landed, self._in_flight = self._in_flight, None
+        if landed is None:
+            return
+        for slot, request, handle in landed.firsts:
+            request.in_flight -= 1
+            self._first_token(slot, request,
+                              int(self.engine.collect(handle)[0]))
+        if landed.decode is None:
+            return
+        tokens = self.engine.collect(landed.decode)
+        gap = time.perf_counter() - self._step_start
+        late = 0
+        with span(SPAN_RETIRE, self.engine.tracer, category="serve"):
+            for slot, request in landed.rows:
+                request.in_flight -= 1
+                if request.done:
+                    # it ended by EOS a step ago, seen after this row
+                    # was launched: the token is nobody's
+                    late += 1
+                    continue
+                kept, finished = self._feed(request, [int(tokens[slot])],
+                                            gap)
+                self._emitted += kept
+                if not finished and self.tracing is not None:
+                    self.tracing.on_step_tokens(request, kept)
+        self._late_rows += late
+        self.metrics.on_rows(len(landed.rows), late)
+
+    def _speculative_step(self) -> int:
+        """k drafted tokens per slot verified in ONE [S, k+1] call, read
+        back at once; each live slot emits accepted+1 tokens (EOS /
+        budget may truncate the span — the engine slot is retired then,
+        so the overshoot never lands anywhere)."""
+        drafts = self.draft.propose()
+        out, accepted = self.engine.decode_speculative(drafts)
+        gap = time.perf_counter() - self._step_start
+        with span(SPAN_RETIRE, self.engine.tracer, category="serve"):
+            return self._retire_verified(drafts, out, accepted, gap)
 
     def _retire_verified(self, drafts: np.ndarray, out: np.ndarray,
                          accepted: np.ndarray, gap: float) -> int:
@@ -693,7 +827,7 @@ class ContinuousBatchingScheduler:
         for slot, request in list(self._running.items()):
             tokens = out[slot, :int(accepted[slot]) + 1]
             accepted_counts.append(int(accepted[slot]))
-            kept, finished = self._feed(slot, request, tokens, gap)
+            kept, finished = self._feed(request, tokens, gap)
             emitted += kept
             if not finished:
                 if self.tracing is not None:

@@ -11,9 +11,15 @@
 #   JAX_PLATFORMS=cpu python tests/data/record_untouched_pool_programs.py \
 #       /tmp/parent tests/data/untouched_pool_programs.json
 #
-# The file in the repo was taken from commit 61bbbe0 (PR 29), before the
-# first edit of PR 30. Record it anew only when a PR means to change
-# what a pool without scales compiles to.
+# The file in the repo was first taken from commit 61bbbe0 (PR 29),
+# before the first edit of PR 30. Record it anew only when a PR means to
+# change what a pool without scales compiles to. PR 34 did, on its own
+# finished tree (the parent cannot lower the new argument list): the
+# decode step returns the advanced positions beside the tokens, and a
+# prefill slice takes `(tokens, positions, active)` and `final` and
+# returns the three with row `slot` put live, so that the next step can
+# be dispatched before this one is read. `verify` and `copy_block` came
+# out byte-equal to PR 29's, as they must: nothing of theirs moved.
 """Record the lowered programs of engines whose pools hold no scales."""
 import hashlib
 import json
@@ -86,7 +92,7 @@ def programs() -> dict:
                     engine._params, engine._cache, table,
                     jnp.full((1, size), engine.pad_token, jnp.int32),
                     jnp.int32(0), jnp.int32(1), jnp.int32(0),
-                    engine._next_key())
+                    engine._next_key(), slot_args[:3], jnp.bool_(False))
         for program, low in lowered.items():
             out[f"{name}/{program}"] = hashlib.sha256(
                 low.as_text().encode()).hexdigest()

@@ -580,9 +580,11 @@ def test_programs_of_pools_without_scales_are_untouched(untouched_programs,
                                                         engine):
     # the lowered text of decode, both prefill slices, verify and the
     # COW copy of a latent engine and of K/V engines in the model's
-    # dtype, byte-equal (by sha256) to what commit 61bbbe0 lowered —
-    # recorded before PR 30's first edit by
-    # tests/data/record_untouched_pool_programs.py
+    # dtype, byte-equal (by sha256) to what was recorded by
+    # tests/data/record_untouched_pool_programs.py: verify and the copy
+    # as commit 61bbbe0 lowered them before PR 30's first edit; decode
+    # and the slices as PR 34 left them (they return the advanced slot
+    # state: the recorder's header says why)
     import os
     recorded = os.path.join(os.path.dirname(__file__), "data",
                             "untouched_pool_programs.json")

@@ -95,29 +95,44 @@ def _drain(scheduler):
 
 def test_scheduler_step_span_tree(recorder, paged):
     """One request of 6 prompt tokens through chunk 4: slice, final
-    slice + first decode, decode — the tree of each step, exactly."""
+    slice + first decode, decode, and a last step that only reads — the
+    tree of each step, exactly: a step dispatches its own work, then
+    reads the step before it."""
     scheduler = ContinuousBatchingScheduler(paged)
-    scheduler.submit(np.arange(1, 7, dtype=np.int32), 3)
-    emitted = [scheduler.step() for _ in range(3)]
-    assert emitted == [0, 1, 1] and scheduler.idle
+    handle = scheduler.submit(np.arange(1, 7, dtype=np.int32), 3)
+    emitted, had = [], []
+    for _ in range(4):
+        assert not scheduler.idle
+        emitted.append(scheduler.step())
+        had.append(len(handle.generated))
+    # the first token is no decode token: a step returns what it fed
+    assert emitted == [0, 0, 1, 1] and had == [0, 0, 2, 3]
+    assert scheduler.idle
     head = [(0, "serve/step"), (1, "serve/admission")]
-    decode = [(1, "serve/decode"), (2, "serve/decode/dispatch"),
-              (2, "serve/decode/readback"), (1, "serve/retire")]
+    dispatch = [(1, "serve/decode"), (2, "serve/decode/dispatch")]
+    collect = [(1, "serve/decode/readback"), (1, "serve/retire")]
     # step 0: admitted (tables changed), first slice, nothing decodes yet
     assert _tree(recorder.entered, 0) == head + [
         (1, "serve/prefill_chunk"), (2, "serve/table_upload"),
         (1, "serve/gauges")]
-    # step 1: final slice reads the first token back; the slot decodes
+    # step 1: the final slice puts the row live on the device, the slot
+    # decodes behind it; nothing is read
     assert _tree(recorder.entered, 1) == head + [
-        (1, "serve/prefill_chunk"), (2, "serve/prefill_chunk/readback"),
-        (1, "serve/gauges")] + decode
-    # step 2: decode only; the last token retires the request
-    assert _tree(recorder.entered, 2) == head + [(1, "serve/gauges")] + decode
+        (1, "serve/prefill_chunk"), (1, "serve/gauges")] + dispatch
+    # step 2: the last decode (the budget's, by count) is dispatched,
+    # THEN step 1 is read: its first token, its decode token
+    assert _tree(recorder.entered, 2) == head + [
+        (1, "serve/gauges")] + dispatch + [
+        (1, "serve/prefill_chunk/readback")] + collect
+    # step 3: nothing left to launch; step 2 is read, the request ends
+    assert _tree(recorder.entered, 3) == head + [(1, "serve/gauges")] + collect
     stats = [s for _, name, s in recorder.entered if name == "serve/step"]
-    assert [s["step"] for s in stats] == [scheduler.steps - 3 + i
-                                          for i in range(3)]
+    assert [s["step"] for s in stats] == [scheduler.steps - 4 + i
+                                          for i in range(4)]
     assert (stats[0]["queued"], stats[1]["prefilling"],
             stats[2]["running"]) == (1, 1, 1)
+    assert [s["in_flight"] for s in stats] == [0, 0, 1, 1]
+    assert [s["late_rows"] for s in stats] == [0, 0, 0, 0]
     assert not recorder.open
 
 
@@ -133,8 +148,11 @@ def test_decode_running_stat_is_the_tokens_emitted(recorder, paged):
                    if name == "serve/decode"]
         per_step.append((emitted, running))
     assert any(emitted == 2 for emitted, _ in per_step)
-    for emitted, running in per_step:
-        # `live` counts acquired slots; `running` those that emit a token
+    # `live` counts acquired slots; `running` those that emit a token:
+    # the tokens the NEXT step delivers, when it reads this one
+    launched = [running for _, running in per_step]
+    delivered = [emitted for emitted, _ in per_step[1:]] + [0]
+    for running, emitted in zip(launched, delivered):
         assert running == ([emitted] if emitted else [])
 
 
@@ -200,9 +218,9 @@ def test_table_upload_only_after_the_tables_changed(recorder, paged):
         uploads.append(sum(name == "serve/table_upload"
                            for _, name, _ in recorder.entered[before:]))
     # admission dirtied the tables once (the first step prefills and
-    # decodes); four more decode steps reuse the device copy;
-    # retirement dirties them for whoever comes next
-    assert uploads == [1, 0, 0, 0, 0]
+    # decodes); four more decode steps reuse the device copy, the last
+    # step only reads; retirement dirties them for whoever comes next
+    assert uploads == [1, 0, 0, 0, 0, 0]
     scheduler.submit(np.arange(1, 4, dtype=np.int32), 1)
     scheduler.step()
     upload = [s for _, name, s in recorder.entered

@@ -349,8 +349,10 @@ def test_chunked_prefill_interleaves_with_decode():
     scheduler = ContinuousBatchingScheduler(engine)
     short = scheduler.submit(np.asarray([1, 2, 3], np.int32),
                              max_new_tokens=16)
-    scheduler.step()
-    assert short.state == "running"
+    scheduler.step()  # launches the slice and the first decode step
+    assert short.state == "prefilling" and not scheduler.idle
+    scheduler.step()  # ... and reads them while the next one runs
+    assert short.state == "running" and len(short.generated) == 2
     long = scheduler.submit((np.arange(3 * chunk + 2) % 32)
                             .astype(np.int32), max_new_tokens=2)
     ticks = 0

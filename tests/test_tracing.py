@@ -124,7 +124,11 @@ def test_crash_mid_step_closes_every_inflight_span(tmp_path):
     scheduler, tracing, tracer = _traced_scheduler(tmp_path)
     prompt = np.arange(4, dtype=np.int32) % 32
     handles = [scheduler.submit(prompt, max_new_tokens=8) for _ in range(2)]
-    scheduler.step()  # admit + first tokens
+    scheduler.step()  # admit + first tokens; the first decode is launched
+    # one step stays in flight: the crash below finds it unread
+    assert not scheduler.idle
+    assert [len(h.generated) for h in handles] == [1, 1]
+    assert [h.in_flight for h in handles] == [1, 1]
 
     injector = chaos.install()
     injector.act_at("serve.step", call=injector.counts.get("serve.step", 0)
