@@ -101,6 +101,11 @@ def format_serve_status(status: dict) -> str:
                            and isinstance(status[k], (int, float))),
                           key=lambda k: float(k.rsplit("_p", 1)[1])):
             parts.append(f"{key}={status[key]:.1f}")
+    if status.get("slow_steps"):
+        # scheduler steps over five times the running median and 50 ms
+        # (ServeMetrics.on_step_end; each is a WARNING line in the log)
+        parts.append(f"slow_steps={int(status['slow_steps'])}")
+        parts.append(f"slowest_step_ms={status['slowest_step_ms']:.1f}")
     if status.get("slo", {}).get("alerting"):
         burning = [name for name, entry
                    in status["slo"].get("budgets", {}).items()

@@ -57,7 +57,7 @@ def serve_formatter() -> Formatter:
         "requests": "d", "completed": "d", "rejected": "d", "expired": "d",
         "tokens": "d", "finish_*": "d",
         "spec_drafted": "d", "spec_emitted": "d",
-        "steps_in_flight": "d", "late_rows": "d",
+        "steps_in_flight": "d", "late_rows": "d", "slow_steps": "d",
         "late_row_share": lambda value: f"{value * 100:.2f}%",
     })
 

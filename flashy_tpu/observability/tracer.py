@@ -90,10 +90,21 @@ class JsonlJournal:
             self._file = None
 
 
+class _Open(threading.local):
+    """What the span primitive keeps a thread: the dict of the
+    `phases=` span open on it, and how many spans are open inside."""
+    phases: tp.Optional[tp.Dict[str, float]] = None
+    depth = 0
+
+
+_open = _Open()
+
+
 @contextmanager
 def span(name: str, tracer: tp.Optional["Tracer"] = None,
-         category: str = "host", **stats: tp.Any):
-    """THE span primitive: one named host interval, two sinks.
+         category: str = "host",
+         phases: tp.Optional[tp.Dict[str, float]] = None, **stats: tp.Any):
+    """THE span primitive: one named host interval, three sinks.
 
     It always enters `jax.profiler.TraceAnnotation(name, **stats)`: a
     few hundred nanoseconds while no profiler session runs, and under
@@ -102,7 +113,11 @@ def span(name: str, tracer: tp.Optional["Tracer"] = None,
     device's `XLA Ops` — so device idle time can be laid against the
     host phase that caused it. When `tracer` (or, if None, the active
     telemetry's tracer) exists, the same name and stats are also
-    recorded as a Chrome 'X' event, as `Tracer.span` always did.
+    recorded as a Chrome 'X' event, as `Tracer.span` always did. A
+    span given a dict as `phases` collects into it, from the same two
+    clock reads: its own seconds under its own name when it closes, and
+    before that the seconds of every span opened DIRECTLY inside it, on
+    its thread, summed by name (the scheduler's record of a slow step).
     Yields that tracer (or None). Names follow the `sub/name` track
     convention (FT006's `TRACK_RE`).
     """
@@ -111,14 +126,24 @@ def span(name: str, tracer: tp.Optional["Tracer"] = None,
         from .telemetry import get_telemetry
         telemetry = get_telemetry()
         tracer = telemetry.tracer if telemetry is not None else None
+    into, depth = _open.phases, _open.depth
+    if phases is not None:
+        _open.phases, _open.depth = phases, 0
+    elif into is not None:
+        _open.depth = depth + 1
     start = time.perf_counter()
     with jax.profiler.TraceAnnotation(name, **stats):
         try:
             yield tracer
         finally:
+            took = time.perf_counter() - start
             if tracer is not None:
-                tracer.complete(name, start, time.perf_counter() - start,
-                                category=category, **stats)
+                tracer.complete(name, start, took, category=category, **stats)
+            _open.phases, _open.depth = into, depth
+            if phases is not None:
+                phases[name] = took
+            elif into is not None and depth == 0:
+                into[name] = into.get(name, 0.0) + took
 
 
 class Tracer:

@@ -55,7 +55,7 @@ from .engine import (  # noqa
 )
 from .metrics import (  # noqa
     ServeMetrics, percentile, COUNTER_QUEUE, COUNTER_OCCUPANCY,
-    COUNTER_ACCEPTANCE, COUNTER_POOL, COUNTER_PREFIX, COUNTER_KV_BYTES,
+    COUNTER_ACCEPTANCE, COUNTER_POOL, COUNTER_PREFIX,
 )
 from .paged import (  # noqa
     BlockPool, PoolExhausted, PrefixIndex, POOL_FAULT_SITE,
@@ -70,5 +70,4 @@ __all__ = [
     "percentile", "SPAN_DECODE", "SPAN_PREFILL", "SPAN_PREFILL_CHUNK",
     "SPAN_VERIFY", "COUNTER_QUEUE", "COUNTER_OCCUPANCY",
     "COUNTER_ACCEPTANCE", "COUNTER_POOL", "COUNTER_PREFIX",
-    "COUNTER_KV_BYTES",
 ]

@@ -12,6 +12,7 @@
 # shapes), so the whole serving lifetime still touches a fixed,
 # pre-warmable set of compiled shapes.
 """DecodeEngine: fixed-slot KV cache + static-shape decode/verify steps."""
+import gc
 import logging
 import typing as tp
 
@@ -45,6 +46,15 @@ class StepHandle(tp.NamedTuple):
     span: str     # SPAN_DECODE | SPAN_PREFILL_CHUNK: whose children read it
     read: tp.Any  # int32 device array: the tokens, the expert counts behind
     tap: tp.Any   # the float32 logits they were taken from, or None
+    # the `step` stat of the serve/step that dispatched it (None by
+    # hand): its read-back, a scheduler step later, carries the same
+    step: tp.Optional[int] = None
+
+
+def _step_stat(step: tp.Optional[int]) -> tp.Dict[str, int]:
+    """The `step` stat of a dispatch span and of the read-back that
+    belongs to it: the scheduler's step number, nothing by hand."""
+    return {} if step is None else {"step": step}
 
 
 def _zero_ssd_leaves(cache: tp.Any, fresh: tp.Any) -> tp.Any:
@@ -1067,6 +1077,11 @@ class DecodeEngine:
         self._positions_host = np.full((self.slots,), self.max_seq_len,
                                        np.int64)
         self._active_host = np.zeros((self.slots,), bool)
+        # what imports, tracing and compiling left is collected NOW:
+        # left alone, the interpreter's next full collection lands on a
+        # live step a few hundred steps into traffic (80-110 ms there
+        # beside a 13 ms step: PR 35's slow-step record, every run)
+        gc.collect()
         logger.info("serve warm-up done: %d executables (%s)",
                     len(self.compile_cache), ", ".join(warmed))
 
@@ -1225,18 +1240,20 @@ class DecodeEngine:
         return first
 
     def prefill_chunk(self, slot: int, prompt: np.ndarray, start: int,
-                      uid: tp.Optional[int] = None
+                      uid: tp.Optional[int] = None,
+                      step: tp.Optional[int] = None
                       ) -> tp.Tuple[int, tp.Optional[int]]:
         """Advance `slot`'s prefill by ONE fixed-size slice and wait for
         it where it is the last: `dispatch_prefill_chunk()` followed by
         `collect()`. Returns `(next_start, first_token)`; `first_token`
         is None until the final slice."""
         start, handle = self.dispatch_prefill_chunk(slot, prompt, start,
-                                                    uid=uid)
+                                                    uid=uid, step=step)
         return start, None if handle is None else int(self.collect(handle)[0])
 
     def dispatch_prefill_chunk(self, slot: int, prompt: np.ndarray,
-                               start: int, uid: tp.Optional[int] = None
+                               start: int, uid: tp.Optional[int] = None,
+                               step: tp.Optional[int] = None
                                ) -> tp.Tuple[int, tp.Optional[StepHandle]]:
         """Enqueue ONE fixed-size slice of `slot`'s prefill; waits for
         nothing.
@@ -1251,8 +1268,10 @@ class DecodeEngine:
         `collect(handle)[0]` is that first token. The
         scheduler interleaves these ticks with decode steps, bounding
         the stall a long prompt can impose on live slots to one
-        slice's compute. `uid` (the scheduler's request id) only rides
-        on the span, so a request's slices can be followed in a trace.
+        slice's compute. `uid` (the scheduler's request id) and `step`
+        (its step number) only ride on the span, so a request's slices
+        can be followed in a trace and a read-back paired with the
+        dispatch it reads.
         """
         import jax.numpy as jnp
         if self.chunk is None:
@@ -1282,6 +1301,7 @@ class DecodeEngine:
             self._key("prefill_chunk", size),
             lambda: self._build_prefill_chunk(size))
         stats = {} if uid is None else {"uid": uid}
+        stats.update(_step_stat(step))
         stats.update(self._kv_read_stats(size, [start]))
         stats.update(self._state_stats(1))
         with span(SPAN_PREFILL_CHUNK, self.tracer, category="serve",
@@ -1305,7 +1325,8 @@ class DecodeEngine:
                 self._pool.on_live(self.pool_key(slot))
         tap = packed.pop() if self.keep_logits else None
         return start + used, StepHandle(SPAN_PREFILL_CHUNK,
-                                        packed[0] if packed else first, tap)
+                                        packed[0] if packed else first, tap,
+                                        step)
 
     def collect(self, handle: StepHandle) -> np.ndarray:
         """Wait for a dispatched step and read it back: a decode step's
@@ -1314,7 +1335,7 @@ class DecodeEngine:
         `tapped[...]` now: `tapped` holds the logits of the tokens most
         recently read, not of a step still in flight."""
         with span(handle.span + SPAN_READBACK, self.tracer,
-                  category="serve"):
+                  category="serve", **_step_stat(handle.step)):
             read = np.asarray(handle.read).reshape(-1)
         if self._moe_stats:
             self._moe_span(handle.span, read[-2:])
@@ -1393,38 +1414,42 @@ class DecodeEngine:
             return {}
         return {"ssm_state_bytes": 2 * rows * self._state_row_bytes}
 
-    def decode(self) -> np.ndarray:
+    def decode(self, step: tp.Optional[int] = None) -> np.ndarray:
         """One [S, 1] decode step over every slot, waited for:
         `dispatch_decode()` followed by `collect()`. Returns the [S]
         next tokens (pad_token on inactive slots)."""
-        return self.collect(self.dispatch_decode())
+        return self.collect(self.dispatch_decode(step=step))
 
-    def dispatch_decode(self) -> StepHandle:
+    def dispatch_decode(self, step: tp.Optional[int] = None) -> StepHandle:
         """Enqueue one [S, 1] decode step over every slot; waits for
         nothing. Always the same compiled executable, whatever the live
         mix. The step feeds each live slot its own token back and
         advances its position on the device, so the next step (or the
         next slice) can be dispatched before `collect(handle)` reads
-        this one's tokens."""
+        this one's tokens. `step` (the scheduler's step number) rides
+        on the spans and the handle, so the read-back says which
+        dispatch it read."""
         fn = self.compile_cache.get(self._key("decode", self.slots),
                                     self._build_decode)
+        numbered = _step_stat(step)
         with span(SPAN_DECODE, self.tracer, category="serve",
                   live=self.allocator.live_count,
-                  running=int(self._active_host.sum()),
+                  running=int(self._active_host.sum()), **numbered,
                   **self._kv_read_stats(1, self._positions_host),
                   **self._state_stats(int(self._active_host.sum()))):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_DECODE + SPAN_DISPATCH, self.tracer,
-                      category="serve"):
+                      category="serve", **numbered):
                 self._tokens, self._cache, self._positions, *packed = fn(
                     self._params, self._cache, *layout, self._tokens,
                     self._positions, self._active, key)
             self._positions_host += self._active_host
         tap = packed.pop() if self.keep_logits else None
         return StepHandle(SPAN_DECODE, packed[0] if packed else self._tokens,
-                          tap)
+                          tap, step)
 
-    def decode_speculative(self, drafts: np.ndarray
+    def decode_speculative(self, drafts: np.ndarray,
+                           step: tp.Optional[int] = None
                            ) -> tp.Tuple[np.ndarray, np.ndarray]:
         """One `[S, k+1]` verify step over every slot against `drafts`
         ([S, k] proposed tokens; inactive rows ignored).
@@ -1437,7 +1462,9 @@ class DecodeEngine:
         tokens; see `models.decoding.speculative_acceptance`. Rollback
         after rejection is free: the step advances each slot's
         position by accepted+1, and the stale draft K/V rows beyond it
-        are past every causal horizon until overwritten.
+        are past every causal horizon until overwritten. `step` (the
+        scheduler's step number) rides on `serve/verify` and its
+        read-back.
         """
         import jax.numpy as jnp
         if self.cache_layout == "ssd":
@@ -1453,19 +1480,20 @@ class DecodeEngine:
         k = int(drafts.shape[1])
         fn = self.compile_cache.get(self._key("verify", self.slots, k),
                                     lambda: self._build_verify(k))
+        numbered = _step_stat(step)
         with span(SPAN_VERIFY, self.tracer, category="serve", k=k,
                   live=self.allocator.live_count,
-                  running=int(self._active_host.sum()),
+                  running=int(self._active_host.sum()), **numbered,
                   **self._kv_read_stats(k + 1, self._positions_host)):
             layout, key = self._layout_args(), self._next_key()
             with span(SPAN_VERIFY + SPAN_DISPATCH, self.tracer,
-                      category="serve"):
+                      category="serve", **numbered):
                 (out, accepted, self._tokens, self._positions,
                  self._cache) = fn(
                     self._params, self._cache, *layout, self._tokens,
                     jnp.asarray(drafts), self._positions, self._active, key)
             with span(SPAN_VERIFY + SPAN_READBACK, self.tracer,
-                      category="serve"):
+                      category="serve", **numbered):
                 out_np = np.asarray(out)
                 accepted_np = np.asarray(accepted)
             if self._moe_stats:

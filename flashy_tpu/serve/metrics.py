@@ -10,7 +10,12 @@
 # every experiment logging backend, and snapshotted to `serve.json` in
 # the XP folder for `python -m flashy_tpu.info`.
 """ServeMetrics: TTFT / ITL / queue depth / occupancy / acceptance."""
+import collections
+import gc
 import json
+import logging
+import statistics
+import time
 import typing as tp
 from pathlib import Path
 
@@ -24,7 +29,43 @@ COUNTER_OCCUPANCY = "serve/slot_occupancy"
 COUNTER_ACCEPTANCE = "serve/acceptance"
 COUNTER_POOL = "serve/pool_occupancy"
 COUNTER_PREFIX = "serve/prefix_hit"
-COUNTER_KV_BYTES = "serve/kv_bytes_per_token"
+# the scheduler's step span: `on_step_end` is handed what it collected
+SPAN_STEP = "serve/step"
+
+logger = logging.getLogger(__name__)
+
+# A scheduler step is SLOW when its wall time exceeds both: this many
+# times the median of the steps before it (the last SLOW_STEP_WINDOW of
+# them, once SLOW_STEP_HISTORY have been seen: a median of three says
+# nothing), and the floor below which nobody would go looking.
+SLOW_STEP_FACTOR = 5.0
+SLOW_STEP_FLOOR = 0.050  # seconds
+SLOW_STEP_WINDOW = 128
+SLOW_STEP_HISTORY = 16
+SLOW_STEP_RECORDS = 16  # slow steps kept, the oldest dropped
+
+# The interpreter's collections, for the whole process: seconds spent in
+# them, how many, and when the one now running began. One `gc.callbacks`
+# hook, added at the first `gc_totals()`; it costs nothing between
+# collections.
+_gc = [0.0, 0, 0.0]
+
+
+def _on_gc(phase: str, info: tp.Dict[str, int]) -> None:
+    if phase == "start":
+        _gc[2] = time.perf_counter()
+    else:
+        _gc[0] += time.perf_counter() - _gc[2]
+        _gc[1] += 1
+
+
+def gc_totals() -> tp.Tuple[float, int]:
+    """(seconds inside garbage collections, collections) of this
+    process since the first call: read at both ends of an interval, the
+    difference is what the collector took of it."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    return _gc[0], _gc[1]
 
 
 class ServeMetrics:
@@ -87,7 +128,6 @@ class ServeMetrics:
         self.accepted_per_step: tp.List[int] = []
         # paged KV cache: block-pool occupancy + prefix-cache hits
         self.pool_occupancy: tp.List[float] = []
-        self.kv_bytes_per_token: tp.List[float] = []
         self.prefix_matched_tokens = 0
         self.prefix_prompt_tokens = 0
         self.prefix_admissions = 0
@@ -99,6 +139,15 @@ class ServeMetrics:
         self.steps_in_flight = 0
         self.rows_decoded = 0
         self.late_rows = 0
+        # slow steps (`on_step_end`): how many, the slowest, the last
+        # SLOW_STEP_RECORDS of them whole; judged against the wall times
+        # of the steps before
+        self.slow_steps = 0
+        self.slowest_step = 0.0
+        self.slow_step_records: tp.Deque[tp.Dict[str, tp.Any]] = \
+            collections.deque(maxlen=SLOW_STEP_RECORDS)
+        self._step_walls: tp.Deque[float] = collections.deque(
+            maxlen=SLOW_STEP_WINDOW)
 
     # ------------------------------------------------------------------
     # scheduler hooks
@@ -195,22 +244,61 @@ class ServeMetrics:
                 / self.prefix_prompt_tokens)
 
     def on_pool(self, occupancy: float, in_use: int, capacity: int,
-                cached: int, bytes_per_token: float) -> None:
+                cached: int) -> None:
         """Sample the block pool (once per step, paged layout only)."""
         self.pool_occupancy.append(occupancy)
-        if bytes_per_token > 0:
-            self.kv_bytes_per_token.append(bytes_per_token)
         if self.tracer is not None:
             self.tracer.counter(COUNTER_POOL, in_use=in_use,
                                 cached=cached, occupancy=occupancy)
-            if bytes_per_token > 0:
-                self.tracer.counter(COUNTER_KV_BYTES,
-                                    bytes=bytes_per_token)
 
     def on_step(self, in_flight: int) -> None:
         """One scheduler step began; `in_flight` (0 | 1): the step
         before it was still unread on the device."""
         self.steps_in_flight += in_flight
+
+    def on_step_end(self, step: int, phases: tp.Dict[str, float],
+                    cpu_seconds: float, gc_seconds: float,
+                    gc_collections: int, **state: int) -> None:
+        """One scheduler step ended. `phases`: what its `serve/step`
+        span collected (`observability.span(phases=)`: the step's own
+        seconds under its own name, the seconds in each direct child by
+        name); `cpu_seconds`: `time.thread_time()` over it, which tells
+        a host that worked from one that was blocked or descheduled;
+        the collector's part of it; `state`: the scheduler's counts as
+        the step began (`in_flight`, `queued`, `prefilling`,
+        `running`). A slow step (SLOW_STEP_*) is counted, kept whole in
+        `slow_step_records`, logged once at WARNING and journaled as
+        `serve_slow_step`; any other step costs an append."""
+        wall = phases[SPAN_STEP]
+        walls = self._step_walls
+        if wall > SLOW_STEP_FLOOR and len(walls) >= SLOW_STEP_HISTORY:
+            median = statistics.median(walls)
+            if wall > SLOW_STEP_FACTOR * median:
+                # under the step's own name: what no child of it covers
+                own = wall - (sum(phases.values()) - wall)
+                spent = {name: seconds * 1e3 for name, seconds in sorted(
+                    {**phases, SPAN_STEP: own}.items(),
+                    key=lambda item: -item[1])}
+                record = {"step": step, "wall_ms": wall * 1e3,
+                          "cpu_ms": cpu_seconds * 1e3,
+                          "median_ms": median * 1e3, "phases": spent,
+                          "gc_ms": gc_seconds * 1e3,
+                          "gc_collections": gc_collections, **state}
+                self.slow_steps += 1
+                self.slowest_step = max(self.slowest_step, wall)
+                self.slow_step_records.append(record)
+                logger.warning(
+                    "serve: step %d took %.1f ms (median %.1f, cpu %.1f): "
+                    "%s; gc %d in %.1f ms; %s", step, record["wall_ms"],
+                    record["median_ms"], record["cpu_ms"],
+                    ", ".join(f"{name} {ms:.1f}"
+                              for name, ms in spent.items()),
+                    gc_collections, record["gc_ms"],
+                    ", ".join(f"{key} {value}"
+                              for key, value in state.items()))
+                if self.tracer is not None:
+                    self.tracer.record({"type": "serve_slow_step", **record})
+        walls.append(wall)
 
     def on_rows(self, decoded: int, late: int) -> None:
         """One decode step was read back: it advanced `decoded` rows,
@@ -240,6 +328,8 @@ class ServeMetrics:
             "expired": self.expired,
             "preempted": self.preempted,
             "tokens": self.tokens,
+            "slow_steps": self.slow_steps,
+            "slowest_step_ms": self.slowest_step * 1e3,
         }
         for name, samples, scale in (("ttft_ms", self.ttft, 1e3),
                                      ("itl_ms", self.itl, 1e3),
@@ -257,9 +347,6 @@ class ServeMetrics:
             for p in self.percentiles:
                 out[f"pool_occupancy_p{p:g}"] = percentile(
                     self.pool_occupancy, p)
-        if self.kv_bytes_per_token:
-            out["kv_bytes_per_token_p50"] = percentile(
-                self.kv_bytes_per_token, 50)
         if self.prefix_admissions:
             out["prefix_hit_rate"] = (
                 self.prefix_matched_tokens / self.prefix_prompt_tokens

@@ -20,7 +20,7 @@ import numpy as np
 from ..observability import span
 from ..resilience import chaos
 from .engine import DecodeEngine, StepHandle
-from .metrics import ServeMetrics
+from .metrics import SPAN_STEP, ServeMetrics, gc_totals
 from .paged import PoolExhausted
 
 logger = logging.getLogger(__name__)
@@ -29,10 +29,14 @@ logger = logging.getLogger(__name__)
 # engine's spans nest inside: serve/prefill_chunk, serve/table_upload,
 # serve/decode and its dispatch child; the read-backs of the step before
 # it, serve/decode/readback and serve/prefill_chunk/readback, follow).
-SPAN_STEP = "serve/step"
+# SPAN_STEP is serve/metrics.py's: the step's record is judged there.
 SPAN_ADMISSION = "serve/admission"
 SPAN_GAUGES = "serve/gauges"
 SPAN_RETIRE = "serve/retire"
+# the slots given back by count as their budget's last step is launched
+# (`rows`: how many), and the bookkeeping of a first token just read
+SPAN_LAUNCH_OUT = "serve/launch_out"
+SPAN_FIRST_TOKEN = "serve/first_token"
 
 
 class QueueFull(RuntimeError):
@@ -112,6 +116,7 @@ class _InFlight:
     """One launched step whose tokens nobody has read: the engine's
     handles and the host's record of who owned which row AT LAUNCH — a
     slot may have been handed to another request since."""
+    step: int  # the `step` stat of the serve/step that launched it
     # (slot, request, the final slice's handle): first tokens, in order
     firsts: tp.List[tp.Tuple[int, Request, StepHandle]] = dataclasses.field(
         default_factory=list)
@@ -390,6 +395,15 @@ class ContinuousBatchingScheduler:
             self.draft.begin(slot, request.prompt, first)
             self._draft_slots.add(slot)
 
+    def _read_first(self, slot: int, request: Request,
+                    handle: StepHandle) -> None:
+        """Read a final slice's first token (the engine's read-back
+        span), then do its bookkeeping under a span of its own."""
+        first = int(self.engine.collect(handle)[0])
+        with span(SPAN_FIRST_TOKEN, self.engine.tracer, category="serve",
+                  slot=slot):
+            self._first_token(slot, request, first)
+
     def _pop_next(self) -> Request:
         """Remove and return the next request to admit: the highest
         `priority`, earliest-queued among equals — so an all-default
@@ -615,7 +629,7 @@ class ContinuousBatchingScheduler:
                 break
             request, start, prompt = self._prefilling[slot]
             new_start, handle = self.engine.dispatch_prefill_chunk(
-                slot, prompt, start, uid=request.uid)
+                slot, prompt, start, uid=request.uid, step=launched.step)
             budget -= 1
             if self.tracing is not None:
                 self.tracing.on_prefill_chunk(request, start, new_start)
@@ -626,15 +640,16 @@ class ContinuousBatchingScheduler:
             del self._prefilling[slot]
             if self.draft is not None:  # lock-step: the draft needs it
                 self._running[slot] = request
-                self._first_token(slot, request,
-                                  int(self.engine.collect(handle)[0]))
+                self._read_first(slot, request, handle)
                 continue
             # the slice put the row live on the device: the decode run
             # launched next carries it, unless its budget is this token
             request.in_flight += 1
             launched.firsts.append((slot, request, handle))
             if request.launched_out:
-                self.engine.retire(slot)
+                with span(SPAN_LAUNCH_OUT, self.engine.tracer,
+                          category="serve", rows=1):
+                    self.engine.retire(slot)
             else:
                 self._running[slot] = request
         self.max_prefill_tokens_per_step = max(
@@ -716,67 +731,86 @@ class ContinuousBatchingScheduler:
         # the ITL clock starts here: a token's gap is the whole step
         # that delivered it, the prefill slice the step carried included
         self._step_start = time.perf_counter()
-        before = self._emitted
-        tracer = self.engine.tracer
         # `in_flight`: a step is unread as this one starts; `late_rows`:
         # rows found late (decoded for a request that had ended) by the
         # read-backs since the last step opened — a span's stats are
         # fixed when it opens, and a read-back comes last in its step
-        in_flight = int(self._in_flight is not None)
+        state = {"in_flight": int(self._in_flight is not None),
+                 "queued": len(self._queue),
+                 "prefilling": len(self._prefilling),
+                 "running": len(self._running)}
         late, self._late_rows = self._late_rows, 0
-        self.metrics.on_step(in_flight)
-        with span(SPAN_STEP, tracer, category="serve", step=self.steps,
-                  queued=len(self._queue), prefilling=len(self._prefilling),
-                  running=len(self._running), in_flight=in_flight,
-                  late_rows=late):
+        self.metrics.on_step(state["in_flight"])
+        launched = _InFlight(step=self.steps)
+        # what the step's span and its children take, by name, from the
+        # span primitive's own clock reads; beside it the thread's CPU
+        # time and the collector's: the record of a slow step
+        phases: tp.Dict[str, float] = {}
+        cpu, collected = time.thread_time(), gc_totals()
+        with span(SPAN_STEP, self.engine.tracer, category="serve",
+                  phases=phases, step=launched.step, late_rows=late, **state):
             self.steps += 1
-            launched = _InFlight()
-            with span(SPAN_ADMISSION, tracer, category="serve",
-                      queued=len(self._queue)):
-                self._shed_expired()
-                self._admit()
-            self._advance_prefill(launched)
-            with span(SPAN_GAUGES, tracer, category="serve"):
-                self.metrics.on_gauges(queue_depth=len(self._queue),
-                                       live=self.engine.live_count,
-                                       capacity=self.engine.slots)
-                pool = self.engine.pool_stats()
-                if pool is not None:
-                    self.metrics.on_pool(
-                        occupancy=pool["occupancy"],
-                        in_use=int(pool["in_use"]),
-                        capacity=int(pool["capacity"]),
-                        cached=int(pool["cached"]),
-                        bytes_per_token=pool["kv_bytes_per_token"])
-            if self._running:
-                # inside the ITL-measured region on purpose: an injected
-                # delay here lands in the per-token `gap` the SLO engine
-                # samples, and an injected raise still unwinds through
-                # step()'s finalize
-                chaos.fault_point("serve.step", queue_depth=len(self._queue),
-                                  live=len(self._running))
-                if self.draft is not None:
-                    return self._speculative_step()
-                self._launch_decode(launched)
-            # the step before this one ended on the device while the
-            # host did the above: read it, then leave this one in flight
-            self._collect()
-            if launched.firsts or launched.decode is not None:
-                self._in_flight = launched
-            return self._emitted - before
+            emitted = self._run_step(launched)
+        cpu, now = time.thread_time() - cpu, gc_totals()
+        self.metrics.on_step_end(launched.step, phases, cpu,
+                                 now[0] - collected[0], now[1] - collected[1],
+                                 **state)
+        return emitted
+
+    def _run_step(self, launched: _InFlight) -> int:
+        """The inside of `serve/step`: every phase a child span."""
+        before = self._emitted
+        tracer = self.engine.tracer
+        with span(SPAN_ADMISSION, tracer, category="serve",
+                  queued=len(self._queue)):
+            self._shed_expired()
+            self._admit()
+        self._advance_prefill(launched)
+        with span(SPAN_GAUGES, tracer, category="serve"):
+            self.metrics.on_gauges(queue_depth=len(self._queue),
+                                   live=self.engine.live_count,
+                                   capacity=self.engine.slots)
+            pool = self.engine.pool_stats()
+            if pool is not None:
+                self.metrics.on_pool(occupancy=pool["occupancy"],
+                                     in_use=int(pool["in_use"]),
+                                     capacity=int(pool["capacity"]),
+                                     cached=int(pool["cached"]))
+        if self._running:
+            # inside the ITL-measured region on purpose: an injected
+            # delay here lands in the per-token `gap` the SLO engine
+            # samples, and an injected raise still unwinds through
+            # step()'s finalize
+            chaos.fault_point("serve.step", queue_depth=len(self._queue),
+                              live=len(self._running))
+            if self.draft is not None:
+                return self._speculative_step(launched.step)
+            self._launch_decode(launched)
+        # the step before this one ended on the device while the host
+        # did the above: read it, then leave this one in flight
+        self._collect()
+        if launched.firsts or launched.decode is not None:
+            self._in_flight = launched
+        return self._emitted - before
 
     def _launch_decode(self, launched: _InFlight) -> None:
         """Dispatch one decode step over the running rows and note who
         owns them. A request whose budget this step completes gives its
         slot back NOW, values unread: the parking is enqueued behind the
         step, ahead of whatever the slot's next owner dispatches."""
-        launched.decode = self.engine.dispatch_decode()
+        launched.decode = self.engine.dispatch_decode(step=launched.step)
         launched.rows = list(self._running.items())
+        out = []
         for slot, request in launched.rows:
             request.in_flight += 1
             if request.launched_out:
-                del self._running[slot]
-                self.engine.retire(slot)
+                out.append(slot)
+        if out:
+            with span(SPAN_LAUNCH_OUT, self.engine.tracer, category="serve",
+                      rows=len(out)):
+                for slot in out:
+                    del self._running[slot]
+                    self.engine.retire(slot)
 
     def _collect(self) -> None:
         """Read the step in flight back, if any, and deliver its tokens
@@ -786,8 +820,7 @@ class ContinuousBatchingScheduler:
             return
         for slot, request, handle in landed.firsts:
             request.in_flight -= 1
-            self._first_token(slot, request,
-                              int(self.engine.collect(handle)[0]))
+            self._read_first(slot, request, handle)
         if landed.decode is None:
             return
         tokens = self.engine.collect(landed.decode)
@@ -809,13 +842,13 @@ class ContinuousBatchingScheduler:
         self._late_rows += late
         self.metrics.on_rows(len(landed.rows), late)
 
-    def _speculative_step(self) -> int:
+    def _speculative_step(self, step: int) -> int:
         """k drafted tokens per slot verified in ONE [S, k+1] call, read
         back at once; each live slot emits accepted+1 tokens (EOS /
         budget may truncate the span — the engine slot is retired then,
         so the overshoot never lands anywhere)."""
         drafts = self.draft.propose()
-        out, accepted = self.engine.decode_speculative(drafts)
+        out, accepted = self.engine.decode_speculative(drafts, step=step)
         gap = time.perf_counter() - self._step_start
         with span(SPAN_RETIRE, self.engine.tracer, category="serve"):
             return self._retire_verified(drafts, out, accepted, gap)

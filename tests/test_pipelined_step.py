@@ -331,3 +331,222 @@ def test_a_late_row_writes_inside_its_reservation_or_the_sentinel(
     assert not engine._table_host.any()  # every row back at the sentinel
     assert np.asarray(engine._positions).tolist() == [engine.max_seq_len] * 2
     assert not np.asarray(engine._active).any()
+
+
+# ----------------------------------------------------------------------
+# the record of a slow step (ServeMetrics.on_step_end)
+# ----------------------------------------------------------------------
+STATE = {"in_flight": 1, "queued": 0, "prefilling": 0, "running": 2}
+
+
+def _fed(metrics, walls, children=None):
+    """Feed `ServeMetrics.on_step_end` one synthetic step a wall time."""
+    for step, wall in enumerate(walls):
+        phases = {"serve/step": wall, **(children or {})}
+        metrics.on_step_end(step, phases, cpu_seconds=wall / 4,
+                            gc_seconds=0.0, gc_collections=0, **STATE)
+
+
+def test_the_slow_step_rule_is_five_medians_and_fifty_ms(caplog):
+    from flashy_tpu.serve import ServeMetrics
+    metrics = ServeMetrics()
+    fast = [0.004] * 130
+    with caplog.at_level("WARNING", logger="flashy_tpu.serve.metrics"):
+        # five medians but under 50 ms; over 50 ms but under five
+        # medians of a slow engine; no history to judge by: none is slow
+        _fed(metrics, fast + [0.045])
+        _fed(ServeMetrics(), [0.030] * 130 + [0.140])
+        _fed(ServeMetrics(), [0.004] * 5 + [2.0])
+        assert not caplog.records
+    summary = metrics.summary()
+    assert summary["slow_steps"] == 0 and summary["slowest_step_ms"] == 0.0
+    assert not metrics.slow_step_records
+    with caplog.at_level("WARNING", logger="flashy_tpu.serve.metrics"):
+        _fed(metrics, [0.0801], children={"serve/decode/readback": 0.07,
+                                          "serve/admission": 0.004})
+    (record,) = metrics.slow_step_records
+    assert record["wall_ms"] == pytest.approx(80.1)
+    assert record["median_ms"] == pytest.approx(4.0)
+    assert record["cpu_ms"] == pytest.approx(80.1 / 4)
+    # largest first; under its own name what no child covers
+    assert list(record["phases"]) == ["serve/decode/readback", "serve/step",
+                                      "serve/admission"]
+    assert record["phases"]["serve/step"] == pytest.approx(6.1)
+    assert {key: record[key] for key in STATE} == STATE
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert line.startswith("serve: step 0 took 80.1 ms (median 4.0, cpu 20.0)"
+                           ": serve/decode/readback 70.0, serve/step 6.1, "
+                           "serve/admission 4.0; gc 0 in 0.0 ms; in_flight 1")
+    assert metrics.summary()["slow_steps"] == 1
+    assert metrics.summary()["slowest_step_ms"] == pytest.approx(80.1)
+    # serve.json's two keys reach `flashy_tpu.info` and the stage line
+    from flashy_tpu.info import format_serve_status
+    from flashy_tpu.logging import serve_formatter
+    assert "slow_steps=1  slowest_step_ms=80.1" in format_serve_status(
+        metrics.summary())
+    assert "slow_steps" not in format_serve_status(ServeMetrics().summary())
+    shown = serve_formatter()(metrics.summary())
+    assert (shown["slow_steps"], shown["slowest_step_ms"]) == ("1", "80.1ms")
+
+
+def test_forty_slow_steps_keep_sixteen_records():
+    from flashy_tpu.serve import ServeMetrics
+    from flashy_tpu.serve.metrics import SLOW_STEP_RECORDS
+    metrics = ServeMetrics()
+    # three fast steps between slow ones: the median stays a fast step's
+    _fed(metrics, [0.002] * 130 + [0.002, 0.002, 0.002, 0.1] * 40)
+    assert metrics.summary()["slow_steps"] == 40
+    assert SLOW_STEP_RECORDS == 16 == len(metrics.slow_step_records)
+    assert [r["step"] for r in metrics.slow_step_records] == list(
+        range(130 + 4 * 24 + 3, 130 + 160, 4))  # the oldest went
+
+
+def _busy(scheduler, steps):
+    """`steps` scheduler steps with both slots decoding in nearly all."""
+    prompt = prompts()[0][0]
+    target = scheduler.steps + steps
+    while scheduler.steps < target:
+        while scheduler.queue_depth < 2:
+            scheduler.submit(prompt, 24)
+        scheduler.step()
+
+
+def _finish(scheduler):
+    scheduler.run()
+    assert scheduler.engine.live_count == 0
+
+
+@pytest.fixture
+def injector():
+    from flashy_tpu.resilience import chaos
+    yield chaos.install()
+    chaos.uninstall(verify=False)
+
+
+def _the_record(metrics, caplog, step):
+    """The one record of scheduler step `step`. Every record has its
+    WARNING line and its count (another step of this toy engine may be
+    stalled by a loaded test machine: it is then slow too, and says so)."""
+    records = list(metrics.slow_step_records)
+    lines = [r.getMessage() for r in caplog.records
+             if r.name == "flashy_tpu.serve.metrics"]
+    assert len(lines) == len(records) == metrics.summary()["slow_steps"]
+    assert all(r["wall_ms"] > 50 for r in records)
+    (record,) = [r for r in records if r["step"] == step]
+    (line,) = [line for line in lines
+               if line.startswith(f"serve: step {step} took ")]
+    return record, line
+
+
+def test_a_delay_in_no_child_is_the_steps_own_time(engines, injector, caplog,
+                                                   tmp_path):
+    import gc
+    import time
+    from flashy_tpu.observability import Tracer
+    from flashy_tpu.serve import ServeMetrics
+    journal = tmp_path / "telemetry.jsonl"
+    metrics = ServeMetrics(tracer=Tracer(jsonl_path=journal))
+    scheduler = ContinuousBatchingScheduler(engines("int8"), metrics=metrics)
+    with caplog.at_level("WARNING", logger="flashy_tpu.serve.metrics"):
+        _busy(scheduler, 130)
+        # the fault point of `serve/step` lies in none of its children
+        injector.delay_at("serve.step", injector.counts["serve.step"] + 1,
+                          0.08)
+        _busy(scheduler, 1)
+        delayed = scheduler.steps - 1
+        # a second one, with a collection forced inside it
+        injector.act_at("serve.step", injector.counts["serve.step"] + 3,
+                        lambda: (gc.collect(), time.sleep(0.08)))
+        _busy(scheduler, 3)
+        collected = scheduler.steps - 1
+        _finish(scheduler)
+    assert injector.hits("serve.step") == 2
+    record, line = _the_record(metrics, caplog, delayed)
+    assert record["wall_ms"] >= 80 and record["cpu_ms"] < record["wall_ms"] / 2
+    assert next(iter(record["phases"])) == "serve/step"
+    assert record["phases"]["serve/step"] >= 80
+    assert sum(record["phases"].values()) == pytest.approx(record["wall_ms"])
+    assert record["median_ms"] < 16 and record["running"] == 2
+    assert record["in_flight"] == 1 and record["gc_collections"] == 0
+    assert "serve/step 8" in line and "; gc 0 in 0.0 ms; in_flight 1" in line
+    record, _ = _the_record(metrics, caplog, collected)
+    assert record["gc_collections"] >= 1
+    # the sleep is not the collector's, and a collection is CPU time
+    assert 0 < record["gc_ms"] < record["wall_ms"] - 75
+    assert record["cpu_ms"] > record["gc_ms"] / 2
+    # the journal has each record whole, the WARNING's numbers included
+    lines = [json.loads(line) for line in journal.read_text().splitlines()]
+    slow = [line for line in lines if line["type"] == "serve_slow_step"]
+    assert [line["step"] for line in slow] == [
+        r["step"] for r in metrics.slow_step_records]
+    assert slow[-1]["phases"] == record["phases"]
+    assert metrics.summary()["slowest_step_ms"] >= 80
+
+
+def test_a_delay_in_the_readback_is_found_there(engines, caplog, monkeypatch):
+    import time
+    engine = engines("int8")
+    scheduler = ContinuousBatchingScheduler(engine)
+
+    class Late:
+        """A step's tokens that take 80 ms to come back."""
+
+        def __init__(self, read):
+            self.read = read
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(0.08)
+            return np.asarray(self.read)
+
+    dispatch = engine.dispatch_decode
+
+    def late_once(step=None):
+        monkeypatch.setattr(engine, "dispatch_decode", dispatch)
+        handle = dispatch(step=step)
+        return handle._replace(read=Late(handle.read))
+
+    with caplog.at_level("WARNING", logger="flashy_tpu.serve.metrics"):
+        _busy(scheduler, 130)
+        monkeypatch.setattr(engine, "dispatch_decode", late_once)
+        _busy(scheduler, 2)  # dispatched in one step, read in the next
+        _finish(scheduler)
+    record, line = _the_record(scheduler.metrics, caplog, 131)
+    assert next(iter(record["phases"])) == "serve/decode/readback"
+    assert record["phases"]["serve/decode/readback"] >= 80
+    assert record["phases"]["serve/step"] < 40
+    assert record["cpu_ms"] < record["wall_ms"] / 2
+    assert "): serve/decode/readback 8" in line
+
+
+def test_fast_steps_leave_no_record_and_cost_no_collection_hook(engines):
+    import gc
+    from flashy_tpu.serve.metrics import _on_gc
+    scheduler = ContinuousBatchingScheduler(engines("int8"))
+    handle = scheduler.submit(prompts()[2][0], 6)
+    _drive(scheduler, [handle])
+    summary = scheduler.metrics.summary()
+    # under sixteen steps nothing is judged, whatever a step took
+    assert scheduler.steps < 16 and summary["slow_steps"] == 0
+    assert not scheduler.metrics.slow_step_records
+    assert len(scheduler.metrics._step_walls) == scheduler.steps
+    # ONE hook for the process, however many schedulers have stepped
+    assert gc.callbacks.count(_on_gc) == 1
+
+
+def test_warmup_ends_with_a_full_collection(engines):
+    # what the slow-step record found in every run of the chat cell: the
+    # garbage of tracing and compiling, collected a few hundred steps
+    # into traffic; warm-up collects it, so the next full collection is
+    # a quarter of the long-lived heap away
+    import gc
+    seen = []
+
+    def hook(phase, info):
+        seen.append((phase, info["generation"]))
+
+    gc.callbacks.append(hook)
+    try:
+        engines("int8").warmup()
+    finally:
+        gc.callbacks.remove(hook)
+    assert ("stop", 2) in seen[-4:]  # its last act, bar a young one after

@@ -18,6 +18,7 @@ DECODE_SCOPES = ("embed", "norm", "qkv", "rotary", "kv_write", "attn",
 SPAN_NAMES = (
     scheduler_lib.SPAN_STEP, scheduler_lib.SPAN_ADMISSION,
     scheduler_lib.SPAN_GAUGES, scheduler_lib.SPAN_RETIRE,
+    scheduler_lib.SPAN_LAUNCH_OUT, scheduler_lib.SPAN_FIRST_TOKEN,
     engine_lib.SPAN_TABLE_UPLOAD, engine_lib.SPAN_PREFILL,
     engine_lib.SPAN_PREFILL_CHUNK,
     engine_lib.SPAN_PREFILL_CHUNK + engine_lib.SPAN_READBACK,
@@ -119,11 +120,13 @@ def test_scheduler_step_span_tree(recorder, paged):
     # decodes behind it; nothing is read
     assert _tree(recorder.entered, 1) == head + [
         (1, "serve/prefill_chunk"), (1, "serve/gauges")] + dispatch
-    # step 2: the last decode (the budget's, by count) is dispatched,
-    # THEN step 1 is read: its first token, its decode token
+    # step 2: the last decode (the budget's, by count) is dispatched
+    # and its slot given back, THEN step 1 is read: its first token,
+    # its decode token
     assert _tree(recorder.entered, 2) == head + [
         (1, "serve/gauges")] + dispatch + [
-        (1, "serve/prefill_chunk/readback")] + collect
+        (1, "serve/launch_out"), (1, "serve/prefill_chunk/readback"),
+        (1, "serve/first_token")] + collect
     # step 3: nothing left to launch; step 2 is read, the request ends
     assert _tree(recorder.entered, 3) == head + [(1, "serve/gauges")] + collect
     stats = [s for _, name, s in recorder.entered if name == "serve/step"]
@@ -134,6 +137,107 @@ def test_scheduler_step_span_tree(recorder, paged):
     assert [s["in_flight"] for s in stats] == [0, 0, 1, 1]
     assert [s["late_rows"] for s in stats] == [0, 0, 0, 0]
     assert not recorder.open
+    # the two spans `serve/step` used to hide: one slot launched out,
+    # the first token of the request in that slot
+    by_name = {name: s for _, name, s in recorder.entered}
+    assert by_name["serve/launch_out"] == {"rows": 1}
+    assert by_name["serve/first_token"] == {"slot": handle.slot}
+
+
+def _by_step(entered):
+    """[(number of the serve/step a span lies in, or None outside every
+    step; name; stats)] of the spans below serve/step."""
+    out, current = [], None
+    for depth, name, stats in entered:
+        if name == scheduler_lib.SPAN_STEP:
+            current = stats["step"]
+        elif depth == 0:
+            out.append((None, name, stats))
+        else:
+            out.append((current, name, stats))
+    return out
+
+
+DISPATCHES = ("serve/decode", "serve/decode/dispatch", "serve/prefill_chunk")
+
+
+def test_a_readback_carries_the_step_that_dispatched_it(recorder, paged):
+    """PR 34 split one call into two that lie a step apart: the stat
+    `step` ties them. A dispatch carries its own step's number, the
+    read-back in step k + 1 says `step == k`, and each read-back has
+    exactly one dispatch of that number."""
+    scheduler = ContinuousBatchingScheduler(paged)
+    scheduler.submit(np.arange(40, 51, dtype=np.int32), 5)
+    scheduler.submit(np.arange(51, 54, dtype=np.int32), 3)
+    _drain(scheduler)
+    spans = _by_step(recorder.entered)
+    for inside, name, stats in spans:
+        if name in DISPATCHES:
+            assert stats["step"] == inside, name
+        elif name.endswith("/readback"):
+            assert stats["step"] == inside - 1, name
+    decodes = [s["step"] for _, name, s in spans if name == "serve/decode"]
+    assert len(decodes) > 3 and decodes == sorted(set(decodes))
+    assert decodes == [s["step"] for _, name, s in spans
+                       if name == "serve/decode/dispatch"]
+    assert decodes == [s["step"] for _, name, s in spans
+                       if name == "serve/decode/readback"]
+    # a first token is read where its FINAL slice was dispatched
+    finals = [s["step"] for _, name, s in spans
+              if name == "serve/prefill_chunk" and s["final"]]
+    assert len(finals) == 2 and finals == [
+        s["step"] for _, name, s in spans
+        if name == "serve/prefill_chunk/readback"]
+
+
+def test_lockstep_readbacks_carry_their_own_step(recorder, paged):
+    # `step(); flush()`: the read-back follows its dispatch at once,
+    # outside every serve/step, under the number of the step just run
+    scheduler = ContinuousBatchingScheduler(paged)
+    handle = scheduler.submit(np.arange(54, 60, dtype=np.int32), 3)
+    while not handle.done:
+        before = len(recorder.entered)
+        scheduler.step()
+        scheduler.flush()
+        for inside, name, stats in _by_step(recorder.entered[before:]):
+            if name.endswith("/readback"):
+                assert inside is None and stats["step"] == scheduler.steps - 1
+    reads = [name for _, name, _ in recorder.entered
+             if name.endswith("/readback")]
+    assert reads.count("serve/decode/readback") == 2
+    assert reads.count("serve/prefill_chunk/readback") == 1
+    # by hand there is no scheduler step to name
+    slot = paged.acquire_slot()
+    prompt = np.arange(60, 63, dtype=np.int32)
+    before = len(recorder.entered)
+    _, first = paged.prefill_chunk(slot, prompt, paged.admit(slot, prompt, 2))
+    paged.decode()
+    paged.retire(slot)
+    assert first is not None
+    assert not any("step" in stats
+                   for _, _, stats in recorder.entered[before:])
+
+
+def test_the_verify_step_carries_its_own_step(recorder):
+    from flashy_tpu.serve import NGramDraft
+    model, params = _tiny_model()
+    engine = DecodeEngine(model, params, slots=2, cache_layout="paged",
+                          block_size=4, chunk=4, spec_k=2)
+    scheduler = ContinuousBatchingScheduler(engine,
+                                            draft=NGramDraft(2, k=2))
+    scheduler.submit(np.arange(1, 6, dtype=np.int32), 6)
+    _drain(scheduler)
+    verifies = [(inside, name, stats)
+                for inside, name, stats in _by_step(recorder.entered)
+                if name.startswith("serve/verify")]
+    assert {name for _, name, _ in verifies} == {
+        "serve/verify", "serve/verify/dispatch", "serve/verify/readback"}
+    assert all(stats["step"] == inside for inside, _, stats in verifies)
+    # with a draft the slice is read at once: its own step's number
+    reads = [(inside, stats["step"])
+             for inside, name, stats in _by_step(recorder.entered)
+             if name == "serve/prefill_chunk/readback"]
+    assert reads and all(inside == step for inside, step in reads)
 
 
 def test_decode_running_stat_is_the_tokens_emitted(recorder, paged):
