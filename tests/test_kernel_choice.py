@@ -207,14 +207,60 @@ def test_cell_walk_is_the_recorded_one(cell, step):
 
 
 # ----------------------------------------------------------------------
-# (c) the training cell's flash kernels run 256 x 256 blocks
+# (c) the training cell's flash kernels run the swept schedule
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_fused"],
-                         ids=["fwd", "bwd"])
+# olmo1b-train-2k: 8 sequences of 2,048 tokens, 16 heads of 128, in
+# bfloat16. `ops.attention.TILES` (PERF.md section 6, PR 36): the
+# forward is ONE masked 2048 x 2048 tile a head; the backward 1024 x
+# 1024, four steps a head of which the one above the diagonal is
+# skipped. Through PR 35 both were (128, 8, 8): 8,192 steps, 4,608 of
+# them at work. kernel -> (the rule's name, grid, steps at work)
+TRAIN_FLASH = {"flash_fwd": ("fwd", (8 * 16, 1, 1), 128),
+               "flash_bwd_fused": ("bwd", (8 * 16, 2, 2), 384)}
+
+
+@pytest.mark.parametrize("kernel", list(TRAIN_FLASH), ids=["fwd", "bwd"])
 def test_train_cell_flash_tiles(kernel):
-    # olmo1b-train-2k: 8 sequences of 2,048 tokens, 16 heads of 128, in
-    # bfloat16 — a grid of (batch x heads, 2048 / 256, 2048 / 256)
     x = jax.ShapeDtypeStruct((8, 2048, 16, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return attention.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr
+    calls = {eqn.params["name"]: eqn for eqn in pallas_calls(jaxpr)}
+    assert sorted(calls) == sorted(TRAIN_FLASH)
+    name, grid, working = TRAIN_FLASH[kernel]
+    assert tuple(calls[kernel].params["grid_mapping"].grid) == grid
+    schedule = attention.flash_schedule(name, 8 * 16, 2048, 2048, 128, 2,
+                                        True)
+    tiles_q, tiles_k = 2048 // schedule.block_q, 2048 // schedule.block_k
+    assert grid == ((8 * 16, tiles_q, tiles_k) if name == "fwd"
+                    else (8 * 16, tiles_k, tiles_q))
+    assert (schedule.steps, schedule.working) == (
+        grid[0] * grid[1] * grid[2], working)
+    assert schedule.vmem_bytes <= attention.VMEM_LIMIT
+    # the benchmark's reader finds the calls by their [B*H, T, D]
+    # operands: exactly three a forward call, more a backward call
+    folded = [v.aval.shape for v in calls[kernel].invars].count(
+        (8 * 16, 2048, 128))
+    assert folded == 3 if name == "fwd" else folded > 3
+    # a head's dQ is summed in VMEM: every output of the backward is
+    # [B*H, T, D], none carries a k-tile axis ([B*H, nk, T, D] partials)
+    assert [v.aval.shape for v in calls["flash_bwd_fused"].outvars] == (
+        [(8 * 16, 2048, 128)] * 3)
+    assert attention.fused_backward_fits(2048, 128, 2)
+
+
+def test_a_dq_too_long_for_vmem_takes_the_split_backward():
+    # one budget, `_vmem_estimate` against `VMEM_LIMIT`: at 65,536 rows a
+    # head's float32 dQ (32 MiB) and its output block pass it at the
+    # smallest tiles, so the backward is the two split kernels, at THEIR
+    # schedule (no resident dQ to make room for), not at tiles halved to
+    # nothing; at 32,768 rows the fused kernel still fits
+    assert attention.fused_backward_fits(32768, 128, 2)
+    assert not attention.fused_backward_fits(65536, 128, 2)
+    x = jax.ShapeDtypeStruct((1, 65536, 1, 128), jnp.bfloat16)
 
     def loss(q, k, v):
         return attention.flash_attention(
@@ -223,7 +269,12 @@ def test_train_cell_flash_tiles(kernel):
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr
     grids = {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
              for eqn in pallas_calls(jaxpr)}
-    assert grids[kernel] == (8 * 16, 8, 8), grids
+    assert grids == {"flash_fwd": (1, 32, 32), "flash_bwd_dq": (1, 64, 64),
+                     "flash_bwd_dkv": (1, 64, 64)}
+    schedule = attention.flash_schedule("bwd_split", 1, 65536, 65536, 128,
+                                        2, True)
+    assert (schedule.block_q, schedule.block_k) == (1024, 1024)
+    assert schedule.vmem_bytes <= attention.VMEM_LIMIT
 
 
 # ----------------------------------------------------------------------

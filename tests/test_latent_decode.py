@@ -3,9 +3,9 @@
 # the XLA table gather it replaces on a TPU
 # (ops/paged_attention.py:latent_paged_attention, the oracle), then
 # through the engine, then compiled — not run — for the v5e at the
-# benchmark cell's widths (the grouped pool's walk too: one file holds
-# the tests that load the TPU's compiler). Every tolerance states its
-# reason.
+# benchmark cell's widths (the grouped pool's walk and the training
+# cell's flash kernels too: one file holds the tests that load the
+# TPU's compiler). Every tolerance states its reason.
 """The latent pool's fused read against the gather read."""
 import numpy as np
 import pytest
@@ -295,3 +295,42 @@ def test_the_cells_grouped_reads_compile_for_the_v5e(one_chip, slots,
         entry, sds((slots, 1088), jnp.int32),
         sds((slots,), jnp.int32)).compile()
     assert "grouped_decode_fused" in compiled.as_text()
+
+
+@pytest.mark.parametrize("seq_len,dim,dtype,fused", [
+    (2048, 128, jnp.bfloat16, True), (2048, 128, jnp.bfloat16, False),
+    (4096, 128, jnp.bfloat16, True), (2048, 128, jnp.float32, True),
+    (2048, 256, jnp.bfloat16, True), (4096, 256, jnp.float32, True),
+    (2048, 256, jnp.float32, False), (2048, 512, jnp.bfloat16, True)],
+    ids=["cell", "split_backward", "several_tiles", "float32", "wide_heads",
+         "float32_wide_heads", "float32_wide_heads_split",
+         "estimate_at_the_limit"])
+def test_the_flash_kernels_compile_for_the_v5e(one_chip, seq_len, dim, dtype,
+                                               fused):
+    # the same for the training cell's attention, forward and backward
+    # at the schedule `ops.attention.flash_schedule` gives its shapes
+    # (8 x 16 heads of 128, bf16, causal: ONE 2048 x 2048 forward tile a
+    # head and the 16 MB float32 scores it keeps, a head's dQ in VMEM),
+    # for the split backward the ring path calls, at twice the length,
+    # where tiles lie under, on and past the diagonal and a skipped
+    # step's index map names the block before it, and in float32 and at
+    # wider heads, where the rule's estimate comes nearest `VMEM_LIMIT`
+    # (float32 at 128: 94% of it; bf16 at 512: all of it, which Mosaic
+    # takes, so the estimate is no lower than Mosaic's own count) or
+    # halves the tiles; here because one file holds the tests that load
+    # the TPU's compiler (tests/test_ops.py has the values)
+    from flashy_tpu.ops import attention
+    batch, heads = 8 * 2048 // seq_len, 16 * 128 // dim
+    x = jax.ShapeDtypeStruct((batch, seq_len, heads, dim), dtype,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return attention.flash_attention(
+            q, k, v, causal=True, interpret=False,
+            fused_backward=fused).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    backward = ["flash_bwd_fused"] if fused else ["flash_bwd_dq",
+                                                  "flash_bwd_dkv"]
+    assert all(name in text for name in ["flash_fwd"] + backward)

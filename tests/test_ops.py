@@ -14,20 +14,40 @@ def _rand_qkv(shape, seed=0):
                  for _ in range(3))
 
 
+# T of several tiles in both directions: tiles under, on and past the
+# causal diagonal (one a row of tiles crossed by it at square tiles, two
+# or four at oblong ones); a tile past it is skipped and its index map
+# names the block before it
+@pytest.mark.parametrize("t,blocks", [(128, (64, 64)), (256, (32, 32)),
+                                      (256, (64, 32)), (256, (32, 128))])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_dense(causal):
-    q, k, v = _rand_qkv((2, 128, 4, 32))
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+def test_flash_matches_dense(causal, t, blocks):
+    q, k, v = _rand_qkv((2, t, 4, 32))
+    out = flash_attention(q, k, v, causal=causal, block_q=blocks[0],
+                          block_k=blocks[1])
     ref = dot_product_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
 
 
-def test_flash_gradients_match():
+# forward | backward tiles: equal (a caller's blocks are both kernels'),
+# and differing as `flash_schedule` may give them (each kernel walks its
+# own tiling of the same T x T scores)
+@pytest.mark.parametrize("tiles", [((32, 32), (32, 32)), ((32, 16), (16, 64)),
+                                   ((64, 64), (16, 16))])
+def test_flash_gradients_match(tiles):
+    from flashy_tpu.ops import attention
     q, k, v = _rand_qkv((1, 64, 2, 16), seed=1)
+    forward, backward = (
+        attention.flash_schedule(kernel, 2, 64, 64, 16, 4, True,
+                                 block_q=blocks[0], block_k=blocks[1])
+        for kernel, blocks in zip(("fwd", "bwd"), tiles))
+    assert (forward.block_q, forward.block_k) == tiles[0]
+    assert (backward.block_q, backward.block_k) == tiles[1]
 
     def flash_loss(q, k, v):
-        return (flash_attention(q, k, v, causal=True, block_q=32, block_k=32) ** 2).sum()
+        return (attention._flash(q, k, v, True, forward, backward, True,
+                                 True) ** 2).sum()
 
     def dense_loss(q, k, v):
         return (dot_product_attention(q, k, v, causal=True) ** 2).sum()
@@ -100,8 +120,9 @@ def test_flash_backward_cross_length():
                                    rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("fused", [True, False])
 @pytest.mark.parametrize("block_q", [16, 32])
-def test_flash_empty_rows_zero(block_q):
+def test_flash_empty_rows_zero(block_q, fused):
     # t_k < t_q with causal: offset = t_k - t_q < 0, so queries
     # i < t_q - t_k see NO keys at all. Convention: they attend to
     # nothing — zero output, zero gradients. Regressions this guards:
@@ -119,11 +140,16 @@ def test_flash_empty_rows_zero(block_q):
     n_empty = q.shape[1] - k.shape[1]
 
     def flash_loss(q, k, v):
-        return (flash_attention(q, k, v, causal=True,
-                                block_q=block_q, block_k=16) ** 2).sum()
+        return (flash_attention(q, k, v, causal=True, block_q=block_q,
+                                block_k=16, fused_backward=fused) ** 2).sum()
 
     def dense_loss(q, k, v):
         return (dot_product_attention(q, k, v, causal=True) ** 2).sum()
+
+    # the guard against exp(0), a compare with NEG_INF / 2 = -5e+29, is
+    # traced into the kernels
+    assert "e+29" in str(jax.make_jaxpr(
+        jax.grad(flash_loss, argnums=(0, 1, 2)))(q, k, v))
 
     out = flash_attention(q, k, v, causal=True, block_q=block_q, block_k=16)
     np.testing.assert_array_equal(np.asarray(out[:, :n_empty]), 0.0)
@@ -270,6 +296,11 @@ class TestChunkedCrossEntropy:
 # split pair's accumulation order op for op, so np.array_equal — not
 # allclose — is the contract; any nonzero delta is a kernel bug.
 # ----------------------------------------------------------------------
+# 2048 / block_q, 2048 / block_k of `flash_bwd_fused` in olmo1b-train-2k,
+# at T = 256 here: a head's dQ is summed over the same count of k-tiles
+CELL_RATIOS = (256 // 4, 256 // 4)
+
+
 def _flash_grads(q, k, v, *, causal, block_q, block_k, fused):
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=causal, block_q=block_q,
@@ -283,6 +314,15 @@ def _flash_grads(q, k, v, *, causal, block_q, block_k, fused):
     ((2, 128, 2, 64), (2, 128, 2, 64), True, (64, 64), jnp.float32),
     ((1, 64, 2, 32), (1, 128, 2, 32), False, (32, 64), jnp.float32),
     ((1, 128, 2, 32), (1, 128, 2, 32), True, (64, 32), jnp.bfloat16),
+    # the training cell's tile-to-sequence ratios (CELL_RATIOS)
+    ((1, 256, 2, 32), (1, 256, 2, 32), True, CELL_RATIOS, jnp.bfloat16),
+    # more keys than queries (offset > 0): every row sees the first tiles
+    ((1, 64, 2, 32), (1, 128, 2, 32), True, (32, 32), jnp.float32),
+    ((1, 64, 1, 32), (1, 192, 1, 32), True, (16, 64), jnp.bfloat16),
+    # fewer (offset < 0): the first rows are empty, the guard zeroes them
+    ((1, 128, 2, 32), (1, 64, 2, 32), True, (32, 32), jnp.bfloat16),
+    ((1, 128, 1, 32), (1, 32, 1, 32), True, (64, 16), jnp.float32),
+    ((2, 128, 2, 32), (2, 128, 2, 32), False, (32, 32), jnp.bfloat16),
 ])
 def test_flash_fused_backward_bit_identical_to_split(shape_q, shape_k,
                                                      causal, blocks, dtype):
@@ -298,3 +338,33 @@ def test_flash_fused_backward_bit_identical_to_split(shape_q, shape_k,
     for a, b in zip(fused, split):
         assert a.dtype == b.dtype
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# ----------------------------------------------------------------------
+# at the tiles every training run before PR 36 compiled (256 x 256), the
+# kernels give the parent's values: the schedule changed, not the
+# mathematics (tests/data/record_flash_parent_outputs.py is the one
+# definition of the cases; run on a checkout of the parent it wrote the
+# .npz, run here it gives what to compare, bit for bit)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def flash_at_256():
+    import importlib.util
+    import os
+    data = os.path.join(os.path.dirname(__file__), "data")
+    spec = importlib.util.spec_from_file_location(
+        "record_flash_parent_outputs",
+        os.path.join(data, "record_flash_parent_outputs.py"))
+    recorder = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(recorder)
+    recorded = np.load(os.path.join(data, "flash_parent_outputs.npz"))
+    return recorded, recorder.outputs()
+
+
+@pytest.mark.parametrize("array", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", ["diagonal", "all_keys", "self",
+                                  "longer_keys"])
+def test_flash_at_256_tiles_is_the_parents(flash_at_256, case, array):
+    recorded, now = flash_at_256
+    np.testing.assert_array_equal(now[f"{case}/{array}"],
+                                  recorded[f"{case}/{array}"])
