@@ -54,11 +54,15 @@
 # the triangular decay sums it rebuilds every step (ROADMAP S14); it
 # stays the TPU path because it never materialises b and c a head. One
 # decode token a row against 129 resident states (`ssd_state_update`):
-# XLA's gather, update and scatter 5.84 ms a layer, the kernel 2.25 |
-# 2.13 | 2.14 ms at 16 | 32 | 64 heads a step (128 exceed VMEM), where
-# the bytes alone take 1.31: `UPDATE_HEADS` 32.
+# XLA's gather, update and scatter 5.84 ms a layer; the kernel 1.67
+# (`tools/ssd_update_sweep.py`: 2.11 with a lane broadcast of the decay
+# and of v and a lane sum for y per head), where copies alone take
+# 1.65-1.74 ms however they are issued and the bytes at the HBM's peak
+# 1.31: the copies set the pace. `UPDATE_HEADS` 32 (64 and 128 heads a
+# step: under 1% less).
 """SSD/linear-attention dual forms: chunked scan + recurrent step."""
 import functools
+import math
 import typing as tp
 
 import jax
@@ -463,30 +467,43 @@ def ssd_recurrent_scan(c: jax.Array, b: jax.Array, v: jax.Array,
 # one token a row against a table of resident states (the decode run)
 # ----------------------------------------------------------------------
 # Heads a grid step of the update kernel carries: 32 x [64, 128] f32 is
-# 1 MB in and 1 MB out a step, double-buffered 4 MB (PERF.md section 6,
-# PR 33, has the sweep).
+# 1 MB in and 1 MB out a step, double-buffered 4 MB (PERF.md section 6
+# has the sweeps).
 UPDATE_HEADS = 32
+# The lanes of a vector register: v and y travel as rows this wide.
+LANES = 128
 
 
 def _update_body(rows_ref, decay_ref, v_ref, b_ref, c_ref, state_ref,
                  y_ref, out_ref, *, heads: int, share: int):
     """One (row, head block) grid step: `heads` states [P, N] of the
-    row's table entry advanced in place. decay and v arrive with the
-    state's P on the sublanes ([P, heads]: a head's column broadcasts
-    along the lanes), b and c as the groups' rows [1, N]."""
+    row's table entry advanced in place. The decay arrives as the
+    block's scalars in SMEM; v and y as lane-dense rows [1, W] that hold
+    W / P heads' P values each; b and c as the groups' rows [1, N].
+
+    A transpose is the only cross-lane work: one turns a row of v into
+    its heads' columns, each value along the lanes, and one turns those
+    heads' `new * c` [W, N] into [N, W], whose sum over the sublanes is
+    their row of y. A head then costs its state's vector arithmetic,
+    which hides under the copies of its entry (PERF.md section 6)."""
     del rows_ref  # consumed by the index maps
-    decay, v = decay_ref[0, 0], v_ref[0, 0]                # [P, heads]
-    lane = jax.lax.broadcasted_iota(jnp.int32, decay.shape, 1)
-    y = jnp.zeros(decay.shape, jnp.float32)
-    for j in range(heads):
-        group = j // share  # within the block's groups (0: one group)
-        b_row, c_row = b_ref[0, group], c_ref[0, group]    # [1, N]
-        new = (decay[:, j:j + 1] * state_ref[0, j]
-               + v[:, j:j + 1] * b_row)                    # [P, N]
-        out_ref[0, j] = new
-        y = jnp.where(lane == j,
-                      jnp.sum(new * c_row, axis=1, keepdims=True), y)
-    y_ref[0, 0] = y
+    dim, dstate = state_ref.shape[2], state_ref.shape[3]
+    width = y_ref.shape[2]
+    per_row = width // dim
+    for r in range(heads // per_row):
+        v_cols = jnp.transpose(jnp.broadcast_to(
+            v_ref[0, r:r + 1, :], (dstate, width)))         # [W, N]
+        products = []
+        for part in range(per_row):
+            j = r * per_row + part
+            group = j // share  # within the block's groups (0: one group)
+            new = (decay_ref[0, 0, j] * state_ref[0, j]
+                   + v_cols[part * dim:(part + 1) * dim]
+                   * b_ref[0, group])                      # [P, N]
+            out_ref[0, j] = new
+            products.append(new * c_ref[0, group])
+        y_ref[0, r:r + 1, :] = jnp.sum(
+            jnp.transpose(jnp.concatenate(products)), axis=0, keepdims=True)
 
 
 def _update_call(state, rows, decay, v, b, c, *, heads_per_step: int,
@@ -499,14 +516,21 @@ def _update_call(state, rows, decay, v, b, c, *, heads_per_step: int,
         raise ValueError(f"{hb} heads a step must divide {heads} heads and "
                          f"be whole groups of {share} (or divide one)")
     blocks, per_block = heads // hb, max(1, hb // share)
-
-    def columns(x):  # [B, H, P] -> [B, H / hb, P, hb]
-        return jnp.swapaxes(x.reshape(batch, blocks, hb, dim), 2, 3)
+    # a lane-dense row of v or y: as many of the step's heads as fill
+    # the lanes
+    per_row = math.gcd(hb, max(1, LANES // dim))
+    width = per_row * dim
 
     def group_index(bi, hi, rows):
         return (bi, hi * hb // (share * per_block), 0, 0)
 
-    column = pl.BlockSpec((1, 1, dim, hb), lambda bi, hi, rows: (bi, hi, 0, 0))
+    lane_rows = pl.BlockSpec((1, hb // per_row, width),
+                             lambda bi, hi, rows: (bi, hi, 0))
+    # one [1, hb] row of scalars a block: a block's last two dimensions
+    # are whole tiles or the array's own
+    scalars = pl.BlockSpec((1, 1, hb),
+                           lambda bi, hi, rows: (bi * blocks + hi, 0, 0),
+                           memory_space=pltpu.SMEM)
     group = pl.BlockSpec((1, per_block, 1, dstate), group_index)
     entry = pl.BlockSpec((1, hb, dim, dstate),
                          lambda bi, hi, rows: (rows[bi], hi, 0, 0))
@@ -514,18 +538,20 @@ def _update_call(state, rows, decay, v, b, c, *, heads_per_step: int,
         functools.partial(_update_body, heads=hb, share=share),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(batch, blocks),
-            in_specs=[column, column, group, group, entry],
-            out_specs=[column, entry]),
+            in_specs=[scalars, lane_rows, group, group, entry],
+            out_specs=[lane_rows, entry]),
         out_shape=[
-            jax.ShapeDtypeStruct((batch, blocks, dim, hb), jnp.float32),
+            jax.ShapeDtypeStruct((batch, heads // per_row, width),
+                                 jnp.float32),
             jax.ShapeDtypeStruct(state.shape, jnp.float32)],
         # operands count the prefetched rows: the table is operand 5
         input_output_aliases={5: 1},
         interpret=interpret,
         name="ssd_state_update",
-    )(rows, columns(jnp.broadcast_to(decay[:, :, None], v.shape)),
-      columns(v), b[:, :, None, :], c[:, :, None, :], state)
-    return jnp.swapaxes(y, 2, 3).reshape(batch, heads, dim), state
+    )(rows, decay.reshape(batch * blocks, 1, hb),
+      v.reshape(batch, heads // per_row, width), b[:, :, None, :],
+      c[:, :, None, :], state)
+    return y.reshape(batch, heads, dim), state
 
 
 def ssd_state_update(state: jax.Array, rows: jax.Array, decay: jax.Array,

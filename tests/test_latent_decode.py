@@ -3,9 +3,10 @@
 # the XLA table gather it replaces on a TPU
 # (ops/paged_attention.py:latent_paged_attention, the oracle), then
 # through the engine, then compiled — not run — for the v5e at the
-# benchmark cell's widths (the grouped pool's walk and the training
-# cell's flash kernels too: one file holds the tests that load the
-# TPU's compiler). Every tolerance states its reason.
+# benchmark cell's widths (the grouped pool's walk, the training
+# cell's flash kernels and the recurrent cell's state update too: one
+# file holds the tests that load the TPU's compiler). Every tolerance
+# states its reason.
 """The latent pool's fused read against the gather read."""
 import numpy as np
 import pytest
@@ -334,3 +335,27 @@ def test_the_flash_kernels_compile_for_the_v5e(one_chip, seq_len, dim, dtype,
     backward = ["flash_bwd_fused"] if fused else ["flash_bwd_dq",
                                                   "flash_bwd_dkv"]
     assert all(name in text for name in ["flash_fwd"] + backward)
+
+
+@pytest.mark.parametrize("heads_per_step", [16, 32, 64])
+def test_the_cells_state_update_compiles_for_the_v5e(one_chip,
+                                                     heads_per_step):
+    # the recurrent cell's decode-run update (ops/ssd_scan.py:
+    # _update_call) at its widths: 128 rows against 129 entries of 128
+    # heads x [64, 128] float32, b and c by 8 groups, the table aliased in
+    # place; the decay a block's scalars in SMEM, v and y lane-dense rows
+    # of two heads, at the rule's 32 heads a step and either side of it
+    from flashy_tpu.ops import ssd_scan
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def update(state, rows, decay, v, b, c):
+        return ssd_scan._update_call(state, rows, decay, v, b, c,
+                                     heads_per_step=heads_per_step,
+                                     interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=0).lower(
+        sds((129, 128, 64, 128)), sds((128,), jnp.int32), sds((128, 128)),
+        sds((128, 128, 64)), sds((128, 8, 128)), sds((128, 8, 128))).compile()
+    assert "ssd_state_update" in compiled.as_text()

@@ -165,16 +165,22 @@ def test_chunked_scan_is_the_recurrence_is_the_token_loop(kernel, chunk):
     np.testing.assert_allclose(s, h, atol=2e-5)
 
 
-@pytest.mark.parametrize("heads_per_step", [2, 4, 8])
-def test_the_state_update_kernel_advances_entries_in_place(heads_per_step):
+@pytest.mark.parametrize("heads_per_step,heads,groups,dim,state", [
+    pytest.param(2, 8, 2, 4, 16, id="2"), pytest.param(4, 8, 2, 4, 16, id="4"),
+    pytest.param(8, 8, 2, 4, 16, id="8"),
+    # the cell's own layout: 128 heads in 8 groups of 16, [64, 128] a
+    # head, 32 heads a step spanning two groups
+    pytest.param(32, 128, 8, 64, 128, id="cell")])
+def test_the_state_update_kernel_advances_entries_in_place(
+        heads_per_step, heads, groups, dim, state):
     rng = np.random.default_rng(1)
-    heads, groups, dim, state = 8, 2, 4, 16
     draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
     table = draw(6, heads, dim, state)
     rows = jnp.asarray([4, 0, 2, 0], jnp.int32)  # two parked at entry 0
     decay = jax.nn.sigmoid(draw(4, heads))
+    # c scaled so that y spreads as in the toy cases at any width
     v, b, c = draw(4, heads, dim), draw(4, groups, state), draw(
-        4, groups, state)
+        4, groups, state) * np.sqrt(16 / state)
     want_y, want = ssd_scan.ssd_state_update(table, rows, decay, v, b, c,
                                              kernel="gather")
     y, out = ssd_scan.ssd_state_update(
